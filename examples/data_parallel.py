@@ -32,6 +32,10 @@ from pytorchdistributed_tpu.runtime.mesh import batch_leaf_sharding, create_mesh
 
 
 def main():
+    from pytorchdistributed_tpu.runtime.xla_cache import use_persistent_cache
+
+    use_persistent_cache()
+
     mesh = create_mesh()  # all devices on the "data" axis
     model = MLP(features=(10, 20, 10, 5))  # the notebook's 4-layer demo net
     rng = np.random.default_rng(0)

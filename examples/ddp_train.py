@@ -33,6 +33,10 @@ def main():
     parser.add_argument("--strategy", choices=["dp", "fsdp"], default="dp")
     args = parser.parse_args()
 
+    from pytorchdistributed_tpu.runtime.xla_cache import use_persistent_cache
+
+    use_persistent_cache()
+
     ptd.init_process_group()
     try:
         dataset = SyntheticRegressionDataset(size=2048, in_dim=20, out_dim=1)

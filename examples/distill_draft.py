@@ -54,6 +54,10 @@ def main():
     parser.add_argument("--requests", type=int, default=6)
     args = parser.parse_args()
 
+    from pytorchdistributed_tpu.runtime.xla_cache import use_persistent_cache
+
+    use_persistent_cache()
+
     cfg = gpt2_config("test", num_layers=4, max_seq_len=128)
     model = GPT2(cfg)
     spec_k = args.spec_k

@@ -50,9 +50,9 @@ def main():
                              "(0 = no fault injection)")
     args = parser.parse_args()
 
-    if os.environ.get("JAX_PLATFORMS") == "cpu":
-        import jax
-        jax.config.update("jax_platforms", "cpu")
+    from pytorchdistributed_tpu.runtime.xla_cache import use_persistent_cache
+
+    use_persistent_cache()
 
     import optax
 

@@ -36,6 +36,10 @@ def main():
     parser.add_argument("--top_k", type=int, default=None)
     args = parser.parse_args()
 
+    from pytorchdistributed_tpu.runtime.xla_cache import use_persistent_cache
+
+    use_persistent_cache()
+
     ptd.init_process_group()
     cfg = llama_config("test", max_seq_len=64)
     model = Llama(cfg)

@@ -45,6 +45,10 @@ def main() -> None:
     parser.add_argument("--steps", type=int, default=10)
     args = parser.parse_args()
 
+    from pytorchdistributed_tpu.runtime.xla_cache import use_persistent_cache
+
+    use_persistent_cache()
+
     import jax.numpy as jnp
     import optax
 

@@ -64,6 +64,10 @@ def main():
                         help="micro-batch sweep -> split_size_tradeoff.png")
     args = parser.parse_args()
 
+    from pytorchdistributed_tpu.runtime.xla_cache import use_persistent_cache
+
+    use_persistent_cache()
+
     rng = np.random.default_rng(0)
     batch = {
         "tokens": rng.integers(0, 512, (32, 128)).astype(np.int32),

@@ -120,6 +120,11 @@ def main():
                              "its streams to a survivor with the SAME "
                              "tokens")
     args = parser.parse_args()
+
+    from pytorchdistributed_tpu.runtime.xla_cache import use_persistent_cache
+
+    use_persistent_cache()
+
     if args.sessions:
         if args.autoscale:
             parser.error("--sessions and --autoscale are separate "

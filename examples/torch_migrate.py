@@ -38,6 +38,10 @@ def main() -> None:
     parser.add_argument("--steps", type=int, default=30)
     args = parser.parse_args()
 
+    from pytorchdistributed_tpu.runtime.xla_cache import use_persistent_cache
+
+    use_persistent_cache()
+
     import torch
     import transformers
 

@@ -23,6 +23,10 @@ from pytorchdistributed_tpu.config import (  # noqa: E402
 
 
 def main():
+    from pytorchdistributed_tpu.runtime.xla_cache import use_persistent_cache
+
+    use_persistent_cache()
+
     cfg = parse_cli()
     select_backend(cfg.backend)
 

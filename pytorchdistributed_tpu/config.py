@@ -159,15 +159,21 @@ def select_backend(backend: str) -> None:
     if backend == "auto":
         return
     if backend == "tpu":
-        os.environ.pop("JAX_PLATFORMS", None)
+        # a requirement, not a preference: pin the platform so autodetect
+        # cannot land on the CPU, then make the chip answer here
+        import jax
+        from jax._src import xla_bridge
+
+        if not xla_bridge.backends_are_initialized():
+            os.environ["JAX_PLATFORMS"] = "tpu"
+            jax.config.update("jax_platforms", "tpu")
         try:
-            import jax
-            # jax may already be imported with a platform baked into its
-            # config (the package __init__ re-asserts env) — reset to
-            # autodetect, which picks the TPU plugin when present
-            jax.config.update("jax_platforms", None)
-        except ImportError:
-            pass
+            found = jax.default_backend()
+        except RuntimeError as e:
+            raise RuntimeError(f"--backend tpu: no TPU backend ({e})") from e
+        if found != "tpu":
+            raise RuntimeError(
+                f"--backend tpu: JAX is already running on {found!r}")
         return
     if backend.startswith("cpu-sim"):
         n = int(backend[len("cpu-sim"):] or "8")

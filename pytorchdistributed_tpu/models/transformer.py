@@ -447,8 +447,10 @@ def _attention_fn(kind: str) -> Callable:
     if kind == "dense":
         return dense_attention
     if kind == "pallas":
-        from pytorchdistributed_tpu.ops.pallas_attention import flash_attention
-        return flash_attention
+        from pytorchdistributed_tpu.ops.pallas_attention import (
+            flash_attention_sharded,
+        )
+        return flash_attention_sharded
     if kind == "ring":
         from pytorchdistributed_tpu.ops.ring_attention import (
             ring_attention_sharded,

@@ -46,12 +46,10 @@ def _out_sds(shape, dtype, like):
     check_vma=True shard_map (ring_attention_sharded / ulysses_attention
     compiled on hardware) every kernel output must declare its
     varying-manual-axes set, and the outputs vary exactly like the
-    operands they are computed from. Outside a checked trace the aval
-    carries an empty/absent vma and this is a plain ShapeDtypeStruct."""
-    vma = getattr(jax.typeof(like), "vma", None)
-    if vma:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    return jax.ShapeDtypeStruct(shape, dtype)
+    operands they are computed from — the empty set included (operands
+    replicated over the whole mesh), which the checker tells apart from
+    an undeclared ``None``. Outside a checked trace the set is empty."""
+    return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, *refs,
@@ -468,6 +466,52 @@ def flash_attention(q, k, v, *, causal: bool = False,
     return out.reshape(b, h, s, d).transpose(0, 2, 1, 3)
 
 
+def flash_attention_sharded(q, k, v, *, causal: bool = False,
+                            scale: float | None = None, block_q: int = 1024,
+                            block_k: int = 1024,
+                            interpret: bool | None = None):
+    """`flash_attention` for a multi-device jit: XLA cannot partition a
+    Mosaic kernel itself ("Mosaic kernels cannot be automatically
+    partitioned"), so under an ambient mesh of more than one device the
+    call runs inside `jax.shard_map`, each device on its own batch rows
+    and heads. The specs come from the logical rules in force — the ones
+    the activations' own `with_logical_constraint` reads (parallel/tp.py):
+    batch over (data, fsdp), heads over tensor under the tp strategies,
+    replicated otherwise. The sequence stays whole per device; a
+    seq-sharded mesh belongs to ring/ulysses attention. No collective is
+    added — attention is independent per (batch row, head). Without a
+    mesh, or on one device, this is the plain call. So it is inside
+    another shard_map's manual region (a pipeline stage body): there the
+    kernel stays bare, and a TPU compile still refuses it when an axis
+    left automatic is larger than 1 (PERF.md, open questions)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1 or mesh.manual_axes:
+        return flash_attention(q, k, v, causal=causal, scale=scale,
+                               block_q=block_q, block_k=block_k,
+                               interpret=interpret)
+    import flax.linen as nn
+    from jax.sharding import PartitionSpec as P
+
+    from pytorchdistributed_tpu.parallel.tp import Logical
+
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    spec = P(*nn.logical_to_mesh_axes(
+        (Logical.BATCH, None, Logical.HEADS, None)))
+    fn = jax.shard_map(
+        functools.partial(flash_attention, causal=causal, scale=scale,
+                          block_q=block_q, block_k=block_k,
+                          interpret=interpret),
+        mesh=mesh,
+        in_specs=(spec, spec, spec),
+        out_specs=spec,
+        # same rule as ring/ulysses: checked when the kernels compile,
+        # off under interpret mode, whose internals trip the checker
+        check_vma=not interpret,
+    )
+    return fn(q, k, v)
+
+
 # ---------------------------------------------------------------------------
 # Paged decode attention (ISSUE 7): the Pallas twin of
 # ops/attention.paged_attention. One decode tick's q ([slots, heads, d])
@@ -480,20 +524,28 @@ def flash_attention(q, k, v, *, causal: bool = False,
 
 
 def _paged_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, *rest,
-                  block_size: int, num_blocks: int, kv_heads: int,
-                  scale: float, quantized: bool, sink: int, window: int):
+                  block_size: int, num_blocks: int, scale: float,
+                  quantized: bool, sink: int, window: int):
     """Online-softmax over one slot's table blocks; grid
-    (slots·kv_heads, blocks_per_slot), rows = the kv head's q group.
-    ``quantized`` adds two scale refs (int8 pool, fp32 per-row scales,
-    dequantized in VMEM right before the dots); ``window`` > 0 applies
-    the sink+sliding-window mask and skips fully-dead middle blocks —
-    the blocks the serving engine retires to the allocator."""
+    (slots, blocks_per_slot). One program sees every kv head of its pool
+    block: refs are q/o ``[group, kv_heads, d]`` and k/v ``[block_size,
+    kv_heads, d]`` — the pool's own minor dims, which is the only KV
+    block shape Mosaic's (8, 128) tiling rule accepts for this layout
+    (a block of 1 of ``kv_heads`` rows is refused at lowering). The
+    contraction is a VPU multiply + lane reduce kept rank-3 end to end
+    (``keepdims``), so kv_heads never leaves the sublane dim and no
+    in-kernel transpose is needed; a decode tick is bandwidth-bound, the
+    MXU would see M = group rows. ``quantized`` adds two scale refs
+    (int8 pool, fp32 ``[block_size, kv_heads]`` per-row scales,
+    dequantized in VMEM right before the products); ``window`` > 0
+    applies the sink+sliding-window mask and skips fully-dead middle
+    blocks — the blocks the serving engine retires to the allocator."""
     if quantized:
         ks_ref, vs_ref, o_ref, acc_s, m_s, l_s = rest
     else:
         ks_ref = vs_ref = None
         o_ref, acc_s, m_s, l_s = rest
-    b, ji = pl.program_id(0), pl.program_id(1)
+    slot, ji = pl.program_id(0), pl.program_id(1)
 
     @pl.when(ji == 0)
     def _init():
@@ -501,7 +553,7 @@ def _paged_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, *rest,
         m_s[...] = jnp.full_like(m_s, _NEG_INF)
         l_s[...] = jnp.zeros_like(l_s)
 
-    length = lengths_ref[b // kv_heads]
+    length = lengths_ref[slot]
     # skip blocks wholly past the slot's live window (the current token
     # sits at position `length`, so positions <= length are attendable);
     # dead slots (length 0) still run block 0 — masked rows are exact
@@ -511,47 +563,44 @@ def _paged_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, *rest,
         # sliding window: a middle block whose last position already fell
         # out of every live query's window (and past the sinks) is fully
         # masked — and its table entry points at trash once the engine
-        # retires it — so skip its DMA outright
+        # retires it — so skip it outright
         dead = ((ji * block_size >= sink)
                 & ((ji + 1) * block_size <= length - window + 1))
         run = run & ~dead
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0]                                       # [group, d]
-        k = k_ref[0, :, 0]                                 # [bs, d]
-        if quantized:
-            # canonical dequant (ops/quant.kv_dequantize spelling):
-            # int8 → fp32 × per-row scale → compute dtype
-            k = (k.astype(jnp.float32)
-                 * ks_ref[0, :, 0][:, None]).astype(q.dtype)
-        logits = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale    # [group, bs]
+        def load(ref, s_ref):
+            x = ref[...].astype(jnp.float32)               # [bs, hk, d]
+            if quantized:
+                # canonical dequant (ops/quant.kv_dequantize spelling):
+                # int8 → fp32 × per-row scale → compute dtype
+                x = (x * s_ref[...][:, :, None]).astype(
+                    q_ref.dtype).astype(jnp.float32)
+            return x
+
+        k, v = load(k_ref, ks_ref), load(v_ref, vs_ref)
         pos = ji * block_size + lax.broadcasted_iota(
-            jnp.int32, logits.shape, 1)
+            jnp.int32, (block_size, k.shape[1], 1), 0)
         valid = pos <= length
         if window:
             valid &= (pos < sink) | (pos > length - window)
-        logits = jnp.where(valid, logits, _NEG_INF)
-        m_prev = m_s[...]
-        m_new = jnp.maximum(m_prev, jnp.max(logits, -1, keepdims=True))
-        corr = jnp.exp(m_prev - m_new)
-        p = jnp.where(valid, jnp.exp(logits - m_new), 0.0)
-        l_s[...] = l_s[...] * corr + jnp.sum(p, -1, keepdims=True)
-        m_s[...] = m_new
-        v = v_ref[0, :, 0]                                 # [bs, d]
-        if quantized:
-            v = (v.astype(jnp.float32)
-                 * vs_ref[0, :, 0][:, None]).astype(q_ref.dtype)
-        acc_s[...] = acc_s[...] * corr + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        for g in range(q_ref.shape[0]):  # static: the kv head's q group
+            q = q_ref[g].astype(jnp.float32)               # [hk, d]
+            logits = jnp.sum(k * q[None], -1, keepdims=True) * scale
+            logits = jnp.where(valid, logits, _NEG_INF)    # [bs, hk, 1]
+            m_prev = m_s[g]                                # [hk, 1]
+            m_new = jnp.maximum(m_prev, jnp.max(logits, axis=0))
+            corr = jnp.exp(m_prev - m_new)
+            p = jnp.where(valid, jnp.exp(logits - m_new[None]), 0.0)
+            l_s[g] = l_s[g] * corr + jnp.sum(p, axis=0)
+            m_s[g] = m_new
+            acc_s[g] = acc_s[g] * corr + jnp.sum(p * v, axis=0)
 
     @pl.when(ji == num_blocks - 1)
     def _finalize():
-        o_ref[0] = (acc_s[...]
-                    / jnp.maximum(l_s[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[...] = (acc_s[...]
+                      / jnp.maximum(l_s[...], 1e-30)).astype(o_ref.dtype)
 
 
 def paged_flash_attention(q, k_pool, v_pool, block_tables, lengths, *,
@@ -560,8 +609,8 @@ def paged_flash_attention(q, k_pool, v_pool, block_tables, lengths, *,
                           scale: float | None = None,
                           interpret: bool | None = None):
     """One decode tick of paged attention, pool-native — the serving
-    engine's default decode hot path (ISSUE 13; gather fallback via
-    ``ServingEngine(paged_attn=...)`` / ``PTD_PAGED_ATTN``).
+    engine's default decode hot path on TPU (gather elsewhere; see
+    ``ServingEngine(paged_attn=...)``).
 
     Args:
       q: ``[slots, heads, head_dim]`` — each slot's single current-token
@@ -578,19 +627,16 @@ def paged_flash_attention(q, k_pool, v_pool, block_tables, lengths, *,
       sink_tokens / window_tokens: static sink+sliding-window mask
         (window_tokens 0 = full attention): position j is attendable iff
         ``j < sink_tokens or j > length - window_tokens``; fully-dead
-        middle blocks are skipped (no DMA) — they are the blocks the
-        engine retires back to the allocator mid-stream.
+        middle blocks are skipped — they are the blocks the engine
+        retires back to the allocator mid-stream.
 
     Returns ``[slots, heads, head_dim]``. Matches
     ops.attention.paged_attention to fp32 online-softmax tolerance (the
     reassociated flash recurrence is not bitwise — the bitwise-parity
     contract vs generate() holds on the reference gather path; this
     kernel never materializes the [slots, blocks*block_size, ...]
-    gathered copy, the HBM-traffic-optimal hot path). Grouped-query
-    native: each (slot, kv_head) program streams its group's shared KV
-    block once. On TPU the group width (heads/kv_heads) rides the
-    sublane dim — pad q to a multiple of 8 rows for compiled-mode
-    tiling; interpret mode (the CPU sim) has no such constraint."""
+    gathered copy). Grouped-query native: each (slot, block) program
+    streams the shared KV block once for the whole q group."""
     slots, h, d = q.shape
     nb, bs, hk, _ = k_pool.shape
     if h % hk:
@@ -618,44 +664,43 @@ def paged_flash_attention(q, k_pool, v_pool, block_tables, lengths, *,
         interpret = jax.default_backend() != "tpu"
     from jax.experimental.pallas import tpu as pltpu
 
-    qf = q.reshape(slots * hk, group, d)  # kv head g owns q rows g·group+
-    kv_spec = pl.BlockSpec((1, bs, 1, d),
-                           lambda b, j, tbl, ln: (tbl[b // hk, j], 0,
-                                                  b % hk, 0))
-    in_specs = [
-        pl.BlockSpec((1, group, d), lambda b, j, tbl, ln: (b, 0, 0)),
-        kv_spec,
-        kv_spec,
-    ]
+    # kv head g owns q rows g·group+; group-major so q_ref[g] is [hk, d]
+    qf = q.reshape(slots, hk, group, d).swapaxes(1, 2)
+    # every block's two minor dims equal the array's own: the one shape
+    # the TPU lowering takes for a pool whose kv_heads is not a
+    # multiple of 8 (leading dims are squeezed, not blocked at 1)
+    q_spec = pl.BlockSpec((None, group, hk, d),
+                          lambda s, j, tbl, ln: (s, 0, 0, 0))
+    kv_spec = pl.BlockSpec((None, bs, hk, d),
+                           lambda s, j, tbl, ln: (tbl[s, j], 0, 0, 0))
+    in_specs = [q_spec, kv_spec, kv_spec]
     operands = [qf, k_pool, v_pool]
     if quantized:
         scale_spec = pl.BlockSpec(
-            (1, bs, 1), lambda b, j, tbl, ln: (tbl[b // hk, j], 0, b % hk))
+            (None, bs, hk), lambda s, j, tbl, ln: (tbl[s, j], 0, 0))
         in_specs += [scale_spec, scale_spec]
         operands += [k_scale.astype(jnp.float32),
                      v_scale.astype(jnp.float32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(slots * hk, mb),
+        grid=(slots, mb),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, group, d),
-                               lambda b, j, tbl, ln: (b, 0, 0)),
+        out_specs=q_spec,
         scratch_shapes=[
-            _vmem_scratch((group, d)),
-            _vmem_scratch((group, 1)),
-            _vmem_scratch((group, 1)),
+            _vmem_scratch((group, hk, d)),
+            _vmem_scratch((group, hk, 1)),
+            _vmem_scratch((group, hk, 1)),
         ],
     )
     kernel = functools.partial(
-        _paged_kernel, block_size=bs, num_blocks=mb, kv_heads=hk,
-        scale=scale, quantized=quantized, sink=int(sink_tokens),
+        _paged_kernel, block_size=bs, num_blocks=mb, scale=scale,
+        quantized=quantized, sink=int(sink_tokens),
         window=int(window_tokens))
-    out_dtype = q.dtype
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((slots * hk, group, d), out_dtype),
+        out_shape=jax.ShapeDtypeStruct(qf.shape, q.dtype),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
       *operands)
-    return out.reshape(slots, h, d)
+    return out.swapaxes(1, 2).reshape(slots, h, d)

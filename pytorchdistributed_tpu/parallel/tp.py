@@ -22,6 +22,8 @@ SURVEY.md §7 (strategies are PartitionSpec choices, not model rewrites).
 
 from __future__ import annotations
 
+import math
+
 import flax.linen as nn
 import jax
 from jax.sharding import Mesh
@@ -123,8 +125,26 @@ def has_logical_annotations(abstract_params) -> bool:
 
 
 def logical_shardings(abstract_params, mesh: Mesh, strategy: str):
-    """NamedShardings for a boxed (logically-annotated) param tree."""
+    """NamedShardings for a boxed (logically-annotated) param tree.
+
+    A dim its assigned mesh axes do not divide is left replicated: jit
+    refuses uneven argument shardings, and GPT-2's published vocabulary
+    (50,257, odd) meets that under every ``vocab → tensor`` rule. The
+    embedding/LM-head then replicates over that axis — memory and head
+    FLOPs a padded vocabulary would save, which is a model change."""
     specs = nn.get_partition_spec(abstract_params)
-    return nn.logical_to_mesh_sharding(specs, mesh, logical_rules(strategy))
+    shardings = nn.logical_to_mesh_sharding(
+        specs, mesh, logical_rules(strategy))
+
+    def extent(axes) -> int:  # devices behind one PartitionSpec entry
+        axes = (axes,) if isinstance(axes, str) else axes or ()
+        return math.prod(mesh.shape[a] for a in axes)
+
+    def even(leaf, sharding):
+        return jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec(
+            *(axes if dim % extent(axes) == 0 else None
+              for dim, axes in zip(leaf.shape, sharding.spec))))
+
+    return jax.tree.map(even, nn.meta.unbox(abstract_params), shardings)
 
 

@@ -106,27 +106,21 @@ def _spawn_group(argv, nproc: int, port: int,
                  telemetry_dir: str | None = None,
                  extra_env: dict[str, str] | None = None,
                  ) -> list[subprocess.Popen]:
+    from pytorchdistributed_tpu.runtime.launch import worker_envs
+
+    # one process for each chip: on a TPU host worker r is shown chip r
+    # only (RuntimeError, before anything is spawned, when the chips
+    # cannot back the group one-to-one)
     procs = []
-    for rank in range(nproc):
+    for worker in worker_envs(nproc, port, devices_per_proc):
         env = dict(os.environ)
         if extra_env:
             env.update(extra_env)
-        env.update({
-            "RANK": str(rank),
-            "LOCAL_RANK": str(rank),
-            "WORLD_SIZE": str(nproc),
-            "MASTER_ADDR": "localhost",
-            "MASTER_PORT": str(port),
-        })
+        env.update(worker)
         if heartbeat_dir is not None:
             env[HEARTBEAT_DIR_ENV] = heartbeat_dir
         if telemetry_dir is not None:
             env[TELEMETRY_DIR_ENV] = telemetry_dir
-        if devices_per_proc is not None:
-            from pytorchdistributed_tpu.runtime.launch import sim_device_flags
-            env["JAX_PLATFORMS"] = "cpu"
-            env["XLA_FLAGS"] = sim_device_flags(
-                env.get("XLA_FLAGS", ""), devices_per_proc)
         procs.append(subprocess.Popen([sys.executable] + argv, env=env))
     return procs
 
@@ -281,9 +275,13 @@ def _main(argv, owned_dirs: list[str]) -> int:
         hb_dir = (tempfile.mkdtemp(prefix="ptd_heartbeat_")
                   if args.heartbeat_timeout > 0 else None)
         spawned_at = time.time()
-        procs = _spawn_group(worker_argv, nproc, port,
-                             args.devices_per_proc, hb_dir,
-                             args.telemetry_dir, faults_env)
+        try:
+            procs = _spawn_group(worker_argv, nproc, port,
+                                 args.devices_per_proc, hb_dir,
+                                 args.telemetry_dir, faults_env)
+        except RuntimeError as e:  # chips cannot back the group: refuse
+            print(f"[run] {e}", file=sys.stderr)
+            return 2
         failed, why = [], "failed"
         while not failed:
             time.sleep(args.monitor_interval)

@@ -17,10 +17,18 @@ provides topology metadata, so these launchers matter for (a) CPU-sim
 multi-process testing — the analog of BASELINE's "gloo CPU smoke" — and
 (b) driving jax.distributed rendezvous when infra (GKE/QueuedResources)
 doesn't.
+
+One process for each chip: a process that touches JAX on a TPU host takes
+every chip it can see, and the next one fails or hangs. So a launcher
+parent stays off JAX (it counts chips from /dev, `local_tpu_chips`) and
+hands worker r the libtpu variables that show it chip r only
+(`chip_binding`, `one_chip_env`). A worker count the host's chips cannot
+back one-to-one is refused here, before anything is spawned.
 """
 
 from __future__ import annotations
 
+import glob
 import multiprocessing
 import os
 import re
@@ -45,26 +53,86 @@ def sim_device_flags(inherited: str, devices_per_proc: int) -> str:
             f"{devices_per_proc}").strip()
 
 
-def _worker_env(rank: int, world_size: int, port: int,
-                devices_per_proc: int | None) -> dict[str, str]:
-    env = {
-        "RANK": str(rank),
-        "LOCAL_RANK": str(rank),
-        "WORLD_SIZE": str(world_size),
-        "MASTER_ADDR": "localhost",
-        "MASTER_PORT": str(port),
-    }
+def local_tpu_chips() -> int:
+    """How many TPU chips this host exposes — read from /dev, never from
+    JAX: numbered vfio groups (v5e and later) or accel nodes (v4 and
+    earlier). 0 when the process is held to the CPU (JAX_PLATFORMS=cpu),
+    where nothing needs binding."""
+    if os.environ.get("JAX_PLATFORMS", "").split(",")[0] == "cpu":
+        return 0
+    try:
+        vfio = [n for n in os.listdir("/dev/vfio") if n.isdigit()]
+    except OSError:
+        vfio = []
+    return len(vfio) or len(glob.glob("/dev/accel[0-9]*"))
+
+
+def one_chip_env(chip: int) -> dict[str, str]:
+    """libtpu variables that show a process chip ``chip`` alone, as a
+    slice of its own — a serving replica, which never talks chip to
+    chip."""
+    return {"TPU_VISIBLE_CHIPS": str(chip),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1"}
+
+
+# libtpu's name for the chip grid of one host, by chip count. Only what
+# has trained is listed (the 2x2 v5e host, PR 21); another host shape is
+# refused until it has run.
+_HOST_CHIP_BOUNDS = {4: "2,2,1"}
+
+
+def chip_binding(nproc: int) -> list[dict[str, str]]:
+    """Per-worker libtpu environment for a training group of ``nproc``
+    non-sim workers on this host: ``[{}] * nproc`` where there is nothing
+    to bind (no chips, or a single worker, which drives every chip
+    itself), else worker r sees chip r alone and the workers form ONE
+    slice — ICI collectives between them, the layout `jax.distributed`
+    trains over. Raises when the chips cannot back the workers
+    one-to-one: a second process on a chip fails or hangs."""
+    chips = local_tpu_chips()
+    if chips == 0 or nproc == 1:
+        return [{} for _ in range(nproc)]
+    if nproc != chips or chips not in _HOST_CHIP_BOUNDS:
+        sizes = f"1 or {chips}" if chips in _HOST_CHIP_BOUNDS else "1"
+        raise RuntimeError(
+            f"{nproc} worker processes on a host with {chips} TPU chip(s): "
+            f"one process drives one chip, so a group here is {sizes} "
+            f"processes (one process can drive every chip itself)")
+    ports = [_free_port() for _ in range(nproc)]
+    addresses = ",".join(f"localhost:{p}" for p in ports)
+    return [{**one_chip_env(r),
+             "TPU_PROCESS_BOUNDS": _HOST_CHIP_BOUNDS[chips],
+             "TPU_PROCESS_ADDRESSES": addresses,
+             "TPU_PROCESS_PORT": str(ports[r]),
+             "CLOUD_TPU_TASK_ID": str(r)} for r in range(nproc)]
+
+
+def worker_envs(world_size: int, port: int,
+                devices_per_proc: int | None) -> list[dict[str, str]]:
+    """What each worker of a group adds to the environment it inherits:
+    the rendezvous variables, then either its CPU-sim devices
+    (``devices_per_proc``) or its chip (`chip_binding` — which raises,
+    before anything is spawned, when the chips cannot back the group)."""
     if devices_per_proc is not None:
         # CPU-sim: each process gets its own simulated chips
-        env["JAX_PLATFORMS"] = "cpu"
-        env["XLA_FLAGS"] = sim_device_flags(
-            os.environ.get("XLA_FLAGS", ""), devices_per_proc)
-    return env
+        device = {"JAX_PLATFORMS": "cpu",
+                  "XLA_FLAGS": sim_device_flags(
+                      os.environ.get("XLA_FLAGS", ""), devices_per_proc)}
+        devices = [device] * world_size
+    else:
+        devices = chip_binding(world_size)
+    return [{"RANK": str(rank),
+             "LOCAL_RANK": str(rank),
+             "WORLD_SIZE": str(world_size),
+             "MASTER_ADDR": "localhost",
+             "MASTER_PORT": str(port),
+             **devices[rank]} for rank in range(world_size)]
 
 
-def _worker(fn: Callable, rank: int, world_size: int, port: int,
-            devices_per_proc: int | None, args: tuple) -> None:
-    os.environ.update(_worker_env(rank, world_size, port, devices_per_proc))
+def _worker(fn: Callable, env: dict[str, str], rank: int,
+            args: tuple) -> None:
+    os.environ.update(env)
     fn(rank, *args)
 
 
@@ -82,11 +150,11 @@ def launch(
     exits nonzero — after terminating the rest (fail-fast, the behavior
     torchrun's agent provides)."""
     ctx = multiprocessing.get_context("spawn")
-    port = _free_port()
+    envs = worker_envs(nprocs, _free_port(), devices_per_proc)
     procs = [
         ctx.Process(
             target=_worker,
-            args=(fn, rank, nprocs, port, devices_per_proc, tuple(args)),
+            args=(fn, envs[rank], rank, tuple(args)),
             name=f"tpu-dist-rank{rank}",
         )
         for rank in range(nprocs)

@@ -1404,23 +1404,9 @@ class ServingEngine:
         elif self._active:
             t0 = time.perf_counter()
             with self._span("serve/decode_tick"), self._mesh_ctx():
-                # one shared per-slot argument tail; the paged tick just
-                # prepends the host-stamped block tables and lengths
-                name, tick, head = (("paged_decode_tick",
-                                     paged_decode_tick,
-                                     (jnp.asarray(self._tables),
-                                      jnp.asarray(self._lengths)))
-                                    if self.paged
-                                    else ("decode_tick", decode_tick, ()))
+                name, tick, args = self._tick_program()
                 self._cache, nxt = self._aot_call(
-                    name, tick, (self._tick_model,),
-                    (self._weights, self._cache, *head,
-                     jnp.asarray(self._tokens),
-                     jnp.asarray(self._key_data),
-                     jnp.asarray(self._counts),
-                     jnp.asarray(self._temps),
-                     jnp.asarray(self._top_ks),
-                     jnp.asarray(self._top_ps)),
+                    name, tick, (self._tick_model,), args,
                     dict(candidates=self.candidates))
                 toks = np.asarray(nxt)  # host sync: streaming delivery
             dt = time.perf_counter() - t0
@@ -2777,6 +2763,50 @@ class ServingEngine:
 
     # ------------------------------------------------------------------
     # internals
+
+    def _tick_program(self):
+        """(name, jitted program, dynamic args) of the plain decode tick
+        over the live host state: one shared per-slot argument tail; the
+        paged tick just prepends the host-stamped block tables and
+        lengths."""
+        name, tick, head = (("paged_decode_tick", paged_decode_tick,
+                             (jnp.asarray(self._tables),
+                              jnp.asarray(self._lengths)))
+                            if self.paged
+                            else ("decode_tick", decode_tick, ()))
+        return name, tick, (self._weights, self._cache, *head,
+                            jnp.asarray(self._tokens),
+                            jnp.asarray(self._key_data),
+                            jnp.asarray(self._counts),
+                            jnp.asarray(self._temps),
+                            jnp.asarray(self._top_ks),
+                            jnp.asarray(self._top_ps))
+
+    def lower_tick(self, *, platforms: tuple[str, ...] | None = None):
+        """AOT-lower this engine's plain decode tick from its live
+        operands (nothing runs, nothing is donated) — the serving twin of
+        `Trainer.lower_step`, ``platforms`` included:
+        ``.compile().as_text()`` is the HLO the tick dispatches, which is
+        how chip_smoke.py and the lowering tests see whether the paged
+        kernel is really in it."""
+        _, tick, args = self._tick_program()
+        with self._mesh_ctx():
+            if platforms is None:
+                return tick.lower(self._tick_model, *args,
+                                  candidates=self.candidates)
+            return tick.trace(self._tick_model, *args,
+                              candidates=self.candidates).lower(
+                                  lowering_platforms=platforms)
+
+    def placement(self) -> dict[str, list[int]]:
+        """Ids of the devices the weights and the KV cache live on — how
+        a fleet of one-chip replicas is checked for having all landed on
+        chip 0."""
+        def ids(tree):
+            return sorted({d.id for leaf in jax.tree.leaves(tree)
+                           for d in leaf.devices()})
+
+        return {"weights": ids(self._weights), "kv": ids(self._cache)}
 
     def _aot_call(self, name, jit_fn, statics, args, kw_statics, *,
                   donation="cache"):

@@ -232,7 +232,9 @@ def main() -> int:
 
     from pytorchdistributed_tpu.faults.inject import FaultInjector
     from pytorchdistributed_tpu.runtime.heartbeat import Heartbeat
+    from pytorchdistributed_tpu.runtime.xla_cache import use_persistent_cache
 
+    use_persistent_cache()
     engine = _build_engine(spec)
     heartbeat = Heartbeat.from_env()
     injector = FaultInjector.from_env()

@@ -495,6 +495,7 @@ class SubprocessReplica:
         full_env = dict(os.environ)
         if env:
             full_env.update(env)
+        full_env.update(self._chip_env(index))
         full_env.update({
             "RANK": str(index), "LOCAL_RANK": str(index),
             "WORLD_SIZE": str(world_size),
@@ -518,6 +519,34 @@ class SubprocessReplica:
              "pytorchdistributed_tpu.serving.replica_worker"],
             stdin=subprocess.PIPE, stdout=subprocess.PIPE,
             env=full_env, text=True, bufsize=1)
+
+    @staticmethod
+    def _chip_env(index: int) -> dict[str, str]:
+        """One process for each chip (runtime/launch.py): on a TPU host
+        worker ``index`` is shown chip ``index`` alone. Refused — rather
+        than left to fail or hang — when this process has already taken
+        the chips by touching JAX, or when there is no chip ``index``."""
+        from pytorchdistributed_tpu.runtime.launch import (
+            local_tpu_chips,
+            one_chip_env,
+        )
+
+        chips = local_tpu_chips()
+        if chips == 0:
+            return {}
+        jax = sys.modules.get("jax")
+        if jax is not None and jax._src.xla_bridge.backends_are_initialized():
+            raise RuntimeError(
+                f"replica {index}: this process has initialised JAX and "
+                f"holds the host's {chips} TPU chip(s), so a worker "
+                f"process cannot take one — keep the router's process off "
+                f"JAX, or serve with InProcessReplica (one process, one "
+                f"replica per chip)")
+        if index >= chips:
+            raise RuntimeError(
+                f"replica {index}: the host has {chips} TPU chip(s) and "
+                f"one worker process drives one chip")
+        return one_chip_env(index)
 
     # -- wire ---------------------------------------------------------
 

@@ -28,7 +28,7 @@ module is the Dapper-style request-scoped half:
     [submit, finish] exactly — the breakdown always adds up to the
     request's terminal latency.
 
-Stage taxonomy (one request's life, router clock unless noted):
+Stages (one request's life, router clock unless noted):
 
   queue      router submit -> WDRR dequeue (admission.popleft stamps)
   admission  dequeue -> accepted by a replica's engine

@@ -484,7 +484,8 @@ class Trainer:
         if self._diag_writer is not None:
             self._diag_writer.close()
 
-    def lower_step(self, sample_batch, seed: int = 0):
+    def lower_step(self, sample_batch, seed: int = 0, *,
+                   platforms: tuple[str, ...] | None = None):
         """AOT-lower the jitted train step from ABSTRACT state: no params
         are materialized and no device computation runs — only tracing.
         Returns the `jax.stages.Lowered`; `.compile()` on it yields the
@@ -493,11 +494,20 @@ class Trainer:
         compiled-invariant tripwires assert against committed numbers
         (tests/test_compiled_invariants.py) — the hardware-independent
         stand-in for the reference's benchmark-as-test discipline
-        (03_model_parallel.ipynb:403-423) when no chip is reachable."""
+        (03_model_parallel.ipynb:403-423) when no chip is reachable.
+
+        ``platforms=("tpu",)`` lowers for the chip from a host that has
+        none (libtpu runs the Pallas→Mosaic lowering on the CPU): the
+        check to make before spending chip time
+        (tests/test_tpu_lowering.py). That result is for reading, not
+        for `.compile()`."""
         state_sds, batch_sds = self._step_sds(sample_batch, seed)
         step_fn = self._build_step()
         with jax.set_mesh(self.mesh):
-            return step_fn.lower(state_sds, batch_sds)
+            if platforms is None:
+                return step_fn.lower(state_sds, batch_sds)
+            return step_fn.trace(state_sds, batch_sds).lower(
+                lowering_platforms=platforms)
 
     def _step_sds(self, sample_batch, seed: int = 0):
         """(state, batch) ShapeDtypeStruct trees with their shardings —

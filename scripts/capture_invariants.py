@@ -25,17 +25,9 @@ if "xla_force_host_platform_device_count" not in flags:
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-import jax  # noqa: E402
-
-jax.config.update("jax_platforms", "cpu")
-
-from pytorchdistributed_tpu._jax_compat import (  # noqa: E402
-    supports_partial_auto_shard_map,
-)
 from pytorchdistributed_tpu.utils.hlo import compiled_invariants  # noqa: E402
 from tests.test_compiled_invariants import (  # noqa: E402
     BUILDERS,
-    PIPELINE_CONFIGS,
     SERVING_NAMES,
     decode_lowered,
     serving_lowered,
@@ -46,15 +38,6 @@ def main() -> None:
     names = sys.argv[1:] or list(BUILDERS) + ["decode"] + list(SERVING_NAMES)
     print("COMMITTED = {")
     for name in names:
-        if (name in PIPELINE_CONFIGS
-                and not supports_partial_auto_shard_map()):
-            # same gate as the test: this jax cannot lower the pipeline
-            # schedules' partial-auto shard_map — keep the old committed
-            # entry rather than capturing garbage
-            print(f"    # {name}: SKIPPED (partial-auto shard_map "
-                  f"unsupported by this jax) — previous entry kept",
-                  flush=True)
-            continue
         if name == "decode":  # the one-shot decode pin (DECODE_COMMITTED)
             inv = compiled_invariants(decode_lowered().compile())
         elif name in SERVING_NAMES:  # the serving pins (SERVE_COMMITTED)

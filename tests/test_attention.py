@@ -13,7 +13,6 @@ import numpy as np
 import optax
 import pytest
 
-from pytorchdistributed_tpu._jax_compat import has_native_check_vma
 from pytorchdistributed_tpu.models import GPT2, gpt2_config
 from pytorchdistributed_tpu.ops.attention import dense_attention
 from pytorchdistributed_tpu.ops.pallas_attention import flash_attention
@@ -278,11 +277,6 @@ def test_ulysses_xla_impl_checked_sim():
     assert np.isfinite(np.asarray(g)).all()
 
 
-@pytest.mark.skipif(
-    not has_native_check_vma(),
-    reason="ring's checked xla path needs the vma checker; the legacy "
-           "check_rep emulation has no rule for checkpoint_name's "
-           "primitive inside the ring's custom_vjp")
 def test_ring_xla_impl_checked_sim():
     """The ring analog of test_ulysses_xla_impl_checked_sim: one checked
     fwd+bwd impl='xla' ring step on the sim, pinning the xla debug path's

@@ -9,9 +9,6 @@ import numpy as np
 import optax
 import pytest
 
-from pytorchdistributed_tpu._jax_compat import (
-    supports_partial_auto_shard_map,
-)
 from pytorchdistributed_tpu.models import Llama, llama_config
 from pytorchdistributed_tpu.models.transformer import apply_rope, rope_tables
 from pytorchdistributed_tpu.runtime.mesh import Axis, create_mesh
@@ -100,17 +97,13 @@ def test_llama_fsdp_matches_dp_loss():
                      mesh=create_mesh(**axes), strategy=strategy)
         losses[strategy] = [float(tr.train_step(batch)["loss"])
                             for _ in range(3)]
-    from tests.test_models import _fsdp_equivalence_tol
+    from tests.test_models import FSDP_EQUIVALENCE_TOL
 
-    tol = _fsdp_equivalence_tol()
+    tol = FSDP_EQUIVALENCE_TOL
     np.testing.assert_allclose(losses["dp"], losses["fsdp"],
                                rtol=tol, atol=tol)
 
 
-@pytest.mark.skipif(
-    not supports_partial_auto_shard_map(),
-    reason="pipeline schedules need partial-auto shard_map "
-           "(axis_names ⊂ mesh axes), unsupported by this jax")
 @pytest.mark.parametrize("schedule", ["gpipe", "1f1b"])
 def test_llama_pipeline_loss_equivalence(schedule):
     rng = np.random.default_rng(7)

@@ -143,10 +143,12 @@ def test_expert_parallel_a2a_matches_single_device():
     """The tentpole parity pin: the explicit all_to_all dispatch/combine
     (shard_map + custom_vjp, ops/overlap.expert_a2a_ffn) on a
     dp2 x expert4 mesh computes the SAME function as the dense grouped
-    einsums on one device — fp32 forward BITWISE, grads to float
-    roundoff (the backward reuses both exchange directions, so this also
-    pins the hand-written cotangent einsums against autodiff of the
-    dense path)."""
+    einsums on one device — fp32 forward and grads to float roundoff
+    (the backward reuses both exchange directions, so this also pins the
+    hand-written cotangent einsums against autodiff of the dense path).
+    The bar is atol 1e-7 on O(1e-3) outputs: the two paths contract in a
+    different order, and XLA on jax 0.9.0 leaves them 1.4e-9 apart where
+    0.4.x happened to leave them bitwise."""
     mesh = create_mesh(data=2, expert=4)
     x = jnp.asarray(np.random.default_rng(5).standard_normal((8, 8, 16)),
                     jnp.float32)
@@ -163,7 +165,7 @@ def test_expert_parallel_a2a_matches_single_device():
     with jax.set_mesh(mesh):
         out = jax.jit(a2a.apply)(params, x)
         g = jax.jit(jax.grad(loss(a2a), argnums=(0, 1)))(params, x)
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-7)
     for got, want in zip(jax.tree.leaves(_unboxed(g)),
                          jax.tree.leaves(_unboxed(ref_g))):
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),

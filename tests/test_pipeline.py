@@ -13,20 +13,7 @@ import numpy as np
 import optax
 import pytest
 
-from pytorchdistributed_tpu._jax_compat import (
-    supports_partial_auto_shard_map,
-)
 from pytorchdistributed_tpu.models import GPT2, gpt2_config
-
-# Both schedules run shard_map with axis_names={"pipe"} (other axes stay
-# auto); jax versions whose shard_map was backfilled from the experimental
-# module (0.4.x) cannot lower that shape — the SPMD partitioner rejects
-# the manual-region PartitionId and CHECK-aborts on the stage ppermute —
-# so the whole module skips there (environment limitation, not a bug).
-pytestmark = pytest.mark.skipif(
-    not supports_partial_auto_shard_map(),
-    reason="pipeline schedules need partial-auto shard_map "
-           "(axis_names ⊂ mesh axes), unsupported by this jax")
 from pytorchdistributed_tpu.parallel.pipeline import gpipe_spmd, one_f_one_b
 from pytorchdistributed_tpu.runtime.mesh import create_mesh
 from pytorchdistributed_tpu.training import Trainer, token_cross_entropy_loss

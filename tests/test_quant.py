@@ -231,15 +231,7 @@ def test_mlp_parity_dp():
 def test_parity_pipeline(schedule):
     """Quant x pipeline parallelism: the README claims every strategy picks
     the int8 operands up unmodified, so the pipeline schedules need the
-    same parity evidence as dp/fsdp/tp. Gated like the rest of the
-    pipeline suite (partial-auto shard_map)."""
-    from pytorchdistributed_tpu._jax_compat import (
-        supports_partial_auto_shard_map,
-    )
-
-    if not supports_partial_auto_shard_map():
-        pytest.skip("pipeline schedules need partial-auto shard_map "
-                    "(axis_names ⊂ mesh axes), unsupported by this jax")
+    same parity evidence as dp/fsdp/tp."""
     import dataclasses
 
     import optax

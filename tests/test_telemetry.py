@@ -12,9 +12,6 @@ import time
 import numpy as np
 import pytest
 
-from pytorchdistributed_tpu._jax_compat import (
-    supports_multiprocess_cpu_collectives,
-)
 from pytorchdistributed_tpu.telemetry import (
     AnomalyDetector,
     EventLog,
@@ -32,10 +29,6 @@ from pytorchdistributed_tpu.telemetry.report import render
 from pytorchdistributed_tpu.utils.hlo import collective_bytes
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-_needs_multiproc = pytest.mark.skipif(
-    not supports_multiprocess_cpu_collectives(),
-    reason="multi-process CPU collectives unimplemented in this jaxlib")
 
 
 # ---------------------------------------------------------------------------
@@ -406,7 +399,6 @@ def test_report_cli_subcommands(tmp_path):
     assert any(e.get("name") == "a" for e in merged["traceEvents"])
 
 
-@_needs_multiproc
 def test_report_cli_two_process_run(tmp_path):
     """The acceptance scenario: a REAL 2-process CPU-sim training run
     (launched through the run.py agent with --telemetry-dir) leaves

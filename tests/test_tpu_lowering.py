@@ -1,0 +1,301 @@
+"""Tripwires for "does it start on the chip", from a host that has none.
+
+libtpu is installed beside JAX, so lowering with
+``lowering_platforms=("tpu",)`` runs the real Pallas→Mosaic lowering on
+the CPU, and `jax.experimental.topologies` hands out abstract v5e devices
+against which ``.compile()`` runs the real XLA:TPU + Mosaic compile.
+Neither touches a chip, and neither is a measurement.
+
+Tier-1 (seconds): the three failures PR 21 found this way, kept as
+tests — the paged decode kernel's block specs (refused by the (8, 128)
+tiling rule), the flash kernel bare under a multi-device jit ("Mosaic
+kernels cannot be automatically partitioned") and tp at GPT-2's odd
+published vocabulary — plus what keeps a CPU from passing for a chip:
+no backend on import, ``--backend tpu`` and `chip_smoke.py` refusing a
+CPU, and the compile cache's one placement rule.
+
+The full-width compiles against the v5e topology (~7 s each on 8 cores)
+are marked ``slow``, out of the quick tier.
+
+CPU tests run the kernels interpreted (the default where the backend is
+not a TPU); ``compiled_kernels`` flips that default the way a chip would.
+"""
+
+from __future__ import annotations
+
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from pytorchdistributed_tpu.models import GPT2, gpt2_config
+from pytorchdistributed_tpu.ops.pallas_attention import (
+    flash_attention,
+    paged_flash_attention,
+)
+from pytorchdistributed_tpu.runtime.mesh import create_mesh
+from pytorchdistributed_tpu.training import Trainer, token_cross_entropy_loss
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+MARKER = "tpu_custom_call"
+TPU = ("tpu",)
+
+# GPT-2 small: the train step's attention shapes and the serving tick's
+HEADS, HEAD_DIM, SEQ, BATCH = 12, 64, 1024, 8
+SLOTS, BLOCK = 8, 16
+
+
+@pytest.fixture
+def compiled_kernels(monkeypatch):
+    """Every kernel entry point picks interpret mode from
+    ``jax.default_backend() != "tpu"``; answer as a chip would."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def sds(shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def lower_for_tpu(fn, *args) -> str:
+    return jax.jit(fn).trace(*args).lower(lowering_platforms=TPU).as_text()
+
+
+def v5e_devices(n: int):
+    from jax.experimental import topologies
+
+    return topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices[:n]
+
+
+# ---------------------------------------------------------------------------
+# kernels
+
+
+def flash_fwd_bwd(q, k, v, g):
+    out, vjp = jax.vjp(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, interpret=False), q, k, v)
+    return (out, *vjp(g))
+
+
+def test_flash_fwd_bwd_lowers_for_tpu():
+    x = sds((BATCH, SEQ, HEADS, HEAD_DIM))
+    assert lower_for_tpu(flash_fwd_bwd, x, x, x, x).count(MARKER) == 3
+
+
+def paged_args(kv_heads: int, int8: bool):
+    pages = SEQ // BLOCK
+    nb = SLOTS * pages + 1
+    pool = sds((nb, BLOCK, kv_heads, HEAD_DIM),
+               jnp.int8 if int8 else jnp.bfloat16)
+    args = [sds((SLOTS, HEADS, HEAD_DIM)), pool, pool,
+            sds((SLOTS, pages), jnp.int32), sds((SLOTS,), jnp.int32)]
+    if int8:
+        args += [sds((nb, BLOCK, kv_heads), jnp.float32)] * 2
+    return args
+
+
+def paged(q, kp, vp, tables, lengths, ks=None, vs=None):
+    return paged_flash_attention(q, kp, vp, tables, lengths, k_scale=ks,
+                                 v_scale=vs, interpret=False)
+
+
+PAGED_CASES = {"bf16": (HEADS, False), "int8": (HEADS, True),
+               "gqa": (HEADS // 3, False)}
+
+
+@pytest.mark.parametrize("case", PAGED_CASES)
+def test_paged_decode_kernel_lowers_for_tpu(case):
+    """The pool's kv_heads (12, or 4 for the GQA group) is not a multiple
+    of 8: a KV block of one head is refused at lowering, the whole-heads
+    block is taken."""
+    assert MARKER in lower_for_tpu(paged, *paged_args(*PAGED_CASES[case]))
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("case", PAGED_CASES)
+def test_paged_decode_kernel_compiles_for_v5e(case):
+    from jax.sharding import SingleDeviceSharding
+
+    one = SingleDeviceSharding(v5e_devices(1)[0])
+    args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)
+            for a in paged_args(*PAGED_CASES[case])]
+    assert MARKER in jax.jit(paged).lower(*args).compile().as_text()
+
+
+# ---------------------------------------------------------------------------
+# the train step under a mesh
+
+
+def lm_batch(batch, seq):
+    return {"tokens": np.zeros((batch, seq), np.int32),
+            "targets": np.zeros((batch, seq), np.int32)}
+
+
+MESHES = {"dp": dict(data=4), "fsdp": dict(data=1, fsdp=4),
+          "tp": dict(data=2, tensor=2), "tp_fsdp": dict(data=1, fsdp=2,
+                                                        tensor=2)}
+
+
+def trainer_on(devices, strategy, cfg, **kw):
+    return Trainer(GPT2(cfg), optax.adamw(1e-4), token_cross_entropy_loss,
+                   mesh=create_mesh(devices=devices, **MESHES[strategy]),
+                   strategy=strategy, **kw)
+
+
+@pytest.mark.parametrize("strategy", ["dp", "fsdp"])
+def test_train_step_with_flash_lowers_on_four_devices(compiled_kernels,
+                                                      strategy):
+    """XLA cannot partition a Mosaic kernel: bare under a 4-device jit the
+    flash call is refused at lowering; inside shard_map it lowers. The
+    interpreted kernel is ordinary HLO, which is why no CPU run of the
+    step could see this."""
+    cfg = gpt2_config("test", attention="pallas")
+    trainer = trainer_on(jax.devices()[:4], strategy, cfg)
+    text = trainer.lower_step(lm_batch(8, 64),
+                              platforms=TPU).as_text()
+    assert text.count(MARKER) == 3  # fwd, dKV, dQ inside the layer scan
+
+
+def test_tp_shards_what_divides_at_published_vocab():
+    """GPT-2's vocabulary (50,257) is odd: under vocab → tensor the
+    embedding stays replicated over that axis rather than failing jit's
+    even-sharding rule; everything that divides is still split."""
+    from pytorchdistributed_tpu.parallel.sharding import (
+        shardings_for_strategy,
+    )
+
+    cfg = gpt2_config("small", num_layers=1)
+    mesh = create_mesh(devices=jax.devices()[:4], **MESHES["tp"])
+    boxed = jax.eval_shape(GPT2(cfg).init, jax.random.key(0),
+                           jnp.zeros((1, 8), jnp.int32))
+    shardings = shardings_for_strategy("tp", boxed, mesh)["params"]
+    assert tuple(shardings["embed"]["tok"]["embedding"].spec) == (None,
+                                                                  None)
+    wi = shardings["h"]["block"]["mlp"]["wi"]["kernel"]
+    assert "tensor" in jax.tree.leaves(tuple(wi.spec))
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("strategy", ["dp", "fsdp", "tp", "tp_fsdp"])
+def test_full_width_train_step_compiles_for_v5e(compiled_kernels, strategy):
+    cfg = gpt2_config("small", attention="pallas")
+    trainer = trainer_on(
+        v5e_devices(4), strategy, cfg,
+        compiler_options={"xla_tpu_scoped_vmem_limit_kib": "24576"})
+    compiled = trainer.lower_step(
+        lm_batch(BATCH, SEQ)).compile()
+    assert MARKER in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9
+
+
+# ---------------------------------------------------------------------------
+# the serving tick
+
+
+def test_engine_default_tick_lowers_the_paged_kernel(compiled_kernels):
+    """On a TPU ``paged_attn`` resolves to the kernel, and the decode
+    tick the engine dispatches really holds it."""
+    from pytorchdistributed_tpu.serving import ServingEngine
+
+    model = GPT2(gpt2_config("test"))
+    params = model.init(jax.random.key(0), jnp.zeros((1, 4), jnp.int32))
+    engine = ServingEngine(model, params, num_slots=2, block_size=16)
+    assert engine.summary()["paged_attn"] == "pallas"
+    assert MARKER in engine.lower_tick(platforms=TPU).as_text()
+
+
+# ---------------------------------------------------------------------------
+# nothing that hides the device
+
+
+def run_py(code: str, **env):
+    return subprocess.run(
+        [sys.executable, "-c", code], cwd=REPO, capture_output=True,
+        text=True, timeout=120, env={**os.environ, **env})
+
+
+def test_launcher_and_router_parents_initialise_no_backend():
+    """A process that initialises JAX on a TPU host takes every chip, so
+    the parents that spawn one process per chip must not."""
+    proc = run_py(
+        "import pytorchdistributed_tpu, pytorchdistributed_tpu.run\n"
+        "import pytorchdistributed_tpu.serving.router\n"
+        "import pytorchdistributed_tpu.serving.soak\n"
+        "from pytorchdistributed_tpu.runtime.launch import chip_binding\n"
+        "chip_binding(4)\n"
+        "from jax._src import xla_bridge\n"
+        "assert not xla_bridge.backends_are_initialized()\n")
+    assert proc.returncode == 0, proc.stderr[-2000:]
+
+
+def test_chip_binding_is_one_to_one(monkeypatch):
+    from pytorchdistributed_tpu.runtime import launch
+
+    monkeypatch.setattr(launch, "local_tpu_chips", lambda: 4)
+    envs = launch.chip_binding(4)
+    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "2", "3"]
+    assert len({e["TPU_PROCESS_PORT"] for e in envs}) == 4
+    assert len({e["TPU_PROCESS_ADDRESSES"] for e in envs}) == 1
+    assert launch.chip_binding(1) == [{}]  # one process drives them all
+    with pytest.raises(RuntimeError, match="one process drives one chip"):
+        launch.chip_binding(2)
+    monkeypatch.setattr(launch, "local_tpu_chips", lambda: 0)
+    assert launch.chip_binding(3) == [{}, {}, {}]  # nothing to bind
+
+
+def test_backend_tpu_refuses_a_cpu():
+    from pytorchdistributed_tpu.config import select_backend
+
+    with pytest.raises(RuntimeError, match="--backend tpu"):
+        select_backend("tpu")
+    assert os.environ["JAX_PLATFORMS"] == "cpu"  # refused before touching
+
+
+def test_chip_smoke_refuses_a_cpu():
+    proc = subprocess.run(
+        [sys.executable, "chip_smoke.py"], cwd=REPO, capture_output=True,
+        text=True, timeout=300, env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert proc.returncode != 0
+    assert '"ok"' not in proc.stdout
+
+
+def test_chip_smoke_result_line_has_the_contract_keys_only():
+    import importlib.util
+    import json
+    import types
+
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", os.path.join(REPO, "chip_smoke.py"))
+    smoke = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(smoke)
+    dev = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    line = smoke.result_line(dev, 4)
+    assert "\n" not in line
+    assert json.loads(line) == {
+        "ok": True,
+        "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 4}}
+
+
+def test_compile_cache_is_placed_from_outside_or_in_the_checkout(
+        monkeypatch):
+    from pytorchdistributed_tpu.runtime.xla_cache import (
+        CACHE_DIR_ENV,
+        use_persistent_cache,
+    )
+
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, value: updates.append((name, value)))
+    monkeypatch.setenv(CACHE_DIR_ENV, "/somewhere/else")
+    assert use_persistent_cache() == "/somewhere/else"
+    assert updates == []  # JAX reads the variable; no code names a directory
+    monkeypatch.delenv(CACHE_DIR_ENV)
+    in_checkout = os.path.join(REPO, ".jax_cache")
+    assert use_persistent_cache() == in_checkout
+    assert updates == [("jax_compilation_cache_dir", in_checkout)]
