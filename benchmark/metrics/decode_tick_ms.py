@@ -1,0 +1,9 @@
+"""Device time of the decode-tick program per execution, mean over the
+traced window."""
+
+
+def read(ctx):
+    runs = ctx.trace.program_runs(ctx.mix["programs"]["tick"])
+    if not runs:
+        return None
+    return sum(r.dur for r in runs) / len(runs) / 1e6
