@@ -379,6 +379,12 @@ _QUICK = (
     # cache's one placement rule), ~15 s together. Its full-width
     # compiles against the v5e topology carry their own `slow` mark.
     "test_tpu_lowering.py",
+    # the one host-span instrument (ISSUE 27): the ring's parent / ids /
+    # snapshot / profiler annotation / host/gc units, and the serve/*,
+    # train/* spans plus the queue-wait counters of an engine, a router
+    # and a Trainer built with NO telemetry directory (~20 s together:
+    # two test-size engines and one router compile)
+    "test_host_spans.py",
 )
 
 

@@ -13,7 +13,6 @@ double-buffered prefetcher overlaps host gather + H2D DMA with device compute
 from __future__ import annotations
 
 import collections
-import contextlib
 from typing import Iterator
 
 import jax
@@ -21,6 +20,7 @@ import numpy as np
 
 from pytorchdistributed_tpu.data.sampler import ShardedSampler
 from pytorchdistributed_tpu.faults import inject as _inject
+from pytorchdistributed_tpu.telemetry.spans import span
 
 
 class DataLoader:
@@ -95,13 +95,12 @@ def prefetch_to_device(
     iterator: Iterator[dict[str, np.ndarray]],
     sharding,
     size: int = 2,
-    tracer=None,
 ) -> Iterator[dict[str, jax.Array]]:
     """Double-buffer: keep ``size`` batches in flight on device so the H2D
-    transfer of batch k+1 overlaps the compute of batch k. ``tracer`` (a
-    telemetry.SpanTracer) records each shard/H2D handoff as an
-    "h2d_transfer" host span — note the span covers the *dispatch* of the
-    transfer; the DMA itself overlaps compute by design.
+    transfer of batch k+1 overlaps the compute of batch k. Each shard/H2D
+    handoff is a "train/h2d" host span (telemetry/spans.py) — note the
+    span covers the *dispatch* of the transfer; the DMA itself overlaps
+    compute by design.
 
     ``size`` is the configurable depth (Trainer(prefetch=N) /
     PTD_PREFETCH): 2 is the committed double-buffer default; deeper
@@ -114,9 +113,7 @@ def prefetch_to_device(
         raise ValueError(f"prefetch size must be >= 0, got {size}")
     queue: collections.deque = collections.deque()
     for batch in iterator:
-        cm = (tracer.span("h2d_transfer") if tracer is not None
-              else contextlib.nullcontext())
-        with cm:
+        with span("train/h2d"):
             queue.append(shard_batch(batch, sharding))
         if len(queue) >= size:  # size 0: always — fully synchronous
             yield queue.popleft()

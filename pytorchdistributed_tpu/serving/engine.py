@@ -92,6 +92,7 @@ from pytorchdistributed_tpu.serving.paging import (
     RadixPrefixCache,
 )
 from pytorchdistributed_tpu.serving.telemetry import ServingTelemetry
+from pytorchdistributed_tpu.telemetry.spans import span
 from pytorchdistributed_tpu.telemetry.tracing import (
     TraceContext,
     from_unix as _trace_from_unix,
@@ -734,6 +735,12 @@ class Request:
         self.done = False
         self.finish_reason: str | None = None
         self.submit_time: float | None = None
+        # when the request left the engine's queue for the prefill lane
+        # (paged) or a slot (dense), stamped ONCE: a preempted request
+        # that is admitted again keeps it. first_token_time - submit_time
+        # == (admit_time - submit_time) + (first_token_time - admit_time):
+        # queue wait plus prefill span
+        self.admit_time: float | None = None
         self.first_token_time: float | None = None
         self.finish_time: float | None = None
         # paged-engine lifecycle (zero on the dense engine): prompt
@@ -1179,6 +1186,7 @@ class ServingEngine:
         self._queue: collections.deque[Request] = collections.deque()
         self._active: dict[int, Request] = {}
         self._draining = False
+        self._steps = 0  # the `step=` id of this engine's host spans
         # health-snapshot state (ISSUE 9): ``_progress`` is a MONOTONIC
         # device-work watermark (never reset by reset_stats) — it moves
         # exactly when a compiled call completed and synced, so a router
@@ -1386,58 +1394,75 @@ class ServingEngine:
             self.drain()
             return {"admitted": 0, "decoded": 0, "expired": 0,
                     "active": 0, "queued": 0}
-        expired = self._expire_deadlines()
-        admitted = 0
-        if self.paged:
-            admitted = self._paged_admissions()
-        else:
-            while self._free and self._queue:
-                self._admit(self._queue.popleft())
-                admitted += 1
-        decoded = 0
-        if self.paged and self._active:
-            self._grow_slots()  # back this tick's write positions
-        if self.per_slot_limits and self._limits_dirty:
-            self._stamp_slot_limits()
-        if self._active and self.spec_k:
-            decoded = self._spec_step()
-        elif self._active:
-            t0 = time.perf_counter()
-            with self._span("serve/decode_tick"), self._mesh_ctx():
+        self._steps += 1
+        with span("serve/engine_step", step=self._steps):
+            expired = self._expire_deadlines()
+            admitted = 0
+            with span("serve/admit"):
+                if self.paged:
+                    admitted = self._paged_admissions()
+                else:
+                    while self._free and self._queue:
+                        self._admit(self._queue.popleft())
+                        admitted += 1
+            decoded = 0
+            if self.paged and self._active:
+                with span("serve/grow_slots"):
+                    self._grow_slots()  # back this tick's write positions
+            if self.per_slot_limits and self._limits_dirty:
+                self._stamp_slot_limits()
+            if self._active and self.spec_k:
+                decoded = self._spec_step()
+            elif self._active:
+                decoded = self._decode_step()
+        return {"admitted": admitted, "decoded": decoded,
+                "expired": expired, "active": len(self._active),
+                "queued": len(self._queue)}
+
+    def _decode_step(self) -> int:
+        """One plain decode tick over all slots and its host bookkeeping:
+        build the operands and dispatch (`serve/tick_dispatch`), wait for
+        the device (`serve/tick_sync`), hand each slot's token to its
+        request (`serve/deliver`). Returns the number of delivered
+        tokens."""
+        t0 = time.perf_counter()
+        with span("serve/decode_tick"), self._mesh_ctx():
+            with span("serve/tick_dispatch"):
                 name, tick, args = self._tick_program()
                 self._cache, nxt = self._aot_call(
                     name, tick, (self._tick_model,), args,
                     dict(candidates=self.candidates))
+            with span("serve/tick_sync"):
                 toks = np.asarray(nxt)  # host sync: streaming delivery
-            dt = time.perf_counter() - t0
-            self._counts += 1
-            self._progress += 1
-            st = self._stats
-            st["ticks"] += 1
-            st["tick_s"] += dt
-            st["occupancy_sum"] += len(self._active) / self.num_slots
-            row = {}
-            if self.paged:
-                used = self._alloc.usable - self._alloc.free_count
-                st["block_used_sum"] += used / self._alloc.usable
-                st["peak_blocks_used"] = max(st["peak_blocks_used"], used)
-                row = dict(blocks_used=used,
-                           blocks_free=self._alloc.free_count)
-                for slot in self._active:
-                    self._lengths[slot] += 1  # this tick's write landed
+        dt = time.perf_counter() - t0
+        self._counts += 1
+        self._progress += 1
+        st = self._stats
+        st["ticks"] += 1
+        st["tick_s"] += dt
+        st["occupancy_sum"] += len(self._active) / self.num_slots
+        row = {}
+        if self.paged:
+            used = self._alloc.usable - self._alloc.free_count
+            st["block_used_sum"] += used / self._alloc.usable
+            st["peak_blocks_used"] = max(st["peak_blocks_used"], used)
+            row = dict(blocks_used=used,
+                       blocks_free=self._alloc.free_count)
+            for slot in self._active:
+                self._lengths[slot] += 1  # this tick's write landed
+        decoded = 0
+        with span("serve/deliver", tokens=len(self._active)):
             for slot, req in list(self._active.items()):
                 self._deliver(req, int(toks[slot]))
                 decoded += 1
-            st["decode_tokens"] += decoded
-            if self.telemetry is not None:
-                self.telemetry.tick(
-                    tick=st["ticks"], tick_ms=round(dt * 1e3, 3),
-                    active=len(self._active), queued=len(self._queue),
-                    slot_occupancy=round(decoded / self.num_slots, 4),
-                    **row)
-        return {"admitted": admitted, "decoded": decoded,
-                "expired": expired, "active": len(self._active),
-                "queued": len(self._queue)}
+        st["decode_tokens"] += decoded
+        if self.telemetry is not None:
+            self.telemetry.tick(
+                tick=st["ticks"], tick_ms=round(dt * 1e3, 3),
+                active=len(self._active), queued=len(self._queue),
+                slot_occupancy=round(decoded / self.num_slots, 4),
+                **row)
+        return decoded
 
     def _spec_step(self) -> int:
         """One speculative decode tick over all slots (spec_decode_tick)
@@ -1452,48 +1477,50 @@ class ServingEngine:
         heads = self._spec_heads > 0
         adaptive = self.adaptive_k
         t0 = time.perf_counter()
-        with self._span("serve/spec_tick"), self._mesh_ctx():
-            # adaptive off keeps the k_eff=None operand list — the exact
-            # pre-ISSUE-16 program, so committed AOT caches and the
-            # serve_spec_tick invariant pin stay valid
-            tail = ((jnp.asarray(self._k_eff),) if adaptive else ())
-            if heads:
-                (self._cache, self._draft_cache, out,
-                 nacc) = self._aot_call(
-                    "spec_decode_tick_heads", spec_decode_tick_heads,
-                    (self._tick_model, self._draft_tick_model),
-                    (self._weights, self._draft_weights, self._cache,
-                     self._draft_cache,
-                     jnp.asarray(self._tables),
-                     jnp.asarray(self._lengths),
-                     jnp.asarray(self._spec_prev_start),
-                     jnp.asarray(self._spec_prev_tokens),
-                     jnp.asarray(self._spec_prev_idx),
-                     jnp.asarray(self._tokens),
-                     jnp.asarray(self._key_data),
-                     jnp.asarray(self._counts),
-                     jnp.asarray(self._temps), jnp.asarray(self._top_ks),
-                     jnp.asarray(self._top_ps)) + tail,
-                    dict(spec_k=self.spec_k, candidates=self.candidates),
-                    donation="cache,draft_cache")
-            else:
-                (self._cache, self._draft_cache, out,
-                 nacc) = self._aot_call(
-                    "spec_decode_tick", spec_decode_tick,
-                    (self._tick_model, self._draft_tick_model),
-                    (self._weights, self._draft_weights, self._cache,
-                     self._draft_cache,
-                     jnp.asarray(self._tables),
-                     jnp.asarray(self._lengths),
-                     jnp.asarray(self._tokens),
-                     jnp.asarray(self._key_data),
-                     jnp.asarray(self._counts),
-                     jnp.asarray(self._temps), jnp.asarray(self._top_ks),
-                     jnp.asarray(self._top_ps)) + tail,
-                    dict(spec_k=self.spec_k, candidates=self.candidates),
-                    donation="cache,draft_cache")
-            toks = np.asarray(out)   # host sync: streaming delivery
-            ns = np.asarray(nacc)
+        with span("serve/spec_tick"), self._mesh_ctx():
+            with span("serve/tick_dispatch"):
+                # adaptive off keeps the k_eff=None operand list — the exact
+                # pre-ISSUE-16 program, so committed AOT caches and the
+                # serve_spec_tick invariant pin stay valid
+                tail = ((jnp.asarray(self._k_eff),) if adaptive else ())
+                if heads:
+                    (self._cache, self._draft_cache, out,
+                     nacc) = self._aot_call(
+                        "spec_decode_tick_heads", spec_decode_tick_heads,
+                        (self._tick_model, self._draft_tick_model),
+                        (self._weights, self._draft_weights, self._cache,
+                         self._draft_cache,
+                         jnp.asarray(self._tables),
+                         jnp.asarray(self._lengths),
+                         jnp.asarray(self._spec_prev_start),
+                         jnp.asarray(self._spec_prev_tokens),
+                         jnp.asarray(self._spec_prev_idx),
+                         jnp.asarray(self._tokens),
+                         jnp.asarray(self._key_data),
+                         jnp.asarray(self._counts),
+                         jnp.asarray(self._temps), jnp.asarray(self._top_ks),
+                         jnp.asarray(self._top_ps)) + tail,
+                        dict(spec_k=self.spec_k, candidates=self.candidates),
+                        donation="cache,draft_cache")
+                else:
+                    (self._cache, self._draft_cache, out,
+                     nacc) = self._aot_call(
+                        "spec_decode_tick", spec_decode_tick,
+                        (self._tick_model, self._draft_tick_model),
+                        (self._weights, self._draft_weights, self._cache,
+                         self._draft_cache,
+                         jnp.asarray(self._tables),
+                         jnp.asarray(self._lengths),
+                         jnp.asarray(self._tokens),
+                         jnp.asarray(self._key_data),
+                         jnp.asarray(self._counts),
+                         jnp.asarray(self._temps), jnp.asarray(self._top_ks),
+                         jnp.asarray(self._top_ps)) + tail,
+                        dict(spec_k=self.spec_k, candidates=self.candidates),
+                        donation="cache,draft_cache")
+            with span("serve/tick_sync"):
+                toks = np.asarray(out)   # host sync: streaming delivery
+                ns = np.asarray(nacc)
         dt = time.perf_counter() - t0
         n_active = len(self._active)
         self._progress += 1
@@ -1504,42 +1531,44 @@ class ServingEngine:
         st["block_used_sum"] += used / self._alloc.usable
         st["peak_blocks_used"] = max(st["peak_blocks_used"], used)
         decoded = accepted = 0
-        for slot, req in list(self._active.items()):
-            n = int(ns[slot])
-            k_used = int(self._k_eff[slot]) if adaptive else self.spec_k
-            # the round's writes + randomness are consumed whether or not
-            # every token gets delivered; a retiring request's slot state
-            # is reset by _release_slot anyway
-            old_len = int(self._lengths[slot])
-            self._lengths[slot] += n + 1
-            self._counts[slot] += n + 1
-            st["draft_tokens"] += k_used
-            st["accepted_tokens"] += n
-            st["target_forwards"] += 1
-            req.draft_tokens += k_used
-            req.accepted_tokens += n
-            if heads:
-                # next round's draft chunk: this round's emitted buffer,
-                # live up to n, written one past the pre-advance length
-                self._spec_prev_tokens[slot] = toks[slot]
-                self._spec_prev_idx[slot] = n
-                self._spec_prev_start[slot] = old_len + 1
-            if adaptive:
-                # acceptance EMA -> next round's depth: propose about as
-                # many tokens as this slot has been accepting (never 0 —
-                # one proposal costs nothing extra, never > spec_k — the
-                # compiled width)
-                ema = ((1.0 - self.SPEC_EMA_ALPHA) * self._accept_ema[slot]
-                       + self.SPEC_EMA_ALPHA * (n / max(k_used, 1)))
-                self._accept_ema[slot] = ema
-                self._k_eff[slot] = min(
-                    self.spec_k, max(1, int(round(ema * self.spec_k))))
-            accepted += n
-            for j in range(n + 1):
-                self._deliver(req, int(toks[slot, j]))
-                decoded += 1
-                if req.done:
-                    break
+        with span("serve/deliver") as delivering:
+            for slot, req in list(self._active.items()):
+                n = int(ns[slot])
+                k_used = int(self._k_eff[slot]) if adaptive else self.spec_k
+                # the round's writes + randomness are consumed whether or not
+                # every token gets delivered; a retiring request's slot state
+                # is reset by _release_slot anyway
+                old_len = int(self._lengths[slot])
+                self._lengths[slot] += n + 1
+                self._counts[slot] += n + 1
+                st["draft_tokens"] += k_used
+                st["accepted_tokens"] += n
+                st["target_forwards"] += 1
+                req.draft_tokens += k_used
+                req.accepted_tokens += n
+                if heads:
+                    # next round's draft chunk: this round's emitted buffer,
+                    # live up to n, written one past the pre-advance length
+                    self._spec_prev_tokens[slot] = toks[slot]
+                    self._spec_prev_idx[slot] = n
+                    self._spec_prev_start[slot] = old_len + 1
+                if adaptive:
+                    # acceptance EMA -> next round's depth: propose about as
+                    # many tokens as this slot has been accepting (never 0 —
+                    # one proposal costs nothing extra, never > spec_k — the
+                    # compiled width)
+                    ema = ((1.0 - self.SPEC_EMA_ALPHA) * self._accept_ema[slot]
+                           + self.SPEC_EMA_ALPHA * (n / max(k_used, 1)))
+                    self._accept_ema[slot] = ema
+                    self._k_eff[slot] = min(
+                        self.spec_k, max(1, int(round(ema * self.spec_k))))
+                accepted += n
+                for j in range(n + 1):
+                    self._deliver(req, int(toks[slot, j]))
+                    decoded += 1
+                    if req.done:
+                        break
+            delivering.note(tokens=decoded)
         st["decode_tokens"] += decoded
         if self.telemetry is not None:
             self.telemetry.tick(
@@ -1587,8 +1616,13 @@ class ServingEngine:
             if self._prefilling is None:
                 if not (self._queue and self._free):
                     break
-                if not self._start_prefill():
-                    break  # pool pressure: wait for retirements
+                with span("serve/start_prefill",
+                          request=self._queue[0].id):
+                    started = self._start_prefill()
+                if not started:
+                    # pool pressure (not the lane): wait for retirements
+                    self._stats["admit_blocked"] += 1
+                    break
             admitted += self._prefill_chunk_step()
             chunks += 1
             if self._active and chunks >= self._chunks_per_step:
@@ -1662,6 +1696,8 @@ class ServingEngine:
                 self._alloc.decref(b)
             return False
         self._queue.popleft()
+        if req.admit_time is None:
+            req.admit_time = time.perf_counter()
         # fleet-shipped (remote) prefix nodes count separately: their
         # tokens were prefilled on ANOTHER replica, so the local
         # prefix_hit_rate must stay comparable to single-engine runs
@@ -1735,7 +1771,8 @@ class ServingEngine:
         pf = self._prefilling
         req, slot = pf["req"], pf["slot"]
         t0 = time.perf_counter()
-        with self._span("serve/prefill"), self._mesh_ctx():
+        with (span("serve/prefill", request=req.id, pos=pf["pos"]),
+              self._mesh_ctx()):
             if pf["pos"] < pf["true_len"]:
                 pos = pf["pos"]
                 final_t = pos + self.chunk >= pf["true_len"]
@@ -1744,7 +1781,8 @@ class ServingEngine:
                     self._weights, self._cache, pf, pos)
                 if final_t:
                     # sync: the TTFT timestamp is honest
-                    pf["first"] = int(first)
+                    with span("serve/prefill_sync", request=req.id):
+                        pf["first"] = int(first)
                 pf["pos"] = pos + self.chunk
             if self.spec_k and pf["dpos"] < pf["true_len"]:
                 self._draft_cache, _ = self._chunk_call(
@@ -1776,8 +1814,8 @@ class ServingEngine:
         if req.first_token_time is None:
             req.first_token_time = now
             if req.submit_time is not None:
-                self._note_ttft(now - req.submit_time)
-        self._trace_span(req, "prefill", req.submit_time, now,
+                self._note_ttft(req, now)
+        self._trace_span(req, "prefill", req.admit_time, now,
                          chunks=req.prefill_chunks,
                          parked=bool(req.prefill_only and not req.done),
                          resumed_from=req.resumed_from)
@@ -2572,7 +2610,7 @@ class ServingEngine:
             expired.append(pf["req"])
         if not expired:
             return 0
-        with self._span("serve/deadline_retire"):
+        with span("serve/deadline_retire"):
             for req in expired:
                 if req.slot is None and req in self._queue:
                     self._queue.remove(req)
@@ -2687,7 +2725,7 @@ class ServingEngine:
         while self._queue:
             out.append(self._queue.popleft())
         out.extend(self._active.values())
-        with self._span("serve/drain"):
+        with span("serve/drain"):
             for req in out:
                 self._retire(req, "drained")
         return out
@@ -2887,11 +2925,9 @@ class ServingEngine:
         return (jax.set_mesh(self.mesh) if self.mesh is not None
                 else contextlib.nullcontext())
 
-    def _span(self, name: str):
-        return (self.telemetry.span(name) if self.telemetry is not None
-                else contextlib.nullcontext())
-
     def _admit(self, req: Request) -> None:
+        if req.admit_time is None:
+            req.admit_time = time.perf_counter()
         slot = self._free.pop()
         # a resume-from-tokens submit (router failover) prefills
         # prompt + already-generated — the dense twin of the paged
@@ -2909,7 +2945,7 @@ class ServingEngine:
         kd = np.asarray(jax.random.key_data(
             jax.random.key(req.sampling.seed)))
         t0 = time.perf_counter()
-        with self._span("serve/prefill"), self._mesh_ctx():
+        with span("serve/prefill", request=req.id), self._mesh_ctx():
             # one AOT program per prefill bucket length, same as the
             # one-jit-signature-per-bucket the plain path compiles
             self._cache, first = self._aot_call(
@@ -2922,7 +2958,8 @@ class ServingEngine:
                  jnp.int32(req.sampling.top_k),
                  jnp.float32(req.sampling.top_p)),
                 dict(candidates=self.candidates))
-            first = int(first)  # sync: the TTFT timestamp is honest
+            with span("serve/prefill_sync", request=req.id):
+                first = int(first)  # sync: the TTFT timestamp is honest
         now = time.perf_counter()
         self._progress += 1
         st = self._stats
@@ -2932,8 +2969,8 @@ class ServingEngine:
         if req.first_token_time is None:
             req.first_token_time = now
             if req.submit_time is not None:
-                self._note_ttft(now - req.submit_time)
-        self._trace_span(req, "prefill", req.submit_time, now,
+                self._note_ttft(req, now)
+        self._trace_span(req, "prefill", req.admit_time, now,
                          resumed_from=req.resumed_from)
         self._active[slot] = req
         self._key_data[slot] = kd
@@ -2998,8 +3035,17 @@ class ServingEngine:
             attrs.setdefault("replica", self.telemetry.rank)
         self.trace.span(req.trace, stage, t0, t1, **attrs)
 
-    def _note_ttft(self, dt: float) -> None:
-        self._stats["ttft_s"].append(dt)
+    def _note_ttft(self, req: Request, now: float) -> None:
+        """The first token of ``req`` landed at ``now``: its TTFT, and the
+        two parts that sum to it — the wait in this engine's queue
+        (submit to admit) and the prefill span (admit to first token)."""
+        dt = now - req.submit_time
+        st = self._stats
+        st["ttft_s"].append(dt)
+        st["queue_wait_s"].append(req.admit_time - req.submit_time)
+        st["prefill_span_s"].append(now - req.admit_time)
+        self._trace_span(req, "queue", req.submit_time, req.admit_time,
+                         where="engine")
         self._ttft_ema = (dt if self._ttft_ema is None
                           else 0.8 * self._ttft_ema + 0.2 * dt)
 
@@ -3178,6 +3224,12 @@ class ServingEngine:
         self._stats = dict(ticks=0, tick_s=0.0, prefills=0, prefill_s=0.0,
                            decode_tokens=0, occupancy_sum=0.0, completed=0,
                            deadline_expired=0, ttft_s=[],
+                           # one entry a request, beside its ttft_s:
+                           # submit -> admit and admit -> first token;
+                           # admit_blocked = steps whose queue head the
+                           # POOL (not the prefill lane) kept waiting
+                           queue_wait_s=[], prefill_span_s=[],
+                           admit_blocked=0,
                            # paged-mode counters (stay 0 on dense)
                            admissions=0, admitted_tokens=0,
                            prefix_hit_tokens=0, prefill_chunks=0,
@@ -3248,6 +3300,13 @@ class ServingEngine:
                 float(np.percentile(ttfts, 50)) * 1e3, 3)
             out["ttft_ms_p99"] = round(
                 float(np.percentile(ttfts, 99)) * 1e3, 3)
+            # TTFT's two parts, one entry a request each: the wait in
+            # this engine's queue, and admission to first token
+            for key, q in (("queue_wait", 50), ("queue_wait", 95),
+                           ("prefill_span", 50)):
+                out[f"{key}_ms_p{q}"] = round(float(np.percentile(
+                    np.asarray(st[f"{key}_s"], np.float64), q)) * 1e3, 3)
+        out["admit_blocked"] = st["admit_blocked"]
         out["kv_hbm_bytes"] = self.kv_hbm_bytes
         if self.paged:
             out["block_size"] = self.block_size

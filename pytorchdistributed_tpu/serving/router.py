@@ -90,6 +90,7 @@ from pytorchdistributed_tpu.serving.paging import (
 )
 from pytorchdistributed_tpu.serving.telemetry import RouterTelemetry
 from pytorchdistributed_tpu.telemetry.events import TELEMETRY_DIR_ENV
+from pytorchdistributed_tpu.telemetry.spans import span
 from pytorchdistributed_tpu.telemetry.tracing import (
     RequestTracer,
     to_unix as _trace_to_unix,
@@ -1400,6 +1401,12 @@ class ReplicaRouter:
                            deadline_s=deadline_s, tenant=tenant,
                            priority=priority, kv_window=kv_window,
                            kv_sink=kv_sink, session_id=session_id)
+        with span("serve/submit", request=rr.id):
+            return self._enqueue(rr)
+
+    def _enqueue(self, rr: RouterRequest) -> RouterRequest:
+        """submit()'s second half: stamp, count, and queue (or shed, or
+        drain) the validated request."""
         rr.submit_time = time.perf_counter()
         if self.trace is not None:
             # mint the request's fleet-wide trace identity here — the
@@ -1461,99 +1468,118 @@ class ReplicaRouter:
             self.drain()
             return self._step_stats(0)
         self._ticks += 1
-        # 1. chaos schedule. One-shot tick specs: in-process replicas
-        # only (subprocess workers fire the injector against their own
-        # RANK — consulting it here too would consume the one-shot
-        # marker and log an injection that never happened). RATE-BASED
-        # schedules (ChaosSchedule) are consulted for EVERY replica —
-        # their seeded decisions live router-side, and the router
-        # applies them (kill/SIGSTOP/wire op) playing the cluster —
-        # with ``rate_only`` guarding subprocess one-shots.
-        if self._faults is not None:
-            rate_based = getattr(self._faults, "rate_based", False)
-            for r in self._replicas:
-                if self._status[r.index] in (DEAD, REMOVED):
-                    continue
-                in_worker = getattr(r, "faults_in_worker", False)
-                if in_worker and not rate_based:
-                    continue
-                kind = (self._faults.on_serving_tick(
-                            self._ticks, r.index, rate_only=True)
-                        if in_worker else
-                        self._faults.on_serving_tick(self._ticks,
-                                                     r.index))
-                if kind:
-                    spec = getattr(self._faults, "last_fired", None)
-                    self._stats["faults_injected"] += 1
-                    self._event("fault_injected", replica=r.index,
-                                fault=kind,
-                                spec=(spec.describe() if spec
-                                      else kind))
-                    try:
-                        r.apply_fault(kind, ms=(spec.ms if spec
-                                                else 100.0))
-                    except (ReplicaCrashed, TimeoutError):
-                        self._declare_dead(r, "crashed")
-        # 2. health + watchdog + quarantine machine
-        self._check_health()
-        # 2b. respawn DEAD replicas with budget left (ISSUE 10) —
-        # recovered capacity rejoins through the quarantine machine
-        self._maybe_respawn()
-        # 3. dispatch
-        dispatched = self._dispatch()
-        # 3b. admission-pressure preemption: a starved compliant tenant
-        # at the head of a saturated fleet may evict an over-budget
-        # tenant's newest stream (losslessly — preempt-requeue)
-        self._maybe_preempt()
-        # 4. step replicas — DRAINING ones too: their resident streams
-        # must finish before the tombstone
-        for r in self._replicas:
-            if self._status[r.index] not in (HEALTHY, DRAINING):
-                continue
-            try:
-                r.step()
-            except ReplicaCrashed:
-                self._declare_dead(r, "crashed")
-        # 4a. persist replica-demoted sessions into the store tiers
-        # (ISSUE 18) — the engine's HBM budget pushed them out; the
-        # store's DRAM/disk tiers keep them reattachable
-        if self.session_store is not None:
+        with span("serve/router_step", step=self._ticks):
+            with span("serve/router_health"):
+                # 1. chaos schedule
+                self._inject_faults()
+                # 2. health + watchdog + quarantine machine
+                self._check_health()
+                # 2b. respawn DEAD replicas with budget left (ISSUE 10)
+                # — recovered capacity rejoins through the quarantine
+                # machine
+                self._maybe_respawn()
+            with span("serve/router_dispatch"):
+                # 3. dispatch
+                dispatched = self._dispatch()
+                # 3b. admission-pressure preemption: a starved compliant
+                # tenant at the head of a saturated fleet may evict an
+                # over-budget tenant's newest stream (losslessly —
+                # preempt-requeue)
+                self._maybe_preempt()
+            # 4. step replicas — DRAINING ones too: their resident
+            # streams must finish before the tombstone
             for r in self._replicas:
                 if self._status[r.index] not in (HEALTHY, DRAINING):
                     continue
                 try:
-                    demoted = r.take_demoted_sessions()
+                    with span("serve/replica_step", replica=r.index):
+                        r.step()
+                except ReplicaCrashed:
+                    self._declare_dead(r, "crashed")
+            with span("serve/router_reap"):
+                # 4a. persist replica-demoted sessions (ISSUE 18)
+                self._persist_demoted_sessions()
+                # 4b. sweep parked prefill-role admissions onto
+                # decode-capable replicas over the KV stream (ISSUE 12)
+                self._handoffs()
+                # 5. reap
+                self._reap()
+                self._expire_queued_deadlines()
+                # 5b. finalize scale-downs: a DRAINING replica with
+                # nothing resident closes and becomes a tombstone
+                self._finalize_removals()
+                if self._ticks % self.sample_every == 0:
+                    self._sample_replicas()
+                self._feed_signals()
+        return self._step_stats(dispatched)
+
+    def _inject_faults(self) -> None:
+        """Consult the chaos schedule for every live replica. One-shot
+        tick specs: in-process replicas only (subprocess workers fire
+        the injector against their own RANK — consulting it here too
+        would consume the one-shot marker and log an injection that
+        never happened). RATE-BASED schedules (ChaosSchedule) are
+        consulted for EVERY replica — their seeded decisions live
+        router-side, and the router applies them (kill/SIGSTOP/wire op)
+        playing the cluster — with ``rate_only`` guarding subprocess
+        one-shots."""
+        if self._faults is None:
+            return
+        rate_based = getattr(self._faults, "rate_based", False)
+        for r in self._replicas:
+            if self._status[r.index] in (DEAD, REMOVED):
+                continue
+            in_worker = getattr(r, "faults_in_worker", False)
+            if in_worker and not rate_based:
+                continue
+            kind = (self._faults.on_serving_tick(
+                        self._ticks, r.index, rate_only=True)
+                    if in_worker else
+                    self._faults.on_serving_tick(self._ticks, r.index))
+            if kind:
+                spec = getattr(self._faults, "last_fired", None)
+                self._stats["faults_injected"] += 1
+                self._event("fault_injected", replica=r.index,
+                            fault=kind,
+                            spec=(spec.describe() if spec else kind))
+                try:
+                    r.apply_fault(kind, ms=(spec.ms if spec else 100.0))
                 except (ReplicaCrashed, TimeoutError):
                     self._declare_dead(r, "crashed")
-                    continue
-                for sid, tenant, payload in demoted:
-                    self.session_store.put(sid, payload, tenant=tenant)
-                    self._session_index.discard(sid)
-                    self._stats["session_demotes"] += 1
-        # 4b. sweep parked prefill-role admissions onto decode-capable
-        # replicas over the KV stream (ISSUE 12)
-        self._handoffs()
-        # 5. reap
-        self._reap()
-        self._expire_queued_deadlines()
-        # 5b. finalize scale-downs: a DRAINING replica with nothing
-        # resident closes and becomes a tombstone
-        self._finalize_removals()
-        if self._ticks % self.sample_every == 0:
-            for r in self._replicas:
-                if self._status[r.index] == REMOVED:
-                    continue
-                h = self._health[r.index]
-                self.telemetry.replica(
-                    tick=self._ticks, replica=r.index,
-                    status=self._status[r.index],
-                    role=self._roles[r.index],
-                    active=h.get("active", 0), queued=h.get("queued", 0),
-                    parked=h.get("parked", 0),
-                    occupancy=round(h.get("occupancy", 0.0), 4),
-                    progress=h.get("progress", -1))
-        self._feed_signals()
-        return self._step_stats(dispatched)
+
+    def _persist_demoted_sessions(self) -> None:
+        """Move replica-demoted sessions into the store tiers (ISSUE 18)
+        — the engine's HBM budget pushed them out; the store's DRAM/disk
+        tiers keep them reattachable."""
+        if self.session_store is None:
+            return
+        for r in self._replicas:
+            if self._status[r.index] not in (HEALTHY, DRAINING):
+                continue
+            try:
+                demoted = r.take_demoted_sessions()
+            except (ReplicaCrashed, TimeoutError):
+                self._declare_dead(r, "crashed")
+                continue
+            for sid, tenant, payload in demoted:
+                self.session_store.put(sid, payload, tenant=tenant)
+                self._session_index.discard(sid)
+                self._stats["session_demotes"] += 1
+
+    def _sample_replicas(self) -> None:
+        """One per-replica health/load row into the router telemetry."""
+        for r in self._replicas:
+            if self._status[r.index] == REMOVED:
+                continue
+            h = self._health[r.index]
+            self.telemetry.replica(
+                tick=self._ticks, replica=r.index,
+                status=self._status[r.index],
+                role=self._roles[r.index],
+                active=h.get("active", 0), queued=h.get("queued", 0),
+                parked=h.get("parked", 0),
+                occupancy=round(h.get("occupancy", 0.0), 4),
+                progress=h.get("progress", -1))
 
     def _feed_signals(self) -> None:
         """One sample per autoscaler signal per tick, into the
@@ -2372,7 +2398,15 @@ class ReplicaRouter:
                 break
             if chain and not rr.tokens:
                 self._maybe_ship_prefix(rr, chain, best)
-            if not self._place(rr, best):
+            with span("serve/dispatch", request=rr.id,
+                      replica=best.index) as placing:
+                placed = self._place(rr, best)
+                # in process the handle is the engine's Request: its id
+                # is what the engine's own spans call `request`
+                eid = getattr(rr._handle, "id", None)
+                if placed and eid is not None:
+                    placing.note(engine_request=eid)
+            if not placed:
                 # the pick died at placement (request was requeued);
                 # stop this pass — the next tick re-dispatches against
                 # refreshed health, never against this stale snapshot

@@ -1,13 +1,14 @@
 """Serving telemetry bridge — the engine's observability half.
 
 Emits through the existing telemetry/ package rather than growing a
-parallel stack: host spans (``serve/prefill`` / ``serve/decode_tick``)
-go through a SpanTracer and dump to the same ``spans_rank{rank}.trace
-.json`` contract the Trainer uses (so `python -m pytorchdistributed_tpu.
-telemetry merge-trace <dir>` folds serving and training onto one
-timeline), and the serving metrics — per-tick queue depth / slot
-occupancy / tick latency, per-request TTFT and decode tokens-per-s —
-land as JSONL rows in ``serve_metrics_rank{rank}.jsonl`` via the shared
+parallel stack: the engine's host spans (``serve/*``) are in the
+process's one span ring (telemetry/spans.py) whether or not anybody
+asked for files; this bridge dumps that ring at close to the same
+``spans_rank{rank}.trace.json`` contract the Trainer uses (so `python -m
+pytorchdistributed_tpu.telemetry merge-trace <dir>` folds serving and
+training onto one timeline), and the serving metrics — per-tick queue
+depth / slot occupancy / tick latency, per-request TTFT and decode
+tokens-per-s — land as JSONL rows in ``serve_metrics_rank{rank}.jsonl`` via the shared
 JsonlWriter (line-buffered append: rows survive a killed server).
 """
 
@@ -17,11 +18,12 @@ import collections
 import os
 import time
 
+from pytorchdistributed_tpu.telemetry import spans
 from pytorchdistributed_tpu.telemetry.events import (
     TELEMETRY_DIR_ENV,
     JsonlWriter,
 )
-from pytorchdistributed_tpu.telemetry.spans import SPAN_TRACE_FILE, SpanTracer
+from pytorchdistributed_tpu.telemetry.spans import SPAN_TRACE_FILE
 
 # writer filename / reader glob pair (same contract discipline as
 # events.py's EVENTS_FILE/EVENTS_GLOB — rename together)
@@ -35,16 +37,17 @@ ROUTER_METRICS_GLOB = "router_metrics_rank*.jsonl"
 
 
 class ServingTelemetry:
-    """Span tracer + serving-metric JSONL sink for one engine/rank."""
+    """Serving-metric JSONL sink (and, at close, the span-trace dump)
+    for one engine/rank."""
 
     def __init__(self, run_dir: str | os.PathLike, rank: int | None = None):
         self.run_dir = str(run_dir)
         os.makedirs(self.run_dir, exist_ok=True)
         self.rank = (rank if rank is not None
                      else int(os.environ.get("RANK", "0")))
-        self.tracer = SpanTracer(rank=self.rank)
         self.metrics = JsonlWriter(os.path.join(
             self.run_dir, SERVE_METRICS_FILE.format(rank=self.rank)))
+        self._since = time.perf_counter()  # the ring may hold older runs
 
     @classmethod
     def from_env(cls) -> "ServingTelemetry | None":
@@ -52,9 +55,6 @@ class ServingTelemetry:
         (None when unset) — the same env the Trainer reads."""
         d = os.environ.get(TELEMETRY_DIR_ENV)
         return cls(d) if d else None
-
-    def span(self, name: str):
-        return self.tracer.span(name)
 
     def tick(self, **row) -> None:
         """One decode-tick metric row (queue depth, occupancy, latency)."""
@@ -103,8 +103,12 @@ class ServingTelemetry:
                             **row})
 
     def close(self) -> None:
-        self.tracer.dump(os.path.join(
-            self.run_dir, SPAN_TRACE_FILE.format(rank=self.rank)))
+        # in-process replicas share the ring: this rank's file holds the
+        # spans under its own ``replica`` id and those that carry none
+        spans.ring().dump(
+            os.path.join(self.run_dir,
+                         SPAN_TRACE_FILE.format(rank=self.rank)),
+            rank=self.rank, replica=self.rank, since=self._since)
         self.metrics.close()
 
     def __enter__(self):

@@ -1,6 +1,8 @@
 """Unified telemetry subsystem (SURVEY.md §5, grown into a layer):
 
-  * spans.py       — host-span tracer (ring buffer → Chrome-trace JSON)
+  * spans.py       — the process's one host-span ring (`span(name,
+                     **ids)`: parent, ids, the profiler's clock →
+                     Chrome-trace JSON)
   * accounting.py  — StepAccounting: MFU / tokens-per-s / comm-bytes from
                      the compiled step joined with wall-clock
   * events.py      — anomaly tripwires → per-rank TelemetryEvent JSONL
@@ -46,6 +48,7 @@ from pytorchdistributed_tpu.telemetry.events import (  # noqa: F401
 from pytorchdistributed_tpu.telemetry.spans import (  # noqa: F401
     SpanTracer,
     merge_chrome_traces,
+    span,
 )
 from pytorchdistributed_tpu.telemetry.tracing import (  # noqa: F401
     TRACE_ENV,

@@ -7,8 +7,6 @@ JSONL sink (``jsonl_path`` / Trainer ``metrics_file``) so per-step metrics
 are first-class data, not just console text. The sink is a `JsonlWriter`
 — lazy-open, line-buffered, idempotent ``close()`` with reopen-on-next-
 write — shared with the telemetry subsystem's per-rank metric files.
-Heavier sinks (TensorBoard via `jax.profiler`) attach in
-utils/profiling.py.
 """
 
 from __future__ import annotations

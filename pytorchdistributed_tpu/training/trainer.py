@@ -49,7 +49,6 @@ from pytorchdistributed_tpu.telemetry import (
     TELEMETRY_DIR_ENV,
     AnomalyDetector,
     EventLog,
-    SpanTracer,
     device_memory_highwater,
 )
 from pytorchdistributed_tpu.telemetry.diagnostics import (
@@ -64,7 +63,8 @@ from pytorchdistributed_tpu.telemetry.events import (
     EVENTS_FILE,
     METRICS_FILE,
 )
-from pytorchdistributed_tpu.telemetry.spans import SPAN_TRACE_FILE
+from pytorchdistributed_tpu.telemetry import spans
+from pytorchdistributed_tpu.telemetry.spans import SPAN_TRACE_FILE, span
 from pytorchdistributed_tpu.training.logging import JsonlWriter, MetricLogger
 from pytorchdistributed_tpu.utils.guards import (
     NaNWatchdog,
@@ -295,15 +295,15 @@ class Trainer:
         # (SURVEY.md §5), one durable line per logged step
         self.logger = MetricLogger(
             jsonl_path=metrics_file if dist.is_main_process() else None)
-        # Unified telemetry (telemetry/): span tracer + anomaly tripwires
-        # + per-rank metric JSONL + StepAccounting, all keyed off one run
-        # directory — the explicit arg, or the launcher's env contract
-        # (run.py --telemetry-dir exports PTD_TELEMETRY_DIR so workers
-        # opt in without code changes). Off (all None) when neither is
-        # set: the hot loop then pays only a handful of `is None` checks.
+        # Unified telemetry (telemetry/): span-trace dump + anomaly
+        # tripwires + per-rank metric JSONL + StepAccounting, all keyed
+        # off one run directory — the explicit arg, or the launcher's env
+        # contract (run.py --telemetry-dir exports PTD_TELEMETRY_DIR so
+        # workers opt in without code changes). Off (all None) when
+        # neither is set: no file is written. The host spans themselves
+        # (telemetry/spans.py, `train/*`) are always in the process ring.
         tdir = telemetry_dir or os.environ.get(TELEMETRY_DIR_ENV)
         self.telemetry_dir = Path(tdir) if tdir else None
-        self._tracer = None
         self._events = None
         self._anomaly = None
         self._telemetry_jsonl = None
@@ -318,7 +318,9 @@ class Trainer:
         if self.telemetry_dir is not None:
             self.telemetry_dir.mkdir(parents=True, exist_ok=True)
             rank = self._telemetry_rank
-            self._tracer = SpanTracer(rank=rank)
+            # the process ring may hold an older run's spans: this
+            # Trainer's trace file starts here
+            self._spans_since = time.perf_counter()
             self._events = EventLog(
                 self.telemetry_dir / EVENTS_FILE.format(rank=rank),
                 rank=rank)
@@ -332,6 +334,7 @@ class Trainer:
                 self._diag_writer = JsonlWriter(
                     self.telemetry_dir / DIAGNOSTICS_FILE.format(rank=rank))
         self._dispatch_shapes: set = set()
+        self._host_steps = 0  # the `step=` id of the `train/step` spans
         self._accounting_attempted = False
         self._last_batch_samples = 0
         self._loss_fn = loss_fn
@@ -417,7 +420,7 @@ class Trainer:
 
         rng = jax.random.key(seed)
         self._prepare_abstract(sample_batch, rng)
-        with self._span("init_state"), jax.set_mesh(self.mesh):
+        with span("train/init_state"), jax.set_mesh(self.mesh):
             self.state = jax.jit(
                 make_state, out_shardings=self.state_shardings,
                 compiler_options=self._compiler_options,
@@ -427,13 +430,6 @@ class Trainer:
         return self.state
 
     # -- telemetry ---------------------------------------------------------
-
-    def _span(self, name: str):
-        """A host span when telemetry is on, else a nullcontext — the
-        single gate every instrumented region goes through."""
-        if self._tracer is None:
-            return contextlib.nullcontext()
-        return self._tracer.span(name)
 
     def step_accounting(self, sample_batch):
         """`telemetry.StepAccounting` for THIS trainer's step at this
@@ -463,7 +459,7 @@ class Trainer:
             return
         self._accounting_attempted = True
         try:
-            with self._span("step_accounting"):
+            with span("train/step_accounting"):
                 self.accounting = self.step_accounting(sample_batch)
             if dist.is_main_process():
                 self.accounting.save(self.telemetry_dir / "accounting.json")
@@ -476,9 +472,10 @@ class Trainer:
         reopens lazily, so multi-epoch fits keep appending."""
         if self.telemetry_dir is None:
             return
-        self._tracer.dump(
+        spans.ring().dump(
             self.telemetry_dir
-            / SPAN_TRACE_FILE.format(rank=self._telemetry_rank))
+            / SPAN_TRACE_FILE.format(rank=self._telemetry_rank),
+            rank=self._telemetry_rank, since=self._spans_since)
         self._events.close()
         self._telemetry_jsonl.close()
         if self._diag_writer is not None:
@@ -918,13 +915,19 @@ class Trainer:
         )
 
     def train_step(self, batch) -> dict[str, float]:
-        """One optimizer step (the reference's ``_run_batch``)."""
+        """One optimizer step (the reference's ``_run_batch``), as one
+        `train/step` host span."""
+        self._host_steps += 1
+        with span("train/step", step=self._host_steps):
+            return self._train_step(batch)
+
+    def _train_step(self, batch) -> dict[str, float]:
         if self.state is None:
             self.init(batch)
         if self._step_fn is None:  # state came from restore(), not init()
             self._step_fn = self._build_step()
         if any(not isinstance(v, jax.Array) for v in batch.values()):
-            with self._span("h2d_transfer"):
+            with span("train/h2d"):
                 batch = shard_batch(batch, self.batch_sharding)
         # AOT dispatch (ISSUE 10): with a compile cache, resolve this
         # batch signature to a persistent-cache executable once — a
@@ -934,11 +937,11 @@ class Trainer:
         # convention rejects) permanently falls this signature back to
         # the jit path: the cache can only ever make restart faster.
         step_fn = self._step_fn
+        sig = self._batch_signature(batch)
         if self._compile_cache is not None:
-            sig = self._batch_signature(batch)
             if sig not in self._aot_steps and sig not in self._aot_failed:
                 try:
-                    with self._span("aot_load_or_compile"):
+                    with span("train/aot_load_or_compile"):
                         self._load_or_compile_step(batch)
                 except Exception as e:  # noqa: BLE001 — never-fails
                     self._aot_failed.add(sig)
@@ -951,17 +954,13 @@ class Trainer:
         # a dispatch of a batch-shape signature not seen before carries
         # an XLA (re)compile — name it so host traces separate compile
         # stalls from steady-state dispatch (e.g. a ragged final batch
-        # recompiling mid-epoch); the key is only built when tracing
-        name = "step_dispatch"
-        if self._tracer is not None:
-            key = tuple(sorted(
-                (k, tuple(getattr(v, "shape", ()))) for k, v in
-                batch.items()))
-            if key not in self._dispatch_shapes:
-                self._dispatch_shapes.add(key)
-                name = "compile_and_dispatch"
+        # recompiling mid-epoch)
+        name = "train/dispatch"
+        if sig not in self._dispatch_shapes:
+            self._dispatch_shapes.add(sig)
+            name = "train/compile_and_dispatch"
         try:
-            with self._span(name), jax.set_mesh(self.mesh):
+            with span(name), jax.set_mesh(self.mesh):
                 self.state, metrics = step_fn(self.state, batch)
         except Exception as e:
             if step_fn is self._step_fn:
@@ -976,7 +975,7 @@ class Trainer:
             if any(getattr(a, "is_deleted", lambda: False)()
                    for a in jax.tree_util.tree_leaves(self.state)):
                 raise
-            with self._span(name), jax.set_mesh(self.mesh):
+            with span(name), jax.set_mesh(self.mesh):
                 self.state, metrics = self._step_fn(self.state, batch)
         if self._diag is not None:
             # route the per-layer [L] tables out of the scalar metric
@@ -1017,10 +1016,8 @@ class Trainer:
         raw = iter(loader)
         for _ in range(skip_steps):  # already trained before the restart
             next(raw, None)
-        if self._tracer is not None:
-            raw = self._spanned_iter(raw)
-        it = prefetch_to_device(raw, self.batch_sharding,
-                                size=self.prefetch, tracer=self._tracer)
+        it = prefetch_to_device(self._spanned_iter(raw),
+                                self.batch_sharding, size=self.prefetch)
         try:
             for i, batch in enumerate(it, start=skip_steps):
                 if self.state is None:
@@ -1071,7 +1068,7 @@ class Trainer:
                 self._last_batch_samples = n
                 if (i + 1) % self.log_every == 0:
                     # the blocking device sync: float() forces the chain
-                    with self._span("metric_sync"):
+                    with span("train/metric_sync"):
                         vals = {k: float(v) for k, v in metrics.items()}
                     # diag/* scalars split out of the primary stream:
                     # they feed the tripwires and the diagnostics JSONL,
@@ -1110,7 +1107,7 @@ class Trainer:
                 if (self.checkpoint is not None
                         and self._checkpoint_every > 0
                         and (i + 1) % self._checkpoint_every == 0):
-                    with self._span("checkpoint"):
+                    with span("train/checkpoint"):
                         self._save_checkpoint()
                 if self._preempt_requested:
                     # the current step is finished — honor the SIGTERM
@@ -1131,9 +1128,9 @@ class Trainer:
 
     def _spanned_iter(self, raw):
         """Wrap the host-side loader iterator so each batch fetch is a
-        "data_load" span (only built when tracing is on)."""
+        "train/data_load" span."""
         while True:
-            with self._span("data_load"):
+            with span("train/data_load"):
                 try:
                     batch = next(raw)
                 except StopIteration:
@@ -1508,7 +1505,7 @@ class Trainer:
             hb = (self._heartbeat.keepalive()
                   if self._heartbeat is not None
                   else contextlib.nullcontext())
-            with hb, self._span("preempt_checkpoint"):
+            with hb, span("train/preempt_checkpoint"):
                 self._save_checkpoint(force=True)
                 self.checkpoint.wait()
         raise SystemExit(EXIT_PREEMPTED)
@@ -1556,7 +1553,7 @@ class Trainer:
                 metrics.update({f"val_{k}": v for k, v in
                                 self.evaluate(val_loader).items()})
             if self.checkpoint is not None:
-                with self._span("checkpoint"):
+                with span("train/checkpoint"):
                     self._save_checkpoint(force=True)
             if dist.is_main_process():
                 self.logger.info(

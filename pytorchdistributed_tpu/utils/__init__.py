@@ -8,7 +8,3 @@ from pytorchdistributed_tpu.utils.guards import (  # noqa: F401
     assert_finite,
     assert_replicas_consistent,
 )
-from pytorchdistributed_tpu.utils.profiling import (  # noqa: F401
-    profile,
-    step_annotation,
-)

@@ -318,7 +318,10 @@ def test_telemetry_bridge_files(tmp_path):
     trace = json.loads(
         (tmp_path / SPAN_TRACE_FILE.format(rank=0)).read_text())
     names = {e["name"] for e in trace["traceEvents"] if e["ph"] == "X"}
-    assert {"serve/prefill", "serve/decode_tick"} <= names
+    assert {"serve/engine_step", "serve/admit", "serve/prefill",
+            "serve/prefill_sync", "serve/decode_tick",
+            "serve/tick_dispatch", "serve/tick_sync",
+            "serve/deliver"} <= names
 
 
 def test_quantized_engine_matches_quantized_generate():
