@@ -185,6 +185,8 @@ def phase_kernels(cfg) -> None:
         if int8:
             (kp, ks), (vp, vs) = kv_quantize(kp), kv_quantize(vp)
             scales = dict(k_scale=ks, v_scale=vs)
+        # the pool's row: one token's kv heads side by side
+        kp, vp = kp.reshape(nb, BLOCK, hk * d), vp.reshape(nb, BLOCK, hk * d)
         kernel = jax.jit(lambda q, kp, vp, sc: paged_flash_attention(
             q, kp, vp, tables, lengths, **sc))
         if KERNEL_MARKER not in compiled_text(kernel, qd, kp, vp, scales):

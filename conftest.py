@@ -376,8 +376,11 @@ _QUICK = (
     # 4-device mesh, tp at vocab 50,257, the engine's default tick) and
     # the no-hidden-fallback walls (no backend on import, one process
     # per chip, --backend tpu / chip_smoke.py refusing a CPU, the
-    # cache's one placement rule), ~15 s together. Its full-width
-    # compiles against the v5e topology carry their own `slow` mark.
+    # cache's one placement rule), ~15 s together, and the serve
+    # programs' pool-copy tripwire (ISSUE 28: the tick and the chunk,
+    # scanned and unrolled, compiled for a v5e at gpt2-medium width with
+    # two layers, ~20 s each). Its other full-width compiles against the
+    # v5e topology carry their own `slow` mark.
     "test_tpu_lowering.py",
     # the one host-span instrument (ISSUE 27): the ring's parent / ids /
     # snapshot / profiler annotation / host/gc units, and the serve/*,

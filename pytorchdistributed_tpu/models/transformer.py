@@ -147,8 +147,14 @@ class TransformerConfig:
     # Paged KV cache (serving/ — ISSUE 7, vLLM's PagedAttention realized
     # TPU-natively): kv_block_size > 0 replaces each attention layer's
     # dense [slots, max_seq_len, kv_heads, head_dim] cache with ONE pool
-    # of kv_blocks fixed-size blocks ([kv_blocks, kv_block_size, kv_heads,
-    # head_dim]) plus a per-slot block table ([decode_slots,
+    # of kv_blocks fixed-size blocks, lane-dense: [kv_blocks,
+    # kv_block_size, kv_heads*head_dim], one row a token with its kv
+    # heads side by side (so the (8, 128) tile holds no padded lanes and
+    # the row write, the gather and the Pallas kernel all see the array
+    # row-major); a scanned stack keeps ONE [num_layers, ...] pool per K
+    # and V at the stack level and carries it through the layer loop,
+    # each layer writing at [layer, block, offset] in place. Plus a
+    # per-slot block table ([decode_slots,
     # max_seq_len/kv_block_size] int32 physical-block ids, a "cache"
     # variable the serving engine overrides from host state every call).
     # Writes scatter each slot's token into table[slot, pos//bs] at offset
@@ -394,6 +400,23 @@ class TransformerConfig:
         return self.max_seq_len // self.kv_block_size
 
     @property
+    def kv_pool_leaves(self) -> dict:
+        """name -> (shape, dtype) of one layer's paged-pool leaves: the
+        K/V rows and, on an int8 pool, the fp32 dequant scale per written
+        (token, head) — same "cache" collection, so the engine's block
+        gather/scatter, export/import and prefix shipping carry the
+        scales with the codes."""
+        rows = (self.kv_blocks, self.kv_block_size)
+        int8 = self.kv_dtype == "int8"
+        kv = (rows + (self.kv_heads * self.head_dim,),
+              jnp.int8 if int8 else self.dtype)
+        leaves = {"cached_key": kv, "cached_value": kv}
+        if int8:
+            scale = (rows + (self.kv_heads,), jnp.float32)
+            leaves.update(cached_key_scale=scale, cached_value_scale=scale)
+        return leaves
+
+    @property
     def ffn_dim(self) -> int:
         return self.mlp_dim if self.mlp_dim is not None else 4 * self.embed_dim
 
@@ -515,15 +538,24 @@ class SelfAttention(nn.Module):
     """Multi-head self-attention with Megatron-ready head sharding.
 
     ``deterministic`` is a module attribute (not a call arg) so lifted
-    transforms (nn.remat / nn.scan) see a plain (x,) call signature —
+    transforms (nn.remat / nn.scan) see only array arguments —
     jax.checkpoint cannot mark keyword-only args static.
+
+    ``paging`` is the paged stack's per-slot state, which every layer
+    reads alike (``TransformerStack``: ``index``, ``block_table`` and,
+    under per-slot limits, ``kv_sinks``/``kv_windows``).
+    ``pool``/``layer`` are the scanned stack's paged KV pool (a dict of
+    layer-stacked leaves, ``TransformerConfig.kv_pool_leaves``) and this
+    layer's index in it: the layer writes its rows at ``[layer, block,
+    offset]`` and the call returns ``(out, pool)``. Left at None, a paged
+    layer owns its pool as "cache" variables (the unrolled stack).
     """
 
     cfg: TransformerConfig
     deterministic: bool = True
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, paging=None, pool=None, layer=None):
         cfg = self.cfg
         deterministic = self.deterministic
         b, s, _ = x.shape
@@ -591,11 +623,15 @@ class SelfAttention(nn.Module):
                 raise ValueError(
                     f"slot-decode batch {b} != decode_slots "
                     f"{cfg.decode_slots} (the engine owns the batch dim)")
-            idx_var = self.variable(
-                "cache", "index",
-                lambda: jnp.zeros((cfg.decode_slots,) if cfg.decode_slots
-                                  else (), jnp.int32))
-            idx = idx_var.value
+            if cfg.kv_block_size:
+                idx = paging["index"]
+            else:
+                idx_var = self.variable(
+                    "cache", "index",
+                    lambda: jnp.zeros((cfg.decode_slots,)
+                                      if cfg.decode_slots else (),
+                                      jnp.int32))
+                idx = idx_var.value
         if cfg.rope:
             cos, sin = rope_tables(cfg.max_seq_len, cfg.head_dim,
                                    cfg.rope_theta)
@@ -616,84 +652,54 @@ class SelfAttention(nn.Module):
             if cfg.kv_block_size:
                 # Paged KV (ISSUE 7): one pool of fixed-size blocks shared
                 # by every slot + a per-slot block table mapping logical
-                # block p//bs to a physical pool block. The table is a
-                # cache variable only so it rides the collection plumbing
-                # — the serving engine overrides it (and idx) from host
-                # state on every compiled call, which is what makes prefix
-                # reuse and copy-free admission pure host-side
-                # bookkeeping. Falls through to the SAME masked-attention
-                # tail as the dense layout: only where K/V rows live
-                # differs, which is what keeps paged outputs bitwise-equal
-                # to dense.
+                # block p//bs to a physical pool block. Table and
+                # positions are the stack's: the serving engine
+                # overrides them from host state on every compiled call,
+                # which is what makes prefix reuse and copy-free
+                # admission pure host-side bookkeeping. Falls through to
+                # the SAME masked-attention tail as the dense layout:
+                # only where K/V rows live differs, which is what keeps
+                # paged outputs bitwise-equal to dense.
                 bs_blk = cfg.kv_block_size
-                pool_dtype = (jnp.int8 if cfg.kv_dtype == "int8"
-                              else cfg.dtype)
-                table_var = self.variable(
-                    "cache", "block_table",
-                    lambda: jnp.zeros((cfg.decode_slots, cfg.kv_pages),
-                                      jnp.int32))
-                if cfg.per_slot_kv_limits and cfg.kv_window_tokens:
-                    # per-slot sink/window (ISSUE 15): cache leaves only
-                    # so they ride the collection plumbing — the engine
-                    # host-stamps them on admission/release, defaulting
-                    # to the cfg statics, and the mask below reads each
-                    # slot's own values
-                    sinks_var = self.variable(
-                        "cache", "kv_sinks",
-                        lambda: jnp.full((cfg.decode_slots,),
-                                         cfg.kv_sink_tokens, jnp.int32))
-                    windows_var = self.variable(
-                        "cache", "kv_windows",
-                        lambda: jnp.full((cfg.decode_slots,),
-                                         cfg.kv_window_tokens, jnp.int32))
-                cached_k = self.variable(
-                    "cache", "cached_key", jnp.zeros,
-                    (cfg.kv_blocks, bs_blk, cfg.kv_heads, cfg.head_dim),
-                    pool_dtype)
-                cached_v = self.variable(
-                    "cache", "cached_value", jnp.zeros,
-                    (cfg.kv_blocks, bs_blk, cfg.kv_heads, cfg.head_dim),
-                    pool_dtype)
-                if cfg.kv_dtype == "int8":
-                    # fp32 dequant scale per written (token, head) row —
-                    # same cache collection, so the engine's block
-                    # gather/scatter, export/import and prefix shipping
-                    # carry the scales with the codes automatically
-                    k_scale_var = self.variable(
-                        "cache", "cached_key_scale", jnp.zeros,
-                        (cfg.kv_blocks, bs_blk, cfg.kv_heads), jnp.float32)
-                    v_scale_var = self.variable(
-                        "cache", "cached_value_scale", jnp.zeros,
-                        (cfg.kv_blocks, bs_blk, cfg.kv_heads), jnp.float32)
+                table = paging["block_table"]
+                own = None
+                if pool is None:
+                    own = {name: self.variable("cache", name, jnp.zeros,
+                                               shape, dtype)
+                           for name, (shape, dtype)
+                           in cfg.kv_pool_leaves.items()}
+                    pool = {name: var.value for name, var in own.items()}
+                int8 = cfg.kv_dtype == "int8"
                 if not self.is_initializing():
-                    # scatter each row's s tokens into its table's blocks;
-                    # positions past the context (padded prefill tails)
-                    # drop into the reserved trash block 0 instead of
-                    # clamping onto a live row
+                    # scatter each row's s tokens into its table's blocks,
+                    # in place; positions past the context (padded prefill
+                    # tails) drop into the reserved trash block 0 instead
+                    # of clamping onto a live row
                     pos = idx[:, None] + jnp.arange(s)           # [b, s]
                     inb = jnp.clip(pos // bs_blk, 0, cfg.kv_pages - 1)
-                    blk = jnp.take_along_axis(table_var.value, inb, axis=1)
+                    blk = jnp.take_along_axis(table, inb, axis=1)
                     blk = jnp.where(pos < cfg.max_seq_len, blk, 0)
-                    off = pos % bs_blk
-                    if cfg.kv_dtype == "int8":
+                    at = (blk, pos % bs_blk)
+                    if layer is not None:
+                        at = (layer,) + at
+                    rows = {"cached_key": k, "cached_value": v}
+                    if int8:
                         from pytorchdistributed_tpu.ops.quant import (
                             kv_quantize,
                         )
 
-                        qk, sk = kv_quantize(k)
-                        qv, sv = kv_quantize(v)
-                        cached_k.value = cached_k.value.at[blk, off].set(qk)
-                        cached_v.value = cached_v.value.at[blk, off].set(qv)
-                        k_scale_var.value = (
-                            k_scale_var.value.at[blk, off].set(sk))
-                        v_scale_var.value = (
-                            v_scale_var.value.at[blk, off].set(sv))
-                    else:
-                        cached_k.value = cached_k.value.at[blk, off].set(
-                            k.astype(cfg.dtype))
-                        cached_v.value = cached_v.value.at[blk, off].set(
-                            v.astype(cfg.dtype))
-                    idx_var.value = idx + s
+                        rows["cached_key"], rows["cached_key_scale"] = (
+                            kv_quantize(k))
+                        rows["cached_value"], rows["cached_value_scale"] = (
+                            kv_quantize(v))
+                    pool = {
+                        name: leaf.at[at].set(
+                            rows[name].reshape(b, s, leaf.shape[-1])
+                            .astype(leaf.dtype))
+                        for name, leaf in pool.items()}
+                    if own is not None:
+                        for name, var in own.items():
+                            var.value = pool[name]
                 attend = cfg.decode_attend_len or cfg.max_seq_len
                 na = -(-attend // bs_blk)
                 attend = na * bs_blk
@@ -709,12 +715,10 @@ class SelfAttention(nn.Module):
                     )
 
                     out = paged_flash_attention(
-                        q[:, 0], cached_k.value, cached_v.value,
-                        table_var.value[:, :na], idx,
-                        k_scale=(k_scale_var.value
-                                 if cfg.kv_dtype == "int8" else None),
-                        v_scale=(v_scale_var.value
-                                 if cfg.kv_dtype == "int8" else None),
+                        q[:, 0], pool["cached_key"], pool["cached_value"],
+                        table[:, :na], idx, layer=layer,
+                        k_scale=pool.get("cached_key_scale"),
+                        v_scale=pool.get("cached_value_scale"),
                         sink_tokens=cfg.kv_sink_tokens,
                         window_tokens=cfg.kv_window_tokens,
                     )[:, None].astype(cfg.dtype)
@@ -725,23 +729,25 @@ class SelfAttention(nn.Module):
                     # window is exactly the dense attend window, so every
                     # reduction below keeps its shape — the bitwise-
                     # parity property the serving tests pin
-                    kc = paged_gather(cached_k.value,
-                                      table_var.value[:, :na])
-                    vc = paged_gather(cached_v.value,
-                                      table_var.value[:, :na])
-                    if cfg.kv_dtype == "int8":
+                    def gathered(name):
+                        return paged_gather(pool[name], table[:, :na],
+                                            layer)
+
+                    def heads(rows):
+                        return rows.reshape(b, attend, cfg.kv_heads,
+                                            cfg.head_dim)
+
+                    kc = heads(gathered("cached_key"))
+                    vc = heads(gathered("cached_value"))
+                    if int8:
                         from pytorchdistributed_tpu.ops.quant import (
                             kv_dequantize,
                         )
 
                         kc = kv_dequantize(
-                            kc, paged_gather(k_scale_var.value,
-                                             table_var.value[:, :na]),
-                            cfg.dtype)
+                            kc, gathered("cached_key_scale"), cfg.dtype)
                         vc = kv_dequantize(
-                            vc, paged_gather(v_scale_var.value,
-                                             table_var.value[:, :na]),
-                            cfg.dtype)
+                            vc, gathered("cached_value_scale"), cfg.dtype)
             else:
                 cached_k = self.variable(
                     "cache", "cached_key", jnp.zeros,
@@ -801,8 +807,8 @@ class SelfAttention(nn.Module):
                         # per-slot values (ISSUE 15): with every slot at
                         # the cfg defaults this computes the identical
                         # valid mask, so untouched streams stay bitwise
-                        snk = sinks_var.value[:, None, None]
-                        win = windows_var.value[:, None, None]
+                        snk = paging["kv_sinks"][:, None, None]
+                        win = paging["kv_windows"][:, None, None]
                         valid &= ((j[None, None, :] < snk)
                                   | (j[None, None, :]
                                      > pos[..., None] - win))
@@ -847,7 +853,7 @@ class SelfAttention(nn.Module):
         )(out)
         if cfg.dropout_rate > 0:
             out = nn.Dropout(cfg.dropout_rate)(out, deterministic=deterministic)
-        return out
+        return out if layer is None else (out, pool)
 
 
 class MlpBlock(nn.Module):
@@ -1008,7 +1014,10 @@ class TransformerBlock(nn.Module):
                      saturation_fraction(x, axis=-1))
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, paging=None, pool=None, layer=None):
+        """``paging``, ``pool``, ``layer``: the paged stack's per-slot
+        state, and the scanned stack's KV pool with this block's index in
+        it (SelfAttention); with a pool the call returns ``(x, pool)``."""
         cfg = self.cfg
         x = nn.with_logical_constraint(
             x, (Logical.BATCH, Logical.SEQ, Logical.EMBED))
@@ -1026,7 +1035,15 @@ class TransformerBlock(nn.Module):
                 return SwitchMoE(cfg, self.deterministic, name="moe")(h)
             return MlpBlock(cfg, self.deterministic, name="mlp")(h)
 
-        attn = SelfAttention(cfg, self.deterministic, name="attn")
+        attn_module = SelfAttention(cfg, self.deterministic, name="attn")
+
+        def attn(h):
+            nonlocal pool
+            out = attn_module(h, paging, pool, layer)
+            if layer is not None:
+                out, pool = out
+            return out
+
         if cfg.norm_position == "post":
             # original-BERT residual order: LN AFTER each sublayer's add
             x = norm("ln1", x + attn(x))
@@ -1035,8 +1052,9 @@ class TransformerBlock(nn.Module):
             x = x + attn(norm("ln1", x))
             x = x + ffn(norm("ln2", x))
         self._sow_diagnostics(x)
-        return nn.with_logical_constraint(
+        x = nn.with_logical_constraint(
             x, (Logical.BATCH, Logical.SEQ, Logical.EMBED))
+        return x if layer is None else (x, pool)
 
 
 def check_pipeline_decomposition(cfg: TransformerConfig) -> int:
@@ -1141,21 +1159,71 @@ class TransformerStack(nn.Module):
             # outputs, redo cheap elementwise) vs full-block recompute
             block = nn.remat(block, prevent_cse=not cfg.scan_layers,
                              policy=checkpoint_policy(cfg.remat_policy))
+        paged = bool(cfg.decode and cfg.kv_block_size)
+        if paged:
+            # what every layer of a paged stack reads alike, held once:
+            # each slot's position and block table and, under per-slot
+            # limits (ISSUE 15), its sink/window — "cache" leaves the
+            # serving engine stamps from host state on every call
+            slots = cfg.decode_slots
+            state = {
+                "index": self.variable(
+                    "cache", "index",
+                    lambda: jnp.zeros((slots,), jnp.int32)),
+                "block_table": self.variable(
+                    "cache", "block_table",
+                    lambda: jnp.zeros((slots, cfg.kv_pages), jnp.int32))}
+            if cfg.per_slot_kv_limits and cfg.kv_window_tokens:
+                state["kv_sinks"] = self.variable(
+                    "cache", "kv_sinks",
+                    lambda: jnp.full((slots,), cfg.kv_sink_tokens,
+                                     jnp.int32))
+                state["kv_windows"] = self.variable(
+                    "cache", "kv_windows",
+                    lambda: jnp.full((slots,), cfg.kv_window_tokens,
+                                     jnp.int32))
+            paging = {name: var.value for name, var in state.items()}
         if cfg.scan_layers:
-            x, _ = nn.scan(
-                lambda mdl, carry, _: (mdl(carry), None),
+            scan = functools.partial(
+                nn.scan,
                 variable_axes={"params": 0, "losses": 0, "cache": 0,
                                "diagnostics": 0},
                 split_rngs={"params": True, "dropout": True},
                 length=cfg.num_layers,
-                metadata_params={nn.PARTITION_NAME: Logical.STAGE},
-            )(block(cfg, deterministic, name="block"), x, None)
+                metadata_params={nn.PARTITION_NAME: Logical.STAGE})
+            if paged:
+                # the paged pool is the loop's CARRY, one [num_layers,
+                # ...] array a leaf, held here and not in the scanned
+                # blocks: as scanned input and output every layer's pool
+                # would be sliced out of one stack and written into a
+                # second, and the whole stack copied back after the loop
+                own = {name: self.variable(
+                           "cache", name, jnp.zeros,
+                           (cfg.num_layers,) + shape, dtype)
+                       for name, (shape, dtype)
+                       in cfg.kv_pool_leaves.items()}
+                (x, pool), _ = scan(
+                    lambda mdl, carry, paging, layer: (
+                        mdl(carry[0], paging, carry[1], layer), None),
+                    in_axes=(nn.broadcast, 0),
+                )(block(cfg, deterministic, name="block"),
+                  (x, {name: var.value for name, var in own.items()}),
+                  paging, jnp.arange(cfg.num_layers))
+                if not self.is_initializing():
+                    for name, var in own.items():
+                        var.value = pool[name]
+            else:
+                x, _ = scan(lambda mdl, carry, _: (mdl(carry), None))(
+                    block(cfg, deterministic, name="block"), x, None)
         else:
             interleave = cfg.moe_experts > 0 and cfg.moe_every > 1
             for i in range(cfg.num_layers):
                 kw = ({"use_moe": (i + 1) % cfg.moe_every == 0}
                       if interleave else {})
-                x = block(cfg, deterministic, name=f"block_{i}", **kw)(x)
+                layer = block(cfg, deterministic, name=f"block_{i}", **kw)
+                x = layer(x, paging) if paged else layer(x)
+        if paged and not self.is_initializing():
+            state["index"].value = paging["index"] + x.shape[1]
         return x
 
     def _pipelined(self, x, deterministic: bool):

@@ -26,20 +26,26 @@ def causal_mask(q_len: int, kv_len: int, *, q_offset: int = 0,
     return jnp.where(q_pos >= kv_pos, 0.0, -jnp.inf).astype(dtype)
 
 
-def paged_gather(pool, block_tables):
+def paged_gather(pool, block_tables, layer=None):
     """Gather block-table paged K or V back into position order.
 
     ``pool`` is the engine's shared block pool ``[num_blocks, block_size,
-    kv_heads, head_dim]``; ``block_tables`` maps each slot's logical block
-    j (positions [j*bs, (j+1)*bs)) to a physical pool block:
-    ``[slots, blocks_per_slot]`` int32. Returns ``[slots,
-    blocks_per_slot*block_size, kv_heads, head_dim]`` — the exact tensor
-    the dense per-slot cache would hold over that window, so downstream
-    masked attention is bitwise-identical to the dense path. Table
-    entries past a slot's live length point at the reserved trash block
-    (0); their rows are finite garbage the position mask zeroes exactly.
+    kv_heads*head_dim]`` (one lane-dense row a token; a scale plane is
+    ``[num_blocks, block_size, kv_heads]``), or the layer-stacked
+    ``[num_layers, num_blocks, ...]`` pool of a scanned stack with
+    ``layer`` the (traced) layer to read — one gather either way, never
+    a slice of a whole layer's pool. ``block_tables`` maps each slot's
+    logical block j (positions [j*bs, (j+1)*bs)) to a physical pool
+    block: ``[slots, blocks_per_slot]`` int32. Returns ``[slots,
+    blocks_per_slot*block_size, kv_heads*head_dim]`` — split into heads,
+    the exact tensor the dense per-slot cache would hold over that
+    window, so downstream masked attention is bitwise-identical to the
+    dense path. Table entries past a slot's live length point at the
+    reserved trash block (0); their rows are finite garbage the position
+    mask zeroes exactly.
     """
-    g = pool[block_tables]          # [slots, nb, bs, kv_heads, head_dim]
+    g = (pool[block_tables] if layer is None
+         else pool[layer, block_tables])       # [slots, nb, bs, width]
     slots, nb, bs = g.shape[:3]
     return g.reshape(slots, nb * bs, *g.shape[3:])
 
@@ -55,7 +61,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, lengths, *,
     Args:
       q: ``[slots, q_len, heads, head_dim]`` current-chunk queries (q_len
         is 1 for a decode tick, >1 for a chunked-prefill step).
-      k_pool / v_pool: ``[num_blocks, block_size, kv_heads, head_dim]``,
+      k_pool / v_pool: ``[num_blocks, block_size, kv_heads*head_dim]``,
         model dtype or int8 (the compressed pool — pass the scales).
       block_tables: ``[slots, blocks_per_slot]`` int32.
       lengths: ``[slots]`` int32 — tokens already cached per slot; query
@@ -79,8 +85,12 @@ def paged_attention(q, k_pool, v_pool, block_tables, lengths, *,
     left at None.
     """
     head_dim = q.shape[-1]
-    kc = paged_gather(k_pool, block_tables)
-    vc = paged_gather(v_pool, block_tables)
+
+    def heads(rows):                 # [slots, j, kv_heads*d] -> [.., hk, d]
+        return rows.reshape(*rows.shape[:2], -1, head_dim)
+
+    kc = heads(paged_gather(k_pool, block_tables))
+    vc = heads(paged_gather(v_pool, block_tables))
     if (k_scale is None) != (v_scale is None):
         raise ValueError("pass both k_scale and v_scale or neither")
     if k_scale is not None:

@@ -31,6 +31,7 @@ sim) the kernels run in Pallas interpret mode automatically.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.ad_checkpoint
@@ -517,26 +518,62 @@ def flash_attention_sharded(q, k, v, *, causal: bool = False,
 # ops/attention.paged_attention. One decode tick's q ([slots, heads, d])
 # attends each slot's block-table-mapped KV blocks streamed STRAIGHT from
 # the shared pool — the [slots, blocks*block_size, ...] gathered copy the
-# reference path materializes in HBM never exists here. The block table
-# and per-slot lengths ride as scalar-prefetch operands so the KV
-# BlockSpec index maps can chase the table (pool block `tables[slot, j]`
-# is DMA'd as grid step j), the canonical PagedAttention dataflow.
+# reference path materializes in HBM never exists here. The block table,
+# the per-slot lengths and the layer ride as scalar-prefetch operands so
+# the KV BlockSpec index maps can chase the table (pool block
+# `[layer, tables[slot, j]]` is DMA'd as grid step j), the canonical
+# PagedAttention dataflow.
+#
+# The pool is lane-dense: one token's K (or V) row is all its kv heads
+# side by side, `[num_blocks, block_size, kv_heads*head_dim]`, so a block
+# is `(block_size, kv_heads*head_dim)` under the (8, 128) tile with no
+# padded lanes, the TPU client keeps the array row-major, and the kernel
+# and the model's in-place row write agree on its layout: nothing
+# pool-sized is ever copied or transposed round the call.
 
 
-def _paged_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, *rest,
-                  block_size: int, num_blocks: int, scale: float,
-                  quantized: bool, sink: int, window: int):
+def _head_sums(x, head_dim: int):
+    """``x [rows, kv_heads*head_dim]`` -> the same shape, every lane
+    holding the sum of ``x`` over its own head's lanes. Mosaic has no
+    reshape that splits lanes into heads, so each head is a masked lane
+    reduce, spread back over the head by the select that masks it; done
+    in static lane slices that hold whole heads and, where the sizes
+    allow, whole 128-lane tiles (``head_dim`` 64: two heads a tile), so a
+    slice costs nothing and a reduce stays inside a vector register."""
+    width = x.shape[-1]
+    step = head_dim * 128 // math.gcd(head_dim, 128)   # lcm
+    if width % step:
+        step = width
+    lane = lax.broadcasted_iota(jnp.int32, (1, step), 1)
+    masks = [(lane >= lo) & (lane < lo + head_dim)
+             for lo in range(0, step, head_dim)]
+    parts = []
+    for lo in range(0, width, step):
+        part = x[:, lo:lo + step]
+        sums = jnp.zeros_like(part)
+        for mask in masks:
+            total = jnp.sum(jnp.where(mask, part, 0.0), axis=-1,
+                            keepdims=True)
+            sums = jnp.where(mask, total, sums)
+        parts.append(sums)
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=-1)
+
+
+def _paged_kernel(tables_ref, lengths_ref, layer_ref, q_ref, k_ref, v_ref,
+                  *rest, block_size: int, num_blocks: int, head_dim: int,
+                  scale: float, quantized: bool, sink: int, window: int):
     """Online-softmax over one slot's table blocks; grid
     (slots, blocks_per_slot). One program sees every kv head of its pool
-    block: refs are q/o ``[group, kv_heads, d]`` and k/v ``[block_size,
-    kv_heads, d]`` — the pool's own minor dims, which is the only KV
-    block shape Mosaic's (8, 128) tiling rule accepts for this layout
-    (a block of 1 of ``kv_heads`` rows is refused at lowering). The
-    contraction is a VPU multiply + lane reduce kept rank-3 end to end
-    (``keepdims``), so kv_heads never leaves the sublane dim and no
-    in-kernel transpose is needed; a decode tick is bandwidth-bound, the
-    MXU would see M = group rows. ``quantized`` adds two scale refs
-    (int8 pool, fp32 ``[block_size, kv_heads]`` per-row scales,
+    block: refs are q/o ``[group, kv_heads*d]`` and k/v ``[block_size,
+    kv_heads*d]``, head ``h`` in lanes ``[h*d, (h+1)*d)``. The per-head
+    reduce of ``k*q`` leaves each head's logit in all of that head's
+    lanes (``_head_sums``), after which softmax, ``p*v`` and the
+    accumulator are plain elementwise work on whole rows: every lane of
+    a head carries that head's logit, max and sum. All float32 on the
+    VPU, no MXU: a decode tick is bandwidth-bound. ``quantized`` adds
+    two scale refs
+    (int8 pool, fp32 ``[block_size, kv_heads]`` per-row scales, spread
+    over each head's lanes by a 0/1 matrix product at full precision and
     dequantized in VMEM right before the products); ``window`` > 0
     applies the sink+sliding-window mask and skips fully-dead middle
     blocks — the blocks the serving engine retires to the allocator."""
@@ -546,6 +583,7 @@ def _paged_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, *rest,
         ks_ref = vs_ref = None
         o_ref, acc_s, m_s, l_s = rest
     slot, ji = pl.program_id(0), pl.program_id(1)
+    width = k_ref.shape[-1]
 
     @pl.when(ji == 0)
     def _init():
@@ -570,32 +608,48 @@ def _paged_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, *rest,
 
     @pl.when(run)
     def _compute():
+        if quantized:
+            # the scale of head h goes to lanes [h*d, (h+1)*d): a product
+            # with a 0/1 matrix, one nonzero term a lane, exact at full
+            # precision
+            kv_heads = ks_ref.shape[-1]
+            lane = lax.broadcasted_iota(jnp.int32, (kv_heads, width), 1)
+            head = lax.broadcasted_iota(jnp.int32, (kv_heads, width), 0)
+            spread = ((lane >= head * head_dim)
+                      & (lane < (head + 1) * head_dim)).astype(jnp.float32)
+
         def load(ref, s_ref):
-            x = ref[...].astype(jnp.float32)               # [bs, hk, d]
+            x = ref[...].astype(jnp.float32)               # [bs, hk*d]
             if quantized:
                 # canonical dequant (ops/quant.kv_dequantize spelling):
                 # int8 → fp32 × per-row scale → compute dtype
-                x = (x * s_ref[...][:, :, None]).astype(
-                    q_ref.dtype).astype(jnp.float32)
+                x = (x * jnp.dot(s_ref[...], spread,
+                                 precision=lax.Precision.HIGHEST,
+                                 preferred_element_type=jnp.float32)
+                     ).astype(q_ref.dtype).astype(jnp.float32)
             return x
 
         k, v = load(k_ref, ks_ref), load(v_ref, vs_ref)
         pos = ji * block_size + lax.broadcasted_iota(
-            jnp.int32, (block_size, k.shape[1], 1), 0)
+            jnp.int32, (block_size, 1), 0)
         valid = pos <= length
         if window:
             valid &= (pos < sink) | (pos > length - window)
         for g in range(q_ref.shape[0]):  # static: the kv head's q group
-            q = q_ref[g].astype(jnp.float32)               # [hk, d]
-            logits = jnp.sum(k * q[None], -1, keepdims=True) * scale
-            logits = jnp.where(valid, logits, _NEG_INF)    # [bs, hk, 1]
-            m_prev = m_s[g]                                # [hk, 1]
-            m_new = jnp.maximum(m_prev, jnp.max(logits, axis=0))
+            q = q_ref[pl.ds(g, 1), :].astype(jnp.float32)  # [1, hk*d]
+            logits = jnp.where(valid, _head_sums(k * q, head_dim) * scale,
+                               _NEG_INF)
+            m_prev = m_s[pl.ds(g, 1), :]                   # [1, hk*d]
+            m_new = jnp.maximum(m_prev,
+                                jnp.max(logits, axis=0, keepdims=True))
             corr = jnp.exp(m_prev - m_new)
-            p = jnp.where(valid, jnp.exp(logits - m_new[None]), 0.0)
-            l_s[g] = l_s[g] * corr + jnp.sum(p, axis=0)
-            m_s[g] = m_new
-            acc_s[g] = acc_s[g] * corr + jnp.sum(p * v, axis=0)
+            p = jnp.where(valid, jnp.exp(logits - m_new), 0.0)
+            l_s[pl.ds(g, 1), :] = (l_s[pl.ds(g, 1), :] * corr
+                                   + jnp.sum(p, axis=0, keepdims=True))
+            m_s[pl.ds(g, 1), :] = m_new
+            acc_s[pl.ds(g, 1), :] = (
+                acc_s[pl.ds(g, 1), :] * corr
+                + jnp.sum(p * v, axis=0, keepdims=True))
 
     @pl.when(ji == num_blocks - 1)
     def _finalize():
@@ -604,7 +658,7 @@ def _paged_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, *rest,
 
 
 def paged_flash_attention(q, k_pool, v_pool, block_tables, lengths, *,
-                          k_scale=None, v_scale=None,
+                          layer=None, k_scale=None, v_scale=None,
                           sink_tokens: int = 0, window_tokens: int = 0,
                           scale: float | None = None,
                           interpret: bool | None = None):
@@ -616,14 +670,19 @@ def paged_flash_attention(q, k_pool, v_pool, block_tables, lengths, *,
       q: ``[slots, heads, head_dim]`` — each slot's single current-token
         query (its K/V already written into the pool, the decode
         contract).
-      k_pool / v_pool: ``[num_blocks, block_size, kv_heads, head_dim]``,
-        the model dtype or int8 (compressed pool).
+      k_pool / v_pool: ``[num_blocks, block_size, kv_heads*head_dim]``,
+        the model dtype or int8 (compressed pool); or the layer-stacked
+        ``[num_layers, num_blocks, block_size, kv_heads*head_dim]`` pool
+        of a scanned stack, with ``layer`` the (traced) int32 layer to
+        read — the index map starts with it, so no layer's pool is ever
+        sliced out of the stack.
       block_tables: ``[slots, blocks_per_slot]`` int32 physical block ids
         (entries past a slot's live length — and retired window blocks —
         point at the trash block 0).
       lengths: ``[slots]`` int32 — the query attends positions <= length.
       k_scale / v_scale: ``[num_blocks, block_size, kv_heads]`` fp32
-        per-(token, head) dequant scales; required iff the pool is int8.
+        per-(token, head) dequant scales (layer-stacked like the pool);
+        required iff the pool is int8.
       sink_tokens / window_tokens: static sink+sliding-window mask
         (window_tokens 0 = full attention): position j is attendable iff
         ``j < sink_tokens or j > length - window_tokens``; fully-dead
@@ -638,20 +697,29 @@ def paged_flash_attention(q, k_pool, v_pool, block_tables, lengths, *,
     gathered copy). Grouped-query native: each (slot, block) program
     streams the shared KV block once for the whole q group."""
     slots, h, d = q.shape
-    nb, bs, hk, _ = k_pool.shape
-    if h % hk:
-        raise ValueError(f"q heads {h} not divisible by kv heads {hk}")
+    if layer is None:      # one layer's own pool: a stack of one
+        layer = 0
+        k_pool, v_pool = k_pool[None], v_pool[None]
+        if k_scale is not None and v_scale is not None:
+            k_scale, v_scale = k_scale[None], v_scale[None]
+    _, nb, bs, width = k_pool.shape
+    hk = width // d
+    if width % d or h % hk:
+        raise ValueError(
+            f"pool rows of {width} lanes do not hold whole heads of {d} "
+            f"that divide the {h} q heads")
     quantized = k_pool.dtype == jnp.int8
     if quantized != (k_scale is not None and v_scale is not None):
         raise ValueError(
             "k_scale/v_scale must be provided iff the pool is int8 "
             f"(pool {k_pool.dtype}, k_scale "
             f"{'set' if k_scale is not None else 'None'})")
-    if quantized and (k_scale.shape != (nb, bs, hk)
-                      or v_scale.shape != (nb, bs, hk)):
+    if quantized and (k_scale.shape[1:] != (nb, bs, hk)
+                      or v_scale.shape[1:] != (nb, bs, hk)):
         raise ValueError(
             f"scale planes must be [num_blocks, block_size, kv_heads] = "
-            f"{(nb, bs, hk)}; got {k_scale.shape} / {v_scale.shape}")
+            f"{(nb, bs, hk)}; got {k_scale.shape[1:]} / "
+            f"{v_scale.shape[1:]}")
     if window_tokens < 0 or sink_tokens < 0 or (
             window_tokens and (window_tokens % bs or sink_tokens % bs)):
         raise ValueError(
@@ -664,37 +732,38 @@ def paged_flash_attention(q, k_pool, v_pool, block_tables, lengths, *,
         interpret = jax.default_backend() != "tpu"
     from jax.experimental.pallas import tpu as pltpu
 
-    # kv head g owns q rows g·group+; group-major so q_ref[g] is [hk, d]
-    qf = q.reshape(slots, hk, group, d).swapaxes(1, 2)
-    # every block's two minor dims equal the array's own: the one shape
-    # the TPU lowering takes for a pool whose kv_heads is not a
-    # multiple of 8 (leading dims are squeezed, not blocked at 1)
-    q_spec = pl.BlockSpec((None, group, hk, d),
-                          lambda s, j, tbl, ln: (s, 0, 0, 0))
-    kv_spec = pl.BlockSpec((None, bs, hk, d),
-                           lambda s, j, tbl, ln: (tbl[s, j], 0, 0, 0))
+    # kv head g owns q rows g·group+; group-major so row g of a slot's
+    # block holds, head by head, the g-th query of every kv head — laid
+    # out like a pool row
+    qf = q.reshape(slots, hk, group, d).swapaxes(1, 2).reshape(
+        slots, group, width)
+    # every block's two minor dims equal the array's own, which the TPU
+    # lowering takes at any width (leading dims are squeezed, not
+    # blocked at 1)
+    q_spec = pl.BlockSpec((None, group, width),
+                          lambda s, j, tbl, ln, ly: (s, 0, 0))
+    kv_spec = pl.BlockSpec(
+        (None, None, bs, width),
+        lambda s, j, tbl, ln, ly: (ly[0], tbl[s, j], 0, 0))
     in_specs = [q_spec, kv_spec, kv_spec]
     operands = [qf, k_pool, v_pool]
     if quantized:
         scale_spec = pl.BlockSpec(
-            (None, bs, hk), lambda s, j, tbl, ln: (tbl[s, j], 0, 0))
+            (None, None, bs, hk),
+            lambda s, j, tbl, ln, ly: (ly[0], tbl[s, j], 0, 0))
         in_specs += [scale_spec, scale_spec]
         operands += [k_scale.astype(jnp.float32),
                      v_scale.astype(jnp.float32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(slots, mb),
         in_specs=in_specs,
         out_specs=q_spec,
-        scratch_shapes=[
-            _vmem_scratch((group, hk, d)),
-            _vmem_scratch((group, hk, 1)),
-            _vmem_scratch((group, hk, 1)),
-        ],
+        scratch_shapes=[_vmem_scratch((group, width))] * 3,
     )
     kernel = functools.partial(
-        _paged_kernel, block_size=bs, num_blocks=mb, scale=scale,
-        quantized=quantized, sink=int(sink_tokens),
+        _paged_kernel, block_size=bs, num_blocks=mb, head_dim=d,
+        scale=scale, quantized=quantized, sink=int(sink_tokens),
         window=int(window_tokens))
     out = pl.pallas_call(
         kernel,
@@ -702,5 +771,6 @@ def paged_flash_attention(q, k_pool, v_pool, block_tables, lengths, *,
         out_shape=jax.ShapeDtypeStruct(qf.shape, q.dtype),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
-      *operands)
-    return out.swapaxes(1, 2).reshape(slots, h, d)
+      jnp.asarray(layer, jnp.int32).reshape(1), *operands)
+    return out.reshape(slots, group, hk, d).swapaxes(1, 2).reshape(
+        slots, h, d)

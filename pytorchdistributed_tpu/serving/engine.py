@@ -129,16 +129,17 @@ def _leaf_name(path) -> str:
 
 
 # The paged pool's cache-collection leaves, with the offset of the block
-# axis from the END of each leaf's shape (scanned layer stacks prepend
-# dims, so the end is the stable anchor): K/V pools are
-# [..., kv_blocks, block_size, kv_heads, head_dim] (block axis ndim-4),
-# the int8 scale planes drop head_dim (ndim-3). Everything that moves
+# axis from the END of each leaf's shape (the scanned stack's pool has a
+# leading layer axis, so the end is the stable anchor): K/V pools are
+# [..., kv_blocks, block_size, kv_heads*head_dim] (one lane-dense row a
+# token), the int8 scale planes [..., kv_blocks, block_size, kv_heads];
+# the block axis is ndim-3 in both. Everything that moves
 # blocks — the compiled gather/scatter pair, the prefill-chunk merge,
 # the export/import payloads and the fleet prefix stream — keys off this
 # one table, which is how the int8 pool's scales ride every existing
 # block-transport path without a second code path.
 POOL_LEAF_AXIS = {
-    "cached_key": 4, "cached_value": 4,
+    "cached_key": 3, "cached_value": 3,
     "cached_key_scale": 3, "cached_value_scale": 3,
 }
 
@@ -150,9 +151,11 @@ def _pool_block_axis(name: str, ndim: int) -> int:
 
 #: KV wire-payload schema version (ISSUE 13): bumped when the payload's
 #: pool-leaf set or meaning changes (v2 added kv_dtype + the int8 scale
-#: planes). import_kv_blocks rejects any other version loudly — a bf16
+#: planes; v3 carries K/V rows lane-dense, ``[..., blocks, block_size,
+#: kv_heads*head_dim]``, and a scanned stack's leaves under the stack's
+#: own path). import_kv_blocks rejects any other version loudly — a bf16
 #: replica must never scatter an int8 payload's codes into its pool.
-KV_WIRE_VERSION = 2
+KV_WIRE_VERSION = 3
 
 
 @functools.partial(
@@ -262,8 +265,8 @@ def paged_slot_models(model, num_slots: int, block_size: int,
 
 def _override_paging(cache, tables, lengths):
     """Stamp the host scheduler's block tables + per-slot lengths over
-    the cache collection's counter/table leaves (every layer reads the
-    same values — leaves just broadcast up the scan axis). The device
+    the cache collection's counter/table leaves (the paged stack holds
+    one of each for all its layers, beside the embedder's). The device
     copies are write-through scratch: the engine re-stamps from host
     state on every compiled call, which is what makes prefix sharing,
     block growth and preemption pure host bookkeeping."""
@@ -524,12 +527,9 @@ def kv_block_scatter(cache, block_ids, payload):
     def put(path, leaf):
         if _leaf_name(path) not in POOL_LEAF_AXIS:
             return leaf
-        new = next(it)
         axis = _pool_block_axis(_leaf_name(path), leaf.ndim)
-        moved = jnp.moveaxis(leaf, axis, 0)
-        out = moved.at[block_ids].set(
-            jnp.moveaxis(new.astype(leaf.dtype), axis, 0))
-        return jnp.moveaxis(out, 0, axis)
+        return leaf.at[(slice(None),) * axis + (block_ids,)].set(
+            next(it).astype(leaf.dtype))
 
     return jax.tree_util.tree_map_with_path(put, cache)
 
@@ -1921,10 +1921,10 @@ class ServingEngine:
 
     def _stamp_slot_limits(self) -> None:
         """Push the host per-slot sink/window vectors into the cache's
-        ``kv_sinks``/``kv_windows`` leaves (every layer reads the same
-        row — broadcast up the scan axis, exactly like
-        _override_paging's table stamp, just host-initiated because
-        the values change on admission/release, not every tick)."""
+        ``kv_sinks``/``kv_windows`` leaves (one of each a stack, read
+        by every layer), exactly like _override_paging's table stamp,
+        just host-initiated because the values change on
+        admission/release, not every tick."""
         sinks = jnp.asarray(self._slot_sinks)
         windows = jnp.asarray(self._slot_windows)
 

@@ -870,16 +870,21 @@ SERVE_COMMITTED: dict[str, dict] = {
     },
     # Paged engine (ISSUE 7):
     # alias_bytes 270336 on the tick IS the donated block POOL
-    # ([33 blocks x 16 x 4 kv x 16] K+V bf16 x 2 layers = 270336 — the
+    # ([2 layers x 33 blocks x 16 x 64 lanes] K+V bf16 = 270336 — the
     # same-HBM pool at 4 slots x 8 pages + trash) — if it drops,
     # donation broke and every tick copies the whole pool; the prefill
-    # chunk additionally aliases the counter/table scratch (270640).
+    # chunk additionally aliases the counter/table scratch (270496).
+    # Re-captured 2026-10-01 (PR 28: the pool lane-dense and carried
+    # through the layer loop — fewer flops and temp bytes because the
+    # per-layer slice/update-slice of the stacked pool is gone; the
+    # chunk passes and aliases 144 bytes less: one position vector and
+    # one block table a stack, not one a layer).
     # Zero collectives: paging is single-chip address arithmetic, a
     # gather/scatter that partitions — an accidental collective in the
     # tick is a per-token latency bug.
     "serve_tick_paged": {
-        "flops": 1774832.0,
-        "temp_bytes": 1127144,
+        "flops": 1503924.0,
+        "temp_bytes": 857600,
         "arg_bytes": 736512,
         "alias_bytes": 270336,
         "collectives": {"all-reduce": 0, "all-gather": 0,
@@ -901,8 +906,8 @@ SERVE_COMMITTED: dict[str, dict] = {
     # draft rollout, verify and the rejection kernel are all
     # single-chip; a collective here is a per-token latency bug.
     "serve_spec_tick": {
-        "flops": 6266669.0,
-        "temp_bytes": 1787112,
+        "flops": 5722313.0,
+        "temp_bytes": 979696,
         "arg_bytes": 1472768,
         "alias_bytes": 540672,
         "collectives": {"all-reduce": 0, "all-gather": 0,
@@ -916,10 +921,10 @@ SERVE_COMMITTED: dict[str, dict] = {
                        "collective-broadcast": 0},
     },
     "serve_prefill_paged": {
-        "flops": 22420752.0,
-        "temp_bytes": 1416992,
-        "arg_bytes": 737136,
-        "alias_bytes": 270640,
+        "flops": 22134916.0,
+        "temp_bytes": 808768,
+        "arg_bytes": 736992,
+        "alias_bytes": 270496,
         "collectives": {"all-reduce": 0, "all-gather": 0,
                         "reduce-scatter": 0, "collective-permute": 0,
                         "all-to-all": 0, "ragged-all-to-all": 0,

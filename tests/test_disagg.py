@@ -160,9 +160,9 @@ def test_kv_payload_wire_roundtrip():
     rng = np.random.default_rng(0)
     leaves = [
         ("layer/cached_key", rng.standard_normal(
-            (2, 3, 8, 2, 4)).astype(np.float32)),
+            (2, 3, 8, 8)).astype(np.float32)),
         ("layer/cached_value", rng.standard_normal(
-            (2, 3, 8, 2, 4)).astype(ml_dtypes.bfloat16)),
+            (2, 3, 8, 8)).astype(ml_dtypes.bfloat16)),
     ]
     payload = KVBlockPayload(
         prompt=np.arange(17, dtype=np.int32), generated=[5, 9],
@@ -692,7 +692,7 @@ def test_subprocess_disagg_e2e():
 
 def test_subprocess_disagg_int8_e2e():
     """ISSUE 13 over the real wire: an int8-pool prefill worker hands
-    compressed blocks (codes + scale planes, wire_version 2) to an
+    compressed blocks (codes + scale planes, the engine's wire_version) to an
     int8-pool decode worker over the line-JSON subprocess transport —
     streams bitwise-equal to the colocated int8 engine's."""
     rng = np.random.default_rng(41)

@@ -143,14 +143,15 @@ def _paged_fixture(lengths, *, bs=8, heads=4, kvh=2, d=16, seed=0):
     attend = mb * bs
     k_rows = rng.normal(size=(slots, attend, kvh, d)).astype(np.float32)
     v_rows = rng.normal(size=(slots, attend, kvh, d)).astype(np.float32)
-    pool_k = np.zeros((slots * mb + 1, bs, kvh, d), np.float32)
+    # the pool's row: one token's kv heads side by side
+    pool_k = np.zeros((slots * mb + 1, bs, kvh * d), np.float32)
     pool_v = np.zeros_like(pool_k)
     tables = np.zeros((slots, mb), np.int32)
     nxt = 1
     for s in range(slots):
         for j in range(mb):
-            pool_k[nxt] = k_rows[s, j * bs:(j + 1) * bs]
-            pool_v[nxt] = v_rows[s, j * bs:(j + 1) * bs]
+            pool_k[nxt] = k_rows[s, j * bs:(j + 1) * bs].reshape(bs, -1)
+            pool_v[nxt] = v_rows[s, j * bs:(j + 1) * bs].reshape(bs, -1)
             tables[s, j] = nxt
             nxt += 1
     q = rng.normal(size=(slots, 1, heads, d)).astype(np.float32)
@@ -217,12 +218,14 @@ def test_paged_flash_matches_reference(lengths):
                                atol=2e-5, rtol=2e-5)
 
 
-def _quantize_fixture_pool(pk, pv):
+def _quantize_fixture_pool(pk, pv, d=16):
+    """int8 codes in the pool's own row shape + the [blocks, bs, kv_heads]
+    scale planes (quantized per head, as the model's write does)."""
     from pytorchdistributed_tpu.ops.quant import kv_quantize
 
-    kc, ks = kv_quantize(pk)
-    vc, vs = kv_quantize(pv)
-    return kc, ks, vc, vs
+    kc, ks = kv_quantize(pk.reshape(*pk.shape[:2], -1, d))
+    vc, vs = kv_quantize(pv.reshape(*pv.shape[:2], -1, d))
+    return kc.reshape(pk.shape), ks, vc.reshape(pv.shape), vs
 
 
 @pytest.mark.parametrize("lengths", [(5, 17, 40, 64), (1, 9, 23, 63)])
@@ -291,6 +294,67 @@ def test_paged_flash_sink_window_matches_reference():
                                  k_scale=ks, v_scale=vs, **kw)
     np.testing.assert_allclose(np.asarray(refq[:, 0]), np.asarray(gotq),
                                atol=2e-5, rtol=2e-5)
+
+
+# the kernel's lane arithmetic at the widths that matter: rows of a whole
+# number of 128-lane tiles (what the serving cells run), the `test`
+# model's 64, a head size that is no power of two, GQA groups
+LANE_CASES = {
+    "w128_mha": dict(heads=8, kvh=8, d=16),
+    "w128_gqa4": dict(heads=8, kvh=2, d=64),
+    "w256": dict(heads=4, kvh=4, d=64),
+    "test_size": dict(heads=4, kvh=4, d=16),
+    "test_size_gqa": dict(heads=4, kvh=2, d=16),
+    "d24": dict(heads=6, kvh=3, d=24),
+}
+
+
+@pytest.mark.parametrize("pool", ["float", "int8", "window", "stacked"])
+@pytest.mark.parametrize("case", LANE_CASES)
+def test_paged_flash_lane_dense_parity(case, pool):
+    """The kernel against the gather path over the lane-dense pool, at
+    every width above: float and int8 pools, sink + window, a dead slot
+    (length 0) and a full one, and one layer read out of a layer-stacked
+    pool by its index (the scanned stack's carry)."""
+    from pytorchdistributed_tpu.ops.pallas_attention import (
+        paged_flash_attention,
+    )
+
+    geo = LANE_CASES[case]
+    q, pk, pv, tbl, lens, _, _ = _paged_fixture((0, 64, 23, 9), **geo)
+    kw = {}
+    if pool == "int8":
+        pk, ks, pv, vs = _quantize_fixture_pool(pk, pv, geo["d"])
+        kw = dict(k_scale=ks, v_scale=vs)
+    if pool == "window":
+        kw = dict(sink_tokens=8, window_tokens=16)
+    ref = paged_attention(q, pk, pv, tbl, lens, **kw)[:, 0]
+    if pool == "stacked":
+        other = jnp.full_like(pk, 1e6)
+        got = paged_flash_attention(
+            q[:, 0], jnp.stack([other, pk, other]),
+            jnp.stack([other, pv, other]), tbl, lens, layer=jnp.int32(1))
+    else:
+        got = paged_flash_attention(q[:, 0], pk, pv, tbl, lens, **kw)
+    np.testing.assert_allclose(np.asarray(ref), np.asarray(got),
+                               atol=2e-5, rtol=2e-5)
+
+
+def test_paged_flash_bf16_pool_matches_reference():
+    """The serving dtype: a bf16 pool read by the kernel (float32 inside)
+    stays within bf16 output rounding of the gather path."""
+    from pytorchdistributed_tpu.ops.pallas_attention import (
+        paged_flash_attention,
+    )
+
+    q, pk, pv, tbl, lens, _, _ = _paged_fixture((5, 17, 40, 64), heads=4,
+                                                kvh=2, d=64)
+    q, pk, pv = (a.astype(jnp.bfloat16) for a in (q, pk, pv))
+    ref = paged_attention(q, pk, pv, tbl, lens)[:, 0]
+    got = paged_flash_attention(q[:, 0], pk, pv, tbl, lens)
+    np.testing.assert_allclose(np.asarray(ref, np.float32),
+                               np.asarray(got, np.float32),
+                               atol=1e-2, rtol=1e-2)
 
 
 # ---------------------------------------------------------------------------

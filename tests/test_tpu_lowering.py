@@ -23,7 +23,9 @@ not a TPU); ``compiled_kernels`` flips that default the way a chip would.
 
 from __future__ import annotations
 
+import dataclasses
 import os
+import re
 import subprocess
 import sys
 
@@ -90,7 +92,7 @@ def test_flash_fwd_bwd_lowers_for_tpu():
 def paged_args(kv_heads: int, int8: bool):
     pages = SEQ // BLOCK
     nb = SLOTS * pages + 1
-    pool = sds((nb, BLOCK, kv_heads, HEAD_DIM),
+    pool = sds((nb, BLOCK, kv_heads * HEAD_DIM),
                jnp.int8 if int8 else jnp.bfloat16)
     args = [sds((SLOTS, HEADS, HEAD_DIM)), pool, pool,
             sds((SLOTS, pages), jnp.int32), sds((SLOTS,), jnp.int32)]
@@ -110,9 +112,9 @@ PAGED_CASES = {"bf16": (HEADS, False), "int8": (HEADS, True),
 
 @pytest.mark.parametrize("case", PAGED_CASES)
 def test_paged_decode_kernel_lowers_for_tpu(case):
-    """The pool's kv_heads (12, or 4 for the GQA group) is not a multiple
-    of 8: a KV block of one head is refused at lowering, the whole-heads
-    block is taken."""
+    """A KV block is whole pool rows, ``(block_size, kv_heads*head_dim)``
+    (768 lanes, or 256 for the GQA group): the array's own minor dims,
+    which the lowering takes at any width."""
     assert MARKER in lower_for_tpu(paged, *paged_args(*PAGED_CASES[case]))
 
 
@@ -208,6 +210,87 @@ def test_engine_default_tick_lowers_the_paged_kernel(compiled_kernels):
     engine = ServingEngine(model, params, num_slots=2, block_size=16)
     assert engine.summary()["paged_attn"] == "pallas"
     assert MARKER in engine.lower_tick(platforms=TPU).as_text()
+
+
+# One layer's pool at the serve cells' geometry: gpt2-medium width, 32
+# slots of 64 blocks of 16 tokens plus the trash block (67 MB in bf16).
+POOL_SLOTS, POOL_CHUNK = 32, 128
+
+
+def serve_program_for_v5e(program: str, scan_layers: bool):
+    """The engine's jitted tick or prefill chunk, compiled for one
+    abstract v5e chip at gpt2-medium width with two layers."""
+    from jax.sharding import SingleDeviceSharding
+
+    from pytorchdistributed_tpu.serving.engine import (
+        paged_decode_tick,
+        paged_prefill_chunk,
+        paged_slot_models,
+    )
+
+    one = SingleDeviceSharding(v5e_devices(1)[0])
+    cfg = dataclasses.replace(gpt2_config("medium"), num_layers=2,
+                              scan_layers=scan_layers)
+    pages = cfg.max_seq_len // BLOCK
+    blocks = POOL_SLOTS * pages + 1
+    tick_model, chunk_model = paged_slot_models(
+        GPT2(cfg), POOL_SLOTS, BLOCK, blocks, paged_attn="pallas")
+
+    def arg(shape=(), dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    state = jax.tree.map(
+        lambda leaf: arg(leaf.shape, leaf.dtype),
+        jax.eval_shape(lambda: tick_model.init(
+            jax.random.key(0), jnp.zeros((POOL_SLOTS, 1), jnp.int32))))
+    key = jax.eval_shape(lambda: jax.random.key_data(jax.random.key(0)))
+    f32 = jnp.float32
+    if program == "tick":
+        per_slot = (POOL_SLOTS,)
+        lowered = paged_decode_tick.lower(
+            tick_model, state["params"], state["cache"],
+            arg((POOL_SLOTS, pages)), arg(per_slot), arg(per_slot),
+            arg(per_slot + key.shape, key.dtype), arg(per_slot),
+            arg(per_slot, f32), arg(per_slot), arg(per_slot, f32),
+            candidates=64)
+    else:
+        lowered = paged_prefill_chunk.lower(
+            chunk_model, state["params"], state["cache"],
+            arg((1, POOL_CHUNK)), arg(), arg((pages,)), arg(),
+            arg(key.shape, key.dtype), arg(), arg((), f32), arg(),
+            arg((), f32), candidates=64)
+    pool_elems = blocks * BLOCK * cfg.embed_dim
+    return lowered.compile(), pool_elems, cfg.num_layers
+
+
+@pytest.mark.parametrize("stack", ["scanned", "unrolled"])
+@pytest.mark.parametrize("program", ["tick", "chunk"])
+def test_serve_programs_move_nothing_pool_sized(compiled_kernels, program,
+                                                stack):
+    """A decode tick and a prefill chunk write their rows into the
+    donated KV pool in place and read it through the kernel's (or the
+    gather's) indices: the optimised v5e program holds no copy, slice,
+    update-slice or fresh buffer as large as one layer's pool, and the
+    output aliases both pools. (Before the pool was lane-dense and
+    carried through the layer loop, every layer of every tick copied its
+    67 MB of pool four times between two layouts.)"""
+    compiled, pool_elems, layers = serve_program_for_v5e(
+        program, stack == "scanned")
+    moved = []
+    for line in compiled.as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]*)\]\S* "
+                     r"([\w\-]+)\(", line)
+        # fusion bodies are lines of the same text, so a copy or a slice
+        # inside a fusion is seen too
+        if m and (m.group(2) in ("copy", "copy-start", "transpose",
+                                 "dynamic-slice", "dynamic-update-slice")
+                  or 'custom_call_target="AllocateBuffer"' in line):
+            dims = [int(d) for d in m.group(1).split(",") if d]
+            if int(np.prod(dims)) >= pool_elems:
+                moved.append(line.strip()[:160])
+    assert not moved, "\n".join(moved)
+    pools = 2 * layers * pool_elems * 2          # K and V, bf16
+    assert compiled.memory_analysis().alias_size_in_bytes >= pools
 
 
 # ---------------------------------------------------------------------------
