@@ -1,68 +1,15 @@
-"""What both drivers share: the adapter between the benchmark's weight
-layout and the program's parameter tree, and the comparison of norms.
-
-The benchmark makes the weights (`reference.make_weights`) and hands them
-to the program in the tree its model expects, as a loader of a published
-checkpoint would. This file is the only place that knows that tree.
+"""What both drivers share: the comparison of norms and the device's peak
+memory. (The adapter between the benchmark's weight layout and the
+program's parameter tree is the family's.)
 """
 
 from __future__ import annotations
 
 import statistics
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
 from benchmark import reference
-
-
-def to_program_tree(w: dict, cfg: dict, scan_layers: bool) -> dict:
-    """Benchmark layout (stacked by layer) -> `GPT2`'s `params` tree."""
-    e, l = cfg["n_embd"], cfg["n_layer"]
-    block = {
-        "attn": {"qkv_kernel": w["qkv_w"].reshape(l, e, 3, e),
-                 "qkv_bias": w["qkv_b"].reshape(l, 3, e),
-                 "out": {"kernel": w["proj_w"], "bias": w["proj_b"]}},
-        "ln1": {"scale": w["ln1_g"], "bias": w["ln1_b"]},
-        "ln2": {"scale": w["ln2_g"], "bias": w["ln2_b"]},
-        "mlp": {"wi": {"kernel": w["fc_w"], "bias": w["fc_b"]},
-                "wo": {"kernel": w["out_w"], "bias": w["out_b"]}},
-    }
-    if scan_layers:
-        h = {"block": block}
-    else:
-        h = {f"block_{i}": jax.tree.map(lambda x, i=i: x[i], block)
-             for i in range(l)}
-    return {"params": {
-        "embed": {"tok": {"embedding": w["wte"]}, "pos": w["wpe"]},
-        "h": h,
-        "ln_f": {"scale": w["lnf_g"], "bias": w["lnf_b"]}}}
-
-
-def from_program_tree(tree: dict, cfg: dict, scan_layers: bool) -> dict:
-    """The inverse, for reading gradients and changes back."""
-    e, l = cfg["n_embd"], cfg["n_layer"]
-    p = tree["params"] if "params" in tree else tree
-    if scan_layers:
-        block = p["h"]["block"]
-    else:
-        block = jax.tree.map(lambda *xs: jnp.stack(xs),
-                             *[p["h"][f"block_{i}"] for i in range(l)])
-    return {
-        "wte": p["embed"]["tok"]["embedding"], "wpe": p["embed"]["pos"],
-        "ln1_g": block["ln1"]["scale"], "ln1_b": block["ln1"]["bias"],
-        "qkv_w": block["attn"]["qkv_kernel"].reshape(l, e, 3 * e),
-        "qkv_b": block["attn"]["qkv_bias"].reshape(l, 3 * e),
-        "proj_w": block["attn"]["out"]["kernel"],
-        "proj_b": block["attn"]["out"]["bias"],
-        "ln2_g": block["ln2"]["scale"], "ln2_b": block["ln2"]["bias"],
-        "fc_w": block["mlp"]["wi"]["kernel"],
-        "fc_b": block["mlp"]["wi"]["bias"],
-        "out_w": block["mlp"]["wo"]["kernel"],
-        "out_b": block["mlp"]["wo"]["bias"],
-        "lnf_g": p["ln_f"]["scale"], "lnf_b": p["ln_f"]["bias"],
-    }
 
 
 def flat_norms(norms: dict) -> dict:

@@ -25,10 +25,6 @@ class ServeSystem:
     one of these (the knee sweep does)."""
 
     def __init__(self, cell, devices, seed: int, phases=None):
-        from pytorchdistributed_tpu.models import GPT2
-        from pytorchdistributed_tpu.models.transformer import (
-            TransformerConfig,
-        )
         from pytorchdistributed_tpu.serving import (
             ReplicaRouter,
             ServingEngine,
@@ -36,28 +32,21 @@ class ServeSystem:
 
         cfg, mix = cell.config, cell.mix
         self.cfg, self.mix, self.devices = cfg, mix, devices
-        pcfg = TransformerConfig(
-            vocab_size=cfg["vocab_size"], num_layers=cfg["n_layer"],
-            embed_dim=cfg["n_embd"], num_heads=cfg["n_head"],
-            mlp_dim=cfg.get("n_inner"), max_seq_len=cfg["n_positions"],
-            causal=True, norm_eps=cfg["layer_norm_epsilon"],
-            # "none" in every cell; the control switches the program's
-            # own int8 path on (`--set quant='"int8_fwd"'`)
-            quant=mix.get("quant", "none"))
-        model = GPT2(pcfg)
-        make = jax.jit(lambda s: common.to_program_tree(
-            reference.make_weights(cfg, s), cfg, True))
-        params = make(reference.seed_u32(seed))
+        fam = cell.family
+        model = fam.program_model(cfg, mix)
+        self.make_params = jax.jit(lambda s: fam.to_program_tree(
+            fam.make_weights(cfg, s), cfg, mix))
+        params = self.make_params(reference.seed_u32(seed))
         jax.block_until_ready(params)
         if phases:
             phases.mark("weights")
-        eng = mix["engine"]
         self.engines = []
 
         def factory():
-            self.engines.append(ServingEngine(
-                model, params, num_slots=int(eng["num_slots"]),
-                block_size=int(eng["block_size"])))
+            # every key of the mix's `engine` by name: a mix sizes the
+            # slots, the blocks and the pool
+            self.engines.append(ServingEngine(model, params,
+                                              **mix["engine"]))
             return self.engines[-1]
 
         self.router = ReplicaRouter(factories=[factory])
@@ -129,6 +118,9 @@ def offer(system: ServeSystem, trace: list, seconds: float, *,
             if phases:
                 phases.window_start(t0)
         if now >= t1:
+            # what fell due during the window's last step is sent, late,
+            # and waits: it is backlog, not a request that failed
+            submit_due(t1)
             break
         if tracer is not None and in_window:
             tracer.poll(now - t0, trace_at)
@@ -198,8 +190,7 @@ def served_gaps(cell, devices, seed: int, sample: list) -> dict:
     the denominator) and the share of tokens that are not the
     reference's first choice; and what the int8 reference control would
     read on the same prompts and tokens (`control_*`)."""
-    ref = reference.ServeReference(cell.config, devices,
-                                   cell.config["n_positions"])
+    ref = reference.ServeReference(cell.family, cell.config, devices)
     ref.load(seed)
     got_g, ctl_g = [], []
     for r in sample:

@@ -35,10 +35,6 @@ class TrainSystem:
     def __init__(self, cell, devices, fault: str | None = None):
         import optax
 
-        from pytorchdistributed_tpu.models import GPT2
-        from pytorchdistributed_tpu.models.transformer import (
-            TransformerConfig,
-        )
         from pytorchdistributed_tpu.runtime.mesh import create_mesh
         from pytorchdistributed_tpu.training import (
             Trainer,
@@ -46,28 +42,18 @@ class TrainSystem:
         )
 
         cfg, mix = cell.config, cell.mix
+        self.family = cell.family
         self.cfg, self.mix, self.devices = cfg, mix, devices
-        self.scan = bool(mix["scan_layers"])
         self.rows = int(mix["rows_per_chip"]) * len(devices)
         self.seq = int(mix["seq_len"])
         self.tokens_per_step = self.rows * self.seq
         self.fault = fault
-        pcfg = TransformerConfig(
-            vocab_size=cfg["vocab_size"], num_layers=cfg["n_layer"],
-            embed_dim=cfg["n_embd"], num_heads=cfg["n_head"],
-            mlp_dim=cfg.get("n_inner"), max_seq_len=cfg["n_positions"],
-            causal=True, norm_eps=cfg["layer_norm_epsilon"],
-            attention=mix["attention"], remat=bool(mix["remat"]),
-            remat_policy=mix.get("remat_policy", "dots"),
-            scan_layers=self.scan,
-            # "none" in every cell; the control switches the program's
-            # own int8 path on (`--set quant='"int8"'`)
-            quant=mix.get("quant", "none"))
         hp = adam_hp(mix)
         opt = optax.adamw(hp["lr"], b1=hp["b1"], b2=hp["b2"],
                           eps=hp["eps"], weight_decay=hp["weight_decay"])
         self.trainer = Trainer(
-            GPT2(pcfg), opt, token_cross_entropy_loss,
+            self.family.program_model(cfg, mix), opt,
+            token_cross_entropy_loss,
             mesh=create_mesh(devices=devices, **mix["mesh"]),
             strategy=mix["strategy"], log_every=10 ** 9, watchdog=False)
 
@@ -78,11 +64,11 @@ class TrainSystem:
         tr = self.trainer
         tr.init(first[0])
         sh = tr.state_shardings.params
-        make = jax.jit(
-            lambda s: common.to_program_tree(
-                reference.make_weights(self.cfg, s), self.cfg, self.scan),
+        fam = self.family
+        self._make = jax.jit(
+            lambda s: fam.to_program_tree(
+                fam.make_weights(self.cfg, s), self.cfg, self.mix),
             out_shardings=sh)
-        self._make = make
         self.install(seed)
         return first
 
@@ -120,25 +106,28 @@ class TrainSystem:
         mu = next(s.mu for s in self.trainer.state.opt_state
                   if hasattr(s, "mu"))
         scale = 1.0 / (1.0 - self.mix["optimizer"]["b1"])
+        fam = self.family
 
         @jax.jit
         def norms(tree):
-            flat = common.from_program_tree(tree, self.cfg, self.scan)
+            flat = fam.from_program_tree(tree, self.cfg, self.mix)
             flat = jax.tree.map(lambda x: x * scale, flat)
-            return reference.leaf_norms(flat), reference.sketch(flat)
+            return (reference.leaf_norms(fam, flat),
+                    reference.sketch(fam, flat))
 
         with jax.set_mesh(self.trainer.mesh):
             return jax.device_get(norms(mu))
 
     def delta_norms(self, seed: int) -> dict:
         """Norms of (parameters now) - (the seed's weights)."""
+        fam = self.family
 
         @jax.jit
         def norms(tree, s):
-            now = common.from_program_tree(tree, self.cfg, self.scan)
-            was = reference.make_weights(self.cfg, s)
+            now = fam.from_program_tree(tree, self.cfg, self.mix)
+            was = fam.make_weights(self.cfg, s)
             return reference.leaf_norms(
-                jax.tree.map(jnp.subtract, now, was))
+                fam, jax.tree.map(jnp.subtract, now, was))
 
         with jax.set_mesh(self.trainer.mesh):
             return jax.device_get(norms(self.trainer.state.params,
@@ -168,7 +157,7 @@ class TrainSystem:
 def reference_run(cell, devices, seed: int, first: list, mode="f32",
                   rows=None) -> dict:
     ref = reference.TrainReference(
-        cell.config, adam_hp(cell.mix), devices, mode=mode,
+        cell.family, cell.config, adam_hp(cell.mix), devices, mode=mode,
         block_rows=int(cell.mix.get("reference_block_rows", 1)))
     return ref.run(seed, first, rows=rows)
 

@@ -1,13 +1,16 @@
 """`BENCHMARK.json` and the files it names.
 
 A cell names a configuration and a traffic mix; the harness finds
-`configs/<config>.json` (through the manifest's `file`), `traffic/<mix>.json`
-and, for each per-layer metric, `metrics/<name>.py` by those names alone.
-Nothing here knows a cell, a mix or a metric by name.
+`configs/<config>.json` (through the manifest's `file`),
+`traffic/<mix>.json`, the configuration's family
+`families/<model_type>.py`, the mix's driver `drivers/<kind>.py` and, for
+each per-layer metric, `metrics/<name>.py` by those names alone. Nothing
+here knows a cell, a mix, a model or a metric by name.
 """
 
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import pathlib
@@ -22,6 +25,22 @@ UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 def load(root: pathlib.Path = ROOT) -> dict:
     with open(root / "BENCHMARK.json") as f:
         return json.load(f)
+
+
+def _module(path: pathlib.Path):
+    """The Python file at `path` as a module of its own."""
+    spec = importlib.util.spec_from_file_location(
+        f"benchmark_{path.parent.name}_" + re.sub(r"\W", "_", path.stem),
+        path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def load_family(bench: pathlib.Path, model_type: str):
+    """`families/<model_type>.py`: all that knows this kind of model
+    (README, "a family")."""
+    return _module(bench / "families" / f"{model_type}.py")
 
 
 def _applies(metric: dict, cell: str) -> bool:
@@ -52,16 +71,21 @@ class Cell:
                            if _applies(m, name)]
         self.per_layer = [m for m in manifest["per_layer"]
                           if _applies(m, name)]
-        self.metrics_dir = bench / "metrics"
+        self.bench = bench
 
     def reader(self, metric_name: str):
         """The `read(ctx)` of `metrics/<name>.py`."""
-        path = self.metrics_dir / f"{metric_name}.py"
-        spec = importlib.util.spec_from_file_location(
-            "benchmark_metric_" + re.sub(r"\W", "_", metric_name), path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod.read
+        return _module(self.bench / "metrics" / f"{metric_name}.py").read
+
+    @functools.cached_property
+    def family(self):
+        return load_family(self.bench, self.config["model_type"])
+
+    @functools.cached_property
+    def driver(self):
+        """`drivers/<kind>.py` of the mix: its `run(cell, devices, args,
+        phases, fault)` is the run after the look for a chip."""
+        return _module(self.bench / "drivers" / f"{self.mix['kind']}.py")
 
 
 def problems(manifest: dict, root: pathlib.Path = ROOT) -> list[str]:
@@ -116,6 +140,13 @@ def problems(manifest: dict, root: pathlib.Path = ROOT) -> list[str]:
             bad.append(f"{c}: needs setup_s and one more end-to-end metric")
         if not any(_applies(m, c) for m in manifest["per_layer"]):
             bad.append(f"{c}: no per-layer metric")
+    bench = root / manifest["paths"][0]
+    for c in manifest["configs"]:
+        with open(root / c["file"]) as f:
+            kind = json.load(f).get("model_type")
+        if not (bench / "families" / f"{kind}.py").exists():
+            bad.append(f"{c['name']}: model_type {kind!r} has no "
+                       f"families/{kind}.py")
     four = sum(1 for w in cells.values() if w["chips"] == 4)
     if four > max(1, len(cells) // 4):
         bad.append(f"{four} four-chip cells of {len(cells)}")

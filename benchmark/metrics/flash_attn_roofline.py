@@ -3,8 +3,6 @@ FLOPs forward and backward of the traced window's steps, counted from
 shapes, over peak, over the device time of the operations named after
 the attention scope (one chip's share of both)."""
 
-from benchmark import counts
-
 
 def read(ctx):
     runs = ctx.trace.program_runs(ctx.mix["programs"]["step"])
@@ -12,6 +10,6 @@ def read(ctx):
     if not runs or seconds == 0:
         return None
     flops = (len(runs) * ctx.rows / ctx.chips
-             * counts.train_attention_flops_per_seq(ctx.config,
+             * ctx.family.train_attention_flops_per_seq(ctx.config,
                                                     ctx.seq_len))
     return 100.0 * flops / ctx.peaks.bf16_flops / seconds

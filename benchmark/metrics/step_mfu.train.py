@@ -2,8 +2,6 @@
 traced window times tokens a step times FLOPs a trained token (counted
 from shapes, recomputation not counted), over window x chips x peak."""
 
-from benchmark import counts
-
 
 def read(ctx):
     runs = ctx.trace.program_runs(ctx.mix["programs"]["step"])
@@ -12,5 +10,5 @@ def read(ctx):
     # from the first step's start to the last one's end: whole steps
     span = (max(r.end for r in runs) - min(r.start for r in runs)) / 1e9
     flops = (len(runs) * ctx.tokens_per_step
-             * counts.train_flops_per_token(ctx.config, ctx.seq_len))
+             * ctx.family.train_flops_per_token(ctx.config, ctx.seq_len))
     return 100.0 * flops / span / (ctx.chips * ctx.peaks.bf16_flops)

@@ -1,13 +1,14 @@
-"""The plain reference: GPT-2 as published, in straightforward `jax.numpy`
-and float32, with no kernel, no cache and no batching tricks.
+"""The plain reference: a family's model as published, in straightforward
+`jax.numpy` and float32, with no kernel, no cache and no batching tricks.
 
-It imports nothing of the program and takes nothing the program made: the
-weights come from `make_weights` and the seed, the same call the driver
-uses to make the weights it hands to the program. Pre-LN blocks, learned
-positions, fused q/k/v projection whose columns are [q | k | v], heads
-split contiguously, softmax(q k^T / sqrt(d)) with a causal mask, tanh
-GELU, tied output embedding, mean token cross-entropy, and AdamW as
-published (decoupled weight decay, bias-corrected moments).
+It imports nothing of the program and takes nothing the program made. The
+model itself (its leaves' shapes, its weights from the seed, its forward
+pass) is the family's (`families/<model_type>.py`: `shapes`,
+`make_weights`, `forward`); `make_weights` is the same call the driver
+uses to make the weights it hands to the program. Here are what every
+family shares: the three precisions, mean token cross-entropy, AdamW as
+published (decoupled weight decay, bias-corrected moments), the norms
+and sketches the comparison reads, and the gaps of served tokens.
 
 `mode` sets the precision: "f32" is the reference (float32 throughout,
 matmuls at `highest`, which on a TPU is what makes a float32 matmul
@@ -17,11 +18,13 @@ operations, float32 accumulation, norms and softmax worked out in
 float32. "int8" is the control, the step below bf16 that a later PR would
 be tempted by: the bf16 computation with the operands of every linear
 layer and of the head rounded to symmetric int8 first (per row of the
-activations, per column of the weights, straight-through backward).
+activations, per column of the weights, straight-through backward). A
+family's `forward` does its matmuls through `mm` and keeps its
+activations in `act(mode)`, so the control means the same step in each.
 
-Memory: layers run under `lax.scan` with `jax.checkpoint`, and a step
-walks the batch in blocks of rows, so the float32 activations of one
-block of one layer are all that lives at once. On several chips every
+Memory: a family's layers run under `lax.scan` with `jax.checkpoint`, and
+a step walks the batch in blocks of rows, so the float32 activations of
+one block of one layer are all that lives at once. On several chips every
 leaf is split along its last axis that the chip count divides and the
 compiler inserts the exchange.
 """
@@ -36,39 +39,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 HIGHEST = jax.lax.Precision.HIGHEST
-STACKED = ("ln1_g", "ln1_b", "qkv_w", "qkv_b", "proj_w", "proj_b",
-           "ln2_g", "ln2_b", "fc_w", "fc_b", "out_w", "out_b")
-
-
-def shapes(cfg: dict) -> dict:
-    e, l, v = cfg["n_embd"], cfg["n_layer"], cfg["vocab_size"]
-    f = int(cfg.get("n_inner") or 4 * e)
-    return {
-        "wte": (v, e), "wpe": (cfg["n_positions"], e),
-        "ln1_g": (l, e), "ln1_b": (l, e),
-        "qkv_w": (l, e, 3 * e), "qkv_b": (l, 3 * e),
-        "proj_w": (l, e, e), "proj_b": (l, e),
-        "ln2_g": (l, e), "ln2_b": (l, e),
-        "fc_w": (l, e, f), "fc_b": (l, f),
-        "out_w": (l, f, e), "out_b": (l, e),
-        "lnf_g": (e,), "lnf_b": (e,),
-    }
-
-
-def make_weights(cfg: dict, seed) -> dict:
-    """Float32 weights from the seed: N(0, initializer_range) everywhere,
-    gains around 1. Biases and gains are random too, so every leaf has a
-    gradient and no two rows of anything are alike. `seed` is a uint32
-    (`seed_u32`), so it can be a traced argument: jit this with the
-    shardings the weights should land in."""
-    std = float(cfg.get("initializer_range", 0.02))
-    key = jax.random.key(seed)
-    out = {}
-    for i, (name, shape) in enumerate(sorted(shapes(cfg).items())):
-        w = std * jax.random.normal(jax.random.fold_in(key, i), shape,
-                                    jnp.float32)
-        out[name] = 1.0 + w if name.endswith("_g") else w
-    return out
 
 
 def seed_u32(seed: int) -> np.uint32:
@@ -86,11 +56,12 @@ def leaf_sharding(mesh: Mesh, shape) -> NamedSharding:
     return NamedSharding(mesh, P(*spec))
 
 
-def weight_shardings(cfg: dict, mesh: Mesh) -> dict:
-    return {k: leaf_sharding(mesh, s) for k, s in shapes(cfg).items()}
+def weight_shardings(family, cfg: dict, mesh: Mesh) -> dict:
+    return {k: leaf_sharding(mesh, s)
+            for k, s in family.shapes(cfg).items()}
 
 
-def _fake_int8(x, axis):
+def fake_int8(x, axis):
     x = x.astype(jnp.float32)
     scale = jnp.max(jnp.abs(x), axis=axis, keepdims=True) / 127.0
     scale = jnp.where(scale == 0, 1.0, scale)
@@ -98,104 +69,46 @@ def _fake_int8(x, axis):
     return x + jax.lax.stop_gradient(q - x)
 
 
-def _act(mode: str):
+def act(mode: str):
     """The type activations are kept in between operations."""
     return jnp.float32 if mode == "f32" else jnp.bfloat16
 
 
-def _mm(x, w, mode: str):
+def mm(x, w, mode: str):
     """A linear layer's matmul; the result is in the activations' type
-    (the head's caller asks for float32 by `_mm(...).astype`)."""
+    (the head's caller asks for float32 by `mm(...).astype`)."""
     if mode == "f32":
         return jnp.matmul(x, w, precision=HIGHEST)
     if mode == "int8":
-        x, w = _fake_int8(x, -1), _fake_int8(w, 0)
+        x, w = fake_int8(x, -1), fake_int8(w, 0)
     return jnp.matmul(x.astype(jnp.bfloat16), w.astype(jnp.bfloat16),
                       preferred_element_type=jnp.float32)
 
 
-def _ln(x, g, b, eps, mode):
-    x = x.astype(jnp.float32)
-    mu = x.mean(-1, keepdims=True)
-    var = ((x - mu) ** 2).mean(-1, keepdims=True)
-    return ((x - mu) * jax.lax.rsqrt(var + eps) * g + b).astype(_act(mode))
-
-
-def _gelu(x):
-    return 0.5 * x * (1 + jnp.tanh(
-        np.sqrt(2 / np.pi) * (x + 0.044715 * x ** 3)))
-
-
-def _block(cfg, mode, x, lp):
-    b, s, e = x.shape
-    h = cfg["n_head"]
-    d = e // h
-    act = _act(mode)
-    eps = cfg["layer_norm_epsilon"]
-    y = _ln(x, lp["ln1_g"], lp["ln1_b"], eps, mode)
-    qkv = (_mm(y, lp["qkv_w"], mode) + lp["qkv_b"]).astype(act)
-    q, k, v = (t.reshape(b, s, h, d) for t in jnp.split(qkv, 3, axis=-1))
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k, precision=HIGHEST,
-                        preferred_element_type=jnp.float32) / np.sqrt(d)
-    mask = jnp.tril(jnp.ones((s, s), bool))
-    scores = jnp.where(mask, scores, -jnp.inf)
-    p = jax.nn.softmax(scores, axis=-1).astype(act)
-    ctx = jnp.einsum("bhqk,bkhd->bqhd", p, v, precision=HIGHEST,
-                     preferred_element_type=jnp.float32)
-    ctx = ctx.astype(act).reshape(b, s, e)
-    x = (x + _mm(ctx, lp["proj_w"], mode) + lp["proj_b"]).astype(act)
-    y = _ln(x, lp["ln2_g"], lp["ln2_b"], eps, mode)
-    y = _gelu((_mm(y, lp["fc_w"], mode) + lp["fc_b"]).astype(act))
-    return (x + _mm(y, lp["out_w"], mode) + lp["out_b"]).astype(act)
-
-
-def forward(cfg: dict, params: dict, tokens, mode: str = "f32"):
-    """Logits [b, s, vocab] in float32."""
-    s = tokens.shape[1]
-    x = (params["wte"][tokens] + params["wpe"][:s]).astype(_act(mode))
-    stacked = {k: params[k] for k in STACKED}
-
-    @jax.checkpoint
-    def body(x, lp):
-        return _block(cfg, mode, x, lp), None
-
-    x, _ = jax.lax.scan(body, x, stacked)
-    x = _ln(x, params["lnf_g"], params["lnf_b"],
-            cfg["layer_norm_epsilon"], mode)
-    return _mm(x, params["wte"].T, mode).astype(jnp.float32)
-
-
-def _ce_sum(cfg, mode, params, tokens, targets):
-    logits = forward(cfg, params, tokens, mode)
+def _ce_sum(family, cfg, mode, params, tokens, targets):
+    logits = family.forward(cfg, params, tokens, mode)
     lse = jax.nn.logsumexp(logits, axis=-1)
     picked = jnp.take_along_axis(logits, targets[..., None], -1)[..., 0]
     return (lse - picked).sum()
 
 
-def leaf_norms(tree: dict) -> dict:
-    """L2 norm of every leaf; of a stacked leaf, one norm per layer. The
-    fused q/k/v projection counts as three leaves: a key's bias has no
-    gradient under softmax, and inside one fused leaf it would hide."""
+def leaf_norms(family, tree: dict) -> dict:
+    """L2 norm of every leaf the family compares
+    (`family.compared_leaves`: a fused projection may count as several);
+    of a leaf stacked by layer, one norm per layer."""
+    leaves, stacked = family.compared_leaves(tree)
     out = {}
-
-    def norm(v, stacked):
+    for k, v in leaves.items():
         v = v.astype(jnp.float32)
-        axes = tuple(range(1, v.ndim)) if stacked else None
-        return jnp.sqrt((v * v).sum(axes))
-
-    for k, v in tree.items():
-        if k in ("qkv_w", "qkv_b"):
-            for part, t in zip("qkv", jnp.split(v, 3, axis=-1)):
-                out[f"{part}_{k[4:]}"] = norm(t, True)
-        else:
-            out[k] = norm(v, k in STACKED)
+        axes = tuple(range(1, v.ndim)) if k in stacked else None
+        out[k] = jnp.sqrt((v * v).sum(axes))
     return out
 
 
 SKETCH_PROBES = 4
 
 
-def sketch(tree: dict) -> dict:
+def sketch(family, tree: dict) -> dict:
     """Every leaf (every layer of a stacked one) projected on a few fixed
     +-1 vectors. The norm of the difference of two sketches estimates the
     norm of the difference of the two trees without both having to be
@@ -205,7 +118,7 @@ def sketch(tree: dict) -> dict:
     base = jax.random.key(20260930)
     for i, (k, v) in enumerate(sorted(tree.items())):
         v = v.astype(jnp.float32)
-        axes = tuple(range(1, v.ndim)) if k in STACKED else None
+        axes = tuple(range(1, v.ndim)) if k in family.STACKED else None
         rows = []
         for p in range(SKETCH_PROBES):
             key = jax.random.fold_in(jax.random.fold_in(base, i), p)
@@ -228,29 +141,31 @@ def sketch_gap(got: dict, ref: dict) -> float:
 class TrainReference:
     """Three AdamW steps of the reference, in blocks of rows."""
 
-    def __init__(self, cfg: dict, hp: dict, devices, mode: str = "f32",
-                 block_rows: int = 1):
+    def __init__(self, family, cfg: dict, hp: dict, devices,
+                 mode: str = "f32", block_rows: int = 1):
         self.cfg, self.hp, self.mode = cfg, hp, mode
         self.block_rows = block_rows
         self.mesh = Mesh(np.asarray(devices), ("x",))
-        self.shardings = weight_shardings(cfg, self.mesh)
+        self.shardings = weight_shardings(family, cfg, self.mesh)
         rep = NamedSharding(self.mesh, P())
         sh = self.shardings
-        self._make = jax.jit(functools.partial(make_weights, cfg),
+        self._make = jax.jit(functools.partial(family.make_weights, cfg),
                              out_shardings=sh)
         self._zeros = jax.jit(
             lambda p: jax.tree.map(jnp.zeros_like, p), out_shardings=sh)
         self._grad = jax.jit(
-            jax.value_and_grad(functools.partial(_ce_sum, cfg, mode)),
+            jax.value_and_grad(
+                functools.partial(_ce_sum, family, cfg, mode)),
             in_shardings=(sh, rep, rep), out_shardings=(rep, sh))
         self._add = jax.jit(
             lambda a, b: jax.tree.map(jnp.add, a, b), donate_argnums=(0,),
             out_shardings=sh)
         self._adam = jax.jit(self._adam_step, donate_argnums=(0, 2, 3),
                              out_shardings=(sh, sh, sh))
-        self._norms = jax.jit(lambda t: (leaf_norms(t), sketch(t)))
-        self._diff_norms = jax.jit(
-            lambda a, b: leaf_norms(jax.tree.map(jnp.subtract, a, b)))
+        self._norms = jax.jit(
+            lambda t: (leaf_norms(family, t), sketch(family, t)))
+        self._diff_norms = jax.jit(lambda a, b: leaf_norms(
+            family, jax.tree.map(jnp.subtract, a, b)))
 
     def _adam_step(self, params, grads, mu, nu, step, n_tokens):
         hp = self.hp
@@ -302,11 +217,12 @@ class ServeReference:
     """One teacher-forced forward over prompt + served tokens, padded to
     one shape so that it compiles once."""
 
-    def __init__(self, cfg: dict, devices, pad_to: int):
-        self.cfg, self.pad_to = cfg, pad_to
+    def __init__(self, family, cfg: dict, devices):
+        self.family, self.cfg = family, cfg
+        self.pad_to = family.positions(cfg)
         self.mesh = Mesh(np.asarray(devices[:1]), ("x",))
-        sh = weight_shardings(cfg, self.mesh)
-        self._make = jax.jit(functools.partial(make_weights, cfg),
+        sh = weight_shardings(family, cfg, self.mesh)
+        self._make = jax.jit(functools.partial(family.make_weights, cfg),
                              out_shardings=sh)
         self._gaps = jax.jit(self._gaps_fn)
         self.params = None
@@ -319,8 +235,8 @@ class ServeReference:
         logit lies below the reference's best, and the same for the token
         the int8 control puts first. `served[i]` is the token emitted
         after position i (or -1 where none was)."""
-        ref = forward(self.cfg, params, seq[None], "f32")[0]
-        low = forward(self.cfg, params, seq[None], "int8")[0]
+        ref = self.family.forward(self.cfg, params, seq[None], "f32")[0]
+        low = self.family.forward(self.cfg, params, seq[None], "int8")[0]
         best = ref.max(-1)
         pick = jnp.take_along_axis(
             ref, jnp.maximum(served, 0)[:, None], -1)[:, 0]

@@ -147,16 +147,10 @@ def drive(cell, devices, pk, args, phases, fault=None,
           all_devices=None) -> dict:
     """Everything of a run after the look for a chip: the driver, the
     per-layer readers, the log line and the result line."""
-    if cell.mix["kind"] == "train":
-        from benchmark.drivers import train as driver
-    elif cell.mix["kind"] == "serve_open_loop":
-        from benchmark.drivers import serve as driver
-    else:
-        raise ValueError(f"unknown kind of mix {cell.mix['kind']!r}")
     phases.watch_compiles()
     args.tracer = (Tracer(ROOT / "benchmark" / ".trace" / cell.name)
                    if args.trace else None)
-    out = driver.run(cell, devices, args, phases, fault=fault)
+    out = cell.driver.run(cell, devices, args, phases, fault=fault)
     t0, t1 = out["t0"], out["t1"]
     setup_s = phases.t_window - _PROCESS_START
     in_window = sum(1 for t in phases.compile_times if t0 <= t < t1)
@@ -174,7 +168,8 @@ def drive(cell, devices, pk, args, phases, fault=None,
         trace = xtrace.Trace.from_dir(str(args.tracer.dir))
         # what a per-layer reader may read
         ctx = types.SimpleNamespace(
-            cell=cell, config=cell.config, mix=cell.mix, peaks=pk,
+            cell=cell, config=cell.config, mix=cell.mix,
+            family=cell.family, peaks=pk,
             chips=len(devices), seconds=args.seconds, t0=t0, t1=t1,
             trace=trace, trace_span=args.tracer.span, e2e=e2e,
             compiles_in_window=in_window,

@@ -8,12 +8,11 @@ input attended n + j positions.
 
 from __future__ import annotations
 
-from benchmark import counts
-
 
 def served_flops(ctx, lo: float, hi: float) -> float:
     """Forward FLOPs of every token delivered in [lo, hi) on the host's
     clock; a prompt counts when its first token is delivered."""
+    counts = ctx.family
     total = 0.0
     for r in ctx.records:
         for j, t in enumerate(r.token_times):
@@ -34,6 +33,7 @@ def decode_work(ctx):
     if not runs or ctx.trace_span is None:
         return None
     lo, hi = ctx.trace_span
+    counts = ctx.family
     kv = counts.kv_bytes_per_position(ctx.config)
     kv_bytes = flops = 0.0
     for r in ctx.records:

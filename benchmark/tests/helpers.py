@@ -1,8 +1,10 @@
 """What the tests share: a temporary copy of the benchmark with a tiny
-configuration, two tiny mixes, their cells and a dummy per-layer metric
-added as NEW files and entries, nothing that is there edited. That the
-harness runs them is itself the proof that a later PR can add a cell, a
-mix, a configuration and a metric as data alone."""
+configuration, two tiny mixes, their cells, a dummy per-layer metric and
+a second family of models that is not GPT-2-shaped (`toy_rope`: its
+family file, a configuration, a train and a serve mix, two cells) added
+as NEW files and entries, nothing that is there edited. That the harness
+runs them is itself the proof that a later PR can add a cell, a mix, a
+configuration, a metric and a kind of model without an edit."""
 
 from __future__ import annotations
 
@@ -59,6 +61,22 @@ TOY_SERVE = dict(
                    "min": 16, "max": 64},
     limits={"served_mean_gap": 6e-5})
 
+# the second family: the program's RoPE / RMSNorm / SwiGLU / no-bias /
+# untied-head dialect with grouped-query heads, wide enough that its
+# serve cell's int8 control reads apart too
+TOY_ROPE_CONFIG = {
+    "model_type": "toy_rope", "hidden_act": "silu",
+    "initializer_range": 0.02, "rms_norm_eps": 1e-05,
+    "rope_theta": 10000.0, "max_position_embeddings": 256,
+    "vocab_size": 8192, "hidden_size": 256, "intermediate_size": 512,
+    "num_attention_heads": 4, "num_key_value_heads": 2,
+    "num_hidden_layers": 6, "tie_word_embeddings": False,
+    "source": "a toy for the CPU tests; no published model"}
+TOY_ROPE_TRAIN = dict(TINY_TRAIN, limits={
+    "loss2_gap": 1e-3, "loss3_gap": 1e-3, "grad_gap": 0.05,
+    "grad_diff": 0.025, "delta_gap": 0.05})
+TOY_ROPE_SERVE = dict(TOY_SERVE, limits={"served_mean_gap": 3.8e-4})
+
 DUMMY_READER = '''"""A dummy per-layer metric: steps or requests attempted."""
 
 
@@ -85,6 +103,14 @@ def temp_benchmark(tmp: pathlib.Path, fsdp4: bool = False) -> pathlib.Path:
         json.dumps(TOY_SERVE_CONFIG))
     (b / "traffic" / "toy-serve.json").write_text(json.dumps(TOY_SERVE))
     (b / "metrics" / "dummy_count.py").write_text(DUMMY_READER)
+    shutil.copy(ROOT / "benchmark" / "tests" / "data" / "toy_rope.py",
+                b / "families" / "toy_rope.py")
+    (b / "configs" / "toy-rope.json").write_text(
+        json.dumps(TOY_ROPE_CONFIG))
+    (b / "traffic" / "toy-rope-train.json").write_text(
+        json.dumps(TOY_ROPE_TRAIN))
+    (b / "traffic" / "toy-rope-serve.json").write_text(
+        json.dumps(TOY_ROPE_SERVE))
     m = json.loads((tmp / "BENCHMARK.json").read_text())
     m["configs"].append({
         "name": "tiny", "source": "none: a toy for the CPU tests",
@@ -94,9 +120,17 @@ def temp_benchmark(tmp: pathlib.Path, fsdp4: bool = False) -> pathlib.Path:
         "name": "toy-serve", "source": "none: a toy for the CPU tests",
         "file": "benchmark/configs/toy-serve.json", "reduced": [],
         "why": "a toy"})
+    m["configs"].append({
+        "name": "toy-rope", "source": "none: a toy for the CPU tests",
+        "file": "benchmark/configs/toy-rope.json", "reduced": [],
+        "why": "a toy of another family"})
     m["workloads"].append({"name": "toy-serve", "config": "toy-serve",
                            "traffic": "toy-serve", "chips": 1,
                            "why": "toy"})
+    for cell in ("toy-rope-train", "toy-rope-serve"):
+        m["workloads"].append({"name": cell, "config": "toy-rope",
+                               "traffic": cell, "chips": 1,
+                               "why": "toy"})
     cells = [("tiny-train", 1), ("tiny-serve", 1)]
     if fsdp4:  # a second four-chip cell: only the tests of `correct` ask
         cells.append(("tiny-fsdp4", 4))
@@ -108,14 +142,17 @@ def temp_benchmark(tmp: pathlib.Path, fsdp4: bool = False) -> pathlib.Path:
         if metric["name"] == "train_tokens_per_s":
             metric["workloads"] += [c for c, _ in cells
                                     if c != "tiny-serve"]
+            metric["workloads"].append("toy-rope-train")
         if metric["name"] in ("itl_p95_ms",
                               "serve_tokens_per_s"):
-            metric["workloads"] += ["tiny-serve", "toy-serve"]
+            metric["workloads"] += ["tiny-serve", "toy-serve",
+                                    "toy-rope-serve"]
     m["per_layer"].append({
         "name": "dummy_count", "unit": "count", "better": "higher",
         "source": "program_counter", "layer": "entry",
         "moves": "setup_s",
-        "workloads": [c for c, _ in cells] + ["toy-serve"]})
+        "workloads": [c for c, _ in cells] + [
+            "toy-serve", "toy-rope-train", "toy-rope-serve"]})
     (tmp / "BENCHMARK.json").write_text(json.dumps(m))
     for p, data in before.items():
         assert p.read_bytes() == data, f"{p} was edited"

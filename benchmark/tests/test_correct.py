@@ -44,9 +44,14 @@ def drive(root, name, fault=None, seed=2 ** 31 + 11, seconds=1.0):
     ("tiny-train", "train_tokens_per_s"),
     ("tiny-fsdp4", "train_tokens_per_s"),
     ("tiny-serve", "serve_tokens_per_s"),
+    # the second family, found by its configuration's `model_type`
+    ("toy-rope-train", "train_tokens_per_s"),
+    ("toy-rope-serve", "serve_tokens_per_s"),
 ])
 def test_added_cells_run_and_compare_correct(root, name, metric):
-    line = drive(root, name)
+    # toy-rope-serve: the seed and the length its limit was read at
+    line = drive(root, name, **({"seed": 7, "seconds": 3.0}
+                                if name == "toy-rope-serve" else {}))
     assert line["correct"] is True, line["compared"]
     assert line["failed"] == 0 and line["attempted"] > 0
     assert line["metrics"][metric]["value"] > 0
@@ -64,6 +69,9 @@ def test_added_cells_run_and_compare_correct(root, name, metric):
     ("tiny-fsdp4", "no_exchange", "grad_gap"),
     # a token altered where it is produced
     ("tiny-serve", "token_altered", "served_mean_gap"),
+    # the same two faults under the second family
+    ("toy-rope-train", "half_batch", "grad_gap"),
+    ("toy-rope-serve", "token_altered", "served_mean_gap"),
 ])
 def test_a_broken_timed_path_is_not_correct(root, name, fault, caught_by):
     line = drive(root, name, fault=fault)
@@ -72,34 +80,37 @@ def test_a_broken_timed_path_is_not_correct(root, name, fault, caught_by):
     assert c["value"] > c["limit"], line["compared"]
 
 
-def test_the_serve_control_is_not_correct(root):
+@pytest.mark.parametrize("name,seed", [("toy-serve", 5),
+                                       ("toy-rope-serve", 7)])
+def test_the_serve_control_is_not_correct(root, name, seed):
     """The engine with the program's own int8 path switched on
     (`quant="int8_fwd"`) serves tokens that lie further below the
     reference's best than the limit allows; as the cell states it
     (bf16) the same run is correct."""
     m = manifest.load(root)
-    sound = drive(root, "toy-serve", seed=5, seconds=3.0)
+    sound = drive(root, name, seed=seed, seconds=3.0)
     assert sound["correct"] is True, sound["compared"]
-    cell = manifest.Cell(m, "toy-serve", root)
+    cell = manifest.Cell(m, name, root)
     cell.mix["quant"] = "int8_fwd"
     line = run.drive(cell, jax.devices()[:1], PEAKS,
-                        helpers.run_args(seed=5, seconds=3.0),
-                        run.Phases())
+                     helpers.run_args(seed=seed, seconds=3.0),
+                     run.Phases())
     assert line["correct"] is False
     c = line["compared"]["served_mean_gap"]
     assert c["value"] > c["limit"] > \
         sound["compared"]["served_mean_gap"]["value"]
 
 
-def test_the_control_is_not_correct(root):
+@pytest.mark.parametrize("name", ["tiny-train", "toy-rope-train"])
+def test_the_control_is_not_correct(root, name):
     """The program with its own int8 path switched on (`quant="int8"`:
     int8 matmuls forward and backward), through the same harness: the
     step below bf16 that a later PR would be tempted by has to fail."""
-    cell = manifest.Cell(manifest.load(root), "tiny-train", root)
+    cell = manifest.Cell(manifest.load(root), name, root)
     cell.mix["quant"] = "int8"
     line = run.drive(cell, jax.devices()[:1], PEAKS,
-                        helpers.run_args(seed=2 ** 31 + 11),
-                        run.Phases())
+                     helpers.run_args(seed=2 ** 31 + 11),
+                     run.Phases())
     assert line["correct"] is False
     c = line["compared"]["grad_diff"]
     assert c["value"] > c["limit"], line["compared"]

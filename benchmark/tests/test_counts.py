@@ -1,13 +1,15 @@
-"""The FLOP and byte counts against hand-worked values, and the peaks."""
+"""The FLOP and byte counts of the `gpt2` family against hand-worked
+values, and the peaks."""
 
 import json
 import pathlib
 
 import pytest
 
-from benchmark import counts, peaks
+from benchmark import manifest, peaks
 
 CONFIGS = pathlib.Path(__file__).resolve().parents[1] / "configs"
+counts = manifest.load_family(manifest.BENCH_DIR, "gpt2")
 
 
 def cfg(name):

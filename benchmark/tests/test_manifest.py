@@ -1,7 +1,11 @@
 """`BENCHMARK.json` as committed, and that a later PR can add to it with
 new files and entries alone."""
 
+import json
+import math
 import re
+
+import pytest
 
 from benchmark import manifest
 
@@ -60,12 +64,28 @@ def test_mfu_and_roofline_names():
             assert mfus, n
 
 
-def test_a_later_pr_adds_files_and_entries_alone(tmp_path):
+@pytest.mark.parametrize("name,model_type,kind", [
+    ("tiny-train", "gpt2", "train"),
+    # a family the benchmark did not have, from files of its own
+    ("toy-rope-train", "toy_rope", "train"),
+    ("toy-rope-serve", "toy_rope", "serve_open_loop"),
+])
+def test_a_later_pr_adds_files_and_entries_alone(tmp_path, name,
+                                                 model_type, kind):
+    # `temp_benchmark` itself checks that no file that was there changed
     root = helpers.temp_benchmark(tmp_path)
     m = manifest.load(root)
     assert manifest.problems(m, root) == []
-    cell = manifest.Cell(m, "tiny-train", root)
-    assert cell.config["n_embd"] == 64 and cell.mix["kind"] == "train"
+    cell = manifest.Cell(m, name, root)
+    assert cell.config["model_type"] == model_type
+    assert cell.mix["kind"] == kind
+    assert cell.family.__file__ == str(
+        root / "benchmark" / "families" / f"{model_type}.py")
+    assert callable(cell.driver.run)
+    # the family's sixteen or ten leaves, counted from its own keys
+    shapes = cell.family.shapes(cell.config)
+    assert sum(math.prod(s) for s in shapes.values()) == \
+        cell.family.total_params(cell.config)
     assert "dummy_count" in {p["name"] for p in cell.per_layer}
 
     class Ctx:
@@ -75,3 +95,12 @@ def test_a_later_pr_adds_files_and_entries_alone(tmp_path):
     # and the committed cells are still what they were
     assert manifest.Cell(m, "gpt2m-train-1chip", root).config == \
         manifest.Cell(manifest.load(), "gpt2m-train-1chip").config
+
+
+def test_a_configuration_without_a_family_is_named(tmp_path):
+    root = helpers.temp_benchmark(tmp_path)
+    cfg = root / "benchmark" / "configs" / "tiny.json"
+    cfg.write_text(json.dumps(dict(helpers.TINY_CONFIG,
+                                   model_type="nobody")))
+    bad = manifest.problems(manifest.load(root), root)
+    assert bad == ["tiny: model_type 'nobody' has no families/nobody.py"]

@@ -1,6 +1,11 @@
 """The window arithmetic: a stall injected into a fake clock moves every
 end-to-end metric."""
 
+import time
+import types
+
+import numpy as np
+
 from benchmark import window
 
 
@@ -85,3 +90,56 @@ def test_a_stall_moves_the_train_rate():
 def test_percentile_interpolates():
     assert window.percentile([1, 2, 3, 4], 50) == 2.5
     assert window.percentile([5], 95) == 5
+
+
+class _FakeRouter:
+    """Steps of a millisecond, until `stall_from`: the step that starts
+    after it runs past the window's end."""
+
+    def __init__(self):
+        self.handles = []
+        self.stall_from = float("inf")
+
+    def submit(self, prompt, max_new_tokens, on_token):
+        self.handles.append(types.SimpleNamespace(done=False))
+        return self.handles[-1]
+
+    def step(self):
+        time.sleep(0.040 if time.perf_counter() >= self.stall_from
+                   else 0.001)
+
+
+class _FakeSystem:
+    def __init__(self, mix):
+        self.mix, self.router = mix, _FakeRouter()
+        self.engine = types.SimpleNamespace(
+            reset_stats=lambda: None, active_count=0, summary=dict)
+
+    def queued(self):
+        return len(self.router.handles)
+
+    def busy(self):
+        return True
+
+
+def test_a_request_due_in_the_last_step_is_sent():
+    """The loop leaves at the first iteration after the window's end; a
+    request that fell due while the last step ran is sent then, late, and
+    is no failure of the system's."""
+    from benchmark import loadgen
+    from benchmark.drivers import serve_open_loop as drv
+
+    mix = {"ramp_s": 0.05, "first_token_timeout_s": 1.0}
+    seconds = 0.25
+    trace = [loadgen.Arrival(due, np.zeros(4, np.int32), 4, True)
+             for due in (0.05, seconds - 0.010)]
+    system = _FakeSystem(mix)
+    # the last step starts at least 20 ms before the second request is
+    # due and ends at least 10 ms after the window has closed
+    system.router.stall_from = (time.perf_counter() + mix["ramp_s"]
+                                + seconds - 0.030)
+    out = drv.offer(system, trace, seconds)
+    late = out["records"][1]
+    assert late.sent is not None and late.sent >= out["t1"]
+    assert len(system.router.handles) == 2
+    assert out["counters"]["queued_t1"] == 2

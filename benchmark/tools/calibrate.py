@@ -28,7 +28,9 @@ from benchmark import loadgen, manifest, run as bench_run  # noqa: E402
 
 
 def train(cell, devices, seeds, n_control, witness=True):
-    from benchmark.drivers import common, train as drv
+    from benchmark.drivers import common
+
+    drv = cell.driver
 
     system = drv.TrainSystem(cell, devices)
     progs, firsts = {}, {}
@@ -96,18 +98,15 @@ def _public(cmp: dict) -> dict:
 
 
 def serve(cell, devices, seeds, n_control, seconds):
-    import jax
-
     from benchmark import reference
-    from benchmark.drivers import common, serve as drv
 
+    drv = cell.driver
     system = drv.ServeSystem(cell, devices, seeds[0])
-    make = jax.jit(lambda s: common.to_program_tree(
-        reference.make_weights(cell.config, s), cell.config, True))
     kept = {}
     for i, seed in enumerate(seeds):
         if i:
-            system.engine.set_params(make(reference.seed_u32(seed)))
+            system.engine.set_params(
+                system.make_params(reference.seed_u32(seed)))
             system.engine.invalidate_prefix_cache()
         trace = loadgen.serve_trace(cell.mix, cell.config["vocab_size"],
                                     seed, seconds)
@@ -129,7 +128,8 @@ def serve(cell, devices, seeds, n_control, seconds):
         ctl = drv.ServeSystem(cell, devices, seeds[0])
         for i, seed in enumerate(seeds[:n_control]):
             if i:
-                ctl.engine.set_params(make(reference.seed_u32(seed)))
+                ctl.engine.set_params(
+                    ctl.make_params(reference.seed_u32(seed)))
                 ctl.engine.invalidate_prefix_cache()
             trace = loadgen.serve_trace(
                 cell.mix, cell.config["vocab_size"], seed, seconds)

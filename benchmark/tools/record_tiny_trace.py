@@ -1,5 +1,6 @@
-"""Records the small trace the tests reduce: four steps of a two-layer
-GPT-2 (width 256, flash kernel) on one chip, under the benchmark's spans.
+"""Records the small trace the tests reduce: four steps of the two-layer
+toy of `tests/data/tiny_trace.json` (width 256, flash kernel) on one
+chip, under the benchmark's spans.
 
     python3 benchmark/tools/record_tiny_trace.py   # on the chip
 
@@ -8,6 +9,7 @@ Writes `chiprun_out/tiny_train.xplane.pb`; copy it to
 """
 
 import glob
+import json
 import pathlib
 import shutil
 import sys
@@ -21,7 +23,7 @@ def main() -> int:
     import numpy as np
     import optax
 
-    from pytorchdistributed_tpu.models import GPT2, gpt2_config
+    from benchmark import manifest
     from pytorchdistributed_tpu.runtime.mesh import create_mesh
     from pytorchdistributed_tpu.training import (
         Trainer,
@@ -31,16 +33,18 @@ def main() -> int:
     if jax.devices()[0].platform != "tpu":
         print("needs a TPU", file=sys.stderr)
         return 3
-    cfg = gpt2_config("test", num_layers=2, embed_dim=256, num_heads=4,
-                      vocab_size=1024, max_seq_len=256,
-                      attention="pallas", scan_layers=False)
-    tr = Trainer(GPT2(cfg), optax.adamw(3e-4), token_cross_entropy_loss,
+    cfg = json.loads((manifest.BENCH_DIR / "tests" / "data"
+                      / "tiny_trace.json").read_text())
+    family = manifest.load_family(manifest.BENCH_DIR, cfg["model_type"])
+    model = family.program_model(cfg, {"attention": "pallas",
+                                       "scan_layers": False})
+    tr = Trainer(model, optax.adamw(3e-4), token_cross_entropy_loss,
                  mesh=create_mesh(devices=jax.devices()[:1]),
                  strategy="dp", log_every=10 ** 9)
     rng = np.random.default_rng(0)
 
     def batch():
-        t = rng.integers(0, 1024, (8, 257)).astype(np.int32)
+        t = rng.integers(0, cfg["vocab_size"], (8, 257)).astype(np.int32)
         return {"tokens": t[:, :-1], "targets": t[:, 1:]}
 
     tr.init(batch())

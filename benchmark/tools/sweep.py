@@ -48,8 +48,7 @@ def main() -> int:
     devices, _ = bench_run.find_devices(cell.chips)
     if devices is None:
         return 3
-    from benchmark.drivers import serve as drv
-
+    drv = cell.driver
     system = drv.ServeSystem(cell, devices[:1], a.first_seed)
     rows = []
     timeout = float(cell.mix["first_token_timeout_s"])
@@ -74,6 +73,8 @@ def main() -> int:
             row = {"rate": rate, "seed": seed, **m,
                    "backlog_growth_rps":
                        (c["queued_t1"] - c["queued_t0"]) / a.seconds,
+                   "queued_t0": c["queued_t0"],
+                   "active_t0": c["active_t0"],
                    "queued_t1": c["queued_t1"],
                    "active_t1": c["active_t1"],
                    "ttft_p50_halves_ms": halves,
