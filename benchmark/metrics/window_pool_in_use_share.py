@@ -1,0 +1,7 @@
+"""Blocks of the sliding layers' pool in use over blocks reserved, mean
+over the window's ticks, in percent; from the engine's `summary()`."""
+
+
+def read(ctx):
+    v = ctx.counters["engine"].get("window_block_utilization")
+    return None if v is None else 100.0 * v

@@ -269,6 +269,17 @@ def reset_cache_positions(cache, new_index):
     return jax.tree_util.tree_map_with_path(fix, cache)
 
 
+#: the "cache" collection's K/V payload leaves, by name: per-head keys
+#: and values with their int8 scale planes (models/transformer.py), and
+#: the rows of a model with two cache kinds (models/latent.py: the full
+#: layers' latent rows with the indexer's keys beside them, the sliding
+#: layers' window rows). Everything else in the collection is counters
+#: and tables.
+KV_POOL_LEAVES = ("cached_key", "cached_value", "cached_key_scale",
+                  "cached_value_scale", "cached_latent",
+                  "cached_index_key", "cached_window")
+
+
 def kv_cache_bytes(cache) -> int:
     """HBM bytes of a decode cache collection's K/V payload (dense rows
     or the paged block pool — the counter/table leaves are noise).
@@ -280,8 +291,7 @@ def kv_cache_bytes(cache) -> int:
     total = 0
     for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]:
         name = getattr(path[-1], "key", str(path[-1]))
-        if name in ("cached_key", "cached_value",
-                    "cached_key_scale", "cached_value_scale"):
+        if name in KV_POOL_LEAVES:
             total += int(np.prod(leaf.shape)) * leaf.dtype.itemsize
     return total
 

@@ -2,7 +2,21 @@
 axis (SURVEY.md §2c "EP"; the reference has no MoE content at all, so the
 design is TPU-first rather than a port).
 
-TPU-idiomatic expert parallelism is *not* a per-token gather/scatter loop:
+Two layers live here, and they differ in what happens to overflow:
+
+  * `SwitchMoE` (Switch / GShard; `TransformerConfig.moe_*`) DROPS: every
+    expert has a fixed capacity of slots, an assignment that loses the
+    race for one skips the expert and rides the residual. Softmax scores,
+    top-1 or top-2, GELU experts, all experts held, dense one-hot
+    dispatch. The rest of this docstring describes it.
+  * `DroplessMoE` (DeepSeek-V3 routing; `models/latent.py`'s config)
+    drops NOTHING: sigmoid scores with a selection bias, top-k of any k,
+    SwiGLU experts and shared experts, a SHARE of the experts held
+    (`experts_held`), the assignments sorted by expert and multiplied as
+    groups (`lax.ragged_dot`). Its docstring says the rest.
+
+For `SwitchMoE`, TPU-idiomatic expert parallelism is *not* a per-token
+gather/scatter loop:
 
   * routing is computed densely (router logits → top-k → one-hot dispatch
     and combine tensors), so every shape is static and XLA can tile the
@@ -49,6 +63,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from pytorchdistributed_tpu.ops.quant import absmax_scale, quantize
 from pytorchdistributed_tpu.parallel.tp import Logical
 from pytorchdistributed_tpu.runtime.mesh import Axis
 
@@ -238,3 +253,146 @@ class SwitchMoE(nn.Module):
             out = nn.Dropout(cfg.dropout_rate)(
                 out, deterministic=self.deterministic)
         return out.reshape(b, s, d)
+
+
+def _int8_rounded(x, axis):
+    """`x` rounded to symmetric int8 along `axis` and back: what the int8
+    contraction multiplies, for a contraction (`lax.ragged_dot`) that the
+    injectable dot_general cannot stand in for."""
+    scale = absmax_scale(x, (axis,))
+    return (quantize(x, scale).astype(jnp.float32) * scale).astype(x.dtype)
+
+
+class DroplessMoE(nn.Module):
+    """An expert layer that drops nothing and holds a SHARE of its
+    experts (the layer `SwitchMoE` is not: that one races for capacity
+    and drops the overflow). DeepSeek-V3 routing: ``s = sigmoid(W_r x)``
+    in float32, the ``experts_per_token`` largest of ``s + b`` (``b`` the
+    stored selection bias; one group), weights ``s_e / sum(chosen s)``
+    times ``routed_scale``, SwiGLU experts, plus ``shared_experts``
+    SwiGLUs every token passes through.
+
+    The router keeps its published width ``router_experts``; this layer
+    holds experts ``experts_held = [lo, hi)`` of them and computes their
+    part of the result for the tokens routed to them: the assignments are
+    sorted by expert and the held ones go through one grouped matrix
+    product a projection (`lax.ragged_dot`, groups = the held experts'
+    token counts). The buffer is as long as every assignment, so no
+    imbalance can overflow it. What the absent experts would add is left
+    out (expert parallelism without its exchange: on one chip there is
+    nobody to exchange with), and nothing stands in for them.
+
+    ``cfg`` needs ``embed_dim, moe_dim, router_experts, experts_held,
+    experts_per_token, shared_experts, norm_topk_prob, routed_scale,
+    dtype, param_dtype, quant``. Call ``[b, s, d] -> ([b, s, d],
+    counters)``; ``live [b, s]`` says which tokens the counters count
+    (every token is computed): assignments to held experts and in all,
+    distinct held experts hit, the busiest held expert's tokens, the mean
+    over the held experts, and assignments to a held expert whose row of
+    the sorted buffer does not lie in that expert's group of the grouped
+    product (read from the permutation the combine gathers by and the
+    group sizes the product is given; 0 unless the dispatch is at fault).
+    """
+
+    cfg: "LatentConfig"  # noqa: F821 — models/latent.py's config
+
+    @nn.compact
+    def __call__(self, x, live=None):
+        from pytorchdistributed_tpu.models.transformer import (
+            _cfg_dot_general,
+        )
+
+        cfg = self.cfg
+        d, f, e_pub = cfg.embed_dim, cfg.moe_dim, cfg.router_experts
+        lo, hi = cfg.experts_held
+        held, k = hi - lo, cfg.experts_per_token
+        b, s, _ = x.shape
+        t = b * s
+        init = nn.initializers.normal(stddev=0.02)
+        pd = cfg.param_dtype
+
+        router = self.param("router", init, (d, e_pub), jnp.float32)
+        bias = self.param("router_bias", nn.initializers.zeros_init(),
+                          (e_pub,), jnp.float32)
+        e_gate = self.param("e_gate", init, (held, d, f), pd)
+        e_up = self.param("e_up", init, (held, d, f), pd)
+        e_down = self.param("e_down", init, (held, f, d), pd)
+        fs = f * cfg.shared_experts
+        s_gate = self.param("s_gate", init, (d, fs), pd)
+        s_up = self.param("s_up", init, (d, fs), pd)
+        s_down = self.param("s_down", init, (fs, d), pd)
+
+        xt = x.reshape(t, d).astype(cfg.dtype)
+        # -- routing, in float32 as published -------------------------
+        score = jax.nn.sigmoid(jnp.matmul(
+            xt.astype(jnp.float32), router,
+            precision=lax.Precision.HIGHEST))               # [t, e_pub]
+        _, chosen = lax.top_k(score + bias, k)              # [t, k]
+        picked = jnp.take_along_axis(score, chosen, -1)
+        if cfg.norm_topk_prob:
+            picked = picked / picked.sum(-1, keepdims=True)
+        weight = picked * cfg.routed_scale
+
+        # -- the held experts' part: sort, group, multiply -------------
+        flat = chosen.reshape(t * k)
+        mine = (flat >= lo) & (flat < hi)
+        group = jnp.where(mine, flat - lo, held)   # the others sort last
+        order = jnp.argsort(group, stable=True)
+        sizes = jnp.bincount(group, length=held + 1)[:held].astype(
+            jnp.int32)
+        rows = xt[order // k]                               # [t*k, d]
+        quant = _cfg_dot_general(cfg) is not None
+        if quant:
+            rows = _int8_rounded(rows, 1)
+
+        def grouped(lhs, w):
+            w = w.astype(cfg.dtype)
+            if quant:
+                w = _int8_rounded(w, 1)
+            return lax.ragged_dot(lhs, w, sizes,
+                                  preferred_element_type=jnp.float32)
+
+        h = (nn.silu(grouped(rows, e_gate))
+             * grouped(rows, e_up)).astype(cfg.dtype)
+        if quant:
+            h = _int8_rounded(h, 1)
+        y = grouped(h, e_down)                              # [t*k, d] f32
+        computed = jnp.arange(t * k) < sizes.sum()
+        y = jnp.where(computed[:, None],
+                      y * weight.reshape(t * k)[order][:, None], 0.0)
+        # back into the tokens' order (a gather; every assignment has a
+        # row, the absent experts' rows are nought) and summed a token
+        row_of = jnp.argsort(order)         # an assignment's buffer row
+        routed = y[row_of].reshape(t, k, d).sum(1)
+
+        # -- the shared expert, on every token -------------------------
+        dg = _cfg_dot_general(cfg, lax.dot_general)
+        dims = (((1,), (0,)), ((), ()))
+
+        def dense(lhs, w):
+            return dg(lhs, w.astype(cfg.dtype), dims,
+                      preferred_element_type=jnp.float32)
+
+        hs = (nn.silu(dense(xt, s_gate)) * dense(xt, s_up)).astype(
+            cfg.dtype)
+        out = (routed + dense(hs, s_down)).astype(cfg.dtype)
+
+        # -- what the tick brings back ---------------------------------
+        lv = (jnp.ones((t,), bool) if live is None
+              else live.reshape(t))
+        mine_live = mine & jnp.repeat(lv, k)
+        load = jnp.bincount(jnp.where(mine_live, flat - lo, held),
+                            length=held + 1)[:held]
+        n_held = mine_live.sum()
+        counters = {
+            "moe_assignments_held": n_held,
+            "moe_assignments_total": lv.sum() * k,
+            "moe_experts_hit": (load > 0).sum(),
+            "moe_load_max": load.max(),
+            "moe_load_mean": n_held / held,
+            "moe_dropped": (mine_live & (jnp.searchsorted(
+                jnp.cumsum(sizes), row_of, side="right") != flat - lo)
+                            ).sum(),
+        }
+        return out.reshape(b, s, d), {
+            n: v.astype(jnp.float32) for n, v in counters.items()}

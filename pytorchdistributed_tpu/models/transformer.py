@@ -240,11 +240,18 @@ class TransformerConfig:
     # (the pure forward path always pipelines GPipe-style — schedules only
     # differ in where the backward interleaves).
     pp_schedule: str = "gpipe"
-    # Mixture-of-Experts (models/moe.py): >0 replaces block MLPs with a
-    # top-k routed expert FFN bank, sharded over the "expert" mesh axis.
-    # Use losses that add the sown load-balance/z-loss terms
-    # (training.losses.moe_token_cross_entropy_loss).
+    # Mixture-of-Experts (models/moe.py:SwitchMoE, the layer that DROPS:
+    # an assignment past an expert's capacity rides the residual): >0
+    # replaces block MLPs with a top-k routed expert FFN bank, sharded
+    # over the "expert" mesh axis. Use losses that add the sown
+    # load-balance/z-loss terms
+    # (training.losses.moe_token_cross_entropy_loss). The layer that
+    # drops nothing and holds a share of its experts is
+    # models/moe.py:DroplessMoE, configured by models/latent.py; none of
+    # the moe_* options below reaches it.
     moe_experts: int = 0
+    # SwitchMoE's capacity: ceil(cf * tokens_per_group / experts) slots an
+    # expert; what overflows is dropped (and counted: `moe_overflow`).
     moe_capacity_factor: float = 1.25
     # 1 = Switch top-1 (raw top-prob gate); 2 = GShard-style top-2 with
     # gates renormalized over the chosen pair. First choices always beat
@@ -302,8 +309,11 @@ class TransformerConfig:
                              "moe_groups >= 0")
         if self.moe_experts > 0:
             if self.moe_top_k not in (1, 2):
-                raise ValueError(f"moe_top_k {self.moe_top_k} must be 1 "
-                                 f"(Switch) or 2 (GShard)")
+                raise ValueError(
+                    f"moe_top_k {self.moe_top_k} must be 1 (Switch) or 2 "
+                    f"(GShard): models/moe.py:SwitchMoE races for "
+                    f"capacity in those two orders only (top-k of any k, "
+                    f"without drops, is models/moe.py:DroplessMoE)")
             if self.moe_top_k > self.moe_experts:
                 raise ValueError(
                     f"moe_top_k {self.moe_top_k} needs at least that many "

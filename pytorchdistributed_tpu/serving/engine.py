@@ -76,6 +76,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from pytorchdistributed_tpu.inference import (
+    KV_POOL_LEAVES,
     _zero_cache,
     draft_and_verify,
     draft_and_verify_heads,
@@ -88,8 +89,8 @@ from pytorchdistributed_tpu.runtime.compile_cache import (
     static_repr,
 )
 from pytorchdistributed_tpu.serving.paging import (
-    BlockAllocator,
     RadixPrefixCache,
+    SlotPool,
 )
 from pytorchdistributed_tpu.serving.telemetry import ServingTelemetry
 from pytorchdistributed_tpu.telemetry.spans import span
@@ -133,15 +134,17 @@ def _leaf_name(path) -> str:
 # leading layer axis, so the end is the stable anchor): K/V pools are
 # [..., kv_blocks, block_size, kv_heads*head_dim] (one lane-dense row a
 # token), the int8 scale planes [..., kv_blocks, block_size, kv_heads];
-# the block axis is ndim-3 in both. Everything that moves
+# the block axis is ndim-3 in both, and in the latent, indexer-key and
+# window rows of a model with two cache kinds. Everything that moves
 # blocks — the compiled gather/scatter pair, the prefill-chunk merge,
 # the export/import payloads and the fleet prefix stream — keys off this
 # one table, which is how the int8 pool's scales ride every existing
 # block-transport path without a second code path.
-POOL_LEAF_AXIS = {
-    "cached_key": 3, "cached_value": 3,
-    "cached_key_scale": 3, "cached_value_scale": 3,
-}
+POOL_LEAF_AXIS = dict.fromkeys(KV_POOL_LEAVES, 3)
+
+# the kinds of cache of a model that declares none (`cfg.cache_kinds`):
+# one pool behind `block_table`, every position kept, no id on its spans
+_ONE_POOL = ((None, "block_table", 0),)
 
 
 def _pool_block_axis(name: str, ndim: int) -> int:
@@ -269,16 +272,28 @@ def _override_paging(cache, tables, lengths):
     one of each for all its layers, beside the embedder's). The device
     copies are write-through scratch: the engine re-stamps from host
     state on every compiled call, which is what makes prefix sharing,
-    block growth and preemption pure host bookkeeping."""
+    block growth and preemption pure host bookkeeping. ``tables`` is
+    {table leaf: [slots, pages]}, one entry a kind of cache the model
+    keeps (``block_table`` alone where it keeps one pool)."""
     def fix(path, leaf):
         name = _leaf_name(path)
         if name in ("index", "pos_index"):
             return jnp.broadcast_to(lengths, leaf.shape).astype(leaf.dtype)
-        if name == "block_table":
-            return jnp.broadcast_to(tables, leaf.shape).astype(leaf.dtype)
+        if name in tables:
+            return jnp.broadcast_to(tables[name],
+                                    leaf.shape).astype(leaf.dtype)
         return leaf
 
     return jax.tree_util.tree_map_with_path(fix, cache)
+
+
+def paged_tick_logits(model, weights, cache, tables, lengths, tokens):
+    """The model's part of a paged tick: logits ``[slots, 1, vocab]`` and
+    the mutated collections. Apart from `paged_decode_tick` so that a test
+    can read every slot's logits at the engine's own operands."""
+    cache = _override_paging(cache, tables, lengths)
+    return model.apply({"params": weights, "cache": cache},
+                       tokens[:, None], mutable=["cache", "counters"])
 
 
 @functools.partial(
@@ -296,14 +311,42 @@ def paged_decode_tick(model, weights, cache, tables, lengths, tokens,
     length 0, so their garbage ticks write the reserved trash block and
     can never corrupt a live request's blocks."""
     TRACE_COUNTS["paged_decode_tick"] += 1
-    cache = _override_paging(cache, tables, lengths)
-    logits, mut = model.apply({"params": weights, "cache": cache},
-                              tokens[:, None], mutable=["cache"])
+    logits, mut = paged_tick_logits(model, weights, cache, tables, lengths,
+                                    tokens)
     keys = jax.random.wrap_key_data(key_data)
     subs = jax.vmap(jax.random.fold_in)(keys, counts)
     nxt = sample_slots(logits[:, 0].astype(jnp.float32), subs,
                        temperature, top_k, top_p, candidates=candidates)
-    return mut["cache"], nxt
+    # the third output is what the model counted on the device this tick
+    # (the "counters" collection: a few scalars that ride back with the
+    # tokens); empty, and absent from the program, for a model that
+    # counts nothing
+    return mut["cache"], nxt, dict(mut.get("counters", {}))
+
+
+def paged_chunk_logits(model, weights, cache, chunk, start, table_row):
+    """The model's part of a prefill chunk: logits ``[1, C, vocab]`` of
+    every position of the chunk and the mutated cache. Apart from
+    `paged_prefill_chunk` for the same reason as `paged_tick_logits`.
+    ``table_row`` is {table leaf: [pages]}: the one request's rows of
+    `_override_paging`'s tables."""
+    def shrink(path, leaf):
+        # the chunk model is the same module tree at decode_slots=1:
+        # pool leaves pass through untouched (no slot dim), counter and
+        # table leaves shrink to the one-request row
+        name = _leaf_name(path)
+        if name in ("index", "pos_index"):
+            return jnp.broadcast_to(
+                start, leaf.shape[:-1] + (1,)).astype(leaf.dtype)
+        if name in table_row:
+            row = table_row[name]
+            return jnp.broadcast_to(
+                row, leaf.shape[:-2] + (1,) + row.shape).astype(leaf.dtype)
+        return leaf
+
+    small = jax.tree_util.tree_map_with_path(shrink, cache)
+    return model.apply({"params": weights, "cache": small}, chunk,
+                       mutable=["cache"])
 
 
 @functools.partial(
@@ -327,24 +370,8 @@ def paged_prefill_chunk(model, weights, cache, chunk, start, table_row,
     sample is used; ``count`` is its fold_in index (> 0 when a preempted
     request resumes mid-generation)."""
     TRACE_COUNTS["paged_prefill_chunk"] += 1
-
-    def shrink(path, leaf):
-        # the chunk model is the same module tree at decode_slots=1:
-        # pool leaves pass through untouched (no slot dim), counter and
-        # table leaves shrink to the one-request row
-        name = _leaf_name(path)
-        if name in ("index", "pos_index"):
-            return jnp.broadcast_to(
-                start, leaf.shape[:-1] + (1,)).astype(leaf.dtype)
-        if name == "block_table":
-            return jnp.broadcast_to(
-                table_row,
-                leaf.shape[:-2] + (1,) + table_row.shape).astype(leaf.dtype)
-        return leaf
-
-    small = jax.tree_util.tree_map_with_path(shrink, cache)
-    logits, mut = model.apply({"params": weights, "cache": small}, chunk,
-                              mutable=["cache"])
+    logits, mut = paged_chunk_logits(model, weights, cache, chunk, start,
+                                     table_row)
 
     def merge(path, big, new):
         # only the pools mutated (K/V codes AND, on an int8 pool, their
@@ -960,6 +987,18 @@ class ServingEngine:
         if num_slots < 1:
             raise ValueError(f"num_slots must be >= 1, got {num_slots}")
         self.num_slots = num_slots
+        # the kinds of cache the model keeps, the stream's own first: (the
+        # `pool` id on the spans, the table leaf, the window its layers see
+        # or 0 for every position). One pool, unless the model declares
+        # more (models/latent.py: a latent pool that grows with the
+        # stream, and a window pool whose blocks retire per layer kind)
+        self._kinds = tuple(getattr(model.cfg, "cache_kinds", _ONE_POOL))
+        self._pools: list[SlotPool] = []
+        self._refuse_two_kinds(
+            "the radix prefix cache" if prefix_cache else
+            "a speculative tick (spec_k > 0)" if spec_k else
+            "a session store" if session_store is not None else
+            "the int8 pool" if kv_dtype == "int8" else None)
         self.candidates = candidates
         self.mesh = mesh
         if block_size == 0 and model.cfg.kv_block_size:
@@ -988,7 +1027,13 @@ class ServingEngine:
             # real accelerators; CPU (tests, dev) keeps the gather read,
             # whose decode tick is bitwise generate()'s
             paged_attn = ("pallas" if jax.default_backend() == "tpu"
-                          else "gather")
+                          and len(self._kinds) == 1 else "gather")
+        if paged_attn == "pallas" and len(self._kinds) > 1:
+            raise ValueError(
+                "paged_attn='pallas' is not built for a model with two "
+                "cache kinds: the fused kernel reads per-head keys and "
+                "values of one pool, and this model's rows are latents "
+                "that all heads share, read by XLA (paged_attn='gather')")
         if not self.paged and (kv_dtype != "bf16" or kv_sink_tokens
                                or kv_window_tokens):
             raise ValueError(
@@ -1016,6 +1061,10 @@ class ServingEngine:
                     f"max_seq_len/block_size + the trash block)")
             self.block_size = block_size
             self.num_blocks = num_blocks
+            for _, _, window in self._kinds[1:]:
+                model = self._with_window_pool(
+                    model, window, block_size,
+                    prefill_chunk or prefill_bucket)
             # per-request window/sink overrides (ISSUE 15) need the
             # per-slot mask leaves; the Pallas kernel takes sink/window
             # STATICALLY, so overrides stay gather-only and a pallas
@@ -1036,6 +1085,9 @@ class ServingEngine:
             self._tick_model, self._prefill_model = slot_models(
                 model, num_slots)
         self.cfg = self._tick_model.cfg
+        # the scalars the model counts on the device a tick, by name (the
+        # tick's third output; none for a model that counts nothing)
+        self._counter_names = tuple(getattr(type(model), "counters", ()))
         self.bucket = max(1, min(prefill_bucket, self.cfg.max_seq_len))
         if self.paged:
             chunk = prefill_chunk if prefill_chunk else self.bucket
@@ -1044,14 +1096,24 @@ class ServingEngine:
             self.chunk = min(self._round_up(chunk, block_size),
                              self.cfg.max_seq_len)
             self._chunks_per_step = max(1, prefill_chunks_per_step)
-            self._alloc = BlockAllocator(num_blocks, block_size)
+            # one `SlotPool` a kind. The first is the stream's own:
+            # `_alloc`, `_tables` and `_slot_blocks` are its members, which
+            # the prefix cache, preemption, export and sessions work on;
+            # the others (windowed, sized by `_with_window_pool`) are
+            # backed and retired beside it
+            self._pools = [
+                SlotPool.empty(kind, table,
+                               self.cfg.window_blocks if window
+                               else num_blocks,
+                               block_size, num_slots, self.cfg.kv_pages,
+                               window)
+                for kind, table, window in self._kinds]
+            self._alloc = self._pools[0].alloc
+            self._tables = self._pools[0].tables
+            self._slot_blocks = self._pools[0].blocks
             self._radix = (RadixPrefixCache(self._alloc) if prefix_cache
                            else None)
-            self._tables = np.zeros((num_slots, self.cfg.kv_pages),
-                                    np.int32)
             self._lengths = np.zeros(num_slots, np.int32)
-            self._slot_blocks: list[list[int]] = [
-                [] for _ in range(num_slots)]
             self._admit_order = np.zeros(num_slots, np.int64)
             self._admit_seq = itertools.count(1)
             self._prefilling: dict | None = None
@@ -1305,6 +1367,9 @@ class ServingEngine:
                     f"kv_window must be >= 1, got {kv_window}")
             if kv_sink is not None and kv_sink < 0:
                 raise ValueError(f"kv_sink must be >= 0, got {kv_sink}")
+        self._refuse_two_kinds(
+            "prefill_only (a parked stream for export)" if prefill_only
+            else "session_id" if session_id is not None else None)
         if prefill_only:
             if not self.paged:
                 raise ValueError(
@@ -1407,8 +1472,14 @@ class ServingEngine:
                         admitted += 1
             decoded = 0
             if self.paged and self._active:
-                with span("serve/grow_slots"):
+                with span("serve/grow_slots", **self._pools[0].ids):
                     self._grow_slots()  # back this tick's write positions
+                for pool in self._pools[1:]:
+                    with span("serve/grow_slots", **pool.ids):
+                        for slot in self._active:
+                            n = int(self._lengths[slot])
+                            self._back_window(pool, slot,
+                                              pool.tables[slot], n, n + 1)
             if self.per_slot_limits and self._limits_dirty:
                 self._stamp_slot_limits()
             if self._active and self.spec_k:
@@ -1429,11 +1500,17 @@ class ServingEngine:
         with span("serve/decode_tick"), self._mesh_ctx():
             with span("serve/tick_dispatch"):
                 name, tick, args = self._tick_program()
-                self._cache, nxt = self._aot_call(
+                out = self._aot_call(
                     name, tick, (self._tick_model,), args,
                     dict(candidates=self.candidates))
+                self._cache, nxt = out[:2]
             with span("serve/tick_sync"):
-                toks = np.asarray(nxt)  # host sync: streaming delivery
+                if self._counter_names:
+                    # the device's counters come back with the tokens
+                    toks, counted = jax.device_get((nxt, out[2]["tick"]))
+                    self._stats["device_counters"] += counted
+                else:
+                    toks = np.asarray(nxt)  # host sync: streaming delivery
         dt = time.perf_counter() - t0
         self._counts += 1
         self._progress += 1
@@ -1448,6 +1525,9 @@ class ServingEngine:
             st["peak_blocks_used"] = max(st["peak_blocks_used"], used)
             row = dict(blocks_used=used,
                        blocks_free=self._alloc.free_count)
+            for pool in self._pools[1:]:
+                st[f"{pool.kind}_block_used_sum"] += (
+                    pool.in_use / pool.alloc.usable)
             for slot in self._active:
                 self._lengths[slot] += 1  # this tick's write landed
         decoded = 0
@@ -1490,7 +1570,7 @@ class ServingEngine:
                         (self._tick_model, self._draft_tick_model),
                         (self._weights, self._draft_weights, self._cache,
                          self._draft_cache,
-                         jnp.asarray(self._tables),
+                         self._device_tables(),
                          jnp.asarray(self._lengths),
                          jnp.asarray(self._spec_prev_start),
                          jnp.asarray(self._spec_prev_tokens),
@@ -1509,7 +1589,7 @@ class ServingEngine:
                         (self._tick_model, self._draft_tick_model),
                         (self._weights, self._draft_weights, self._cache,
                          self._draft_cache,
-                         jnp.asarray(self._tables),
+                         self._device_tables(),
                          jnp.asarray(self._lengths),
                          jnp.asarray(self._tokens),
                          jnp.asarray(self._key_data),
@@ -1588,6 +1668,61 @@ class ServingEngine:
     @staticmethod
     def _round_up(n: int, q: int) -> int:
         return -(-n // q) * q
+
+    def _refuse_two_kinds(self, what: str | None) -> None:
+        """What the engine has and a model with two cache kinds cannot
+        use yet raises with its reason; nothing falls back silently."""
+        if what is not None and len(self._kinds) > 1:
+            raise ValueError(
+                f"{what} is not built for a model with two cache kinds: "
+                f"a prefix, a draft, an exported or a parked stream is "
+                f"one list of blocks of one pool, and this model's "
+                f"streams hold blocks of two (the window pool's are "
+                f"retired while the stream runs, so a cached prefix "
+                f"would have no window rows to resume from)")
+
+    def _with_window_pool(self, model, window, block_size, chunk):
+        """`model` with its window pool sized (the trash block included):
+        a stream that decodes holds the window's blocks and one at either
+        end; the one stream that prefills holds a chunk's blocks more.
+        Every slot is backed at once, so the window pool never runs dry
+        and nothing is preempted on its account."""
+        cfg = model.cfg
+        back = window - 1
+        chunk = min(self._round_up(chunk, block_size), cfg.max_seq_len)
+        need = (self.num_slots * (-(-back // block_size) + 2)
+                + -(-(chunk + back) // block_size) + 2)
+        return model.clone(cfg=dataclasses.replace(cfg,
+                                                   window_blocks=need))
+
+    def _back_window(self, pool: SlotPool, slot: int, row, lo: int,
+                     hi: int) -> None:
+        """Back positions [lo, hi) of `slot` in a windowed `pool`, after
+        handing back every block that the earliest query still to come
+        (at `lo`) can no longer see. `row` is the table row to keep in
+        step: the tick's view of the slot, or the row of the admission
+        in flight."""
+        bs = self.block_size
+        blocks = pool.blocks[slot]
+        dead = min(max(0, lo - (pool.window - 1)) // bs, len(blocks))
+        first = int(pool.first[slot])
+        if dead > first:
+            with span("serve/retire_window", pool=pool.kind):
+                for bi in range(first, dead):
+                    pool.alloc.decref(blocks[bi])
+                    blocks[bi] = 0
+                    row[bi] = 0
+                self._stats[f"{pool.kind}_blocks_retired"] += dead - first
+                pool.first[slot] = dead
+        last = (min(hi, self.cfg.max_seq_len) - 1) // bs
+        while len(blocks) <= last:
+            fresh = pool.alloc.alloc(1)
+            if fresh is None:
+                raise RuntimeError(
+                    f"the {pool.kind} pool ran dry, which its size rules "
+                    f"out (_with_window_pool): a block was leaked")
+            row[len(blocks)] = fresh[0]
+            blocks.append(fresh[0])
 
     def _clamp_limits(self, kv_sink: int | None,
                       kv_window: int | None) -> tuple[int, int]:
@@ -1713,8 +1848,11 @@ class ServingEngine:
         # prefill chunks, and the mid-prefill slot's garbage tick must
         # write the trash block, not position 0 of the request's first
         # real block. The chunk program reads the real row from pf state.
-        table_row = np.zeros(self.cfg.kv_pages, np.int32)
-        table_row[:len(blocks)] = blocks
+        # A row a pool: the stream's own holds its blocks, a windowed
+        # pool's is backed a chunk at a time (_chunk_call).
+        table_row = {pool.table: np.zeros(self.cfg.kv_pages, np.int32)
+                     for pool in self._pools}
+        table_row[self._pools[0].table][:len(blocks)] = blocks
         req.prefix_hit_tokens += m
         req.remote_hit_tokens += remote_m * bs
         st = self._stats
@@ -1747,11 +1885,19 @@ class ServingEngine:
         chunk = np.zeros((1, self.chunk), np.int32)
         n = min(self.chunk, pf["true_len"] - pos)
         chunk[0, :n] = pf["tokens"][pos:pos + n]
+        for pool in self._pools[1:]:
+            with span("serve/grow_slots", **pool.ids):
+                self._back_window(pool, pf["slot"],
+                                  pf["table_row"][pool.table], pos,
+                                  pos + self.chunk)
         return self._aot_call(
             name, paged_prefill_chunk, (model,),
             (weights, cache,
              jnp.asarray(chunk), jnp.int32(pos),
-             jnp.asarray(pf["table_row"]),
+             # copies (`jnp.array`): the next chunk's `_back_window`
+             # edits the window row in place while this chunk may still
+             # run, and on the CPU `jnp.asarray` can alias host memory
+             jax.tree.map(jnp.array, pf["table_row"]),
              jnp.int32(pf["true_len"]),
              jnp.asarray(pf["kd"]),
              jnp.int32(pf["resume"]),
@@ -1802,7 +1948,17 @@ class ServingEngine:
         # admission complete: cache the prompt's full blocks for future
         # arrivals, publish the real table to the tick's view, rewind to
         # the true length, activate the slot
-        self._tables[slot, :] = pf["table_row"]
+        for pool in self._pools:
+            row = pf["table_row"][pool.table]
+            if pool.window:
+                # the last chunk's pad positions were backed too: hand
+                # back what lies past the block the first tick writes into
+                keep = pf["true_len"] // self.block_size + 1
+                for b in pool.blocks[slot][keep:]:
+                    pool.alloc.decref(b)
+                del pool.blocks[slot][keep:]
+                row[keep:] = 0
+            pool.tables[slot, :] = row
         self._lengths[slot] = pf["true_len"]
         if self._radix is not None:
             nb = pf["true_len"] // self.block_size
@@ -2001,6 +2157,13 @@ class ServingEngine:
         self._slot_blocks[slot] = []
         self._tables[slot, :] = 0
         self._lengths[slot] = 0
+        for pool in self._pools[1:]:
+            for b in pool.blocks[slot]:
+                if b:
+                    pool.alloc.decref(b)
+            pool.blocks[slot] = []
+            pool.first[slot] = 0
+            pool.tables[slot, :] = 0
         if self.per_slot_limits:
             self._set_slot_limits(slot, None, None)
         if self.spec_k:
@@ -2091,6 +2254,7 @@ class ServingEngine:
         locally. After export this engine holds NOTHING for the
         request (radix-cached prefix blocks live on through the
         cache's own reference)."""
+        self._refuse_two_kinds("export of a stream's blocks")
         if not self.paged:
             raise ValueError("export_kv_blocks requires the paged engine")
         rec = self._prefilled.pop(req.id, None)
@@ -2137,6 +2301,7 @@ class ServingEngine:
         falls back to resume-from-tokens redispatch, which is lossless
         by construction. Geometry/model mismatches raise ValueError:
         importing foreign K/V silently would serve garbage."""
+        self._refuse_two_kinds("import of a stream's blocks")
         if not self.paged:
             raise ValueError("import_kv_blocks requires the paged engine")
         if self.spec_k:
@@ -2267,6 +2432,7 @@ class ServingEngine:
         shipping (the remote-hit path: this replica owns the longest
         match, another replica is about to prefill it from scratch).
         None when nothing is cached."""
+        self._refuse_two_kinds("export of prefix blocks")
         if not self.paged or self._radix is None:
             return None
         tokens = np.asarray(tokens, np.int32).reshape(-1)
@@ -2288,6 +2454,7 @@ class ServingEngine:
         local ones). Best-effort by design — returns the number of
         blocks adopted, 0 on any mismatch or pool pressure: a failed
         ship just means this replica prefills the prefix itself."""
+        self._refuse_two_kinds("import of prefix blocks")
         if (not self.paged or self._radix is None or self.spec_k
                 or payload.block_size != self.block_size
                 or payload.kv_dtype != self.kv_dtype
@@ -2332,6 +2499,7 @@ class ServingEngine:
         full-block boundary, the per-request kv_sink/kv_window
         override and the trace identity. Parked prefill_only requests
         delegate to export_kv_blocks."""
+        self._refuse_two_kinds("detaching a stream")
         if not self.paged:
             raise ValueError("detach_request requires the paged engine")
         if self.spec_k:
@@ -2390,6 +2558,7 @@ class ServingEngine:
         window-retired payloads whose gathered trash rows must never
         enter the prefix cache) or pool pressure — a declined seed
         just means a plain re-prefill, lossless by construction."""
+        self._refuse_two_kinds("seeding a session's blocks")
         if (not self.paged or self._radix is None or self.spec_k
                 or payload.block_size != self.block_size
                 or payload.kv_dtype != self.kv_dtype
@@ -2565,6 +2734,7 @@ class ServingEngine:
         pulls the payload here and seeds it there. None when this
         engine holds nothing for the id (the caller falls through to
         the store tiers, then to re-prefill)."""
+        self._refuse_two_kinds("export of a session")
         if not self.paged:
             return None
         rec = self._sessions.pop(session_id, None)
@@ -2795,12 +2965,18 @@ class ServingEngine:
             self._alloc.check_leaks(expected_resident=cached)
             if self._radix is not None:
                 self._radix.clear()
-            self._alloc.check_leaks(0)
+            for pool in self._pools:
+                pool.alloc.check_leaks(0)
         if self.telemetry is not None:
             self.telemetry.close()
 
     # ------------------------------------------------------------------
     # internals
+
+    def _device_tables(self) -> dict:
+        """{table leaf: [slots, pages]} of every pool, for a tick."""
+        return {pool.table: jnp.asarray(pool.tables)
+                for pool in self._pools}
 
     def _tick_program(self):
         """(name, jitted program, dynamic args) of the plain decode tick
@@ -2808,7 +2984,7 @@ class ServingEngine:
         paged tick just prepends the host-stamped block tables and
         lengths."""
         name, tick, head = (("paged_decode_tick", paged_decode_tick,
-                             (jnp.asarray(self._tables),
+                             (self._device_tables(),
                               jnp.asarray(self._lengths)))
                             if self.paged
                             else ("decode_tick", decode_tick, ()))
@@ -3257,6 +3433,14 @@ class ServingEngine:
                            session_detaches=0, session_attaches=0,
                            session_seed_tokens=0, session_demotes=0,
                            session_dropped=0)
+        # what the model counts on the device a tick (summed here), and
+        # each windowed pool's share of the host's bookkeeping
+        if self._counter_names:
+            self._stats["device_counters"] = np.zeros(
+                len(self._counter_names))
+        for pool in self._pools[1:]:
+            self._stats[f"{pool.kind}_blocks_retired"] = 0
+            self._stats[f"{pool.kind}_block_used_sum"] = 0.0
 
     @property
     def queue_depth(self) -> int:
@@ -3372,6 +3556,22 @@ class ServingEngine:
                 dropped=st["session_dropped"])
             if self._radix is not None:
                 out["prefix_cache"] = self._radix.stats()
+        if self._counter_names:
+            # the tick's device-side counters, summed over the window's
+            # ticks (models/latent.py: COUNTERS)
+            out.update(zip(self._counter_names,
+                           (float(v) for v in st["device_counters"])))
+        if len(self._pools) > 1:
+            # each pool by its kind; the first's share over the ticks is
+            # `block_utilization`, above
+            for pool in self._pools:
+                out[f"{pool.kind}_blocks_in_use"] = pool.in_use
+            for pool in self._pools[1:]:
+                out[f"{pool.kind}_blocks_retired"] = st[
+                    f"{pool.kind}_blocks_retired"]
+                out[f"{pool.kind}_block_utilization"] = (
+                    round(st[f"{pool.kind}_block_used_sum"] / st["ticks"],
+                          4) if st["ticks"] else None)
         if self.spec_k:
             out["spec_k"] = self.spec_k
             out["draft_tokens"] = st["draft_tokens"]

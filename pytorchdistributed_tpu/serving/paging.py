@@ -11,6 +11,11 @@ state the scheduler mutates between compiled calls:
     writes can never land in a block another request owns. A block frees
     when its last reference drops — a slot's table entry and a radix-
     cache node each hold one.
+  * `SlotPool` — one kind of cache the engine keeps blocks for: its
+    allocator, the table the tick reads and each slot's block list. An
+    engine holds a list of them: one entry for a model with one pool, a
+    second (with a window, whose blocks retire while the stream runs)
+    for a model whose layer kinds keep their own.
   * `RadixPrefixCache` — a block-granularity radix tree over prompt
     token ids (SGLang's RadixAttention at vLLM's block alignment): a
     node caches ONE full block (`block_size` tokens) of K/V under its
@@ -32,8 +37,11 @@ free list or accounted to at least one live reference.
 
 from __future__ import annotations
 
+import dataclasses
 import hashlib
 import itertools
+
+import numpy as np
 
 
 def block_hashes(tokens, block_size: int) -> list[str]:
@@ -141,6 +149,43 @@ class BlockAllocator:
                 f"KV block leak: {self.resident} blocks still referenced "
                 f"at teardown (expected {expected_resident}): "
                 f"{sorted(self._refs)}")
+
+
+@dataclasses.dataclass
+class SlotPool:
+    """One kind of cache a paged engine keeps blocks for, and which slot
+    holds which of them."""
+
+    kind: str | None            # the `pool` id on the engine's spans
+    table: str                  # the cache leaf the model reads ids from
+    alloc: BlockAllocator
+    tables: np.ndarray          # [slots, pages] ids, the tick's view
+    blocks: list                # a slot's ids in logical order; 0 = retired
+    # positions a query of this pool's layers sees, itself included: a
+    # block goes back to `alloc` once the window has passed it. 0: every
+    # position (the pool grows with the stream)
+    window: int = 0
+    # a slot's first logical block not yet retired, so that a sweep
+    # never walks the dead prefix again
+    first: np.ndarray | None = None
+
+    @classmethod
+    def empty(cls, kind, table, num_blocks, block_size, slots, pages,
+              window=0):
+        return cls(kind, table, BlockAllocator(num_blocks, block_size),
+                   np.zeros((slots, pages), np.int32),
+                   [[] for _ in range(slots)], window,
+                   np.zeros(slots, np.int64))
+
+    @property
+    def in_use(self) -> int:
+        return self.alloc.usable - self.alloc.free_count
+
+    @property
+    def ids(self) -> dict:
+        """What this pool's spans carry: its kind, where an engine keeps
+        several to tell apart."""
+        return {"pool": self.kind} if self.kind else {}
 
 
 class _RadixNode:

@@ -755,7 +755,8 @@ def serving_lowered(name: str):
             chunk_model, weights_sds, cache_sds,
             sds((1, bucket), i32),                       # prompt chunk
             sds((), i32),                                # start
-            sds((tick_model.cfg.kv_pages,), i32),        # table row
+            {"block_table":
+             sds((tick_model.cfg.kv_pages,), i32)},      # table row
             sds((), i32),                                # true_len
             sds(kd.shape, kd.dtype), sds((), i32),       # key, count
             sds((), f32), sds((), i32), sds((), f32),    # sampling params
@@ -767,7 +768,8 @@ def serving_lowered(name: str):
         return spec_decode_tick.lower(
             tick_model, tick_model, weights_sds, weights_sds,
             cache_sds, cache_sds,
-            sds((slots, tick_model.cfg.kv_pages), i32),  # block tables
+            {"block_table":                              # block tables
+             sds((slots, tick_model.cfg.kv_pages), i32)},
             sds((slots,), i32),                          # lengths
             sds((slots,), i32),                          # tokens
             sds((slots,) + kd.shape, kd.dtype), sds((slots,), i32),
@@ -776,7 +778,8 @@ def serving_lowered(name: str):
     if name == "serve_tick_paged":
         return paged_decode_tick.lower(
             tick_model, weights_sds, cache_sds,
-            sds((slots, tick_model.cfg.kv_pages), i32),  # block tables
+            {"block_table":                              # block tables
+             sds((slots, tick_model.cfg.kv_pages), i32)},
             sds((slots,), i32),                          # lengths
             sds((slots,), i32),
             sds((slots,) + kd.shape, kd.dtype), sds((slots,), i32),

@@ -249,14 +249,16 @@ def serve_program_for_v5e(program: str, scan_layers: bool):
         per_slot = (POOL_SLOTS,)
         lowered = paged_decode_tick.lower(
             tick_model, state["params"], state["cache"],
-            arg((POOL_SLOTS, pages)), arg(per_slot), arg(per_slot),
+            {"block_table": arg((POOL_SLOTS, pages))}, arg(per_slot),
+            arg(per_slot),
             arg(per_slot + key.shape, key.dtype), arg(per_slot),
             arg(per_slot, f32), arg(per_slot), arg(per_slot, f32),
             candidates=64)
     else:
         lowered = paged_prefill_chunk.lower(
             chunk_model, state["params"], state["cache"],
-            arg((1, POOL_CHUNK)), arg(), arg((pages,)), arg(),
+            arg((1, POOL_CHUNK)), arg(), {"block_table": arg((pages,))},
+            arg(),
             arg(key.shape, key.dtype), arg(), arg((), f32), arg(),
             arg((), f32), candidates=64)
     pool_elems = blocks * BLOCK * cfg.embed_dim
