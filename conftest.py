@@ -56,6 +56,9 @@ _QUICK = (
     # a model with two cache kinds through the paged engine against the
     # benchmark's plain reference (ISSUE 30)
     "test_latent_serving.py",
+    # the engine's weights in the compute type, cast once where a tree is
+    # taken: bitwise tokens and logits, no convert in the tick (ISSUE 31)
+    "test_serving_weights.py",
     "test_utils.py",                          # meters, guards, trace tools
     "test_mesh.py",                           # mesh/axis construction
     "test_auto.py",                           # sharding-ladder planner

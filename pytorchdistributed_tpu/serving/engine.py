@@ -93,6 +93,7 @@ from pytorchdistributed_tpu.serving.paging import (
     SlotPool,
 )
 from pytorchdistributed_tpu.serving.telemetry import ServingTelemetry
+from pytorchdistributed_tpu.serving.weights import cast_only, narrowed, wider
 from pytorchdistributed_tpu.telemetry.spans import span
 from pytorchdistributed_tpu.telemetry.tracing import (
     TraceContext,
@@ -858,6 +859,9 @@ class ServingEngine:
       model: a causal LM module (GPT2 / Llama ...) — decode or train
         config; the engine derives its slot-decode twin either way.
       params: the trained variables, possibly sharded (pass ``mesh``).
+        Held as ONE tree in the model's compute type: the leaves the
+        forward pass would only cast to ``cfg.dtype`` are cast here,
+        once, not in every tick (``set_params``; serving/weights.py).
       num_slots: concurrent requests resident in the KV cache — the
         engine's batch dim, fixed at compile time.
       prefill_bucket: prompts are right-padded up to this multiple so
@@ -1200,16 +1204,6 @@ class ServingEngine:
                                   kv_sink_tokens=self.kv_sink_tokens,
                                   kv_window_tokens=self.kv_window_tokens,
                                   per_slot_kv_limits=self.per_slot_limits)
-            # unbox (nn.meta) at boot: callers hand model.init output
-            # with LogicallyPartitioned boxes as often as plain trees,
-            # and the hot-swap path compares TREEDEFS — a boxed boot
-            # tree would refuse every trainer-produced (unboxed) swap
-            import flax.linen as nn
-
-            self._draft_weights = nn.meta.unbox(
-                draft_params["params"] if "params" in draft_params
-                else draft_params)
-        self._weights = params["params"] if "params" in params else params
         with self._mesh_ctx():
             self._cache = _zero_cache(
                 self._tick_model, jnp.zeros((num_slots, 1), jnp.int32))
@@ -1217,6 +1211,26 @@ class ServingEngine:
                 self._draft_cache = _zero_cache(
                     self._draft_tick_model,
                     jnp.zeros((num_slots, 1), jnp.int32))
+        # (model, treedef) -> which leaves of such a tree the model only
+        # casts to its compute type: traced the first time a tree brings
+        # a leaf wider than that type (_compute_copy)
+        self._cast_only: dict = {}
+        self.set_params(params)
+        if spec_k:
+            import flax.linen as nn
+
+            # a self-draft shares the target's leaves, so it takes them
+            # as the target now holds them
+            draft_params = (self._weights if draft_params is params
+                            else draft_params)
+            # unbox (nn.meta) at boot: callers hand model.init output
+            # with LogicallyPartitioned boxes as often as plain trees,
+            # and the hot-swap path compares TREEDEFS — a boxed boot
+            # tree would refuse every trainer-produced (unboxed) swap
+            self._draft_weights, _ = self._compute_copy(
+                self._draft_tick_model, self._draft_cache, nn.meta.unbox(
+                    draft_params["params"] if "params" in draft_params
+                    else draft_params))
         # the KV cache HBM footprint (pool or dense rows) — the bench's
         # capacity-per-byte denominator; the draft pool is accounted
         # separately (it shares block IDs, not bytes)
@@ -3301,8 +3315,43 @@ class ServingEngine:
         compiled programs retrace on a structure change, never on new
         values). The quarantine/rejoin path: an operator repairs a
         NaN'd replica by reloading a verified checkpoint here, then the
-        router's warmup re-admission probes it healthy again."""
-        self._weights = params["params"] if "params" in params else params
+        router's warmup re-admission probes it healthy again.
+
+        The engine keeps ONE tree, in the type the programs compute in:
+        a leaf stored wider than ``cfg.dtype`` that the model would only
+        cast to it (every matrix it multiplies by, the embeddings) is
+        cast here, once, and every other leaf (a norm's float32 gain, a
+        tree already stored in the compute type) is kept as it came
+        (serving/weights.py). So a float32 checkpoint and the bfloat16
+        tree of another replica are both good arguments, and neither
+        retraces."""
+        self._weights, self._weight_bytes_cast = self._compute_copy(
+            self._tick_model, self._cache,
+            params["params"] if "params" in params else params)
+
+    def _compute_copy(self, model, cache, tree):
+        """`tree` as `model`'s programs take it (module docstring of
+        serving/weights.py), and the bytes the leaves cast here held
+        before. A tree with no leaf wider than the compute type comes
+        back as it is, untraced."""
+        dtype = model.cfg.dtype
+        leaves, treedef = jax.tree.flatten(tree)
+        if not any(wider(leaf, dtype) for leaf in leaves):
+            return tree, 0
+        flags = self._cast_only.get((model, treedef))
+        if flags is None:
+            tokens = jnp.zeros((self.num_slots, 1), jnp.int32)
+            # a model with proposal heads reads them in `spec_logits`
+            # alone, which reads every other leaf as the call does
+            method = ("spec_logits" if getattr(model.cfg, "spec_heads", 0)
+                      else None)
+            with self._mesh_ctx():
+                flags = self._cast_only[model, treedef] = cast_only(
+                    lambda w, c: model.apply(
+                        {"params": w, "cache": c}, tokens, method=method,
+                        mutable=["cache", "counters"]),
+                    tree, dtype, cache)
+        return narrowed(tree, flags, dtype)
 
     def set_draft_params(self, params) -> None:
         """Hot-swap the DRAFT weights mid-serving (ISSUE 16) — the
@@ -3338,6 +3387,12 @@ class ServingEngine:
                     f"{jax.tree_util.keystr(path)}: engine has "
                     f"{getattr(a, 'shape', None)}, swap brings "
                     f"{getattr(b, 'shape', None)}")
+        # the swap in the type the tick computes in, as the resident tree
+        # is held (set_params): a float32 checkpoint of a draft that was
+        # booted from float32 matches, leaf for leaf
+        new, _ = self._compute_copy(self._draft_tick_model,
+                                    self._draft_cache, new)
+        for (path, a), b in zip(old_leaves[0], jax.tree.leaves(new)):
             if jnp.asarray(b).dtype != getattr(a, "dtype", None):
                 raise ValueError(
                     f"draft param dtype mismatch at "
@@ -3492,6 +3547,12 @@ class ServingEngine:
                     np.asarray(st[f"{key}_s"], np.float64), q)) * 1e3, 3)
         out["admit_blocked"] = st["admit_blocked"]
         out["kv_hbm_bytes"] = self.kv_hbm_bytes
+        # the tree the programs take, and what its leaves held before
+        # the last set_params cast them to the compute type (0: served
+        # in the type it came in)
+        out["weight_bytes_served"] = sum(
+            leaf.nbytes for leaf in jax.tree.leaves(self._weights))
+        out["weight_bytes_cast"] = self._weight_bytes_cast
         if self.paged:
             out["block_size"] = self.block_size
             out["num_blocks"] = self.num_blocks
