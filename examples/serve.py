@@ -51,14 +51,6 @@ def main():
                              "truncating the trained model to its first "
                              "N layers (0 = self-draft with the full "
                              "model, acceptance ~1)")
-    parser.add_argument("--compile-cache", type=str, default=None,
-                        help="persistent AOT executable cache directory "
-                             "(README 'Cold start & elastic recovery'): "
-                             "first run compiles + serializes every "
-                             "serving program, later runs deserialize "
-                             "them — restart reaches its first token "
-                             "with zero XLA compiles. PTD_COMPILE_CACHE "
-                             "works too")
     parser.add_argument("--replicas", type=int, default=1,
                         help="> 1: serve through the health-checked "
                              "ReplicaRouter over this many in-process "
@@ -254,9 +246,7 @@ def main():
             engine_kwargs=dict(num_slots=args.num_slots,
                                prefill_bucket=16,
                                block_size=args.block_size,
-                               spec_k=args.spec_k,
-                               compile_cache=args.compile_cache or "auto",
-                               **spec_kw),
+                               spec_k=args.spec_k, **spec_kw),
             warmup_lens=(16,), telemetry_dir=args.telemetry_dir,
             **router_kw)
         router.warmup()
@@ -382,8 +372,7 @@ def main():
         num_slots=args.num_slots, prefill_bucket=16,
         block_size=args.block_size, spec_k=args.spec_k, **spec_kw,
         mesh=mesh if args.moe_experts else None,
-        telemetry_dir=args.telemetry_dir,
-        compile_cache=args.compile_cache or "auto")
+        telemetry_dir=args.telemetry_dir)
     engine.warmup(prompt_lens=(16,))
 
     # staggered mixed-length traffic: more requests than slots, per-request

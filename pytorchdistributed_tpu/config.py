@@ -116,8 +116,7 @@ class ExperimentConfig:
     # draft_layers > 0 builds the draft by truncating the served model
     # to its first N layers (inference.truncated_draft); 0 self-drafts
     # with the full model. Serving-only knobs: training ignores them
-    # (examples/serve.py --spec-k/--draft-layers and bench.py
-    # PTD_SERVE_SPEC/PTD_SPEC_K consume the same pair).
+    # (examples/serve.py --spec-k/--draft-layers consume the pair).
     spec_k: int = 0
     draft_layers: int = 0
 
@@ -452,8 +451,7 @@ def make_optimizer(cfg: ExperimentConfig):
         opt = optax.sgd(lr, momentum=0.9)
     elif cfg.optimizer == "adafactor":
         # the memory-factored choice: second moment stored as row/col
-        # factors — what lets 1B+ models train on one 16G chip (bench.py
-        # llama1b)
+        # factors — what lets 1B+ models train on one 16G chip
         opt = optax.adafactor(lr)
     else:
         raise ValueError(f"unknown optimizer {cfg.optimizer!r}")

@@ -29,8 +29,8 @@ silence → the per-op timeout machinery.
 
 ``recovery_table`` is the read side: given the router's telemetry event
 stream it matches each injection to its detection and recovery events
-and reports per-fault-class MTTR percentiles — the number the soak
-stamps into BENCH_soak.json.
+and reports per-fault-class MTTR percentiles — the number a soak's
+report carries (serving/soak.py).
 """
 
 from __future__ import annotations

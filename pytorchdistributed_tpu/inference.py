@@ -285,9 +285,8 @@ def kv_cache_bytes(cache) -> int:
     or the paged block pool — the counter/table leaves are noise).
     Includes the int8 pool's fp32 scale planes: they are real HBM the
     compressed pool pays, so "same HBM budget" A/Bs charge for them.
-    Shared by the serving engine's summary and bench.py's paged-capacity
-    A/B, so both sides of every "same HBM budget" claim are measured by
-    the one function."""
+    The serving engine's summary reads it, so both sides of a "same HBM
+    budget" comparison are measured by the one function."""
     total = 0
     for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]:
         name = getattr(path[-1], "key", str(path[-1]))

@@ -11,7 +11,3 @@ from pytorchdistributed_tpu.runtime.dist import (  # noqa: F401
     get_world_size,
     is_initialized,
 )
-from pytorchdistributed_tpu.runtime.compile_cache import (  # noqa: F401
-    COMPILE_CACHE_DIR_ENV,
-    CompileCache,
-)

@@ -1,16 +1,15 @@
 """Where JAX's persistent compilation cache lives.
 
-One rule for every entry point (chip_smoke.py, bench.py, the examples,
-the replica worker, the test bootstrap): when ``JAX_COMPILATION_CACHE_DIR``
+One rule for every entry point (chip_smoke.py, benchmark/run.py, the
+examples, the replica worker, the test bootstrap): when ``JAX_COMPILATION_CACHE_DIR``
 is set, JAX reads it itself and no code names another directory — the
 machine that runs the program decides where compiled programs are kept.
 Unset, the cache is ``<checkout>/.jax_cache``: a fixed path, because the
 path is part of what a warm start must find again, so never a temporary
 name, a pid or a time.
 
-This is JAX's own cache of XLA executables. `runtime/compile_cache.py`
-(``PTD_COMPILE_CACHE``) is the repo's separate AOT-executable store and is
-not switched on here.
+This is JAX's own cache of XLA executables, and the only compile cache
+the repo has.
 """
 
 from __future__ import annotations

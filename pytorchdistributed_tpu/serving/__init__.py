@@ -43,7 +43,7 @@
                    heavy-tail lengths, shared-prefix tenant mixes,
                    multi-turn conversations with think-time gaps) and
                    the fake-clock replay()/replay_conversations()
-                   drivers the bench and the quick test tier share
+                   drivers of the quick test tier and the soak
   * soak.py      — chaos soak (ISSUE 19): InvariantChecker (continuous
                    no-orphans / fairness / SLO-debt / zero-recompile /
                    all-streams-terminal assertions over a live fleet)
@@ -58,9 +58,9 @@
                    FleetSessionIndex steers reattaching turns to the
                    owner or pulls/seeds the payload over the wire
 
-`bench.py --mode serve` drives it under a Poisson arrival trace (plus
-the paged capacity, prefix-reuse and autoscale A/Bs); examples/serve.py
-is the train-then-serve demo.
+benchmark/drivers/serve_open_loop.py drives it open loop on the chip
+(the cells of BENCHMARK.json); examples/serve.py is the train-then-serve
+demo.
 """
 
 from pytorchdistributed_tpu.serving.admission import (  # noqa: F401

@@ -8,7 +8,7 @@ rings — queue depth, TTFT EMA, shed rate, slot occupancy, prefill
 backlog — against an ``SLOConfig``, and turns sustained breaches into
 ``router.add_replica()`` (the warm-join path: in-process joins share
 the jit cache and compile NOTHING; subprocess joins restore from
-checkpoint + the persistent AOT cache) and sustained idleness into
+checkpoint + JAX's persistent compilation cache) and sustained idleness into
 ``router.remove_replica()`` (graceful DRAINING -> tombstone — no
 stream is ever dropped by a scale-down).
 
@@ -32,7 +32,7 @@ snapshot that justified it, and emitted as an ``autoscale_up`` /
 ``autoscale_down`` TelemetryEvent — the report CLI's scaling timeline.
 ``reaction_times()`` joins scale-up decisions against
 ``router.first_token_times`` to measure decision -> first-token wall
-latency, the bench's reaction stamp.
+latency.
 
 The router surface consumed here is deliberately narrow —
 ``telemetry.snapshot()``, ``pool_state()``, ``add_replica()`` /
@@ -264,7 +264,7 @@ class Autoscaler:
     def reaction_times(self) -> list[dict]:
         """Per scale-up decision: wall seconds from the decision to the
         new replica's FIRST delivered token (None while it hasn't
-        served yet) — the autoscale bench's reaction stamp."""
+        served yet)."""
         ftt = self.router.first_token_times
         out = []
         for d in self.decisions:

@@ -84,10 +84,6 @@ from pytorchdistributed_tpu.inference import (
     sample_slots,
     stop_ids_tuple,
 )
-from pytorchdistributed_tpu.runtime.compile_cache import (
-    CompileCache,
-    static_repr,
-)
 from pytorchdistributed_tpu.serving.paging import (
     RadixPrefixCache,
     SlotPool,
@@ -928,17 +924,6 @@ class ServingEngine:
         bitwise-invariant to the mask). Default off: the accounting
         (draft_tokens counts the effective depth) and the extra operand
         change nothing unless asked for.
-      compile_cache: the persistent AOT executable cache (ISSUE 10,
-        runtime/compile_cache.py): a CompileCache, a directory path, or
-        the default "auto" (the PTD_COMPILE_CACHE env contract; off
-        when unset). With a cache attached, every compiled program —
-        tick/prefill/spec/probe — dispatches through an AOT executable
-        that is DESERIALIZED from disk on a hit and
-        lower().compile()'d + published on a miss, so a restarted or
-        respawned engine reaches its first token with zero XLA
-        compiles; warmup() collapses to one probe round per bucket.
-        The contract is never-fails: any cache defect quarantines the
-        entry and the engine falls back to the plain jit path.
       kv_dtype: paged pool storage dtype (ISSUE 13): "bf16" (default —
         the model dtype; the bitwise-vs-generate() contract holds) or
         "int8" — blocks store int8 codes plus per-(token, head) fp32
@@ -983,7 +968,7 @@ class ServingEngine:
                  prefill_chunks_per_step: int = 1,
                  spec_k: int = 0, draft_config=None, draft_params=None,
                  adaptive_k: bool = False,
-                 compile_cache="auto", kv_dtype: str | None = None,
+                 kv_dtype: str | None = None,
                  kv_sink_tokens: int | None = None,
                  kv_window_tokens: int | None = None,
                  paged_attn: str | None = None, trace=None,
@@ -1145,7 +1130,7 @@ class ServingEngine:
             # holding its slot's block list (refcounts transferred off
             # the slot at retirement). dict order == LRU; past
             # ``session_hbm_max`` the eldest demotes into a
-            # KVBlockPayload (gather — the existing AOT program) bound
+            # KVBlockPayload (gather — the existing program) bound
             # for ``session_store`` (host-DRAM/disk tiers) or the
             # spill queue a router drains over the wire
             self._sessions: dict[str, dict] = {}
@@ -1231,8 +1216,8 @@ class ServingEngine:
                 self._draft_tick_model, self._draft_cache, nn.meta.unbox(
                     draft_params["params"] if "params" in draft_params
                     else draft_params))
-        # the KV cache HBM footprint (pool or dense rows) — the bench's
-        # capacity-per-byte denominator; the draft pool is accounted
+        # the KV cache HBM footprint (pool or dense rows); the draft
+        # pool is accounted
         # separately (it shares block IDs, not bytes)
         self.kv_hbm_bytes = kv_cache_bytes(self._cache)
         self.draft_kv_hbm_bytes = (
@@ -1283,18 +1268,6 @@ class ServingEngine:
         # (the owner does); rows are line-buffered, so a crashed worker
         # loses nothing.
         self.trace = trace
-        # AOT executable table (ISSUE 10): with a compile cache
-        # attached, every compiled-program call goes through _aot_call —
-        # a per-program jax.stages.Compiled either deserialized from the
-        # cache or lower().compile()'d once and published. Without one
-        # (the default when PTD_COMPILE_CACHE is unset) the engine calls
-        # the module-level jit wrappers exactly as before.
-        self._compile_cache = CompileCache.resolve(compile_cache)
-        self._exec: dict[str, object] = {}
-        self._aot_failed: set[str] = set()
-        #: name -> "hit" | "miss" per AOT-resolved program (tests and
-        #: the coldstart bench read this after warmup)
-        self.aot_outcomes: dict[str, str] = {}
         self.reset_stats()
 
     # ------------------------------------------------------------------
@@ -1513,10 +1486,9 @@ class ServingEngine:
         t0 = time.perf_counter()
         with span("serve/decode_tick"), self._mesh_ctx():
             with span("serve/tick_dispatch"):
-                name, tick, args = self._tick_program()
-                out = self._aot_call(
-                    name, tick, (self._tick_model,), args,
-                    dict(candidates=self.candidates))
+                tick, args = self._tick_program()
+                out = tick(self._tick_model, *args,
+                           candidates=self.candidates)
                 self._cache, nxt = out[:2]
             with span("serve/tick_sync"):
                 if self._counter_names:
@@ -1530,7 +1502,6 @@ class ServingEngine:
         self._progress += 1
         st = self._stats
         st["ticks"] += 1
-        st["tick_s"] += dt
         st["occupancy_sum"] += len(self._active) / self.num_slots
         row = {}
         if self.paged:
@@ -1574,44 +1545,40 @@ class ServingEngine:
         with span("serve/spec_tick"), self._mesh_ctx():
             with span("serve/tick_dispatch"):
                 # adaptive off keeps the k_eff=None operand list — the exact
-                # pre-ISSUE-16 program, so committed AOT caches and the
-                # serve_spec_tick invariant pin stay valid
+                # pre-ISSUE-16 program, so the serve_spec_tick invariant
+                # pin stays valid
                 tail = ((jnp.asarray(self._k_eff),) if adaptive else ())
                 if heads:
                     (self._cache, self._draft_cache, out,
-                     nacc) = self._aot_call(
-                        "spec_decode_tick_heads", spec_decode_tick_heads,
-                        (self._tick_model, self._draft_tick_model),
-                        (self._weights, self._draft_weights, self._cache,
-                         self._draft_cache,
-                         self._device_tables(),
-                         jnp.asarray(self._lengths),
-                         jnp.asarray(self._spec_prev_start),
-                         jnp.asarray(self._spec_prev_tokens),
-                         jnp.asarray(self._spec_prev_idx),
-                         jnp.asarray(self._tokens),
-                         jnp.asarray(self._key_data),
-                         jnp.asarray(self._counts),
-                         jnp.asarray(self._temps), jnp.asarray(self._top_ks),
-                         jnp.asarray(self._top_ps)) + tail,
-                        dict(spec_k=self.spec_k, candidates=self.candidates),
-                        donation="cache,draft_cache")
+                     nacc) = spec_decode_tick_heads(
+                        self._tick_model, self._draft_tick_model,
+                        self._weights, self._draft_weights, self._cache,
+                        self._draft_cache,
+                        self._device_tables(),
+                        jnp.asarray(self._lengths),
+                        jnp.asarray(self._spec_prev_start),
+                        jnp.asarray(self._spec_prev_tokens),
+                        jnp.asarray(self._spec_prev_idx),
+                        jnp.asarray(self._tokens),
+                        jnp.asarray(self._key_data),
+                        jnp.asarray(self._counts),
+                        jnp.asarray(self._temps), jnp.asarray(self._top_ks),
+                        jnp.asarray(self._top_ps), *tail,
+                        spec_k=self.spec_k, candidates=self.candidates)
                 else:
                     (self._cache, self._draft_cache, out,
-                     nacc) = self._aot_call(
-                        "spec_decode_tick", spec_decode_tick,
-                        (self._tick_model, self._draft_tick_model),
-                        (self._weights, self._draft_weights, self._cache,
-                         self._draft_cache,
-                         self._device_tables(),
-                         jnp.asarray(self._lengths),
-                         jnp.asarray(self._tokens),
-                         jnp.asarray(self._key_data),
-                         jnp.asarray(self._counts),
-                         jnp.asarray(self._temps), jnp.asarray(self._top_ks),
-                         jnp.asarray(self._top_ps)) + tail,
-                        dict(spec_k=self.spec_k, candidates=self.candidates),
-                        donation="cache,draft_cache")
+                     nacc) = spec_decode_tick(
+                        self._tick_model, self._draft_tick_model,
+                        self._weights, self._draft_weights, self._cache,
+                        self._draft_cache,
+                        self._device_tables(),
+                        jnp.asarray(self._lengths),
+                        jnp.asarray(self._tokens),
+                        jnp.asarray(self._key_data),
+                        jnp.asarray(self._counts),
+                        jnp.asarray(self._temps), jnp.asarray(self._top_ks),
+                        jnp.asarray(self._top_ps), *tail,
+                        spec_k=self.spec_k, candidates=self.candidates)
             with span("serve/tick_sync"):
                 toks = np.asarray(out)   # host sync: streaming delivery
                 ns = np.asarray(nacc)
@@ -1619,7 +1586,6 @@ class ServingEngine:
         n_active = len(self._active)
         self._progress += 1
         st["ticks"] += 1
-        st["tick_s"] += dt
         st["occupancy_sum"] += n_active / self.num_slots
         used = self._alloc.usable - self._alloc.free_count
         st["block_used_sum"] += used / self._alloc.usable
@@ -1889,12 +1855,11 @@ class ServingEngine:
                 jax.random.key(req.sampling.seed))))
         return True
 
-    def _chunk_call(self, name, model, weights, cache, pf, pos):
+    def _chunk_call(self, model, weights, cache, pf, pos):
         """One paged_prefill_chunk call for the admission in flight, at
         absolute position ``pos`` of its token stream — shared by the
-        target and (spec mode) draft cache fills, which are distinct
-        AOT programs (``name`` keys the executable table: same shapes,
-        different static model)."""
+        target and (spec mode) draft cache fills (same shapes, different
+        static model)."""
         req = pf["req"]
         chunk = np.zeros((1, self.chunk), np.int32)
         n = min(self.chunk, pf["true_len"] - pos)
@@ -1904,21 +1869,20 @@ class ServingEngine:
                 self._back_window(pool, pf["slot"],
                                   pf["table_row"][pool.table], pos,
                                   pos + self.chunk)
-        return self._aot_call(
-            name, paged_prefill_chunk, (model,),
-            (weights, cache,
-             jnp.asarray(chunk), jnp.int32(pos),
-             # copies (`jnp.array`): the next chunk's `_back_window`
-             # edits the window row in place while this chunk may still
-             # run, and on the CPU `jnp.asarray` can alias host memory
-             jax.tree.map(jnp.array, pf["table_row"]),
-             jnp.int32(pf["true_len"]),
-             jnp.asarray(pf["kd"]),
-             jnp.int32(pf["resume"]),
-             jnp.float32(req.sampling.temperature),
-             jnp.int32(req.sampling.top_k),
-             jnp.float32(req.sampling.top_p)),
-            dict(candidates=self.candidates))
+        return paged_prefill_chunk(
+            model, weights, cache,
+            jnp.asarray(chunk), jnp.int32(pos),
+            # copies (`jnp.array`): the next chunk's `_back_window`
+            # edits the window row in place while this chunk may still
+            # run, and on the CPU `jnp.asarray` can alias host memory
+            jax.tree.map(jnp.array, pf["table_row"]),
+            jnp.int32(pf["true_len"]),
+            jnp.asarray(pf["kd"]),
+            jnp.int32(pf["resume"]),
+            jnp.float32(req.sampling.temperature),
+            jnp.int32(req.sampling.top_k),
+            jnp.float32(req.sampling.top_p),
+            candidates=self.candidates)
 
     def _prefill_chunk_step(self) -> int:
         """Run ONE chunk step of the in-flight admission — a target
@@ -1930,15 +1894,13 @@ class ServingEngine:
         sampled next token. Returns 1 on completed admission, else 0."""
         pf = self._prefilling
         req, slot = pf["req"], pf["slot"]
-        t0 = time.perf_counter()
         with (span("serve/prefill", request=req.id, pos=pf["pos"]),
               self._mesh_ctx()):
             if pf["pos"] < pf["true_len"]:
                 pos = pf["pos"]
                 final_t = pos + self.chunk >= pf["true_len"]
                 self._cache, first = self._chunk_call(
-                    "paged_prefill_chunk", self._chunk_model,
-                    self._weights, self._cache, pf, pos)
+                    self._chunk_model, self._weights, self._cache, pf, pos)
                 if final_t:
                     # sync: the TTFT timestamp is honest
                     with span("serve/prefill_sync", request=req.id):
@@ -1946,13 +1908,12 @@ class ServingEngine:
                 pf["pos"] = pos + self.chunk
             if self.spec_k and pf["dpos"] < pf["true_len"]:
                 self._draft_cache, _ = self._chunk_call(
-                    "paged_prefill_chunk_draft", self._draft_chunk_model,
-                    self._draft_weights, self._draft_cache, pf, pf["dpos"])
+                    self._draft_chunk_model, self._draft_weights,
+                    self._draft_cache, pf, pf["dpos"])
                 pf["dpos"] += self.chunk
         now = time.perf_counter()
         self._progress += 1
         st = self._stats
-        st["prefill_s"] += now - t0
         st["prefill_chunks"] += 1
         req.prefill_chunks += 1
         if pf["pos"] < pf["true_len"] or (
@@ -2228,9 +2189,7 @@ class ServingEngine:
         ids = np.zeros(self.cfg.kv_pages, np.int32)
         ids[:nb] = blocks
         with self._mesh_ctx():
-            gathered = self._aot_call(
-                "kv_block_gather", kv_block_gather, (),
-                (self._cache, jnp.asarray(ids)), {}, donation="")
+            gathered = kv_block_gather(self._cache, jnp.asarray(ids))
         out = []
         for name, leaf in zip(self._pool_leaf_names(), gathered):
             a = np.asarray(leaf)  # host sync
@@ -2253,10 +2212,8 @@ class ServingEngine:
             pad[axis] = (0, self.cfg.kv_pages - a.shape[axis])
             padded.append(jnp.asarray(np.pad(a, pad)))
         with self._mesh_ctx():
-            self._cache = self._aot_call(
-                "kv_block_scatter", kv_block_scatter, (),
-                (self._cache, jnp.asarray(ids), padded), {},
-                donation="cache")
+            self._cache = kv_block_scatter(self._cache, jnp.asarray(ids),
+                                           padded)
 
     def export_kv_blocks(self, req: Request) -> KVBlockPayload:
         """Gather a PARKED request's KV blocks off the pool into a
@@ -2833,40 +2790,17 @@ class ServingEngine:
         jitted programs' _cache_size are the tests' tripwires) and the
         first real TTFT pays no compile.
 
-        TWO serial rounds per bucket on purpose (plain jit path): the
-        engine's fresh cache is an uncommitted array, so round one
-        compiles each program against it, and jit then recompiles —
-        without retracing — when the cache next arrives committed from
-        another executable's output. Round two runs every program with
-        exactly the steady-state (committed) input shardings.
-
-        With a compile cache attached (ISSUE 10), ONE round suffices:
-        every program dispatches through an AOT executable whose input
-        convention was fixed at lower time, so the fresh-vs-committed
-        recompile the second round exists to absorb cannot happen — a
-        cache hit makes the round a pure deserialized-executable probe
-        (zero traces, zero XLA compiles), a miss compiles each program
-        exactly once and publishes it. Either way the TTFT EMA is still
-        reset below: warmup TTFTs (deserialize or compile) must never
-        skew the router's balancer."""
+        TWO serial rounds per bucket on purpose: the engine's fresh
+        cache is an uncommitted array, so round one compiles each
+        program against it, and jit then recompiles — without
+        retracing — when the cache next arrives committed from another
+        executable's output. Round two runs every program with exactly
+        the steady-state (committed) input shardings."""
         lens = tuple(prompt_lens) if prompt_lens else (self.bucket,)
-        rounds = 1 if self._compile_cache is not None else 2
-        for n in lens * rounds:
+        for n in lens * 2:
             n = max(1, min(n, self.cfg.max_seq_len - max_new_tokens))
             self.submit(np.zeros(n, np.int32), max_new_tokens=max_new_tokens)
             self.run_until_idle()
-        if rounds == 1 and self._aot_failed:
-            # a program fell back to jit during the single cached round
-            # (cache defect / unserializable backend): give the jit
-            # path its second round too, or the first real request
-            # would pay the fresh-vs-committed recompile on the hot
-            # path — the never-fails contract covers warmup's
-            # no-first-TTFT-compile promise as well
-            for n in lens:
-                n = max(1, min(n, self.cfg.max_seq_len - max_new_tokens))
-                self.submit(np.zeros(n, np.int32),
-                            max_new_tokens=max_new_tokens)
-                self.run_until_idle()
         # warm the health probe too: a router polling
         # check_params_finite() must find it compiled, or the first
         # steady-state health check pays a trace
@@ -2993,22 +2927,20 @@ class ServingEngine:
                 for pool in self._pools}
 
     def _tick_program(self):
-        """(name, jitted program, dynamic args) of the plain decode tick
-        over the live host state: one shared per-slot argument tail; the
+        """(jitted program, dynamic args) of the plain decode tick over
+        the live host state: one shared per-slot argument tail; the
         paged tick just prepends the host-stamped block tables and
         lengths."""
-        name, tick, head = (("paged_decode_tick", paged_decode_tick,
-                             (self._device_tables(),
-                              jnp.asarray(self._lengths)))
-                            if self.paged
-                            else ("decode_tick", decode_tick, ()))
-        return name, tick, (self._weights, self._cache, *head,
-                            jnp.asarray(self._tokens),
-                            jnp.asarray(self._key_data),
-                            jnp.asarray(self._counts),
-                            jnp.asarray(self._temps),
-                            jnp.asarray(self._top_ks),
-                            jnp.asarray(self._top_ps))
+        tick, head = ((paged_decode_tick, (self._device_tables(),
+                                           jnp.asarray(self._lengths)))
+                      if self.paged else (decode_tick, ()))
+        return tick, (self._weights, self._cache, *head,
+                      jnp.asarray(self._tokens),
+                      jnp.asarray(self._key_data),
+                      jnp.asarray(self._counts),
+                      jnp.asarray(self._temps),
+                      jnp.asarray(self._top_ks),
+                      jnp.asarray(self._top_ps))
 
     def lower_tick(self, *, platforms: tuple[str, ...] | None = None):
         """AOT-lower this engine's plain decode tick from its live
@@ -3017,7 +2949,7 @@ class ServingEngine:
         ``.compile().as_text()`` is the HLO the tick dispatches, which is
         how chip_smoke.py and the lowering tests see whether the paged
         kernel is really in it."""
-        _, tick, args = self._tick_program()
+        tick, args = self._tick_program()
         with self._mesh_ctx():
             if platforms is None:
                 return tick.lower(self._tick_model, *args,
@@ -3035,81 +2967,6 @@ class ServingEngine:
                            for d in leaf.devices()})
 
         return {"weights": ids(self._weights), "kv": ids(self._cache)}
-
-    def _aot_call(self, name, jit_fn, statics, args, kw_statics, *,
-                  donation="cache"):
-        """Dispatch one compiled-program call. With a compile cache:
-        resolve ``name`` to an AOT ``jax.stages.Compiled`` (deserialize
-        on a cache hit — no trace, no XLA compile; ``lower().compile()``
-        + publish on a miss) and call it with the DYNAMIC args only
-        (statics are baked into the executable). The AOT convention is
-        fixed at lower time, so the fresh-vs-committed-cache recompile
-        jit performs (the reason warmup ran two rounds) cannot happen
-        here. Any failure — cache defect, a backend that cannot
-        serialize, an executable rejecting a call — permanently falls
-        this program back to the plain jit path: the cache may only
-        ever make startup faster, never serving wrong or dead. Callers
-        invoke this inside their ``_mesh_ctx()``, so lowering sees the
-        same ambient mesh the jit path traces under."""
-        ex = self._exec.get(name)
-        if (ex is None and self._compile_cache is not None
-                and name not in self._aot_failed):
-            ex = self._aot_load_or_compile(name, jit_fn, statics, args,
-                                           kw_statics, donation)
-        if ex is not None:
-            try:
-                return ex(*args)
-            except Exception as e:  # noqa: BLE001 — never-fails contract
-                self._exec.pop(name, None)
-                self._aot_failed.add(name)
-                if self._compile_cache is not None:
-                    self._compile_cache.note_exec_failure(name, e)
-                # signature/sharding rejections raise BEFORE execution,
-                # leaving the donated buffers intact for the jit retry;
-                # a mid-execution failure (runtime error, OOM) has
-                # already consumed them — re-raise the REAL error
-                # rather than letting the retry mask it with a bogus
-                # "Array has been deleted"
-                if any(getattr(a, "is_deleted", lambda: False)()
-                       for a in jax.tree_util.tree_leaves(args)):
-                    raise
-        return jit_fn(*statics, *args, **kw_statics)
-
-    def _aot_load_or_compile(self, name, jit_fn, statics, args,
-                             kw_statics, donation):
-        srepr = ";".join(
-            [static_repr(s) for s in statics]
-            + [f"{k}={v!r}" for k, v in sorted(kw_statics.items())])
-        cfg_hash = (f"slots={self.num_slots};bucket={self.bucket};"
-                    f"block={self.block_size};blocks={self.num_blocks};"
-                    f"spec_k={self.spec_k};kvd={self.kv_dtype};"
-                    f"sink={self.kv_sink_tokens};"
-                    f"win={self.kv_window_tokens};"
-                    f"pattn={self.paged_attn};"
-                    # per-slot KV limits change the cache tree (kv_sinks/
-                    # kv_windows leaves) — a stale windowed executable from
-                    # before ISSUE 15 would deserialize against the wrong
-                    # donation layout, so the flag is part of the key
-                    f"pslot={int(self.per_slot_limits)};"
-                    # ISSUE 16: proposal heads change the draft tree and
-                    # the tick program; adaptive k adds the k_eff operand
-                    f"sheads={self._spec_heads};"
-                    f"adk={int(self.adaptive_k)}")
-
-        def compile_fn():
-            return jit_fn.lower(*statics, *args, **kw_statics).compile()
-
-        try:
-            compiled, outcome = self._compile_cache.load_or_compile(
-                name, compile_fn, args, statics=srepr,
-                config_hash=cfg_hash, donation=donation)
-        except Exception as e:  # noqa: BLE001 — never-fails contract
-            self._aot_failed.add(name)
-            self._compile_cache.note_exec_failure(name, e)
-            return None
-        self._exec[name] = compiled
-        self.aot_outcomes[name] = outcome
-        return compiled
 
     def _mesh_ctx(self):
         return (jax.set_mesh(self.mesh) if self.mesh is not None
@@ -3134,27 +2991,22 @@ class ServingEngine:
         padded[0, :n] = tokens
         kd = np.asarray(jax.random.key_data(
             jax.random.key(req.sampling.seed)))
-        t0 = time.perf_counter()
         with span("serve/prefill", request=req.id), self._mesh_ctx():
-            # one AOT program per prefill bucket length, same as the
-            # one-jit-signature-per-bucket the plain path compiles
-            self._cache, first = self._aot_call(
-                f"prefill_b{padded_len}", prefill_into_slot,
-                (self._prefill_model,),
-                (self._weights, self._cache,
-                 jnp.asarray(padded), jnp.int32(n), jnp.int32(slot),
-                 jnp.asarray(kd), jnp.int32(resume),
-                 jnp.float32(req.sampling.temperature),
-                 jnp.int32(req.sampling.top_k),
-                 jnp.float32(req.sampling.top_p)),
-                dict(candidates=self.candidates))
+            # one jit signature per prefill bucket length
+            self._cache, first = prefill_into_slot(
+                self._prefill_model, self._weights, self._cache,
+                jnp.asarray(padded), jnp.int32(n), jnp.int32(slot),
+                jnp.asarray(kd), jnp.int32(resume),
+                jnp.float32(req.sampling.temperature),
+                jnp.int32(req.sampling.top_k),
+                jnp.float32(req.sampling.top_p),
+                candidates=self.candidates)
             with span("serve/prefill_sync", request=req.id):
                 first = int(first)  # sync: the TTFT timestamp is honest
         now = time.perf_counter()
         self._progress += 1
         st = self._stats
         st["prefills"] += 1
-        st["prefill_s"] += now - t0
         req.slot = slot
         if req.first_token_time is None:
             req.first_token_time = now
@@ -3305,8 +3157,7 @@ class ServingEngine:
         replica's weights carry NaN/Inf — every token it emits is
         garbage and a router must quarantine it."""
         with self._mesh_ctx():
-            ok = bool(self._aot_call("params_finite", params_finite, (),
-                                     (self._weights,), {}, donation=""))
+            ok = bool(params_finite(self._weights))
         self._sick = not ok
         return ok
 
@@ -3452,8 +3303,8 @@ class ServingEngine:
     # stats
 
     def reset_stats(self) -> None:
-        self._stats = dict(ticks=0, tick_s=0.0, prefills=0, prefill_s=0.0,
-                           decode_tokens=0, occupancy_sum=0.0, completed=0,
+        self._stats = dict(ticks=0, prefills=0, decode_tokens=0,
+                           occupancy_sum=0.0, completed=0,
                            deadline_expired=0, ttft_s=[],
                            # one entry a request, beside its ttft_s:
                            # submit -> admit and admit -> first token;
@@ -3513,10 +3364,10 @@ class ServingEngine:
         return int(self.paged and self._prefilling is not None)
 
     def summary(self) -> dict:
-        """Aggregate serving metrics since the last reset_stats():
-        steady-state decode tokens/s (decoded tokens over tick wall
-        time, prefills excluded), TTFT percentiles, mean slot
-        occupancy — the fields bench.py --mode serve stamps."""
+        """Aggregate serving metrics since the last reset_stats(): the
+        counts, TTFT percentiles and their two parts, mean slot
+        occupancy, and what the pool held. A rate is the benchmark's to
+        compute (benchmark/metrics/), from its own window."""
         st = self._stats
         ttfts = np.asarray(st["ttft_s"], np.float64)
         out = {
@@ -3524,15 +3375,9 @@ class ServingEngine:
             "deadline_expired": st["deadline_expired"],
             "ticks": st["ticks"],
             "prefills": st["prefills"],
-            "decode_tokens_per_s": (
-                round(st["decode_tokens"] / st["tick_s"], 1)
-                if st["tick_s"] > 0 else None),
             "slot_occupancy": (
                 round(st["occupancy_sum"] / st["ticks"], 4)
                 if st["ticks"] else None),
-            "prefill_ms_mean": (
-                round(st["prefill_s"] / st["prefills"] * 1e3, 3)
-                if st["prefills"] else None),
         }
         if ttfts.size:
             out["ttft_ms_p50"] = round(

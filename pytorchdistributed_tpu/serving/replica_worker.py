@@ -31,17 +31,15 @@ the router's probe op must come back non-finite.
 The spec: {"model": "gpt2"|"llama", "size": "test", "overrides": {...
 TransformerConfig overrides}, "init_seed": 1, "engine": {...
 ServingEngine kwargs}, "max_seq_len": ..., "checkpoint": <dir>,
-"checkpoint_step": <int>, "compile_cache": <dir>}. Params come from
+"checkpoint_step": <int>}. Params come from
 ``"checkpoint"`` when set — training/checkpoint.py's VERIFIED
 params-only restore (manifest-checked, corrupt steps quarantined and
 walked past), falling back to ``init_seed`` with a logged
 TelemetryEvent when the checkpoint is absent or unusable (a worker
 that cannot load weights must still join the fleet deterministically,
-not die in a respawn loop). ``"compile_cache"`` points the engine at
-the persistent AOT executable cache (runtime/compile_cache.py; the
-PTD_COMPILE_CACHE env works too) — together they are what makes a
-router-respawned replica serve again in load-bound seconds instead of
-compile-bound minutes (ISSUE 10).
+not die in a respawn loop). Compiled programs come from JAX's
+persistent compilation cache (runtime/xla_cache.py), which a respawned
+worker shares with the one it replaces.
 
 Speculative drafts (ISSUE 16): ``spec["engine"]["draft"]`` = {"num_layers":
 <int|null>, "spec_heads": <int>, "checkpoint": <dir>, "checkpoint_step":
@@ -195,8 +193,6 @@ def _build_engine(spec: dict):
 
     trace = RequestTracer.from_env()
     engine_kwargs = dict(spec.get("engine", {}))
-    if spec.get("compile_cache"):
-        engine_kwargs.setdefault("compile_cache", spec["compile_cache"])
     draft = engine_kwargs.pop("draft", None)
     draft_ckpt = None
     if draft:

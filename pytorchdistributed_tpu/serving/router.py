@@ -43,9 +43,8 @@ in PR 4). This module is the SERVING restatement of that contract:
   * and since ISSUE 10, **auto-respawn**: a DEAD replica is RELAUNCHED
     (``respawn_budget`` attempts with exponential backoff) — subprocess
     workers restart under the same env/spec contract, restoring weights
-    from a verified checkpoint and their executables from the
-    persistent AOT compile cache (runtime/compile_cache.py), so the
-    relaunch is load-bound seconds, not compile-bound minutes — and
+    from a verified checkpoint and their executables from JAX's
+    persistent compilation cache (runtime/xla_cache.py) — and
     rejoins through the same quarantine → clean-probe → canary gauntlet
     as a NaN recovery. A crash is a transient, not a permanent capacity
     loss; torchrun's elastic agent, restated for serving.
@@ -54,9 +53,7 @@ Chaos is first-class: ``faults/inject.py`` grew ``replica_crash`` /
 ``replica_hang`` / ``replica_nan`` serving faults (``PTD_FAULTS`` /
 ``run.py --faults`` syntax, targeted by replica index and router tick);
 the router consults the process-global injector every tick and applies
-whatever fires. tests/test_router.py is the chaos suite;
-``bench.py --mode router`` stamps balanced-occupancy spread, shed rate
-under overload, and failover recovery time.
+whatever fires. tests/test_router.py is the chaos suite.
 """
 
 from __future__ import annotations
@@ -1072,8 +1069,8 @@ class ReplicaRouter:
         (ISSUE 10; default the PTD_ROUTER_RESPAWN env, else 0 = DEAD
         is forever). A crashed/hung replica is rebuilt — subprocess
         workers relaunch under the same spec/env contract (a
-        ``"checkpoint"`` + ``"compile_cache"`` spec makes that a
-        load-bound-seconds restart), in-process replicas re-run their
+        ``"checkpoint"`` spec restores verified weights), in-process
+        replicas re-run their
         engine factory — then rejoins through the EXISTING
         quarantine → clean-probe → canary path, so a recovered
         replica proves itself before real traffic returns. Its
@@ -1269,7 +1266,7 @@ class ReplicaRouter:
         self._respawn_eligible = [0.0 for _ in self._replicas]
         self._warming_deadline = [0.0 for _ in self._replicas]
         # "auto" = the process-global PTD_FAULTS contract; None = chaos
-        # explicitly off (bench baseline legs); or a FaultInjector
+        # explicitly off; or a FaultInjector
         self._faults = (faults_inject.active() if faults == "auto"
                         else faults)
         if (self._faults is not None
@@ -1936,8 +1933,8 @@ class ReplicaRouter:
         hit — ZERO fresh compiles (the warm-join property the
         flash-crowd test pins). Subprocess replicas launch under the
         same spec/env contract as an ISSUE-10 respawn — checkpoint
-        restore + persistent AOT compile cache — warm ASYNCHRONOUSLY
-        and join through the quarantine -> clean-probe gauntlet,
+        restore, JAX's persistent compilation cache — warm
+        ASYNCHRONOUSLY and join through the quarantine -> clean-probe gauntlet,
         exactly like a recovered crash."""
         if role not in ROLES:
             raise ValueError(
@@ -3100,7 +3097,7 @@ class ReplicaRouter:
         return out
 
     def summary(self) -> dict:
-        """Router-level aggregate (the bench's stamp source): request
+        """Router-level aggregate: request
         accounting, failover/shed/quarantine counters, per-replica
         occupancy balance and the recovery-time distribution."""
         st = self._stats

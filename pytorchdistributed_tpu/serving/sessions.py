@@ -31,8 +31,7 @@ is the engine's ordinary (bitwise-lossless) re-prefill.
 The store is HOST-ONLY: no jax, no device work, no compiled programs —
 the zero-steady-state-recompile contract is held by construction.
 
-Offline CLI for the disk tier (mirrors the checkpoint/compile-cache
-CLIs)::
+Offline CLI for the disk tier (mirrors the checkpoint CLI)::
 
     python -m pytorchdistributed_tpu.serving.sessions ls <dir>
     python -m pytorchdistributed_tpu.serving.sessions verify <dir>

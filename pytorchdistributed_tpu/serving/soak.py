@@ -24,8 +24,8 @@ runtime assertions:
   * **clean retire** — ``router.close()`` completes without raising
     (the paged engines' block-pool leak assertion lives inside it).
 
-``run_soak()`` is the driver both ``bench.py --mode soak`` and the
-quick-tier mini-soak share: replay a seeded (usually diurnal) trace
+``run_soak()`` is the driver (the quick-tier mini-soak of
+tests/test_chaos.py runs it): replay a seeded (usually diurnal) trace
 over a router whose ``faults=`` is a ``ChaosSchedule``, autoscaler
 live, checker attached; it returns one report dict with the finish
 accounting, SLO attainment, the per-fault-class recovery table
@@ -204,8 +204,8 @@ def run_soak(router, trace, *, clock=None, tick_s: float = 0.02,
     live ``autoscaler`` to exercise scaling under faults.
 
     Closes the router before returning. ``strict=False`` records
-    violations in the report instead of raising — the bench uses that
-    to stamp a failed soak rather than die mid-measurement."""
+    violations in the report instead of raising, so that a failed soak
+    is reported whole."""
     checker = InvariantChecker(
         router, compliant=compliant, debt_budget_s=debt_budget_s,
         strict=strict, check_every=check_every)

@@ -21,8 +21,8 @@ shared prefix — the radix/fleet prefix cache's hot-prompt shape.
 ``replay()`` drives a ReplicaRouter (or anything with submit/step)
 through a trace against a FakeClock: arrivals are released when the
 fake clock passes them, one router step per tick, optionally stepping
-an Autoscaler — zero wall-clock sleeps, so the quick test tier and the
-bench share one driver.
+an Autoscaler — zero wall-clock sleeps, which is what lets the quick
+test tier drive it.
 """
 
 from __future__ import annotations

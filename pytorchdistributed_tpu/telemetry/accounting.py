@@ -33,7 +33,8 @@ import os
 from pytorchdistributed_tpu.utils.hlo import collective_bytes
 
 # Peak bf16 matmul throughput per chip, by jax device_kind — the MFU
-# denominator (shared with bench.py; previously its private table).
+# denominator of the telemetry report (the benchmark keeps its own table,
+# benchmark/peaks.py, where an unknown device is an error).
 PEAK_BF16_FLOPS = {
     "TPU v4": 275e12,
     "TPU v5 lite": 197e12,
@@ -202,7 +203,7 @@ class StepAccounting:
         expert-parallel MoE dispatch/combine volume (ISSUE 14), already
         inside ``comm_bytes_per_step`` but surfaced on its own because
         it's the term the capacity factor, int8 payloads and chunked
-        overlap all act on (bench --mode moe stamps it per A/B leg)."""
+        overlap all act on."""
         return int(sum(self.comm_bytes_by_op.get(k, 0)
                        for k in ("all-to-all", "ragged-all-to-all")))
 
@@ -212,7 +213,7 @@ class StepAccounting:
         zero-overlap UPPER BOUND (ISSUE 5c): the time the step's
         per-device collective bytes would take at the chip's nominal ICI
         bandwidth, as a fraction of the step. With a measured
-        ``sec_per_step`` (the Trainer/bench path) the denominator is the
+        ``sec_per_step`` (the Trainer's path) the denominator is the
         real step; without one (the structural compiled-invariant pins)
         it is the estimated serial compute + comm time at nominal peaks,
         so the number is a deterministic function of the compiled

@@ -54,8 +54,6 @@ EVENT_RETRY = "io_retry"                # faults/retry.py backoff
 EVENT_PREEMPTED = "preempted"           # Trainer SIGTERM graceful exit
 EVENT_CKPT_QUARANTINED = "ckpt_quarantined"  # integrity verify failed
 EVENT_CKPT_FALLBACK = "ckpt_fallback"   # restore walked back a step
-EVENT_COMPILE_CACHE = "compile_cache"   # runtime/compile_cache.py hit/miss/
-#                                         store/quarantine lifecycle
 EVENT_REPLICA_RESTORE = "replica_restore"  # worker loaded a verified ckpt
 EVENT_REPLICA_RESTORE_FALLBACK = "replica_restore_fallback"  # ckpt absent/
 #                                         bad: worker fell back to init_seed

@@ -65,8 +65,8 @@ from pytorchdistributed_tpu.telemetry.events import (
 TRACE_FILE = "trace_rank{rank}.jsonl"
 TRACE_GLOB = "trace_rank*.jsonl"
 
-#: request tracing master switch (default OFF): subprocess workers and
-#: the bench legs read it; the router's ``trace="auto"`` honors it too.
+#: request tracing master switch (default OFF): subprocess workers read
+#: it; the router's ``trace="auto"`` honors it too.
 TRACE_ENV = "PTD_TRACE"
 
 #: the attributable stages, in sweep priority order (when two spans

@@ -231,7 +231,6 @@ class Trainer:
         overlap: str = "xla",
         prefetch: int | None = None,
         diagnostics: str | DiagnosticsConfig | None = None,
-        compile_cache="auto",
     ):
         self.model = model
         self.optimizer = optimizer
@@ -368,22 +367,6 @@ class Trainer:
         self.state_shardings = None
         self._step_fn = None
         self._eval_fn = None
-        # Persistent AOT executable cache (ISSUE 10, runtime/
-        # compile_cache.py): "auto" reads the PTD_COMPILE_CACHE env
-        # contract (off when unset), a path/instance attaches one
-        # explicitly. With a cache, the train-step executable is keyed
-        # by the sha256 of its LOWERED StableHLO (tracing always runs —
-        # it is what captures the loss closure, optimizer constants and
-        # shardings — only the expensive XLA compile is skipped), so a
-        # relaunched incarnation deserializes the step in seconds and
-        # train_step dispatches through it with zero XLA compiles.
-        # Never-fails: any cache/AOT defect falls back to the jit path.
-        from pytorchdistributed_tpu.runtime.compile_cache import (
-            CompileCache,
-        )
-        self._compile_cache = CompileCache.resolve(compile_cache)
-        self._aot_steps: dict = {}     # batch signature -> Compiled
-        self._aot_failed: set = set()
         # XLA:CPU's in-process collective rendezvous deadlocks when too many
         # multi-device programs sit in the async dispatch queue (observed at
         # ~100 queued 8-device all-reduce steps on the CPU sim). Real jobs
@@ -434,19 +417,12 @@ class Trainer:
     def step_accounting(self, sample_batch):
         """`telemetry.StepAccounting` for THIS trainer's step at this
         batch shape: AOT-lower + compile (`lower_step`) and read the
-        executable's cost analysis and collective-bytes census. With a
-        compile cache attached (ISSUE 10) the executable is loaded
-        through it — a restarted run deserializes instead of paying the
-        extra compile, and the same executable then backs train_step's
-        AOT dispatch, so accounting costs nothing marginal."""
+        executable's cost analysis and collective-bytes census."""
         from pytorchdistributed_tpu.telemetry import StepAccounting
 
-        if self._compile_cache is not None:
-            compiled = self._load_or_compile_step(sample_batch)
-        else:
-            compiled = self.lower_step(sample_batch).compile()
         return StepAccounting.from_compiled(
-            compiled, batch=sample_batch, n_devices=self.mesh.devices.size)
+            self.lower_step(sample_batch).compile(), batch=sample_batch,
+            n_devices=self.mesh.devices.size)
 
     def _maybe_build_accounting(self, sample_batch) -> None:
         """With telemetry on, build StepAccounting once and stamp it into
@@ -498,18 +474,6 @@ class Trainer:
         check to make before spending chip time
         (tests/test_tpu_lowering.py). That result is for reading, not
         for `.compile()`."""
-        state_sds, batch_sds = self._step_sds(sample_batch, seed)
-        step_fn = self._build_step()
-        with jax.set_mesh(self.mesh):
-            if platforms is None:
-                return step_fn.lower(state_sds, batch_sds)
-            return step_fn.trace(state_sds, batch_sds).lower(
-                lowering_platforms=platforms)
-
-    def _step_sds(self, sample_batch, seed: int = 0):
-        """(state, batch) ShapeDtypeStruct trees with their shardings —
-        the train step's exact AOT calling convention, shared by
-        lower_step and the compile-cache key."""
         abstract = self._prepare_abstract(sample_batch, jax.random.key(seed))
         state_sds = jax.tree.map(
             lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype,
@@ -519,40 +483,12 @@ class Trainer:
             lambda v: jax.ShapeDtypeStruct(
                 v.shape, v.dtype, sharding=self.batch_sharding(v)),
             dict(sample_batch))
-        return state_sds, batch_sds
-
-    def _load_or_compile_step(self, sample_batch):
-        """The train-step executable through the persistent cache:
-        trace + lower always run (cheap, and the lowered StableHLO hash
-        is the part of the cache key that captures everything the
-        closure bakes in — loss fn, optimizer hyperparams, precision
-        casts), then the XLA compile is either skipped (deserialize a
-        committed entry) or paid once and published. Memoized per batch
-        signature; shared by step_accounting and the train_step AOT
-        dispatch path."""
-        sig = self._batch_signature(sample_batch)
-        compiled = self._aot_steps.get(sig)
-        if compiled is not None:
-            return compiled
-        import hashlib
-
-        state_sds, batch_sds = self._step_sds(sample_batch)
-        # reuse the live jit wrapper when one exists: its tracing cache
-        # makes this lower() free on the train_step hot path
-        step_fn = (self._step_fn if self._step_fn is not None
-                   else self._build_step())
+        step_fn = self._build_step()
         with jax.set_mesh(self.mesh):
-            lowered = step_fn.lower(state_sds, batch_sds)
-        hlo_hash = hashlib.sha256(
-            lowered.as_text().encode()).hexdigest()
-        compiled, _ = self._compile_cache.load_or_compile(
-            "train_step", lowered.compile, (state_sds, batch_sds),
-            statics=(f"strategy={self.strategy};"
-                     f"accum={self.accum_steps};overlap={self.overlap};"
-                     f"opts={self._compiler_options!r}"),
-            config_hash=hlo_hash, donation="state")
-        self._aot_steps[sig] = compiled
-        return compiled
+            if platforms is None:
+                return step_fn.lower(state_sds, batch_sds)
+            return step_fn.trace(state_sds, batch_sds).lower(
+                lowering_platforms=platforms)
 
     @staticmethod
     def _batch_signature(batch):
@@ -929,28 +865,7 @@ class Trainer:
         if any(not isinstance(v, jax.Array) for v in batch.values()):
             with span("train/h2d"):
                 batch = shard_batch(batch, self.batch_sharding)
-        # AOT dispatch (ISSUE 10): with a compile cache, resolve this
-        # batch signature to a persistent-cache executable once — a
-        # relaunched incarnation deserializes the step instead of
-        # compiling it — and dispatch through it. Any failure (a
-        # backend that cannot serialize, a sharding the baked
-        # convention rejects) permanently falls this signature back to
-        # the jit path: the cache can only ever make restart faster.
-        step_fn = self._step_fn
         sig = self._batch_signature(batch)
-        if self._compile_cache is not None:
-            if sig not in self._aot_steps and sig not in self._aot_failed:
-                try:
-                    with span("train/aot_load_or_compile"):
-                        self._load_or_compile_step(batch)
-                except Exception as e:  # noqa: BLE001 — never-fails
-                    self._aot_failed.add(sig)
-                    if dist.is_main_process():
-                        self.logger.info(
-                            f"compile cache: AOT step unavailable for "
-                            f"this batch shape ({e}); using the jit "
-                            f"path")
-            step_fn = self._aot_steps.get(sig, self._step_fn)
         # a dispatch of a batch-shape signature not seen before carries
         # an XLA (re)compile — name it so host traces separate compile
         # stalls from steady-state dispatch (e.g. a ragged final batch
@@ -959,24 +874,8 @@ class Trainer:
         if sig not in self._dispatch_shapes:
             self._dispatch_shapes.add(sig)
             name = "train/compile_and_dispatch"
-        try:
-            with span(name), jax.set_mesh(self.mesh):
-                self.state, metrics = step_fn(self.state, batch)
-        except Exception as e:
-            if step_fn is self._step_fn:
-                raise
-            self._aot_steps.pop(sig, None)
-            self._aot_failed.add(sig)
-            self._compile_cache.note_exec_failure("train_step", e)
-            # a call REJECTED before execution leaves the donated state
-            # intact for the jit retry; a mid-execution failure has
-            # already consumed it — re-raise the real error instead of
-            # masking it with the retry's "Array has been deleted"
-            if any(getattr(a, "is_deleted", lambda: False)()
-                   for a in jax.tree_util.tree_leaves(self.state)):
-                raise
-            with span(name), jax.set_mesh(self.mesh):
-                self.state, metrics = self._step_fn(self.state, batch)
+        with span(name), jax.set_mesh(self.mesh):
+            self.state, metrics = self._step_fn(self.state, batch)
         if self._diag is not None:
             # route the per-layer [L] tables out of the scalar metric
             # stream on the host (pure dict work — the device arrays are

@@ -9,10 +9,9 @@ change that bloats memory, adds a collective, or changes the op mix fails
 against committed numbers in tests/test_compiled_invariants.py on the CPU
 sim, no hardware needed. This generalizes the round-4 one-off of
 byte-diffing lowered HLO between commits (BASELINE.md "Pallas kernel
-unification") into a harness; the committed-number discipline mirrors
-bench.py's COMMITTED_BASELINES. Reference analog: the benchmark-as-test
-harness at 03_model_parallel.ipynb:403-423 — this is its
-works-without-a-chip half.
+unification") into a harness of committed numbers. Reference analog:
+the benchmark-as-test harness at 03_model_parallel.ipynb:403-423 — this
+is its works-without-a-chip half.
 """
 
 from __future__ import annotations

@@ -21,7 +21,7 @@ and its executable's invariants are asserted against committed numbers:
 
 Two tiers: STRUCTURAL configs (test-size widths, every parallelism
 strategy — dp / fsdp / tp x dp / 1F1B pipeline / ring / Ulysses) compile
-in seconds and run in `-m quick`; FLAGSHIP configs (bench.py's real
+in seconds and run in `-m quick`; FLAGSHIP configs (BASELINE.md's real
 widths, depth cut to 2 layers so CPU compile stays in budget — per-layer
 structure is what regresses, the committed number absorbs the depth) run
 in the full suite.
@@ -29,8 +29,7 @@ in the full suite.
 When a change trips one of these ON PURPOSE (a new collective pattern, a
 deliberate memory/flops tradeoff): re-capture with
 `python scripts/capture_invariants.py [names...]`, update COMMITTED
-below, and record the why in BASELINE.md next to the bench baselines —
-same ritual as COMMITTED_BASELINES in bench.py.
+below, and record the why in BASELINE.md.
 """
 
 from __future__ import annotations
@@ -92,7 +91,7 @@ def _moe_structural():
 
 
 def _flagship_gpt2(size, mesh_kw=None, strategy="dp", **extra):
-    # bench_gpt2's committed config (bench.py) at depth 2: unrolled, no
+    # BASELINE.md's gpt2 recipe at depth 2: unrolled, no
     # remat, dense attention (the CPU stand-in for the Pallas kernels),
     # adamw, batch 8 x 1024. mesh_kw/strategy/extra let the fsdp variant
     # reuse the same recipe.
@@ -104,7 +103,7 @@ def _flagship_gpt2(size, mesh_kw=None, strategy="dp", **extra):
 
 
 def _flagship_llama():
-    # bench_llama1b's committed config at depth 2: adafactor, fused
+    # BASELINE.md's llama-1b recipe at depth 2: adafactor, fused
     # chunked-CE head, dots_all remat, unrolled.
     import optax
 
@@ -129,7 +128,7 @@ def _flagship_llama():
 
 
 def _flagship_resnet():
-    # bench_resnet50's committed config (bf16 compute, sync-BN EMA,
+    # BASELINE.md's resnet50 recipe (bf16 compute, sync-BN EMA,
     # sgd+momentum) at batch 32 instead of 256: CPU compile budget; the
     # per-image structure (conv fusions, BN stats, the single grad
     # all-reduce) is batch-size independent.
@@ -206,8 +205,8 @@ BUILDERS = {
         pipeline_stages=4, pipeline_microbatches=8, pp_schedule="1f1b",
         scan_layers=True),  # the 1F1B stage decomposition requires it
     "llama1b_2l": _flagship_llama(),
-    # the quantized flagship (ISSUE 1 acceptance): bench_gpt2's committed
-    # recipe at depth 2 with --quant int8_fwd — per-device flops and the
+    # the quantized flagship (ISSUE 1 acceptance): the gpt2 recipe
+    # at depth 2 with --quant int8_fwd — per-device flops and the
     # int8 convert/dot mix are the committed tripwire for the quantized
     # train step at real widths (the int8 LM-head dot against the 50257
     # vocab dominates; a site silently falling back to bf16 changes
@@ -652,7 +651,7 @@ DECODE_COMMITTED: dict = {
 
 def decode_lowered():
     """Lower the full generate() program — chunked prefill + 128-tick
-    lax.scan with KV cache, bench_generate's exact shape at depth 2.
+    lax.scan with KV cache, BASELINE.md's decode shape at depth 2.
     Shared by test_decode_invariants and scripts/capture_invariants.py
     (the recapture ritual covers "decode" by name)."""
     import dataclasses
@@ -681,10 +680,9 @@ def decode_lowered():
 
 def test_decode_invariants():
     """The one-shot decode path's tripwire: the committed decode headline
-    (gpt2s_decode_tokens_per_s, bench.py bench_generate) had no
-    hardware-independent guard. Decode is single-chip (the bench's
-    committed point), so the collective census should stay all-zero;
-    temp bytes bound the KV-cache + scan working set."""
+    (BASELINE.md's gpt2s_decode_tokens_per_s) had no hardware-independent
+    guard. Decode is single-chip, so the collective census should stay
+    all-zero; temp bytes bound the KV-cache + scan working set."""
     inv = compiled_invariants(decode_lowered().compile())
     _assert_invariants("decode", inv, DECODE_COMMITTED)
 
@@ -986,25 +984,3 @@ def test_comm_stall_frac_pinned(name):
                      / acct.ici_bytes_per_s / sec), 4)
     assert acct.comm_stall_frac(sec) == want
     assert acct.comm_stall_frac(0.0) is None
-
-
-def test_analytic_flops_formula_pinned():
-    """The MFU denominators for every headline bench claim (bench.py
-    transformer_train_flops_per_token): pin the analytic per-token flops
-    of the FULL flagship configs so the formula (or a config default)
-    can't drift silently under a reported MFU number."""
-    from bench import transformer_train_flops_per_token
-    from pytorchdistributed_tpu.models import gpt2_config, llama_config
-
-    full = {
-        "gpt2_small": gpt2_config("small"),
-        "gpt2_medium": gpt2_config("medium"),
-        "llama_1b": llama_config("1b", max_seq_len=1024),
-    }
-    got = {k: transformer_train_flops_per_token(c) for k, c in full.items()}
-    want = {
-        "gpt2_small": 797815296.0,
-        "gpt2_medium": 2271713280.0,
-        "llama_1b": 6433013760.0,
-    }
-    assert got == want, got

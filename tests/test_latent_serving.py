@@ -13,6 +13,8 @@ largest logit. bf16 would not (its own rounding is 4e-3), so this
 tolerance also says that nothing of the mathematics is left out.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -66,40 +68,45 @@ def weights(fam):
 
 def make_engine(fam, cfg, w, **kw):
     kw = {"num_slots": 3, "block_size": 4, "prefill_chunk": 8,
-          "prefix_cache": False, "compile_cache": None, **kw}
+          "prefix_cache": False, **kw}
     return ServingEngine(fam.program_model(cfg, {}),
                          fam.to_program_tree(w, cfg, {}), **kw)
 
 
 class LogitSpy:
     """Every logit the engine's two programs compute, by request and
-    position: before each dispatch it runs the program's own model part
-    (`paged_chunk_logits`, `paged_tick_logits`) on the same operands."""
+    position: it stands in front of `paged_prefill_chunk` and
+    `paged_decode_tick` in the engine's module and, before each call,
+    runs the program's own model part (`paged_chunk_logits`,
+    `paged_tick_logits`) on the same operands."""
 
-    def __init__(self, eng):
+    def __init__(self, eng, monkeypatch):
         self.eng, self.logits = eng, {}
         self._chunk = jax.jit(engine_mod.paged_chunk_logits,
                               static_argnums=0)
         self._tick = jax.jit(engine_mod.paged_tick_logits,
                              static_argnums=0)
-        self._call = eng._aot_call
-        eng._aot_call = self
+        for name, spy in (("paged_prefill_chunk", self.chunk),
+                          ("paged_decode_tick", self.tick)):
+            monkeypatch.setattr(engine_mod, name, functools.partial(
+                spy, getattr(engine_mod, name)))
 
-    def __call__(self, name, jit_fn, statics, args, kw, **more):
+    def chunk(self, program, model, *args, **kw):
+        eng, pf = self.eng, self.eng._prefilling
+        start = int(args[3])
+        logits, _ = self._chunk(model, *args[:5])
+        rows = self.logits.setdefault(pf["req"].id, {})
+        for i in range(min(eng.chunk, pf["true_len"] - start)):
+            rows[start + i] = np.asarray(logits[0, i])
+        return program(model, *args, **kw)
+
+    def tick(self, program, model, *args, **kw):
         eng = self.eng
-        if name == "paged_prefill_chunk":
-            pf = eng._prefilling
-            start = int(args[3])
-            logits, _ = self._chunk(statics[0], *args[:5])
-            rows = self.logits.setdefault(pf["req"].id, {})
-            for i in range(min(eng.chunk, pf["true_len"] - start)):
-                rows[start + i] = np.asarray(logits[0, i])
-        elif name == "paged_decode_tick":
-            logits, _ = self._tick(statics[0], *args[:5])
-            for slot, req in eng._active.items():
-                self.logits.setdefault(req.id, {})[
-                    int(eng._lengths[slot])] = np.asarray(logits[slot, 0])
-        return self._call(name, jit_fn, statics, args, kw, **more)
+        logits, _ = self._tick(model, *args[:5])
+        for slot, req in eng._active.items():
+            self.logits.setdefault(req.id, {})[
+                int(eng._lengths[slot])] = np.asarray(logits[slot, 0])
+        return program(model, *args, **kw)
 
 
 def check_against_reference(fam, cfg, w, spy, reqs, vocab=None):
@@ -145,7 +152,7 @@ def test_prefill_then_decode_matches_reference_logits(fam, weights, prompt,
         monkeypatch.setattr(latent, "QUERY_BLOCK", query_block)
         cfg = dict(TOY, served_positions=32)
     eng = make_engine(fam, cfg, weights)
-    spy = LogitSpy(eng)
+    spy = LogitSpy(eng, monkeypatch)
     reqs = serve(eng, [(prompt, new)], TOY["vocab_size"])
     check_against_reference(fam, cfg, weights, spy, reqs)
     s = eng.summary()
@@ -161,13 +168,13 @@ def test_prefill_then_decode_matches_reference_logits(fam, weights, prompt,
     eng.close()  # both pools' leak checks
 
 
-def test_mixed_lengths_in_one_batch_and_a_retired_block_reused(fam,
-                                                               weights):
+def test_mixed_lengths_in_one_batch_and_a_retired_block_reused(
+        fam, weights, monkeypatch):
     """Streams of different lengths share the slots; a window block that
     one stream retired is handed to another stream while the first still
     runs, and every logit of both still agrees with the reference."""
     eng = make_engine(fam, TOY, weights)
-    spy = LogitSpy(eng)
+    spy = LogitSpy(eng, monkeypatch)
     retired, reused = {}, []
     win = eng._pools[1].alloc
     decref, alloc = win.decref, win.alloc
@@ -199,13 +206,13 @@ def test_mixed_lengths_in_one_batch_and_a_retired_block_reused(fam,
 
 
 def test_a_vocabulary_slice_gives_the_reference_logits_over_the_slice(
-        fam, weights):
+        fam, weights, monkeypatch):
     half = TOY["vocab_size"] // 2
     cfg = dict(TOY, vocab_size=half)
     w = dict(weights, embed=weights["embed"][:half],
              head=weights["head"][:, :half])
     eng = make_engine(fam, cfg, w)
-    spy = LogitSpy(eng)
+    spy = LogitSpy(eng, monkeypatch)
     reqs = serve(eng, [(19, 5)], half)
     # the uncut reference (all 96 rows), read over the slice
     check_against_reference(fam, TOY, weights, spy, reqs, vocab=half)

@@ -201,8 +201,7 @@ def test_fused_ce_loss_matches_unfused():
 
 
 def test_ce_chunk_config_is_loss_invariant():
-    """cfg.ce_chunk (the r5 HBM-vs-throughput knob, bench.py
-    PTD_CE_CHUNK) resizes the fused head's logit chunks only — loss and
+    """cfg.ce_chunk (the r5 HBM-vs-throughput knob) resizes the fused head's logit chunks only — loss and
     gradients must be identical at any chunk size, including one that
     doesn't divide the token count."""
     from pytorchdistributed_tpu.models import Llama, llama_config
@@ -228,8 +227,7 @@ def test_ce_chunk_config_is_loss_invariant():
 
 
 def test_attn_block_config_is_output_invariant():
-    """cfg.attn_block (the r5 block-size A/B knob, bench.py
-    PTD_ATTN_BLOCK) must thread to the flash kernels without changing the
+    """cfg.attn_block (the r5 block-size A/B knob) must thread to the flash kernels without changing the
     math: a pallas model at a non-default block (forcing a multi-block
     grid with a padded tail at seq 24) matches the dense-attention model
     exactly."""

@@ -264,8 +264,7 @@ def test_parity_pipeline(schedule):
 
 
 def test_bert_vit_quant_configs_train():
-    """The other two transformer families (bench.py now honors PTD_QUANT
-    for them too): one quantized step each, finite and learning-shaped."""
+    """The other two transformer families: one quantized step each, finite and learning-shaped."""
     import optax
 
     from pytorchdistributed_tpu.data import MLMDataset, SyntheticTokenDataset

@@ -160,7 +160,7 @@ def test_lowered_tick_converts_no_float32_parameter(scan_layers):
     assert _param_converts(engine.lower_tick().as_text(), gain) == []
     # the same tick over the float32 tree does convert them: the pattern
     # sees what it is meant to see
-    _, _, args = engine._tick_program()
+    _, args = engine._tick_program()
     old = paged_decode_tick.lower(engine._tick_model, params, *args[1:],
                                   candidates=engine.candidates).as_text()
     assert len(_param_converts(old, gain)) >= 2   # the table, its tied head

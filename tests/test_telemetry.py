@@ -89,17 +89,19 @@ def test_span_overhead_under_budget():
     an engine step ~10, a router step 5 more; even a 5 ms sim step
     grants 50 µs/step at 1%. Budget each span at 10 µs (measured ~2-3 µs
     here: parent, ids and the profiler annotation included, no capture
-    running) with generous headroom for a loaded CI core."""
+    running). The span's own cost is CPU time of this thread
+    (`time.thread_time`), not the wall clock of a core that five other
+    xdist workers share; best of five."""
     from pytorchdistributed_tpu.telemetry import span
 
     n = 2000
     trials = []
-    for _ in range(3):  # best-of-3: a scheduler preemption mid-window on
-        t0 = time.perf_counter()  # a loaded CI core must not flake this
+    for _ in range(5):
+        t0 = time.thread_time()
         for i in range(n):
             with span("x", request=i, step=7):
                 pass
-        trials.append((time.perf_counter() - t0) / n)
+        trials.append((time.thread_time() - t0) / n)
     per_span = min(trials)
     assert per_span < 10e-6, f"span overhead {per_span * 1e6:.1f} µs"
 
@@ -343,15 +345,6 @@ def test_report_step_time_fallback_spans_epochs():
     # explicit step_time_s rows win over the derivation
     assert _derive_step_time(
         [dict(r, step_time_s=0.5) for r in rows]) == pytest.approx(0.5)
-
-
-def test_bench_mfu_refuses_sim_peak():
-    """bench.py's unlabeled analytic `mfu` field must mean real hardware:
-    on the CPU sim _mfu answers None (the labeled accounting path is the
-    sim's only MFU source)."""
-    from bench import _mfu
-
-    assert _mfu(1e12, 1.0) is None  # cpu device_kind not in peak table
 
 
 def test_accounting_built_on_restored_trainer(tmp_path):
