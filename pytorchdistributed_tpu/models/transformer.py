@@ -191,12 +191,14 @@ class TransformerConfig:
     # Decode-tick attention implementation for the paged pool: "gather"
     # reassembles each slot's blocks into position order and runs the
     # masked dense tail (bitwise-equal to the dense cache — the exact
-    # contract); "pallas" runs the scalar-prefetch paged flash kernel
+    # contract); "pallas" runs the paged flash kernel
     # (ops/pallas_attention.paged_flash_attention) straight over the
-    # block pool on single-token ticks — no gather materialization, the
-    # serving default on TPU (tolerance-pinned vs gather, not bitwise:
-    # online softmax reassociates the reduction). Multi-token chunks
-    # (prefill, speculative verify) always take the gather path.
+    # block pool on single-token ticks — no gather materialization, each
+    # slot's live blocks fetched by the kernel's own copies; the serving
+    # default on TPU where kv_heads*head_dim is whole 128-lane tiles
+    # (tolerance-pinned vs gather, not bitwise: online softmax
+    # reassociates the reduction). Multi-token chunks (prefill,
+    # speculative verify) always take the gather path.
     paged_attn: str = "gather"          # gather | pallas
     # Per-slot sink/window overrides (ISSUE 15): the slot-batch decode
     # models read sink/window from per-slot ``kv_sinks``/``kv_windows``
@@ -717,9 +719,14 @@ class SelfAttention(nn.Module):
                     # decode tick on the Pallas paged kernel: q attends
                     # the pool STRAIGHT through the block table — the
                     # gathered [slots, attend, ...] copy below never
-                    # materializes. Tolerance-pinned vs the gather path
-                    # (online softmax reassociates); chunks (s > 1:
-                    # prefill, spec verify) stay on the gather tail.
+                    # materializes. One program a slot copies the slot's
+                    # live blocks out of the pool, a tile of table
+                    # entries at a time, and computes those alone: the
+                    # call costs what the live tokens cost, not what
+                    # the table could hold. Tolerance-pinned vs the
+                    # gather path (online softmax reassociates); chunks
+                    # (s > 1: prefill, spec verify) stay on the gather
+                    # tail.
                     from pytorchdistributed_tpu.ops.pallas_attention import (
                         paged_flash_attention,
                     )

@@ -39,6 +39,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from pytorchdistributed_tpu.ops.attention import paged_gather
+
 _NEG_INF = -1e30
 
 
@@ -514,15 +516,32 @@ def flash_attention_sharded(q, k, v, *, causal: bool = False,
 
 
 # ---------------------------------------------------------------------------
-# Paged decode attention (ISSUE 7): the Pallas twin of
-# ops/attention.paged_attention. One decode tick's q ([slots, heads, d])
-# attends each slot's block-table-mapped KV blocks streamed STRAIGHT from
-# the shared pool — the [slots, blocks*block_size, ...] gathered copy the
-# reference path materializes in HBM never exists here. The block table,
-# the per-slot lengths and the layer ride as scalar-prefetch operands so
-# the KV BlockSpec index maps can chase the table (pool block
-# `[layer, tables[slot, j]]` is DMA'd as grid step j), the canonical
-# PagedAttention dataflow.
+# Paged decode attention (ISSUE 7; this dataflow ISSUE 33): the Pallas
+# twin of ops/attention.paged_attention. One decode tick's q ([slots,
+# heads, d]) attends each slot's block-table-mapped KV blocks streamed
+# STRAIGHT from the shared pool — the [slots, blocks*block_size, ...]
+# gathered copy the reference path materializes in HBM never exists here.
+#
+# PagedAttention dataflow. The grid is one program a slot, run in order.
+# The pools stay in HBM (`pl.ANY` operands); the block table, the per-slot
+# lengths and the layer ride as scalar-prefetch operands, and the program
+# walks its slot's table a TILE at a time: a tile is several consecutive
+# table entries (`_tile_blocks`: 128 positions where the shapes allow,
+# 8 entries at a block of 16), each live entry's pool block `[layer,
+# tables[slot, j]]` copied into VMEM scratch by the kernel's own
+# asynchronous copies, the next live tile's copies (the next slot's first
+# tile after a slot's last) in flight while this one is computed. Inside
+# a tile the live blocks are computed one at a time. A block past the
+# slot's length, or retired from a sliding window, starts no copy and
+# costs nothing, and so does a tile that holds only such blocks: the
+# cost of a tick follows its live tokens, where a grid of (slots, table
+# entries) paid a program for every entry, live or not (49,152 a tick of
+# gpt2-medium at 32 slots, ~0.108 us each: PERF.md section 6, PR 33).
+# Mosaic copies whole 128-lane tiles only, so a compiled call needs
+# `kv_heads*head_dim` to be a multiple of 128 (the serving engine picks
+# the gather path elsewhere), and an int8 pool's scale rows, `kv_heads`
+# lanes wide, are gathered by XLA before the call and ride in a slot at
+# a time.
 #
 # The pool is lane-dense: one token's K (or V) row is all its kv heads
 # side by side, `[num_blocks, block_size, kv_heads*head_dim]`, so a block
@@ -536,10 +555,11 @@ def _head_sums(x, head_dim: int):
     """``x [rows, kv_heads*head_dim]`` -> the same shape, every lane
     holding the sum of ``x`` over its own head's lanes. Mosaic has no
     reshape that splits lanes into heads, so each head is a masked lane
-    reduce, spread back over the head by the select that masks it; done
+    reduce, spread back over the head by a select under its mask; done
     in static lane slices that hold whole heads and, where the sizes
     allow, whole 128-lane tiles (``head_dim`` 64: two heads a tile), so a
-    slice costs nothing and a reduce stays inside a vector register."""
+    slice costs nothing and a reduce stays inside a vector register.
+    Half of the kernel's time on a v5e (PERF.md section 6, PR 33)."""
     width = x.shape[-1]
     step = head_dim * 128 // math.gcd(head_dim, 128)   # lcm
     if width % step:
@@ -550,111 +570,224 @@ def _head_sums(x, head_dim: int):
     parts = []
     for lo in range(0, width, step):
         part = x[:, lo:lo + step]
-        sums = jnp.zeros_like(part)
+        sums = None
         for mask in masks:
             total = jnp.sum(jnp.where(mask, part, 0.0), axis=-1,
                             keepdims=True)
-            sums = jnp.where(mask, total, sums)
-        parts.append(sums)
+            sums = total if sums is None else jnp.where(mask, total, sums)
+        parts.append(jnp.broadcast_to(sums, part.shape))
     return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=-1)
 
 
-def _paged_kernel(tables_ref, lengths_ref, layer_ref, q_ref, k_ref, v_ref,
-                  *rest, block_size: int, num_blocks: int, head_dim: int,
-                  scale: float, quantized: bool, sink: int, window: int):
-    """Online-softmax over one slot's table blocks; grid
-    (slots, blocks_per_slot). One program sees every kv head of its pool
-    block: refs are q/o ``[group, kv_heads*d]`` and k/v ``[block_size,
-    kv_heads*d]``, head ``h`` in lanes ``[h*d, (h+1)*d)``. The per-head
-    reduce of ``k*q`` leaves each head's logit in all of that head's
-    lanes (``_head_sums``), after which softmax, ``p*v`` and the
-    accumulator are plain elementwise work on whole rows: every lane of
-    a head carries that head's logit, max and sum. All float32 on the
-    VPU, no MXU: a decode tick is bandwidth-bound. ``quantized`` adds
-    two scale refs
-    (int8 pool, fp32 ``[block_size, kv_heads]`` per-row scales, spread
-    over each head's lanes by a 0/1 matrix product at full precision and
-    dequantized in VMEM right before the products); ``window`` > 0
-    applies the sink+sliding-window mask and skips fully-dead middle
-    blocks — the blocks the serving engine retires to the allocator."""
-    if quantized:
-        ks_ref, vs_ref, o_ref, acc_s, m_s, l_s = rest
-    else:
-        ks_ref = vs_ref = None
-        o_ref, acc_s, m_s, l_s = rest
-    slot, ji = pl.program_id(0), pl.program_id(1)
-    width = k_ref.shape[-1]
+# What a tile may hold: positions, and bytes of VMEM for the two tiles in
+# flight (every leaf of the pool together).
+_TILE_ROWS = 128
+_TILE_VMEM_BYTES = 4 << 20
 
-    @pl.when(ji == 0)
-    def _init():
-        acc_s[...] = jnp.zeros_like(acc_s)
-        m_s[...] = jnp.full_like(m_s, _NEG_INF)
-        l_s[...] = jnp.zeros_like(l_s)
+
+def _tile_blocks(block_size: int, row_bytes: int, table_len: int) -> int:
+    """Table entries a tile holds, from the shapes the call sees:
+    ``_TILE_ROWS`` positions (8 entries at a block of 16), fewer where a
+    position's row over all pool leaves (``row_bytes``) is so wide that
+    two such tiles would pass ``_TILE_VMEM_BYTES``, never more than the
+    table has and never under one."""
+    rows = min(_TILE_ROWS, _TILE_VMEM_BYTES // (2 * row_bytes))
+    return max(1, min(rows // block_size, table_len))
+
+
+def _paged_kernel(tables_ref, lengths_ref, layer_ref, q_ref, *rest,
+                  block_size: int, tile_blocks: int, table_len: int,
+                  head_dim: int, scale: float, quantized: bool, sink: int,
+                  window: int):
+    """Online softmax over one slot's live table blocks; grid (slots,),
+    run in order. ``rest`` is the k and v pools in HBM, for an int8 pool
+    the slot's fp32 scale rows ``[table positions, kv_heads]`` of each,
+    the output, one VMEM buffer a pool ``[2, tile_blocks, block_size,
+    lanes]`` (two tiles: one computed, one in flight), a DMA semaphore a
+    buffer half, the (acc, m, l) accumulators and the half the next tile
+    lands in (SMEM: it outlives the program, because a slot's last tile
+    starts the next slot's first).
+
+    One block is computed at a time, every kv head of it at once: q/o are
+    ``[group, kv_heads*d]`` and a k/v block ``[block_size, kv_heads*d]``,
+    head ``h`` in lanes ``[h*d, (h+1)*d)``. The per-head reduce of
+    ``k*q`` leaves each head's logit in all of that head's lanes
+    (``_head_sums``), after which softmax, ``p*v`` and the accumulator
+    are plain elementwise work: every lane of a head carries that head's
+    logit, max and sum. The accumulators are ``[8, kv_heads*d]`` a query
+    row, one online softmax a sublane: row ``r`` of a block goes to
+    stream ``r % 8``, so no block reduces over its rows, and the eight
+    partial softmaxes are merged once, when the slot is done, the way two
+    blocks' are. All float32 on the VPU, no MXU. An int8 block is
+    dequantized in VMEM right before the products (its scales spread over
+    each head's lanes by a 0/1 matrix product at full precision);
+    ``window`` > 0 applies the sink+sliding-window mask, and the blocks
+    it retires — whose table entries point at trash once the serving
+    engine hands them back to the allocator — are neither copied nor
+    computed."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    k_hbm, v_hbm, *rest = rest
+    ks_ref = vs_ref = None
+    if quantized:
+        ks_ref, vs_ref, *rest = rest
+    o_ref, k_buf, v_buf, sems, acc_s, m_s, l_s, half_ref = rest
+    slot, slots = pl.program_id(0), pl.num_programs(0)
+    tile = tile_blocks * block_size
+    top = table_len * block_size - 1     # the table's last position
+    streams = acc_s.shape[1]
+    layer = layer_ref[0]
+    width = q_ref.shape[-1]
+
+    def retired(lo, hi, length):
+        """Positions [lo, hi) all lie past the sinks and before the
+        window of a query at ``length``: fully masked."""
+        return (lo >= sink) & (hi <= length - window + 1)
+
+    def live_from(t, length):
+        """Tile ``t``, or the tile the window resumes in when ``t``
+        holds retired blocks only (the retired blocks are one run)."""
+        if not window:
+            return t
+        return jnp.where(retired(t * tile, (t + 1) * tile, length),
+                         (length - window + 1) // tile, t)
+
+    def block_live(j, length):
+        # the current token sits at position `length`, so positions
+        # <= length are attendable: a dead slot (length 0) still reads
+        # its first block, as the reference path does
+        live = j * block_size <= jnp.minimum(length, top)
+        if window:
+            live &= ~retired(j * block_size, (j + 1) * block_size, length)
+        return live
+
+    def tile_copies(s, length, t, half, op):
+        """``op`` ("start" or "wait") the copies of slot ``s``'s tile
+        ``t`` into buffer half ``half``: a K and a V block for every
+        live table entry."""
+        for i in range(tile_blocks):
+            j = t * tile_blocks + i
+
+            @pl.when(block_live(j, length))
+            def _():
+                blk = tables_ref[s, j]
+                for pool, buf in ((k_hbm, k_buf), (v_hbm, v_buf)):
+                    getattr(pltpu.make_async_copy(
+                        pool.at[layer, blk], buf.at[half, i],
+                        sems.at[half]), op)()
 
     length = lengths_ref[slot]
-    # skip blocks wholly past the slot's live window (the current token
-    # sits at position `length`, so positions <= length are attendable);
-    # dead slots (length 0) still run block 0 — masked rows are exact
-    # zeros, the same garbage-tolerance contract as the reference path
-    run = ji * block_size <= length
-    if window:
-        # sliding window: a middle block whose last position already fell
-        # out of every live query's window (and past the sinks) is fully
-        # masked — and its table entry points at trash once the engine
-        # retires it — so skip it outright
-        dead = ((ji * block_size >= sink)
-                & ((ji + 1) * block_size <= length - window + 1))
-        run = run & ~dead
+    reach = jnp.minimum(length, top)     # the last position attended
+    last = reach // tile
 
-    @pl.when(run)
-    def _compute():
+    @pl.when(slot == 0)
+    def _first():
+        half_ref[0] = 0
+        tile_copies(slot, length, live_from(0, length), 0, "start")
+
+    acc_s[...] = jnp.zeros_like(acc_s)
+    m_s[...] = jnp.full_like(m_s, _NEG_INF)
+    l_s[...] = jnp.zeros_like(l_s)
+
+    if quantized:
+        # the scale of head h goes to lanes [h*d, (h+1)*d): a product
+        # with a 0/1 matrix, one nonzero term a lane, exact at full
+        # precision
+        kv_heads = ks_ref.shape[-1]
+        lane = lax.broadcasted_iota(jnp.int32, (kv_heads, width), 1)
+        head = lax.broadcasted_iota(jnp.int32, (kv_heads, width), 0)
+        spread = ((lane >= head * head_dim)
+                  & (lane < (head + 1) * head_dim)).astype(jnp.float32)
+
+    # static: the kv head's q group, a row [1, hk*d] a member
+    queries = [q_ref[pl.ds(g, 1), :].astype(jnp.float32)
+               for g in range(q_ref.shape[0])]
+
+    def load(buf, s_ref, half, i, j):
+        x = buf[half, i].astype(jnp.float32)               # [bs, hk*d]
         if quantized:
-            # the scale of head h goes to lanes [h*d, (h+1)*d): a product
-            # with a 0/1 matrix, one nonzero term a lane, exact at full
-            # precision
-            kv_heads = ks_ref.shape[-1]
-            lane = lax.broadcasted_iota(jnp.int32, (kv_heads, width), 1)
-            head = lax.broadcasted_iota(jnp.int32, (kv_heads, width), 0)
-            spread = ((lane >= head * head_dim)
-                      & (lane < (head + 1) * head_dim)).astype(jnp.float32)
+            # canonical dequant (ops/quant.kv_dequantize spelling):
+            # int8 → fp32 × per-row scale → compute dtype
+            rows = pl.ds(pl.multiple_of(j * block_size, block_size),
+                         block_size)
+            x = (x * jnp.dot(s_ref[rows, :], spread,
+                             precision=lax.Precision.HIGHEST,
+                             preferred_element_type=jnp.float32)
+                 ).astype(q_ref.dtype).astype(jnp.float32)
+        return x
 
-        def load(ref, s_ref):
-            x = ref[...].astype(jnp.float32)               # [bs, hk*d]
-            if quantized:
-                # canonical dequant (ops/quant.kv_dequantize spelling):
-                # int8 → fp32 × per-row scale → compute dtype
-                x = (x * jnp.dot(s_ref[...], spread,
-                                 precision=lax.Precision.HIGHEST,
-                                 preferred_element_type=jnp.float32)
-                     ).astype(q_ref.dtype).astype(jnp.float32)
-            return x
-
-        k, v = load(k_ref, ks_ref), load(v_ref, vs_ref)
-        pos = ji * block_size + lax.broadcasted_iota(
-            jnp.int32, (block_size, 1), 0)
-        valid = pos <= length
-        if window:
-            valid &= (pos < sink) | (pos > length - window)
-        for g in range(q_ref.shape[0]):  # static: the kv head's q group
-            q = q_ref[pl.ds(g, 1), :].astype(jnp.float32)  # [1, hk*d]
-            logits = jnp.where(valid, _head_sums(k * q, head_dim) * scale,
-                               _NEG_INF)
-            m_prev = m_s[pl.ds(g, 1), :]                   # [1, hk*d]
-            m_new = jnp.maximum(m_prev,
-                                jnp.max(logits, axis=0, keepdims=True))
+    def attend_block(half, i, j):
+        k = load(k_buf, ks_ref, half, i, j)
+        v = load(v_buf, vs_ref, half, i, j)
+        groups = [slice(r, r + streams)
+                  for r in range(0, block_size, streams)]
+        valid = []
+        for rows in groups:
+            pos = j * block_size + rows.start + lax.broadcasted_iota(
+                jnp.int32, (streams, 1), 0)
+            ok = pos <= length
+            if window:
+                ok &= (pos < sink) | (pos > length - window)
+            valid.append(ok)
+        for g, q in enumerate(queries):
+            # a masked logit is -inf under a running max that starts
+            # finite, so its probability is an exact 0 with no select
+            logits = [
+                jnp.where(ok, _head_sums(k[rows] * q, head_dim) * scale,
+                          -jnp.inf)
+                for rows, ok in zip(groups, valid)]
+            m_prev = m_s[g]                                # [streams, hk*d]
+            m_new = functools.reduce(jnp.maximum, logits, m_prev)
             corr = jnp.exp(m_prev - m_new)
-            p = jnp.where(valid, jnp.exp(logits - m_new), 0.0)
-            l_s[pl.ds(g, 1), :] = (l_s[pl.ds(g, 1), :] * corr
-                                   + jnp.sum(p, axis=0, keepdims=True))
-            m_s[pl.ds(g, 1), :] = m_new
-            acc_s[pl.ds(g, 1), :] = (
-                acc_s[pl.ds(g, 1), :] * corr
-                + jnp.sum(p * v, axis=0, keepdims=True))
+            l, acc = l_s[g] * corr, acc_s[g] * corr
+            for rows, x in zip(groups, logits):
+                p = jnp.exp(x - m_new)
+                l += p
+                acc += p * v[rows]
+            m_s[g], l_s[g], acc_s[g] = m_new, l, acc
 
-    @pl.when(ji == num_blocks - 1)
-    def _finalize():
-        o_ref[...] = (acc_s[...]
-                      / jnp.maximum(l_s[...], 1e-30)).astype(o_ref.dtype)
+    def tile_step(t):
+        half = half_ref[0]
+        nxt = live_from(t + 1, length)
+
+        @pl.when(nxt <= last)
+        def _next_tile():
+            tile_copies(slot, length, nxt, 1 - half, "start")
+
+        @pl.when((nxt > last) & (slot + 1 < slots))
+        def _next_slot():
+            ahead = lengths_ref[slot + 1]
+            tile_copies(slot + 1, ahead, live_from(0, ahead), 1 - half,
+                        "start")
+
+        tile_copies(slot, length, t, half, "wait")
+        first = t * tile_blocks
+
+        def block_step(i, carry):
+            if window:
+                @pl.when(block_live(first + i, length))
+                def _():
+                    attend_block(half, i, first + i)
+            else:
+                attend_block(half, i, first + i)
+            return carry
+
+        # blocks of this tile up to the one the current token sits in
+        lax.fori_loop(
+            0, jnp.minimum(tile_blocks, reach // block_size - first + 1),
+            block_step, 0)
+        half_ref[0] = 1 - half
+        return nxt
+
+    lax.while_loop(lambda t: t <= last, tile_step, live_from(0, length))
+    for g in range(len(queries)):
+        # the streams' partial softmaxes, merged as two blocks' are
+        m = m_s[g]
+        w = jnp.exp(m - jnp.max(m, axis=0, keepdims=True))
+        o_ref[pl.ds(g, 1), :] = (
+            jnp.sum(acc_s[g] * w, axis=0, keepdims=True)
+            / jnp.maximum(jnp.sum(l_s[g] * w, axis=0, keepdims=True), 1e-30)
+        ).astype(o_ref.dtype)
 
 
 def paged_flash_attention(q, k_pool, v_pool, block_tables, lengths, *,
@@ -674,8 +807,8 @@ def paged_flash_attention(q, k_pool, v_pool, block_tables, lengths, *,
         the model dtype or int8 (compressed pool); or the layer-stacked
         ``[num_layers, num_blocks, block_size, kv_heads*head_dim]`` pool
         of a scanned stack, with ``layer`` the (traced) int32 layer to
-        read — the index map starts with it, so no layer's pool is ever
-        sliced out of the stack.
+        read — every copy's source starts with it, so no layer's pool is
+        ever sliced out of the stack.
       block_tables: ``[slots, blocks_per_slot]`` int32 physical block ids
         (entries past a slot's live length — and retired window blocks —
         point at the trash block 0).
@@ -686,16 +819,22 @@ def paged_flash_attention(q, k_pool, v_pool, block_tables, lengths, *,
       sink_tokens / window_tokens: static sink+sliding-window mask
         (window_tokens 0 = full attention): position j is attendable iff
         ``j < sink_tokens or j > length - window_tokens``; fully-dead
-        middle blocks are skipped — they are the blocks the engine
-        retires back to the allocator mid-stream.
+        middle blocks are neither copied nor computed — they are the
+        blocks the engine retires back to the allocator mid-stream.
 
     Returns ``[slots, heads, head_dim]``. Matches
     ops.attention.paged_attention to fp32 online-softmax tolerance (the
     reassociated flash recurrence is not bitwise — the bitwise-parity
     contract vs generate() holds on the reference gather path; this
     kernel never materializes the [slots, blocks*block_size, ...]
-    gathered copy). Grouped-query native: each (slot, block) program
-    streams the shared KV block once for the whole q group."""
+    gathered copy). One program a slot walks the slot's live table
+    entries a tile at a time (``_tile_blocks`` entries, chosen from the
+    shapes; the table is padded with the trash block to whole tiles),
+    fetching each live block from the pool in HBM by its own
+    asynchronous copy while the tile before is computed: a block, a tile
+    or a slot with nothing live costs no copy and no arithmetic.
+    Grouped-query native: a block is fetched once for the whole q
+    group."""
     slots, h, d = q.shape
     if layer is None:      # one layer's own pool: a stack of one
         layer = 0
@@ -726,51 +865,67 @@ def paged_flash_attention(q, k_pool, v_pool, block_tables, lengths, *,
             f"sink_tokens {sink_tokens} / window_tokens {window_tokens} "
             f"must be non-negative multiples of block_size {bs}")
     group = h // hk
-    mb = block_tables.shape[1]
     scale = (d**-0.5) if scale is None else scale
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     from jax.experimental.pallas import tpu as pltpu
 
+    table_len = block_tables.shape[1]
+    tile_blocks = _tile_blocks(bs, 2 * width * k_pool.dtype.itemsize,
+                               table_len)
+    # whole tiles: the entries added lie past the table's last position,
+    # so they are never live
+    block_tables = jnp.pad(block_tables.astype(jnp.int32),
+                           ((0, 0), (0, -table_len % tile_blocks)))
     # kv head g owns q rows g·group+; group-major so row g of a slot's
     # block holds, head by head, the g-th query of every kv head — laid
     # out like a pool row
     qf = q.reshape(slots, hk, group, d).swapaxes(1, 2).reshape(
         slots, group, width)
-    # every block's two minor dims equal the array's own, which the TPU
-    # lowering takes at any width (leading dims are squeezed, not
+    # the block's two minor dims equal the array's own, which the TPU
+    # lowering takes at any width (the leading dim is squeezed, not
     # blocked at 1)
     q_spec = pl.BlockSpec((None, group, width),
-                          lambda s, j, tbl, ln, ly: (s, 0, 0))
-    kv_spec = pl.BlockSpec(
-        (None, None, bs, width),
-        lambda s, j, tbl, ln, ly: (ly[0], tbl[s, j], 0, 0))
-    in_specs = [q_spec, kv_spec, kv_spec]
+                          lambda s, tbl, ln, ly: (s, 0, 0))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs = [q_spec, hbm, hbm]
     operands = [qf, k_pool, v_pool]
     if quantized:
-        scale_spec = pl.BlockSpec(
-            (None, None, bs, hk),
-            lambda s, j, tbl, ln, ly: (ly[0], tbl[s, j], 0, 0))
-        in_specs += [scale_spec, scale_spec]
-        operands += [k_scale.astype(jnp.float32),
-                     v_scale.astype(jnp.float32)]
+        # Mosaic copies whole 128-lane tiles, and a scale row is kv_heads
+        # lanes: the scale rows of every table entry are gathered here
+        # (a 1/head_dim of the pool's bytes a position, the pool's own
+        # rows stay where they are) and ride in a slot at a time
+        rows = block_tables.shape[1] * bs
+        in_specs += [pl.BlockSpec((None, rows, hk),
+                                  lambda s, tbl, ln, ly: (s, 0, 0))] * 2
+        operands += [
+            paged_gather(x, block_tables, layer).astype(jnp.float32)
+            for x in (k_scale, v_scale)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(slots, mb),
+        grid=(slots,),
         in_specs=in_specs,
         out_specs=q_spec,
-        scratch_shapes=[_vmem_scratch((group, width))] * 3,
+        scratch_shapes=[
+            *[pltpu.VMEM((2, tile_blocks, bs, width), k_pool.dtype)] * 2,
+            pltpu.SemaphoreType.DMA((2,)),
+            *[_vmem_scratch((group, math.gcd(bs, 8), width))] * 3,
+            pltpu.SMEM((1,), jnp.int32),
+        ],
     )
     kernel = functools.partial(
-        _paged_kernel, block_size=bs, num_blocks=mb, head_dim=d,
-        scale=scale, quantized=quantized, sink=int(sink_tokens),
-        window=int(window_tokens))
+        _paged_kernel, block_size=bs, tile_blocks=tile_blocks,
+        table_len=table_len, head_dim=d, scale=scale, quantized=quantized,
+        sink=int(sink_tokens), window=int(window_tokens))
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qf.shape, q.dtype),
+        # in order: a slot's last tile starts the next slot's first copies
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
+    )(block_tables, lengths.astype(jnp.int32),
       jnp.asarray(layer, jnp.int32).reshape(1), *operands)
     return out.reshape(slots, group, hk, d).swapaxes(1, 2).reshape(
         slots, h, d)

@@ -948,8 +948,10 @@ class ServingEngine:
         ops/pallas_attention.paged_flash_attention — no [slots,
         attend_len] gather materialization), or None (default) →
         the PTD_PAGED_ATTN env var, else "auto" = pallas on TPU
-        backends, gather elsewhere. Prefill chunks and the spec tick's
-        draft rollout always use the gather read.
+        backends where kv_heads*head_dim is whole 128-lane tiles (the
+        kernel's own copies move whole tiles), gather elsewhere.
+        Prefill chunks and the spec tick's draft rollout always use
+        the gather read.
     """
 
     #: adaptive-k acceptance-EMA smoothing (ISSUE 16): high enough to
@@ -1011,18 +1013,29 @@ class ServingEngine:
             raise ValueError(
                 f"paged_attn must be 'auto', 'gather' or 'pallas', got "
                 f"{paged_attn!r}")
+        on_tpu = jax.default_backend() == "tpu"
+        one_kind = len(self._kinds) == 1
+        # the kernel copies pool rows by its own DMAs, which Mosaic
+        # takes in whole 128-lane tiles only
+        lanes = model.cfg.kv_heads * model.cfg.head_dim if one_kind else 0
         if paged_attn == "auto":
             # backend-aware default: the fused kernel is the hot path on
             # real accelerators; CPU (tests, dev) keeps the gather read,
             # whose decode tick is bitwise generate()'s
-            paged_attn = ("pallas" if jax.default_backend() == "tpu"
-                          and len(self._kinds) == 1 else "gather")
-        if paged_attn == "pallas" and len(self._kinds) > 1:
+            paged_attn = ("pallas" if on_tpu and one_kind
+                          and lanes % 128 == 0 else "gather")
+        if paged_attn == "pallas" and not one_kind:
             raise ValueError(
                 "paged_attn='pallas' is not built for a model with two "
                 "cache kinds: the fused kernel reads per-head keys and "
                 "values of one pool, and this model's rows are latents "
                 "that all heads share, read by XLA (paged_attn='gather')")
+        if paged_attn == "pallas" and on_tpu and lanes % 128:
+            raise ValueError(
+                f"paged_attn='pallas' on a TPU needs pool rows of whole "
+                f"128-lane tiles, and kv_heads*head_dim is {lanes}: the "
+                f"kernel's own copies move whole tiles "
+                f"(paged_attn='gather' reads any width)")
         if not self.paged and (kv_dtype != "bf16" or kv_sink_tokens
                                or kv_window_tokens):
             raise ValueError(

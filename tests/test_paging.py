@@ -134,12 +134,11 @@ def _dense_decode_oracle(q, k_rows, v_rows, lengths):
                       preferred_element_type=jnp.float32).astype(q.dtype)
 
 
-def _paged_fixture(lengths, *, bs=8, heads=4, kvh=2, d=16, seed=0):
+def _paged_fixture(lengths, *, bs=8, heads=4, kvh=2, d=16, seed=0, mb=8):
     """Build a pool + tables whose gathered content equals dense rows
     holding the same K/V — the two layouts of one logical cache."""
     rng = np.random.default_rng(seed)
     slots = len(lengths)
-    mb = 8
     attend = mb * bs
     k_rows = rng.normal(size=(slots, attend, kvh, d)).astype(np.float32)
     v_rows = rng.normal(size=(slots, attend, kvh, d)).astype(np.float32)
@@ -355,6 +354,106 @@ def test_paged_flash_bf16_pool_matches_reference():
     np.testing.assert_allclose(np.asarray(ref, np.float32),
                                np.asarray(got, np.float32),
                                atol=1e-2, rtol=1e-2)
+
+
+# The kernel walks a slot's table a tile of entries at a time (ISSUE 33):
+# 16 entries at this fixture's block of 8, so a table of 40 entries is
+# two tiles and a half (padded to three), and positions 127 | 128 are a
+# tile's edge.
+TILE_TABLE = 40
+TILE_LENGTHS = (0, 127, 128, 129, TILE_TABLE * 8 - 1)
+TILE_CASES = {
+    "float": dict(),
+    "bf16": dict(dtype=jnp.bfloat16, tol=1e-2),
+    "int8": dict(int8=True),
+    "grouped": dict(geo=dict(heads=8, kvh=2, d=64)),
+    # the run of retired blocks covers whole tiles of the longest slot
+    "window": dict(kw=dict(sink_tokens=8, window_tokens=16)),
+    # and, with no sink, its first tile: the walk starts where the
+    # window does
+    "window_nosink": dict(kw=dict(sink_tokens=0, window_tokens=16)),
+}
+
+
+def test_paged_flash_tile_rule():
+    """The tile comes from the shapes: 128 positions where the table and
+    VMEM allow (8 entries at the serve cells' block of 16), never more
+    than the table holds, fewer where two tiles of K and V rows would
+    pass the VMEM the kernel gives them, and never under one entry."""
+    from pytorchdistributed_tpu.ops.pallas_attention import _tile_blocks
+
+    assert _tile_blocks(16, 2 * 1024 * 2, 64) == 8     # the serve cells
+    assert _tile_blocks(8, 2 * 32 * 4, TILE_TABLE) == 16
+    assert _tile_blocks(8, 2 * 32 * 4, 8) == 8         # a short table
+    assert _tile_blocks(16, 2 * 8192 * 4, 64) == 2     # rows of 64 KB
+    assert _tile_blocks(16, 2 * 65536 * 4, 64) == 1
+
+
+@pytest.mark.parametrize("case", TILE_CASES)
+def test_paged_flash_tile_edges(case):
+    """Parity with the gather path where the kernel's tiles end: a slot
+    one position under, at and one over a tile's edge, a dead slot
+    (length 0) beside one that fills a table the tile does not divide;
+    for float, bf16, int8, grouped and windowed pools."""
+    from pytorchdistributed_tpu.ops.pallas_attention import (
+        _tile_blocks,
+        paged_flash_attention,
+    )
+
+    spec = TILE_CASES[case]
+    geo = spec.get("geo", dict(kvh=2))
+    q, pk, pv, tbl, lens, _, _ = _paged_fixture(
+        TILE_LENGTHS, mb=TILE_TABLE, **geo)
+    assert TILE_TABLE % _tile_blocks(
+        pk.shape[1], 2 * pk.shape[2] * 4, TILE_TABLE)
+    kw = dict(spec.get("kw", {}))
+    if spec.get("int8"):
+        pk, ks, pv, vs = _quantize_fixture_pool(pk, pv, geo.get("d", 16))
+        kw.update(k_scale=ks, v_scale=vs)
+    if "dtype" in spec:
+        q, pk, pv = (a.astype(spec["dtype"]) for a in (q, pk, pv))
+    ref = paged_attention(q, pk, pv, tbl, lens, **kw)[:, 0]
+    got = paged_flash_attention(q[:, 0], pk, pv, tbl, lens, **kw)
+    tol = spec.get("tol", 2e-5)
+    np.testing.assert_allclose(np.asarray(ref, np.float32),
+                               np.asarray(got, np.float32),
+                               atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("kw", [{}, dict(sink_tokens=8, window_tokens=16)],
+                         ids=["full", "window"])
+def test_paged_flash_dead_tiles_never_read(kw):
+    """The kernel's cost follows the live tokens: a tile wholly past a
+    slot's length — or wholly inside the run of blocks a sliding window
+    has retired — starts no copy and computes nothing. Its table entries
+    pointed at a block of NaN leave the output equal and finite (one
+    product with a NaN, even at probability 0, would show)."""
+    from pytorchdistributed_tpu.ops.pallas_attention import (
+        _tile_blocks,
+        paged_flash_attention,
+    )
+
+    q, pk, pv, tbl, lens, _, _ = _paged_fixture(
+        TILE_LENGTHS, mb=TILE_TABLE, kvh=2)
+    bs = pk.shape[1]
+    tile = bs * _tile_blocks(bs, 2 * pk.shape[2] * 4, TILE_TABLE)
+    want = paged_flash_attention(q[:, 0], pk, pv, tbl, lens, **kw)
+    poison = pk.shape[0]
+    pk = jnp.concatenate([pk, jnp.full_like(pk[:1], jnp.nan)])
+    pv = jnp.concatenate([pv, jnp.full_like(pv[:1], jnp.nan)])
+    tbl_np = np.asarray(tbl).copy()
+    dead = 0
+    for s, n in enumerate(TILE_LENGTHS):
+        for lo in range(0, TILE_TABLE * bs, tile):
+            retired = kw and lo >= 8 and lo + tile <= n - 16 + 1
+            if lo > n or retired:
+                tbl_np[s, lo // bs:(lo + tile) // bs] = poison
+                dead += 1
+    assert dead == (7 if kw else 6)      # of the 15 tiles
+    got = paged_flash_attention(q[:, 0], pk, pv, jnp.asarray(tbl_np), lens,
+                                **kw)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
 
 
 # ---------------------------------------------------------------------------

@@ -89,33 +89,42 @@ def test_flash_fwd_bwd_lowers_for_tpu():
     assert lower_for_tpu(flash_fwd_bwd, x, x, x, x).count(MARKER) == 3
 
 
-def paged_args(kv_heads: int, int8: bool):
+def paged_args(heads=HEADS, kv_heads=None, int8=False, slots=SLOTS,
+               layers=0):
+    """q, the two pools, tables, lengths, then the scale planes and the
+    layer (None where the case has none)."""
+    kv_heads = kv_heads or heads
     pages = SEQ // BLOCK
-    nb = SLOTS * pages + 1
-    pool = sds((nb, BLOCK, kv_heads * HEAD_DIM),
+    stack = (layers,) if layers else ()
+    nb = slots * pages + 1
+    pool = sds(stack + (nb, BLOCK, kv_heads * HEAD_DIM),
                jnp.int8 if int8 else jnp.bfloat16)
-    args = [sds((SLOTS, HEADS, HEAD_DIM)), pool, pool,
-            sds((SLOTS, pages), jnp.int32), sds((SLOTS,), jnp.int32)]
-    if int8:
-        args += [sds((nb, BLOCK, kv_heads), jnp.float32)] * 2
-    return args
+    scales = sds(stack + (nb, BLOCK, kv_heads), jnp.float32) if int8 else None
+    return [sds((slots, heads, HEAD_DIM)), pool, pool,
+            sds((slots, pages), jnp.int32), sds((slots,), jnp.int32),
+            scales, scales, sds((), jnp.int32) if layers else None]
 
 
-def paged(q, kp, vp, tables, lengths, ks=None, vs=None):
+def paged(q, kp, vp, tables, lengths, ks, vs, layer):
     return paged_flash_attention(q, kp, vp, tables, lengths, k_scale=ks,
-                                 v_scale=vs, interpret=False)
+                                 v_scale=vs, layer=layer, interpret=False)
 
 
-PAGED_CASES = {"bf16": (HEADS, False), "int8": (HEADS, True),
-               "gqa": (HEADS // 3, False)}
+PAGED_CASES = {
+    "bf16": dict(), "int8": dict(int8=True), "gqa": dict(kv_heads=HEADS // 3),
+    # the serve cells' own call: gpt2-medium's layer-stacked pool (24 x
+    # 2,049 blocks of 16 rows of 1,024 lanes), 32 slots of 64 table
+    # entries, the layer traced
+    "cell": dict(heads=16, slots=32, layers=24),
+}
 
 
 @pytest.mark.parametrize("case", PAGED_CASES)
 def test_paged_decode_kernel_lowers_for_tpu(case):
-    """A KV block is whole pool rows, ``(block_size, kv_heads*head_dim)``
-    (768 lanes, or 256 for the GQA group): the array's own minor dims,
-    which the lowering takes at any width."""
-    assert MARKER in lower_for_tpu(paged, *paged_args(*PAGED_CASES[case]))
+    """The pools stay in HBM and the kernel copies whole pool rows,
+    ``(block_size, kv_heads*head_dim)`` a block (768 lanes, 256 for the
+    GQA group, 1,024 in the serve cells), by its own DMAs."""
+    assert MARKER in lower_for_tpu(paged, *paged_args(**PAGED_CASES[case]))
 
 
 @pytest.mark.slow
@@ -124,8 +133,9 @@ def test_paged_decode_kernel_compiles_for_v5e(case):
     from jax.sharding import SingleDeviceSharding
 
     one = SingleDeviceSharding(v5e_devices(1)[0])
-    args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)
-            for a in paged_args(*PAGED_CASES[case])]
+    args = [None if a is None
+            else jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)
+            for a in paged_args(**PAGED_CASES[case])]
     assert MARKER in jax.jit(paged).lower(*args).compile().as_text()
 
 
@@ -205,11 +215,30 @@ def test_engine_default_tick_lowers_the_paged_kernel(compiled_kernels):
     tick the engine dispatches really holds it."""
     from pytorchdistributed_tpu.serving import ServingEngine
 
-    model = GPT2(gpt2_config("test"))
+    model = GPT2(gpt2_config("test", embed_dim=128, num_heads=2))
     params = model.init(jax.random.key(0), jnp.zeros((1, 4), jnp.int32))
     engine = ServingEngine(model, params, num_slots=2, block_size=16)
     assert engine.summary()["paged_attn"] == "pallas"
     assert MARKER in engine.lower_tick(platforms=TPU).as_text()
+
+
+def test_engine_keeps_the_kernel_to_rows_of_whole_lane_tiles(
+        compiled_kernels):
+    """The kernel's own copies move whole 128-lane tiles (Mosaic refuses
+    a DMA slice of any other width: found by the v5e compile of the int8
+    scale planes, ISSUE 33), so on a TPU the default is the kernel only
+    where ``kv_heads*head_dim`` is such a row, and asking for it
+    elsewhere is refused when the engine is built, not when the first
+    tick compiles."""
+    from pytorchdistributed_tpu.serving import ServingEngine
+
+    model = GPT2(gpt2_config("test"))              # rows of 64 lanes
+    params = model.init(jax.random.key(0), jnp.zeros((1, 4), jnp.int32))
+    engine = ServingEngine(model, params, num_slots=2, block_size=16)
+    assert engine.summary()["paged_attn"] == "gather"
+    with pytest.raises(ValueError, match="whole 128-lane tiles"):
+        ServingEngine(model, params, num_slots=2, block_size=16,
+                      paged_attn="pallas")
 
 
 # One layer's pool at the serve cells' geometry: gpt2-medium width, 32
