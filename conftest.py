@@ -56,6 +56,10 @@ _QUICK = (
     # a model with two cache kinds through the paged engine against the
     # benchmark's plain reference (ISSUE 30)
     "test_latent_serving.py",
+    # EVA attention (a tumbling window beside a summary row a chunk)
+    # through the paged engine's two pools against the benchmark's plain
+    # reference, and the pools' books (ISSUE 34)
+    "test_eva_serving.py",
     # the engine's weights in the compute type, cast once where a tree is
     # taken: bitwise tokens and logits, no convert in the tick (ISSUE 31)
     "test_serving_weights.py",

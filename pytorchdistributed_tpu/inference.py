@@ -273,11 +273,13 @@ def reset_cache_positions(cache, new_index):
 #: and values with their int8 scale planes (models/transformer.py), and
 #: the rows of a model with two cache kinds (models/latent.py: the full
 #: layers' latent rows with the indexer's keys beside them, the sliding
-#: layers' window rows). Everything else in the collection is counters
+#: layers' window rows; models/eva.py: a chunk's summary key and value
+#: beside the window's exact ones). Everything else in the collection is counters
 #: and tables.
 KV_POOL_LEAVES = ("cached_key", "cached_value", "cached_key_scale",
                   "cached_value_scale", "cached_latent",
-                  "cached_index_key", "cached_window")
+                  "cached_index_key", "cached_window",
+                  "cached_summary_key", "cached_summary_value")
 
 
 def kv_cache_bytes(cache) -> int:

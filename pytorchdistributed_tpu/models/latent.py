@@ -51,6 +51,7 @@ import jax.numpy as jnp
 
 from pytorchdistributed_tpu.models.moe import DroplessMoE
 from pytorchdistributed_tpu.models.transformer import (
+    CacheKind,
     Embedder,
     _cfg_dot_general,
     _layer_norm,
@@ -185,11 +186,9 @@ class LatentConfig:
 
     @property
     def cache_kinds(self) -> tuple:
-        """The pools the engine keeps, the stream's own first: (the
-        `pool` id on its spans, the table leaf, the window its layers see
-        or 0 for every position)."""
-        return (("latent", "block_table", 0),
-                ("window", "window_table", self.sliding_window))
+        """The pools the engine keeps, the stream's own first."""
+        return (CacheKind("latent", "block_table"),
+                CacheKind("window", "window_table", self.sliding_window))
 
 
 def _linear(mod, cfg, name, shape, x):
@@ -532,7 +531,7 @@ class LatentLM(nn.Module):
                                    jnp.int32).value,
             **{table: self.variable("cache", table, jnp.zeros,
                                     (slots, pages), jnp.int32).value
-               for _, table, _ in cfg.cache_kinds}}
+               for _, table, *_ in cfg.cache_kinds}}
         # a free slot ticks along at length 0: computed, never counted
         live = jnp.broadcast_to((paging["index"] > 0)[:, None], (b, s))
         x = Embedder(cfg, name="embed")(tokens)

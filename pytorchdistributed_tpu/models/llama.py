@@ -37,8 +37,18 @@ class Llama(nn.Module):
         self.ln_f = _layer_norm(cfg, None)
         self.lm_head = LMHead(cfg)
 
+    @property
+    def counters(self) -> tuple:
+        """Names of the "counters" collection's vector (the engine's
+        summary): what an EVA stack counts on the device a tick."""
+        from pytorchdistributed_tpu.models import eva
+
+        return eva.COUNTERS if self.cfg.eva_window else ()
+
     def _backbone(self, tokens, deterministic):
         x = self.embed(tokens)
+        if self.cfg.fp32_residual:
+            x = x.astype(jnp.float32)
         x = self.h(x, deterministic=deterministic)
         return self.ln_f(x)
 

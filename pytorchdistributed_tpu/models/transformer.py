@@ -24,7 +24,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Callable
+from typing import Any, Callable, NamedTuple
 
 import flax.linen as nn
 import jax
@@ -38,6 +38,28 @@ from pytorchdistributed_tpu.ops.attention import (
 from pytorchdistributed_tpu.parallel.tp import Logical
 
 Dtype = Any
+
+
+class CacheKind(NamedTuple):
+    """One kind of cache a paged model keeps, as the serving engine's
+    `SlotPool` takes it (`cfg.cache_kinds`, the stream's own first)."""
+    kind: str | None        # the `pool` id on the engine's spans
+    table: str              # the "cache" leaf the model reads block ids from
+    # positions a query of this pool's layers sees, itself included; 0:
+    # every position (the pool grows with the stream and never retires)
+    window: int = 0
+    # positions one row of the pool stands for (a summary row a chunk)
+    stride: int = 1
+    # how a windowed pool retires: False slides (a block goes once the
+    # window has passed it), True tumbles (the blocks of a window all go
+    # when the stream crosses a multiple of `window`, none before)
+    tumbling: bool = False
+
+    def pages(self, kv_pages: int) -> int:
+        """Width of this kind's block table, where one a position's is
+        `kv_pages` wide: the blocks that back one full-context slot's
+        rows of it."""
+        return -(-kv_pages // self.stride)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -208,6 +230,27 @@ class TransformerConfig:
     # sink/window as STATIC parameters); off by default so the static
     # mask — and every pinned HLO — is byte-identical.
     per_slot_kv_limits: bool = False
+    # EVA attention (models/eva.py; Zheng et al., ICLR 2023, as EvaByte
+    # serves it): eva_window > 0 makes every layer attend, in ONE softmax,
+    # the exact keys and values of the query's own tumbling window of
+    # `eva_window` positions and one learned summary row (a key and a
+    # value per head) for each chunk of `eva_chunk` positions of every
+    # FINISHED window. Served through the paged engine only, where the
+    # two kinds of row live in two pools of the same lane-dense layout
+    # (`cache_kinds`): the window pool (`cached_key`/`cached_value` at
+    # the blocks of ``window_table``, `window_blocks` of them, sized by
+    # the engine) and the summary pool (`cached_summary_key`/`_value` at
+    # the blocks of ``block_table``, `kv_blocks` of them, one row a
+    # chunk).
+    eva_window: int = 0
+    eva_chunk: int = 0
+    window_blocks: int = 0
+    # `RMS(x) * (1 + g)`: the gain is stored about a unit offset
+    norm_unit_offset: bool = False
+    # the residual stream in float32 (the sublayers still compute in
+    # `dtype`), and the logits summed and left in float32
+    fp32_residual: bool = False
+    fp32_logits: bool = False
     # Multi-token proposal heads (ISSUE 16, the Medusa recipe — Cai et
     # al. 2024) for a speculative DRAFT model: > 0 adds that many extra
     # decoding heads, each a zero-init SiLU residual block on the final
@@ -378,6 +421,12 @@ class TransformerConfig:
                     f"kv_sink_tokens {self.kv_sink_tokens} must be "
                     f"multiples of kv_block_size {self.kv_block_size} "
                     f"(retirement is whole-block)")
+        if self.eva_window:
+            self._check_eva()
+        if self.norm_unit_offset and (self.norm != "rmsnorm"
+                                      or self.fused_norms):
+            raise ValueError("norm_unit_offset is built for the plain "
+                             "RMSNorm (norm='rmsnorm', fused_norms=False)")
         if self.spec_heads < 0:
             raise ValueError(f"spec_heads must be >= 0, got "
                              f"{self.spec_heads}")
@@ -398,10 +447,54 @@ class TransformerConfig:
                 f"(build the decode model with attention='dense' to "
                 f"silence this)", stacklevel=3)
 
+    def _check_eva(self) -> None:
+        win, chunk, bs = self.eva_window, self.eva_chunk, self.kv_block_size
+        if chunk < 1 or win % chunk:
+            raise ValueError(f"eva_chunk {chunk} must divide eva_window "
+                             f"{win}")
+        if self.kv_heads != self.num_heads:
+            raise ValueError("EVA summarises per head: grouped-query "
+                             "heads (num_kv_heads < num_heads) are not "
+                             "built")
+        if not self.rope or not self.scan_layers:
+            raise ValueError("EVA attention is built for the scanned RoPE "
+                             "stack (rope=True, scan_layers=True)")
+        if self.decode and not bs:
+            raise ValueError(
+                "a model with two cache kinds is served through the "
+                "paged engine only (block_size > 0): the dense per-slot "
+                "cache has one layout for every position")
+        if self.kv_dtype != "bf16" or self.kv_window_tokens:
+            raise ValueError(
+                "kv_dtype='int8' and kv_window_tokens are not built for "
+                "EVA's two pools: a summary row is computed from the "
+                "stored rows, and the window is the model's own "
+                "(eva_window), retired a whole window at a time")
+        if bs:
+            if bs % chunk or win % bs or self.max_seq_len % win:
+                raise ValueError(
+                    f"kv_block_size {bs} must be a multiple of eva_chunk "
+                    f"{chunk} (a chunk's rows lie in one block) and "
+                    f"divide eva_window {win}, which must divide "
+                    f"max_seq_len {self.max_seq_len}")
+            if self.window_blocks < 2:
+                raise ValueError("window_blocks must be >= 2 (block 0 of "
+                                 "the window pool is its trash block)")
+
     @property
     def kv_heads(self) -> int:
         return (self.num_kv_heads if self.num_kv_heads is not None
                 else self.num_heads)
+
+    @property
+    def cache_kinds(self) -> tuple:
+        """The pools the serving engine keeps, the stream's own first."""
+        if self.eva_window:
+            return (CacheKind("summary", "block_table",
+                              stride=self.eva_chunk),
+                    CacheKind("window", "window_table", self.eva_window,
+                              tumbling=True))
+        return (CacheKind(None, "block_table"),)
 
     @property
     def kv_pages(self) -> int:
@@ -422,6 +515,12 @@ class TransformerConfig:
         int8 = self.kv_dtype == "int8"
         kv = (rows + (self.kv_heads * self.head_dim,),
               jnp.int8 if int8 else self.dtype)
+        if self.eva_window:
+            # the window's exact rows and the chunks' summary rows: the
+            # same row layout, a pool each
+            win = ((self.window_blocks,) + kv[0][1:], kv[1])
+            return {"cached_key": win, "cached_value": win,
+                    "cached_summary_key": kv, "cached_summary_value": kv}
         leaves = {"cached_key": kv, "cached_value": kv}
         if int8:
             scale = (rows + (self.kv_heads,), jnp.float32)
@@ -660,7 +759,27 @@ class SelfAttention(nn.Module):
 
         rep = cfg.num_heads // cfg.kv_heads
 
-        if cfg.decode:
+        if cfg.eva_window:
+            # EVA (models/eva.py): the window's exact rows and the
+            # finished windows' summary rows under one softmax, both read
+            # from the scanned stack's pools; `phi` scores a chunk's keys
+            # for its summary, `mu` is added to the summary's key
+            if not (cfg.decode and cfg.kv_block_size) or pool is None:
+                raise NotImplementedError(
+                    "EVA attention is served through the paged engine "
+                    "(ServingEngine(model, params, block_size=...)); a "
+                    "cacheless forward is the benchmark's plain reference")
+            from pytorchdistributed_tpu.models import eva
+
+            phi, mu = (self.param(
+                name, nn.with_logical_partitioning(
+                    nn.initializers.normal(stddev=0.1),
+                    (Logical.HEADS, Logical.KV)),
+                (cfg.num_heads, cfg.head_dim), jnp.float32)
+                for name in ("eva_phi", "eva_mu"))
+            out, pool = eva.paged_attention(cfg, q, k, v, phi, mu, paging,
+                                            pool, layer)
+        elif cfg.decode:
             if cfg.kv_block_size:
                 # Paged KV (ISSUE 7): one pool of fixed-size blocks shared
                 # by every slot + a per-slot block table mapping logical
@@ -925,6 +1044,26 @@ class MlpBlock(nn.Module):
         return out
 
 
+class UnitOffsetRMSNorm(nn.Module):
+    """`x / sqrt(mean(x^2) + eps) * (1 + g)`, in float32: an RMSNorm whose
+    stored gain `g` (``scale``) is about a unit offset
+    (`cfg.norm_unit_offset`)."""
+
+    epsilon: float
+    param_dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        g = self.param(
+            "scale", nn.with_logical_partitioning(
+                nn.initializers.zeros_init(), (Logical.EMBED,)),
+            (x.shape[-1],), self.param_dtype)
+        x = x.astype(jnp.float32)
+        return x * jax.lax.rsqrt(
+            jnp.mean(x * x, axis=-1, keepdims=True) + self.epsilon) * (
+                1.0 + g.astype(jnp.float32))
+
+
 def _layer_norm(cfg, name):
     """cfg.fused_norms=True: the custom_vjp norms (ops/norms.py) — fp32
     normalization math like the flax originals (same param trees, so
@@ -951,6 +1090,9 @@ def _layer_norm(cfg, name):
                               param_dtype=cfg.param_dtype,
                               scale_init=scale_init, bias_init=bias_init,
                               name=name)
+    if cfg.norm == "rmsnorm" and getattr(cfg, "norm_unit_offset", False):
+        return UnitOffsetRMSNorm(epsilon=cfg.norm_eps,
+                                 param_dtype=cfg.param_dtype, name=name)
     if cfg.norm == "rmsnorm":
         return nn.RMSNorm(
             epsilon=cfg.norm_eps,
@@ -1187,9 +1329,12 @@ class TransformerStack(nn.Module):
                 "index": self.variable(
                     "cache", "index",
                     lambda: jnp.zeros((slots,), jnp.int32)),
-                "block_table": self.variable(
-                    "cache", "block_table",
-                    lambda: jnp.zeros((slots, cfg.kv_pages), jnp.int32))}
+                # one block table a kind of cache (`block_table` alone,
+                # unless the model keeps two pools)
+                **{kind.table: self.variable(
+                       "cache", kind.table, jnp.zeros,
+                       (slots, kind.pages(cfg.kv_pages)), jnp.int32)
+                   for kind in cfg.cache_kinds}}
             if cfg.per_slot_kv_limits and cfg.kv_window_tokens:
                 state["kv_sinks"] = self.variable(
                     "cache", "kv_sinks",
@@ -1219,13 +1364,25 @@ class TransformerStack(nn.Module):
                            (cfg.num_layers,) + shape, dtype)
                        for name, (shape, dtype)
                        in cfg.kv_pool_leaves.items()}
+                carried = {name: var.value for name, var in own.items()}
+                if cfg.eva_window:
+                    # what the layers count of the masks they attend
+                    # under rides the carry beside the pools
+                    from pytorchdistributed_tpu.models import eva
+
+                    carried[eva.COUNTS] = jnp.zeros(
+                        (len(eva.COUNTERS),), jnp.float32)
                 (x, pool), _ = scan(
                     lambda mdl, carry, paging, layer: (
                         mdl(carry[0], paging, carry[1], layer), None),
                     in_axes=(nn.broadcast, 0),
                 )(block(cfg, deterministic, name="block"),
-                  (x, {name: var.value for name, var in own.items()}),
-                  paging, jnp.arange(cfg.num_layers))
+                  (x, carried), paging, jnp.arange(cfg.num_layers))
+                if cfg.eva_window and self.is_mutable_collection(
+                        "counters"):
+                    self.variable("counters", "tick", jnp.zeros,
+                                  (len(eva.COUNTERS),)).value = pool[
+                                      eva.COUNTS]
                 if not self.is_initializing():
                     for name, var in own.items():
                         var.value = pool[name]
@@ -1307,6 +1464,12 @@ class LMHead(nn.Module):
         x = x.astype(self.cfg.dtype)
         kernel = self.kernel.astype(self.cfg.dtype)
         dg = _cfg_dot_general(self.cfg)
+        if self.cfg.fp32_logits:
+            # products in `dtype`, summed and left in float32, so that a
+            # near-tie between logits is not decided by rounding the sum
+            return (dg or jax.lax.dot_general)(
+                x, kernel, (((x.ndim - 1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
         if dg is None:
             return x @ kernel
         return dg(x, kernel, (((x.ndim - 1,), (0,)), ((), ())))

@@ -84,6 +84,7 @@ from pytorchdistributed_tpu.inference import (
     sample_slots,
     stop_ids_tuple,
 )
+from pytorchdistributed_tpu.models.transformer import CacheKind
 from pytorchdistributed_tpu.serving.paging import (
     RadixPrefixCache,
     SlotPool,
@@ -141,7 +142,7 @@ POOL_LEAF_AXIS = dict.fromkeys(KV_POOL_LEAVES, 3)
 
 # the kinds of cache of a model that declares none (`cfg.cache_kinds`):
 # one pool behind `block_table`, every position kept, no id on its spans
-_ONE_POOL = ((None, "block_table", 0),)
+_ONE_POOL = (CacheKind(None, "block_table"),)
 
 
 def _pool_block_axis(name: str, ndim: int) -> int:
@@ -978,11 +979,15 @@ class ServingEngine:
         if num_slots < 1:
             raise ValueError(f"num_slots must be >= 1, got {num_slots}")
         self.num_slots = num_slots
-        # the kinds of cache the model keeps, the stream's own first: (the
-        # `pool` id on the spans, the table leaf, the window its layers see
-        # or 0 for every position). One pool, unless the model declares
-        # more (models/latent.py: a latent pool that grows with the
-        # stream, and a window pool whose blocks retire per layer kind)
+        # the kinds of cache the model keeps, the stream's own first
+        # (`CacheKind`: the `pool` id on the spans, the table leaf, the
+        # window its layers see or 0 for every position, the positions a
+        # row stands for, and whether the window slides or tumbles). One
+        # pool, unless the model declares more (models/latent.py: a latent
+        # pool that grows with the stream, and a window pool whose blocks
+        # retire per layer kind; models/eva.py: a pool of one summary row
+        # a chunk that grows with the stream, and a pool of the current
+        # window's exact rows that retires a whole window at a time)
         self._kinds = tuple(getattr(model.cfg, "cache_kinds", _ONE_POOL))
         self._pools: list[SlotPool] = []
         self._refuse_two_kinds(
@@ -1027,9 +1032,13 @@ class ServingEngine:
         if paged_attn == "pallas" and not one_kind:
             raise ValueError(
                 "paged_attn='pallas' is not built for a model with two "
-                "cache kinds: the fused kernel reads per-head keys and "
-                "values of one pool, and this model's rows are latents "
-                "that all heads share, read by XLA (paged_attn='gather')")
+                "cache kinds: the fused kernel reads the rows of ONE pool "
+                "through one table under one causal mask, and this "
+                "model's queries read two (a learned selection of latent "
+                "rows beside a window's; a window's rows and the "
+                "summaries of finished windows under one softmax), which "
+                "the kernel has neither the masks nor the merge for: "
+                "read by XLA (paged_attn='gather')")
         if paged_attn == "pallas" and on_tpu and lanes % 128:
             raise ValueError(
                 f"paged_attn='pallas' on a TPU needs pool rows of whole "
@@ -1051,7 +1060,8 @@ class ServingEngine:
                 raise ValueError(
                     f"block_size {block_size} must divide max_seq_len "
                     f"{max_len}")
-            pages = max_len // block_size
+            # the stream's own pool's blocks a full-context slot
+            pages = self._kinds[0].pages(max_len // block_size)
             if num_blocks is None:
                 # dense-equivalent HBM by default: one full context per
                 # slot, plus the trash block — shrink it to oversubscribe
@@ -1063,9 +1073,9 @@ class ServingEngine:
                     f"max_seq_len/block_size + the trash block)")
             self.block_size = block_size
             self.num_blocks = num_blocks
-            for _, _, window in self._kinds[1:]:
+            for kind in self._kinds[1:]:
                 model = self._with_window_pool(
-                    model, window, block_size,
+                    model, kind, block_size,
                     prefill_chunk or prefill_bucket)
             # per-request window/sink overrides (ISSUE 15) need the
             # per-slot mask leaves; the Pallas kernel takes sink/window
@@ -1089,7 +1099,7 @@ class ServingEngine:
         self.cfg = self._tick_model.cfg
         # the scalars the model counts on the device a tick, by name (the
         # tick's third output; none for a model that counts nothing)
-        self._counter_names = tuple(getattr(type(model), "counters", ()))
+        self._counter_names = tuple(getattr(model, "counters", ()))
         self.bucket = max(1, min(prefill_bucket, self.cfg.max_seq_len))
         if self.paged:
             chunk = prefill_chunk if prefill_chunk else self.bucket
@@ -1097,6 +1107,14 @@ class ServingEngine:
             # whole blocks) and fit the context
             self.chunk = min(self._round_up(chunk, block_size),
                              self.cfg.max_seq_len)
+            for kind in self._kinds:
+                if kind.tumbling and kind.window % self.chunk:
+                    raise ValueError(
+                        f"prefill_chunk {self.chunk} does not divide the "
+                        f"{kind.kind} pool's tumbling window of "
+                        f"{kind.window}: a chunk that straddles two "
+                        f"windows would need the first one's rows after "
+                        f"they are retired")
             self._chunks_per_step = max(1, prefill_chunks_per_step)
             # one `SlotPool` a kind. The first is the stream's own:
             # `_alloc`, `_tables` and `_slot_blocks` are its members, which
@@ -1104,12 +1122,13 @@ class ServingEngine:
             # the others (windowed, sized by `_with_window_pool`) are
             # backed and retired beside it
             self._pools = [
-                SlotPool.empty(kind, table,
-                               self.cfg.window_blocks if window
+                SlotPool.empty(k.kind, k.table,
+                               self.cfg.window_blocks if k.window
                                else num_blocks,
-                               block_size, num_slots, self.cfg.kv_pages,
-                               window)
-                for kind, table, window in self._kinds]
+                               block_size, num_slots,
+                               k.pages(self.cfg.kv_pages),
+                               k.window, k.stride, k.tumbling)
+                for k in self._kinds]
             self._alloc = self._pools[0].alloc
             self._tables = self._pools[0].tables
             self._slot_blocks = self._pools[0].blocks
@@ -1505,8 +1524,11 @@ class ServingEngine:
                 self._cache, nxt = out[:2]
             with span("serve/tick_sync"):
                 if self._counter_names:
-                    # the device's counters come back with the tokens
-                    toks, counted = jax.device_get((nxt, out[2]["tick"]))
+                    # the device's counters come back with the tokens:
+                    # the collection's one vector, wherever in its tree
+                    # the model keeps it
+                    toks, counted = jax.device_get(
+                        (nxt, jax.tree.leaves(out[2])[0]))
                     self._stats["device_counters"] += counted
                 else:
                     toks = np.asarray(nxt)  # host sync: streaming delivery
@@ -1674,17 +1696,23 @@ class ServingEngine:
                 f"retired while the stream runs, so a cached prefix "
                 f"would have no window rows to resume from)")
 
-    def _with_window_pool(self, model, window, block_size, chunk):
-        """`model` with its window pool sized (the trash block included):
-        a stream that decodes holds the window's blocks and one at either
-        end; the one stream that prefills holds a chunk's blocks more.
-        Every slot is backed at once, so the window pool never runs dry
-        and nothing is preempted on its account."""
+    def _with_window_pool(self, model, kind, block_size, chunk):
+        """`model` with its window pool sized (the trash block included).
+        Sliding: a stream that decodes holds the window's blocks and one
+        at either end; the one stream that prefills holds a chunk's
+        blocks more. Tumbling: a stream, decoding or prefilling, holds at
+        most one window's blocks (a chunk lies in one window, and the
+        window before is handed back first). Every slot is backed at
+        once, so the window pool never runs dry and nothing is preempted
+        on its account."""
         cfg = model.cfg
-        back = window - 1
+        back = kind.window - 1
         chunk = min(self._round_up(chunk, block_size), cfg.max_seq_len)
-        need = (self.num_slots * (-(-back // block_size) + 2)
-                + -(-(chunk + back) // block_size) + 2)
+        if kind.tumbling:
+            need = self.num_slots * (kind.window // block_size) + 1
+        else:
+            need = (self.num_slots * (-(-back // block_size) + 2)
+                    + -(-(chunk + back) // block_size) + 2)
         return model.clone(cfg=dataclasses.replace(cfg,
                                                    window_blocks=need))
 
@@ -1695,9 +1723,8 @@ class ServingEngine:
         (at `lo`) can no longer see. `row` is the table row to keep in
         step: the tick's view of the slot, or the row of the admission
         in flight."""
-        bs = self.block_size
         blocks = pool.blocks[slot]
-        dead = min(max(0, lo - (pool.window - 1)) // bs, len(blocks))
+        dead = min(pool.retired_before(lo), len(blocks))
         first = int(pool.first[slot])
         if dead > first:
             with span("serve/retire_window", pool=pool.kind):
@@ -1707,7 +1734,7 @@ class ServingEngine:
                     row[bi] = 0
                 self._stats[f"{pool.kind}_blocks_retired"] += dead - first
                 pool.first[slot] = dead
-        last = (min(hi, self.cfg.max_seq_len) - 1) // bs
+        last = pool.block_of(min(hi, self.cfg.max_seq_len) - 1)
         while len(blocks) <= last:
             fresh = pool.alloc.alloc(1)
             if fresh is None:
@@ -1806,7 +1833,11 @@ class ServingEngine:
         m = len(matched) * bs
         span = min(self._round_up(true_len - m, self.chunk),
                    self.cfg.max_seq_len - m)
-        fresh = self._alloc_blocks(self._round_up(span, bs) // bs)
+        # the stream's own pool backs the whole span at once, by the
+        # positions a row of it stands for; a windowed pool is backed a
+        # chunk at a time (_chunk_call)
+        own = self._pools[0]
+        fresh = self._alloc_blocks(own.blocks_for(m + span) - len(matched))
         if fresh is None and not self._active and m:
             # nothing will retire and the shared prefix is squatting the
             # pool: fall back to a full private prefill so the lone
@@ -1818,7 +1849,7 @@ class ServingEngine:
                 self._radix.clear()
             span = min(self._round_up(true_len, self.chunk),
                        self.cfg.max_seq_len)
-            fresh = self._alloc_blocks(self._round_up(span, bs) // bs)
+            fresh = self._alloc_blocks(own.blocks_for(span))
         if fresh is None:
             for b in matched:
                 self._alloc.decref(b)
@@ -1843,7 +1874,7 @@ class ServingEngine:
         # real block. The chunk program reads the real row from pf state.
         # A row a pool: the stream's own holds its blocks, a windowed
         # pool's is backed a chunk at a time (_chunk_call).
-        table_row = {pool.table: np.zeros(self.cfg.kv_pages, np.int32)
+        table_row = {pool.table: np.zeros(pool.tables.shape[1], np.int32)
                      for pool in self._pools}
         table_row[self._pools[0].table][:len(blocks)] = blocks
         req.prefix_hit_tokens += m
@@ -2036,8 +2067,9 @@ class ServingEngine:
                         blocks[bi] = 0
                         self._tables[slot, bi] = 0
                         self._stats["retired_blocks"] += 1
-            bi = min(int(self._lengths[slot]) + self.spec_k,
-                     self.cfg.max_seq_len - 1) // self.block_size
+            bi = self._pools[0].block_of(
+                min(int(self._lengths[slot]) + self.spec_k,
+                    self.cfg.max_seq_len - 1))
             while bi >= len(blocks):
                 fresh = self._alloc_blocks(1)
                 if fresh is not None:
@@ -3482,9 +3514,11 @@ class ServingEngine:
                            (float(v) for v in st["device_counters"])))
         if len(self._pools) > 1:
             # each pool by its kind; the first's share over the ticks is
-            # `block_utilization`, above
+            # `block_utilization`, above, and under its kind's name here
             for pool in self._pools:
                 out[f"{pool.kind}_blocks_in_use"] = pool.in_use
+            out[f"{self._pools[0].kind}_block_utilization"] = out[
+                "block_utilization"]
             for pool in self._pools[1:]:
                 out[f"{pool.kind}_blocks_retired"] = st[
                     f"{pool.kind}_blocks_retired"]

@@ -15,7 +15,12 @@ state the scheduler mutates between compiled calls:
     allocator, the table the tick reads and each slot's block list. An
     engine holds a list of them: one entry for a model with one pool, a
     second (with a window, whose blocks retire while the stream runs)
-    for a model whose layer kinds keep their own.
+    for a model whose layer kinds keep their own. Two numbers and a
+    flag say how a pool's blocks follow the stream: how many positions
+    one of its rows stands for (`stride`: a summary row a chunk), the
+    `window` its layers see, and whether the window slides (a block
+    retires once the window has passed it) or tumbles (`tumbling`: all
+    of a window's blocks retire when the stream crosses into the next).
   * `RadixPrefixCache` — a block-granularity radix tree over prompt
     token ids (SGLang's RadixAttention at vLLM's block alignment): a
     node caches ONE full block (`block_size` tokens) of K/V under its
@@ -168,18 +173,38 @@ class SlotPool:
     # a slot's first logical block not yet retired, so that a sweep
     # never walks the dead prefix again
     first: np.ndarray | None = None
+    # positions one row stands for: a block of `block_size` rows backs
+    # `block_size * stride` positions
+    stride: int = 1
+    # a windowed pool's retirement: sliding, or a whole window at a time
+    tumbling: bool = False
 
     @classmethod
     def empty(cls, kind, table, num_blocks, block_size, slots, pages,
-              window=0):
+              window=0, stride=1, tumbling=False):
         return cls(kind, table, BlockAllocator(num_blocks, block_size),
                    np.zeros((slots, pages), np.int32),
                    [[] for _ in range(slots)], window,
-                   np.zeros(slots, np.int64))
+                   np.zeros(slots, np.int64), stride, tumbling)
 
     @property
     def in_use(self) -> int:
         return self.alloc.usable - self.alloc.free_count
+
+    def block_of(self, position: int) -> int:
+        """The logical block that holds `position`'s row."""
+        return position // (self.alloc.block_size * self.stride)
+
+    def blocks_for(self, positions: int) -> int:
+        """Blocks that back the rows of the first `positions` positions."""
+        return -(-positions // (self.alloc.block_size * self.stride))
+
+    def retired_before(self, lo: int) -> int:
+        """Leading logical blocks of a windowed pool that no query at or
+        after position `lo` can see."""
+        if self.tumbling:
+            return self.block_of(lo // self.window * self.window)
+        return self.block_of(max(0, lo - (self.window - 1)))
 
     @property
     def ids(self) -> dict:
