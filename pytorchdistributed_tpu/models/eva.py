@@ -37,15 +37,25 @@ window closes. A summary of a chunk that is not full yet (the padded tail
 of a prompt's last prefill chunk) is garbage nobody sees: the tick that
 fills the chunk writes the row again.
 
-Read by XLA through whole-block gathers. The summaries' part follows the
+Two reads, chosen by `cfg.paged_attn`. A tick under ``"pallas"`` (the
+engine's choice on a TPU) reads each pool through the paged decode kernel
+(`ops/pallas_attention.paged_flash_attention`): the window pool through
+``window_table`` from the window's first position to the query's own, the
+summary pool through ``block_table`` as far as the finished windows'
+rows, each live row copied from HBM once, and the two calls' partial
+softmaxes merged by their log-sum-exp into the one softmax above
+(`merge_attention_parts`); a stream of window 0 has no summary to read,
+and that call costs it nothing. Everything else (``"gather"``: the CPU,
+the tests' reference; a chunk's many queries under either) is read by XLA
+through whole-block gathers. There the summaries' part follows the
 longest stream of the call: a branch a count of finished windows, chosen
 on the device, gathers that many windows' summary blocks and no more
 (against one gather of every block, at EvaByte's size on a v5e: a tick
 of 16 streams 40.6 ms for 45.1, the prefill of their prompts 13.6 s for
 17.7; PERF.md section 6, PR 34). A
-tick's single query a stream is multiplied with the gathered rows where
-they lie, whole lane-dense rows through the matrix unit; a chunk's
-queries are many, and there the rows are split into heads.
+gathered tick's single query a stream is multiplied with the gathered
+rows where they lie, whole lane-dense rows through the matrix unit; a
+chunk's queries are many, and there the rows are split into heads.
 """
 
 from __future__ import annotations
@@ -55,8 +65,9 @@ import jax.numpy as jnp
 
 #: the device-side scalars of one call, in the order of the "counters"
 #: collection's one vector: rows the live streams' queries attended in the
-#: window pool and in the summary pool (summed from the masks the
-#: attention ran under, over the layers), and summary rows written
+#: window pool and in the summary pool (summed over the layers: from the
+#: masks the gathered read ran under, or from the positions where the
+#: kernel reads, the same numbers), and summary rows written
 COUNTERS = ("eva_window_rows", "eva_summary_rows", "eva_summaries_written")
 
 #: the key under which the counts ride the scanned stack's carry, beside
@@ -95,7 +106,6 @@ def paged_attention(cfg, q, k, v, phi, mu, paging, pool, layer):
     `cfg.dtype`, the pool with the layer's counts added)."""
     b, s, h, d = q.shape
     win, chunk, bs = cfg.eva_window, cfg.eva_chunk, cfg.kv_block_size
-    per = win // chunk                      # summaries a window
     dt = cfg.dtype
     lanes = h * d
     idx = paging["index"]                                       # [b]
@@ -149,6 +159,58 @@ def paged_attention(cfg, q, k, v, phi, mu, paging, pool, layer):
             rows.reshape(*crow.shape, lanes).astype(dt))
 
     # -- attention: the window's rows and the finished windows' summaries
+    read = (_kernel_tick if s == 1 and cfg.paged_attn == "pallas"
+            else _gathered_read)
+    out, n_window, n_summary = read(cfg, q, pool, layer, wtable, stable, pos)
+
+    # -- the counts; a free slot ticks along at length 0: computed, never
+    # counted
+    live = (idx > 0)[:, None]
+    counts = jnp.stack([
+        jnp.where(live, n_window, 0).sum(),
+        jnp.where(live, n_summary, 0).sum(),
+        jnp.where(live & (sblk > 0), 1, 0).sum()]).astype(jnp.float32)
+    pool[COUNTS] = pool[COUNTS] + counts
+    return out.astype(dt), pool
+
+
+def _kernel_tick(cfg, q, pool, layer, wtable, stable, pos):
+    """A tick's read through the paged decode kernel, a call a pool: the
+    window's rows from its first position to the query's own, the summary
+    rows of the finished windows (none in window 0: that slot costs the
+    second call nothing), merged by their log-sum-exp into the one
+    softmax. `q` [b, 1, heads, d], `pos` [b, 1] -> (out [b, 1, heads, d]
+    float32, the window rows and the summary rows each query attended
+    [b, 1])."""
+    from pytorchdistributed_tpu.ops.pallas_attention import (
+        merge_attention_parts,
+        paged_flash_attention,
+    )
+
+    win = cfg.eva_window
+    at = pos[:, 0]
+    w = window_of(at, win)
+    first, seen = win * w, summaries_seen(w, win // cfg.eva_chunk)
+    out = merge_attention_parts([
+        paged_flash_attention(
+            q[:, 0], pool["cached_key"], pool["cached_value"], wtable, at,
+            starts=first, layer=layer, return_lse=True),
+        paged_flash_attention(
+            q[:, 0], pool["cached_summary_key"],
+            pool["cached_summary_value"], stable, seen - 1, layer=layer,
+            return_lse=True)])
+    return out[:, None], (at - first + 1)[:, None], seen[:, None]
+
+
+def _gathered_read(cfg, q, pool, layer, wtable, stable, pos):
+    """The read by XLA's gathers, of a tick or a chunk: `q` [b, s, heads,
+    d] at `pos` [b, s] -> (out [b, s, heads, d] float32, the window rows
+    and the summary rows each query attended [b, s], summed from the
+    masks)."""
+    b, s, h, d = q.shape
+    win, bs, dt = cfg.eva_window, cfg.kv_block_size, cfg.dtype
+    per = win // cfg.eva_chunk               # summaries a window
+    lanes = h * d
     scale = d ** -0.5
     w = window_of(pos, win)                                     # [b, s]
     first = w[:, 0] * (win // bs)           # a stream's window's first block
@@ -220,13 +282,4 @@ def paged_attention(cfg, q, k, v, phi, mu, paging, pool, layer):
     out, n_summary = jax.lax.switch(
         jnp.clip(jnp.max(w), 0, n_win - 1),
         [lambda n=n: attend(n) for n in range(n_win)])
-
-    # -- the counts, from the masks above; a free slot ticks along at
-    # length 0: computed, never counted
-    live = (idx > 0)[:, None]
-    counts = jnp.stack([
-        jnp.where(live, live_w.sum(-1), 0).sum(),
-        jnp.where(live, n_summary, 0).sum(),
-        jnp.where(live & (sblk > 0), 1, 0).sum()]).astype(jnp.float32)
-    pool[COUNTS] = pool[COUNTS] + counts
-    return out.astype(dt), pool
+    return out, live_w.sum(-1), n_summary

@@ -54,6 +54,11 @@ class CacheKind(NamedTuple):
     # window has passed it), True tumbles (the blocks of a window all go
     # when the stream crosses a multiple of `window`, none before)
     tumbling: bool = False
+    # lanes of one row where a row is every kv head's key (or value) side
+    # by side, `kv_heads * head_dim` of them (`kv_pool_leaves`): the rows
+    # the paged decode kernel reads. 0: rows of another make (a latent
+    # that all heads share), read by XLA
+    lanes: int = 0
 
     def pages(self, kv_pages: int) -> int:
         """Width of this kind's block table, where one a position's is
@@ -219,8 +224,9 @@ class TransformerConfig:
     # slot's live blocks fetched by the kernel's own copies; the serving
     # default on TPU where kv_heads*head_dim is whole 128-lane tiles
     # (tolerance-pinned vs gather, not bitwise: online softmax
-    # reassociates the reduction). Multi-token chunks (prefill,
-    # speculative verify) always take the gather path.
+    # reassociates the reduction); an EVA tick calls it once a pool and
+    # merges the two by their log-sum-exp (models/eva.py). Multi-token
+    # chunks (prefill, speculative verify) always take the gather path.
     paged_attn: str = "gather"          # gather | pallas
     # Per-slot sink/window overrides (ISSUE 15): the slot-batch decode
     # models read sink/window from per-slot ``kv_sinks``/``kv_windows``
@@ -488,13 +494,15 @@ class TransformerConfig:
 
     @property
     def cache_kinds(self) -> tuple:
-        """The pools the serving engine keeps, the stream's own first."""
+        """The pools the serving engine keeps, the stream's own first;
+        every one holds per-head key and value rows (`kv_pool_leaves`)."""
+        lanes = self.kv_heads * self.head_dim
         if self.eva_window:
             return (CacheKind("summary", "block_table",
-                              stride=self.eva_chunk),
+                              stride=self.eva_chunk, lanes=lanes),
                     CacheKind("window", "window_table", self.eva_window,
-                              tumbling=True))
-        return (CacheKind(None, "block_table"),)
+                              tumbling=True, lanes=lanes))
+        return (CacheKind(None, "block_table", lanes=lanes),)
 
     @property
     def kv_pages(self) -> int:

@@ -532,11 +532,16 @@ def flash_attention_sharded(q, k, v, *, causal: bool = False,
 # asynchronous copies, the next live tile's copies (the next slot's first
 # tile after a slot's last) in flight while this one is computed. Inside
 # a tile the live blocks are computed one at a time. A block past the
-# slot's length, or retired from a sliding window, starts no copy and
-# costs nothing, and so does a tile that holds only such blocks: the
-# cost of a tick follows its live tokens, where a grid of (slots, table
-# entries) paid a program for every entry, live or not (49,152 a tick of
-# gpt2-medium at 32 slots, ~0.108 us each: PERF.md section 6, PR 33).
+# slot's length, or before the slot's first attendable position (`starts`,
+# a per-slot operand beside the lengths since ISSUE 35: a sliding window,
+# a tumbling one or none), starts no copy and costs nothing, and so does
+# a tile that holds only such blocks, and a slot that attends nothing:
+# the cost of a tick follows its live tokens, where a grid of (slots,
+# table entries) paid a program for every entry, live or not (49,152 a
+# tick of gpt2-medium at 32 slots, ~0.108 us each: PERF.md section 6,
+# PR 33). A model that keeps two pools of such rows under one softmax
+# (models/eva.py) calls the kernel once a pool, asks each call for its
+# heads' log-sum-exp and merges the two (`merge_attention_parts`).
 # Mosaic copies whole 128-lane tiles only, so a compiled call needs
 # `kv_heads*head_dim` to be a multiple of 128 (the serving engine picks
 # the gather path elsewhere), and an int8 pool's scale rows, `kv_heads`
@@ -559,7 +564,10 @@ def _head_sums(x, head_dim: int):
     in static lane slices that hold whole heads and, where the sizes
     allow, whole 128-lane tiles (``head_dim`` 64: two heads a tile), so a
     slice costs nothing and a reduce stays inside a vector register.
-    Half of the kernel's time on a v5e (PERF.md section 6, PR 33)."""
+    Where a slice is one head (``head_dim`` a multiple of 128: EvaByte's
+    heads are a tile each) it is one lane reduce and a broadcast, with
+    no mask and no select. Half of the kernel's time on a v5e at two
+    heads a tile (PERF.md section 6, PR 33)."""
     width = x.shape[-1]
     step = head_dim * 128 // math.gcd(head_dim, 128)   # lcm
     if width % step:
@@ -570,11 +578,15 @@ def _head_sums(x, head_dim: int):
     parts = []
     for lo in range(0, width, step):
         part = x[:, lo:lo + step]
-        sums = None
-        for mask in masks:
-            total = jnp.sum(jnp.where(mask, part, 0.0), axis=-1,
-                            keepdims=True)
-            sums = total if sums is None else jnp.where(mask, total, sums)
+        if len(masks) == 1:
+            sums = jnp.sum(part, axis=-1, keepdims=True)
+        else:
+            sums = None
+            for mask in masks:
+                total = jnp.sum(jnp.where(mask, part, 0.0), axis=-1,
+                                keepdims=True)
+                sums = (total if sums is None
+                        else jnp.where(mask, total, sums))
         parts.append(jnp.broadcast_to(sums, part.shape))
     return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=-1)
 
@@ -595,18 +607,33 @@ def _tile_blocks(block_size: int, row_bytes: int, table_len: int) -> int:
     return max(1, min(rows // block_size, table_len))
 
 
-def _paged_kernel(tables_ref, lengths_ref, layer_ref, q_ref, *rest,
-                  block_size: int, tile_blocks: int, table_len: int,
+def _paged_kernel(tables_ref, lengths_ref, starts_ref, layer_ref, q_ref,
+                  *rest, block_size: int, tile_blocks: int, table_len: int,
                   head_dim: int, scale: float, quantized: bool, sink: int,
-                  window: int):
+                  from_zero: bool, with_lse: bool):
     """Online softmax over one slot's live table blocks; grid (slots,),
     run in order. ``rest`` is the k and v pools in HBM, for an int8 pool
     the slot's fp32 scale rows ``[table positions, kv_heads]`` of each,
-    the output, one VMEM buffer a pool ``[2, tile_blocks, block_size,
+    the output and, where the caller asked for it, each head's
+    log-sum-exp, one VMEM buffer a pool ``[2, tile_blocks, block_size,
     lanes]`` (two tiles: one computed, one in flight), a DMA semaphore a
     buffer half, the (acc, m, l) accumulators and the half the next tile
     lands in (SMEM: it outlives the program, because a slot's last tile
     starts the next slot's first).
+
+    A slot attends the positions ``start <= j <= length`` and, where
+    ``sink`` is set, ``j < sink`` beside them: one rule for a sliding
+    window (``start = length - window + 1``), a tumbling one (``window *
+    (length // window)``) and none (``0``). The blocks between the sinks
+    and ``start`` — whose table entries point at trash once the serving
+    engine hands them back to the allocator — are neither copied nor
+    computed. A slot with no position to attend (``start > length``; with
+    sinks, ``length < 0``) starts no copy and computes nothing: its
+    output is zeros and its log-sum-exp ``-inf``, and the slot before it
+    starts the first copies of the next slot that has any. ``from_zero``
+    (static) says that every ``start`` is 0, as in a pool that keeps
+    every position: the starts are then not read, and a table entry and
+    a block cost what they cost before there was a ``start``.
 
     One block is computed at a time, every kv head of it at once: q/o are
     ``[group, kv_heads*d]`` and a k/v block ``[block_size, kv_heads*d]``,
@@ -620,55 +647,76 @@ def _paged_kernel(tables_ref, lengths_ref, layer_ref, q_ref, *rest,
     partial softmaxes are merged once, when the slot is done, the way two
     blocks' are. All float32 on the VPU, no MXU. An int8 block is
     dequantized in VMEM right before the products (its scales spread over
-    each head's lanes by a 0/1 matrix product at full precision);
-    ``window`` > 0 applies the sink+sliding-window mask, and the blocks
-    it retires — whose table entries point at trash once the serving
-    engine hands them back to the allocator — are neither copied nor
-    computed."""
+    each head's lanes by a 0/1 matrix product at full precision)."""
     from jax.experimental.pallas import tpu as pltpu
 
     k_hbm, v_hbm, *rest = rest
-    ks_ref = vs_ref = None
+    ks_ref = vs_ref = lse_ref = None
     if quantized:
         ks_ref, vs_ref, *rest = rest
-    o_ref, k_buf, v_buf, sems, acc_s, m_s, l_s, half_ref = rest
+    o_ref, *rest = rest
+    if with_lse:
+        lse_ref, *rest = rest
+    k_buf, v_buf, sems, acc_s, m_s, l_s, half_ref = rest
     slot, slots = pl.program_id(0), pl.num_programs(0)
-    tile = tile_blocks * block_size
     top = table_len * block_size - 1     # the table's last position
     streams = acc_s.shape[1]
     layer = layer_ref[0]
     width = q_ref.shape[-1]
 
-    def retired(lo, hi, length):
-        """Positions [lo, hi) all lie past the sinks and before the
-        window of a query at ``length``: fully masked."""
-        return (lo >= sink) & (hi <= length - window + 1)
+    # The walk reckons in blocks: a slot's live blocks run from the one
+    # its `start` lies in (`sb`) to the one its current token sits in
+    # (`rb`), with the blocks that hold sinks beside them; a few scalar
+    # operations a table entry, which is what a program of few live
+    # blocks is made of.
+    sink_blocks = -(-sink // block_size)
 
-    def live_from(t, length):
-        """Tile ``t``, or the tile the window resumes in when ``t``
-        holds retired blocks only (the retired blocks are one run)."""
-        if not window:
+    def span(s):
+        """Slot ``s``'s last and first attended block past the sinks."""
+        return (jnp.minimum(lengths_ref[s], top) // block_size,
+                0 if from_zero else starts_ref[s] // block_size)
+
+    def live_from(t, sb):
+        """Tile ``t``, or the tile block ``sb`` lies in when ``t`` holds
+        only blocks past the sinks and before it (they are one run)."""
+        if from_zero:
             return t
-        return jnp.where(retired(t * tile, (t + 1) * tile, length),
-                         (length - window + 1) // tile, t)
+        return jnp.where((t * tile_blocks >= sink_blocks)
+                         & ((t + 1) * tile_blocks <= sb),
+                         sb // tile_blocks, t)
 
-    def block_live(j, length):
+    def from_start(at, begin, sinks):
+        """``at`` (a block or a position) is attendable from below: at or
+        past ``begin``, or among the ``sinks``."""
+        return (at >= begin) | (at < sinks) if sink else at >= begin
+
+    def block_live(j, rb, sb):
         # the current token sits at position `length`, so positions
-        # <= length are attendable: a dead slot (length 0) still reads
-        # its first block, as the reference path does
-        live = j * block_size <= jnp.minimum(length, top)
-        if window:
-            live &= ~retired(j * block_size, (j + 1) * block_size, length)
-        return live
+        # <= length are attendable: a dead slot of one pool (length 0,
+        # start 0) still reads its first block, as the reference path
+        # does
+        live = j <= rb
+        return live if from_zero else live & from_start(j, sb, sink_blocks)
 
-    def tile_copies(s, length, t, half, op):
+    def attends(s):
+        """Slot ``s`` has a position to attend."""
+        return lengths_ref[s] >= (0 if sink or from_zero else starts_ref[s])
+
+    def next_live(s):
+        """The first slot from ``s`` on that attends anything; ``slots``
+        where there is none."""
+        return lax.while_loop(
+            lambda s: (s < slots) & ~attends(jnp.minimum(s, slots - 1)),
+            lambda s: s + 1, s)
+
+    def tile_copies(s, rb, sb, t, half, op):
         """``op`` ("start" or "wait") the copies of slot ``s``'s tile
         ``t`` into buffer half ``half``: a K and a V block for every
-        live table entry."""
+        live table entry (``rb``, ``sb``: the slot's `span`)."""
         for i in range(tile_blocks):
             j = t * tile_blocks + i
 
-            @pl.when(block_live(j, length))
+            @pl.when(block_live(j, rb, sb))
             def _():
                 blk = tables_ref[s, j]
                 for pool, buf in ((k_hbm, k_buf), (v_hbm, v_buf)):
@@ -676,14 +724,25 @@ def _paged_kernel(tables_ref, lengths_ref, layer_ref, q_ref, *rest,
                         pool.at[layer, blk], buf.at[half, i],
                         sems.at[half]), op)()
 
+    def start_first_tile(s, half):
+        """The first live tile of the first slot from ``s`` on that
+        attends anything, into buffer half ``half``."""
+        s = next_live(s)
+
+        @pl.when(s < slots)
+        def _():
+            ahead_rb, ahead_sb = span(s)
+            tile_copies(s, ahead_rb, ahead_sb, live_from(0, ahead_sb),
+                        half, "start")
+
     length = lengths_ref[slot]
-    reach = jnp.minimum(length, top)     # the last position attended
-    last = reach // tile
+    rb, sb = span(slot)
+    last = rb // tile_blocks
 
     @pl.when(slot == 0)
     def _first():
         half_ref[0] = 0
-        tile_copies(slot, length, live_from(0, length), 0, "start")
+        start_first_tile(slot, 0)
 
     acc_s[...] = jnp.zeros_like(acc_s)
     m_s[...] = jnp.full_like(m_s, _NEG_INF)
@@ -726,9 +785,8 @@ def _paged_kernel(tables_ref, lengths_ref, layer_ref, q_ref, *rest,
             pos = j * block_size + rows.start + lax.broadcasted_iota(
                 jnp.int32, (streams, 1), 0)
             ok = pos <= length
-            if window:
-                ok &= (pos < sink) | (pos > length - window)
-            valid.append(ok)
+            valid.append(ok if from_zero else ok & from_start(
+                pos, starts_ref[slot], sink))
         for g, q in enumerate(queries):
             # a masked logit is -inf under a running max that starts
             # finite, so its probability is an exact 0 with no select
@@ -748,51 +806,78 @@ def _paged_kernel(tables_ref, lengths_ref, layer_ref, q_ref, *rest,
 
     def tile_step(t):
         half = half_ref[0]
-        nxt = live_from(t + 1, length)
+        nxt = live_from(t + 1, sb)
 
         @pl.when(nxt <= last)
         def _next_tile():
-            tile_copies(slot, length, nxt, 1 - half, "start")
+            tile_copies(slot, rb, sb, nxt, 1 - half, "start")
 
-        @pl.when((nxt > last) & (slot + 1 < slots))
+        @pl.when(nxt > last)
         def _next_slot():
-            ahead = lengths_ref[slot + 1]
-            tile_copies(slot + 1, ahead, live_from(0, ahead), 1 - half,
-                        "start")
+            start_first_tile(slot + 1, 1 - half)
 
-        tile_copies(slot, length, t, half, "wait")
+        tile_copies(slot, rb, sb, t, half, "wait")
         first = t * tile_blocks
 
         def block_step(i, carry):
-            if window:
-                @pl.when(block_live(first + i, length))
+            if sink and not from_zero:
+                @pl.when(block_live(first + i, rb, sb))
                 def _():
                     attend_block(half, i, first + i)
             else:
                 attend_block(half, i, first + i)
             return carry
 
-        # blocks of this tile up to the one the current token sits in
-        lax.fori_loop(
-            0, jnp.minimum(tile_blocks, reach // block_size - first + 1),
-            block_step, 0)
+        # blocks of this tile from the one `start` lies in (past the
+        # sinks, a block at a time) up to the one the current token sits
+        # in
+        lax.fori_loop(0 if sink or from_zero
+                      else jnp.maximum(sb - first, 0),
+                      jnp.minimum(tile_blocks, rb - first + 1),
+                      block_step, 0)
         half_ref[0] = 1 - half
         return nxt
 
-    lax.while_loop(lambda t: t <= last, tile_step, live_from(0, length))
+    # a slot that attends nothing walks no tile: nothing was started for
+    # it, so nothing may be waited for
+    lax.while_loop(lambda t: t <= last, tile_step,
+                   jnp.where(attends(slot), live_from(0, sb), last + 1))
     for g in range(len(queries)):
         # the streams' partial softmaxes, merged as two blocks' are
         m = m_s[g]
-        w = jnp.exp(m - jnp.max(m, axis=0, keepdims=True))
+        top_m = jnp.max(m, axis=0, keepdims=True)
+        w = jnp.exp(m - top_m)
+        total = jnp.sum(l_s[g] * w, axis=0, keepdims=True)
         o_ref[pl.ds(g, 1), :] = (
             jnp.sum(acc_s[g] * w, axis=0, keepdims=True)
-            / jnp.maximum(jnp.sum(l_s[g] * w, axis=0, keepdims=True), 1e-30)
-        ).astype(o_ref.dtype)
+            / jnp.maximum(total, 1e-30)).astype(o_ref.dtype)
+        if with_lse:
+            lse_ref[pl.ds(g, 1), :] = jnp.where(
+                total > 0, top_m + jnp.log(jnp.maximum(total, 1e-30)),
+                -jnp.inf)
+
+
+def merge_attention_parts(parts):
+    """One softmax over the rows of several calls: ``parts`` is a list of
+    (normalised output ``[..., heads, d]``, log-sum-exp ``[..., heads]``),
+    each the attention of the same queries over its own rows; returns
+    the attention over all of them, in float32: the outputs weighted by
+    ``exp(lse - max)`` over the sum of the weights. A part that attended
+    nothing (``lse = -inf``) weighs nothing; where no part attended
+    anything the result is zeros."""
+    lse = jnp.stack([l for _, l in parts])
+    top = jnp.max(lse, axis=0)
+    w = jnp.exp(lse - jnp.where(jnp.isfinite(top), top, 0.0))
+    out = sum(o.astype(jnp.float32) * wi[..., None]
+              for (o, _), wi in zip(parts, w))
+    return out / jnp.maximum(jnp.sum(w, axis=0), 1e-30)[..., None]
 
 
 def paged_flash_attention(q, k_pool, v_pool, block_tables, lengths, *,
-                          layer=None, k_scale=None, v_scale=None,
-                          sink_tokens: int = 0, window_tokens: int = 0,
+                          starts=None, layer=None, k_scale=None,
+                          v_scale=None, sink_tokens: int = 0,
+                          window_tokens: int = 0,
+                          return_lse: bool = False,
                           scale: float | None = None,
                           interpret: bool | None = None):
     """One decode tick of paged attention, pool-native — the serving
@@ -813,28 +898,45 @@ def paged_flash_attention(q, k_pool, v_pool, block_tables, lengths, *,
         (entries past a slot's live length — and retired window blocks —
         point at the trash block 0).
       lengths: ``[slots]`` int32 — the query attends positions <= length.
+      starts: ``[slots]`` int32, or None for 0 — the first position the
+        query attends: position j is attendable iff ``start <= j <=
+        length`` (or ``j < sink_tokens``). Blocks wholly before it are
+        neither copied nor computed. A slot with ``start > length`` (with
+        sinks: ``length < 0``) has nothing to attend: it costs no copy
+        and no arithmetic, its output is zeros and its log-sum-exp
+        ``-inf``. So a pool whose rows a query sees ``n`` of is read with
+        ``lengths = n - 1``, and a tumbling window of ``W`` with
+        ``starts = W * (lengths // W)``.
       k_scale / v_scale: ``[num_blocks, block_size, kv_heads]`` fp32
         per-(token, head) dequant scales (layer-stacked like the pool);
         required iff the pool is int8.
       sink_tokens / window_tokens: static sink+sliding-window mask
         (window_tokens 0 = full attention): position j is attendable iff
-        ``j < sink_tokens or j > length - window_tokens``; fully-dead
-        middle blocks are neither copied nor computed — they are the
-        blocks the engine retires back to the allocator mid-stream.
+        ``j < sink_tokens or j > length - window_tokens``, which is
+        ``starts = max(0, lengths - window_tokens + 1)`` beside the
+        sinks, and is handed to the kernel so (``starts`` must then be
+        None); fully-dead middle blocks are neither copied nor computed
+        — they are the blocks the engine retires back to the allocator
+        mid-stream.
+      return_lse: also return each head's log-sum-exp over the rows it
+        attended, ``[slots, heads]`` float32 (``m + log l`` of the
+        softmax's accumulators): what `merge_attention_parts` needs to
+        make one softmax of several calls over several pools. Not
+        written unless asked for.
 
-    Returns ``[slots, heads, head_dim]``. Matches
-    ops.attention.paged_attention to fp32 online-softmax tolerance (the
-    reassociated flash recurrence is not bitwise — the bitwise-parity
-    contract vs generate() holds on the reference gather path; this
-    kernel never materializes the [slots, blocks*block_size, ...]
-    gathered copy). One program a slot walks the slot's live table
-    entries a tile at a time (``_tile_blocks`` entries, chosen from the
-    shapes; the table is padded with the trash block to whole tiles),
-    fetching each live block from the pool in HBM by its own
-    asynchronous copy while the tile before is computed: a block, a tile
-    or a slot with nothing live costs no copy and no arithmetic.
-    Grouped-query native: a block is fetched once for the whole q
-    group."""
+    Returns ``[slots, heads, head_dim]``, or with ``return_lse`` the pair
+    (that, the log-sum-exp). Matches ops.attention.paged_attention to
+    fp32 online-softmax tolerance (the reassociated flash recurrence is
+    not bitwise — the bitwise-parity contract vs generate() holds on the
+    reference gather path; this kernel never materializes the [slots,
+    blocks*block_size, ...] gathered copy). One program a slot walks the
+    slot's live table entries a tile at a time (``_tile_blocks``
+    entries, chosen from the shapes; the table is padded with the trash
+    block to whole tiles), fetching each live block from the pool in HBM
+    by its own asynchronous copy while the tile before is computed: a
+    block, a tile or a slot with nothing live costs no copy and no
+    arithmetic. Grouped-query native: a block is fetched once for the
+    whole q group."""
     slots, h, d = q.shape
     if layer is None:      # one layer's own pool: a stack of one
         layer = 0
@@ -864,6 +966,18 @@ def paged_flash_attention(q, k_pool, v_pool, block_tables, lengths, *,
         raise ValueError(
             f"sink_tokens {sink_tokens} / window_tokens {window_tokens} "
             f"must be non-negative multiples of block_size {bs}")
+    if starts is not None and window_tokens:
+        raise ValueError(
+            "starts and window_tokens both say where a slot's live rows "
+            "begin: pass one")
+    lengths = lengths.astype(jnp.int32)
+    # a pool read from its first row: the kernel is told so statically
+    # and leaves the starts unread
+    from_zero = starts is None and not window_tokens
+    if from_zero:
+        starts = jnp.zeros_like(lengths)
+    elif starts is None:
+        starts = jnp.maximum(lengths - window_tokens + 1, 0)
     group = h // hk
     scale = (d**-0.5) if scale is None else scale
     if interpret is None:
@@ -886,7 +1000,7 @@ def paged_flash_attention(q, k_pool, v_pool, block_tables, lengths, *,
     # lowering takes at any width (the leading dim is squeezed, not
     # blocked at 1)
     q_spec = pl.BlockSpec((None, group, width),
-                          lambda s, tbl, ln, ly: (s, 0, 0))
+                          lambda s, tbl, ln, st, ly: (s, 0, 0))
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     in_specs = [q_spec, hbm, hbm]
     operands = [qf, k_pool, v_pool]
@@ -897,15 +1011,20 @@ def paged_flash_attention(q, k_pool, v_pool, block_tables, lengths, *,
         # rows stay where they are) and ride in a slot at a time
         rows = block_tables.shape[1] * bs
         in_specs += [pl.BlockSpec((None, rows, hk),
-                                  lambda s, tbl, ln, ly: (s, 0, 0))] * 2
+                                  lambda s, tbl, ln, st, ly: (s, 0, 0))] * 2
         operands += [
             paged_gather(x, block_tables, layer).astype(jnp.float32)
             for x in (k_scale, v_scale)]
+    # a head's log-sum-exp rides out as the output does, in every lane of
+    # the head
+    out_shape = [jax.ShapeDtypeStruct(qf.shape, q.dtype)]
+    if return_lse:
+        out_shape.append(jax.ShapeDtypeStruct(qf.shape, jnp.float32))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(slots,),
         in_specs=in_specs,
-        out_specs=q_spec,
+        out_specs=[q_spec] * len(out_shape),
         scratch_shapes=[
             *[pltpu.VMEM((2, tile_blocks, bs, width), k_pool.dtype)] * 2,
             pltpu.SemaphoreType.DMA((2,)),
@@ -916,16 +1035,22 @@ def paged_flash_attention(q, k_pool, v_pool, block_tables, lengths, *,
     kernel = functools.partial(
         _paged_kernel, block_size=bs, tile_blocks=tile_blocks,
         table_len=table_len, head_dim=d, scale=scale, quantized=quantized,
-        sink=int(sink_tokens), window=int(window_tokens))
-    out = pl.pallas_call(
+        sink=int(sink_tokens), from_zero=from_zero, with_lse=return_lse)
+    out, *lse = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(qf.shape, q.dtype),
+        out_shape=out_shape,
         # in order: a slot's last tile starts the next slot's first copies
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(block_tables, lengths.astype(jnp.int32),
+    )(block_tables, lengths, starts.astype(jnp.int32),
       jnp.asarray(layer, jnp.int32).reshape(1), *operands)
-    return out.reshape(slots, group, hk, d).swapaxes(1, 2).reshape(
-        slots, h, d)
+
+    def heads(x):   # [slots, group, hk*d] -> [slots, heads, d]
+        return x.reshape(slots, group, hk, d).swapaxes(1, 2).reshape(
+            slots, h, d)
+
+    if return_lse:
+        return heads(out), heads(lse[0])[..., 0]
+    return heads(out)

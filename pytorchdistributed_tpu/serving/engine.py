@@ -84,7 +84,6 @@ from pytorchdistributed_tpu.inference import (
     sample_slots,
     stop_ids_tuple,
 )
-from pytorchdistributed_tpu.models.transformer import CacheKind
 from pytorchdistributed_tpu.serving.paging import (
     RadixPrefixCache,
     SlotPool,
@@ -139,11 +138,6 @@ def _leaf_name(path) -> str:
 # one table, which is how the int8 pool's scales ride every existing
 # block-transport path without a second code path.
 POOL_LEAF_AXIS = dict.fromkeys(KV_POOL_LEAVES, 3)
-
-# the kinds of cache of a model that declares none (`cfg.cache_kinds`):
-# one pool behind `block_table`, every position kept, no id on its spans
-_ONE_POOL = (CacheKind(None, "block_table"),)
-
 
 def _pool_block_axis(name: str, ndim: int) -> int:
     """Block-axis index for a pool leaf, by its (path or bare) name."""
@@ -949,8 +943,12 @@ class ServingEngine:
         ops/pallas_attention.paged_flash_attention — no [slots,
         attend_len] gather materialization), or None (default) →
         the PTD_PAGED_ATTN env var, else "auto" = pallas on TPU
-        backends where kv_heads*head_dim is whole 128-lane tiles (the
-        kernel's own copies move whole tiles), gather elsewhere.
+        backends where every pool the model keeps (`cfg.cache_kinds`)
+        holds per-head key and value rows of whole 128-lane tiles (the
+        kernel's own copies move whole tiles): GPT-2's one pool, EVA's
+        two, a call each, merged by their log-sum-exp; gather
+        elsewhere, and for a model one of whose pools holds latent
+        rows ("pallas" is refused there, by the pool's name).
         Prefill chunks and the spec tick's draft rollout always use
         the gather read.
     """
@@ -988,7 +986,7 @@ class ServingEngine:
         # retire per layer kind; models/eva.py: a pool of one summary row
         # a chunk that grows with the stream, and a pool of the current
         # window's exact rows that retires a whole window at a time)
-        self._kinds = tuple(getattr(model.cfg, "cache_kinds", _ONE_POOL))
+        self._kinds = tuple(model.cfg.cache_kinds)
         self._pools: list[SlotPool] = []
         self._refuse_two_kinds(
             "the radix prefix cache" if prefix_cache else
@@ -1019,31 +1017,33 @@ class ServingEngine:
                 f"paged_attn must be 'auto', 'gather' or 'pallas', got "
                 f"{paged_attn!r}")
         on_tpu = jax.default_backend() == "tpu"
-        one_kind = len(self._kinds) == 1
-        # the kernel copies pool rows by its own DMAs, which Mosaic
-        # takes in whole 128-lane tiles only
-        lanes = model.cfg.kv_heads * model.cfg.head_dim if one_kind else 0
+        # the kernel reads per-head key and value rows (`CacheKind.lanes`
+        # of them a row), a call a pool, and copies them by its own DMAs,
+        # which Mosaic takes in whole 128-lane tiles only
+        rowless = [k.kind for k in self._kinds if not k.lanes]
+        ragged = [k.lanes for k in self._kinds if k.lanes % 128]
         if paged_attn == "auto":
             # backend-aware default: the fused kernel is the hot path on
-            # real accelerators; CPU (tests, dev) keeps the gather read,
-            # whose decode tick is bitwise generate()'s
-            paged_attn = ("pallas" if on_tpu and one_kind
-                          and lanes % 128 == 0 else "gather")
-        if paged_attn == "pallas" and not one_kind:
+            # real accelerators wherever every pool of the model is such
+            # rows; CPU (tests, dev) keeps the gather read, whose decode
+            # tick is bitwise generate()'s
+            paged_attn = ("pallas" if on_tpu and not rowless and not ragged
+                          else "gather")
+        if paged_attn == "pallas" and rowless:
             raise ValueError(
-                "paged_attn='pallas' is not built for a model with two "
-                "cache kinds: the fused kernel reads the rows of ONE pool "
-                "through one table under one causal mask, and this "
-                "model's queries read two (a learned selection of latent "
-                "rows beside a window's; a window's rows and the "
-                "summaries of finished windows under one softmax), which "
-                "the kernel has neither the masks nor the merge for: "
-                "read by XLA (paged_attn='gather')")
-        if paged_attn == "pallas" and on_tpu and lanes % 128:
+                f"paged_attn='pallas' is not built for this model's "
+                f"{rowless[0]!r} pool: the fused kernel is an online "
+                f"softmax over per-head key and value rows (one pool, or "
+                f"several whose calls are merged by their log-sum-exp), "
+                f"and that pool's rows are latents that all heads share, "
+                f"read under a learned selection, which it has neither "
+                f"the products nor the mask for: read by XLA "
+                f"(paged_attn='gather')")
+        if paged_attn == "pallas" and on_tpu and ragged:
             raise ValueError(
                 f"paged_attn='pallas' on a TPU needs pool rows of whole "
-                f"128-lane tiles, and kv_heads*head_dim is {lanes}: the "
-                f"kernel's own copies move whole tiles "
+                f"128-lane tiles, and kv_heads*head_dim is {ragged[0]}: "
+                f"the kernel's own copies move whole tiles "
                 f"(paged_attn='gather' reads any width)")
         if not self.paged and (kv_dtype != "bf16" or kv_sink_tokens
                                or kv_window_tokens):

@@ -10,7 +10,10 @@ ticks through two pools against one pass a window): logits agree within
 2e-4 of the largest logit. bf16 would not (its own rounding is 4e-3), so
 the tolerance also says that nothing of the mathematics is left out:
 prefill and decoding through both pools, summaries written by the chunk
-and by the tick, windows retired a whole window at a time.
+and by the tick, windows retired a whole window at a time. A tick has two
+reads of the pools (ISSUE 35): XLA's gathers, and the paged decode kernel
+called once a pool with the two calls merged by their log-sum-exp (in
+interpret mode here); both are held to the reference and to each other.
 """
 
 import math
@@ -20,6 +23,7 @@ import numpy as np
 import pytest
 
 from benchmark import manifest, reference
+from pytorchdistributed_tpu.models import eva
 from pytorchdistributed_tpu.serving import ServingEngine
 from tests.test_latent_serving import (
     LogitSpy,
@@ -205,7 +209,7 @@ def test_the_summary_carries_both_pools_by_their_kinds(fam, weights):
     ({"prefix_cache": True}, "radix prefix cache"),
     ({"spec_k": 2}, "speculative tick"),
     ({"kv_dtype": "int8"}, "int8 pool"),
-    ({"paged_attn": "pallas"}, "paged_attn='pallas'.*one causal mask"),
+    ({"paged_attn": "mosaic"}, "'auto', 'gather' or 'pallas'"),
 ])
 def test_what_the_two_pools_cannot_run_under_is_refused_with_its_reason(
         fam, weights, kw, what):
@@ -215,3 +219,125 @@ def test_what_the_two_pools_cannot_run_under_is_refused_with_its_reason(
         make_engine(fam, weights, cfg, **kw)
     # no reason given is the one that is true of latent rows alone
     assert "latents that all heads share" not in str(e.value)
+
+
+# What a tick's query can meet, as (prompt, new tokens) of the streams that
+# share the ticks: the kernel path (`paged_attn="pallas"`, interpreted
+# here) against the gather path and the reference (ISSUE 35)
+TICK_CASES = {
+    # no summary yet: the summary pool's call has nothing to read
+    "window_0": [(5, 6)],
+    # ticks at W - 3 .. W + 4: the last rows of a window, its first row
+    # alone (one position after crossing), and the first summaries seen
+    "crossing_a_window": [(WIN - 3, 9)],
+    # ticks at positions that fill a chunk, whose summary the tick writes
+    "filling_chunks": [(WIN + CHUNK - 2, 2 * CHUNK + 2)],
+    "several_finished_windows": [(3 * WIN + 5, 6)],
+    # three streams in different windows beside a free slot
+    "mixed_beside_a_free_slot": [(5, 6), (WIN - 3, 9),
+                                 (2 * WIN + CHUNK - 2, 6)],
+}
+
+
+@pytest.mark.parametrize("case", TICK_CASES)
+def test_a_tick_through_the_kernel_matches_the_gather_and_the_reference(
+        fam, weights, case, monkeypatch):
+    """Every logit of every tick (`paged_tick_logits` at the engine's own
+    operands) on the kernel path: against the reference's full pass,
+    against the gather path's at the same positions, and the three
+    device counters equal between the two paths."""
+    served = {}
+    for mode in ("gather", "pallas"):
+        with monkeypatch.context() as patch:
+            eng = make_engine(fam, weights, num_slots=4, paged_attn=mode)
+            assert eng.summary()["paged_attn"] == mode
+            spy = LogitSpy(eng, patch)
+            reqs = serve(eng, TICK_CASES[case], TOY["vocab_size"], seed=35)
+            check_against_reference(fam, TOY, weights, spy, reqs)
+            s = eng.summary()
+            served[mode] = ([spy.logits[r.id] for r in reqs],
+                            [r.new_tokens for r in reqs],
+                            {k: s[k] for k in eva.COUNTERS})
+            eng.close()
+    (glog, gtok, gcount), (plog, ptok, pcount) = (served["gather"],
+                                                  served["pallas"])
+    assert gtok == ptok
+    assert gcount == pcount and gcount["eva_window_rows"] > 0
+    layers = TOY["num_hidden_layers"]
+    rows = [rows_attended(n, m) for n, m in TICK_CASES[case]]
+    assert pcount["eva_window_rows"] == layers * sum(r[0] for r in rows)
+    assert pcount["eva_summary_rows"] == layers * sum(r[1] for r in rows)
+    for g, p, (n, m) in zip(glog, plog, TICK_CASES[case]):
+        ticks = range(n, n + m - 1)
+        top = max(np.abs(g[i]).max() for i in ticks)
+        assert max(np.abs(g[i] - p[i]).max() for i in ticks) < 2e-5 * top
+
+
+def wide_toy():
+    """The toy with one head of 128: a pool row is one whole lane tile."""
+    return dict(TOY, hidden_size=128, num_attention_heads=1,
+                num_key_value_heads=1)
+
+
+def test_auto_takes_the_kernel_where_every_pool_is_per_head_rows(
+        fam, monkeypatch):
+    """On a TPU `"auto"` is the kernel where every kind of cache the model
+    declares holds per-head key and value rows of whole 128-lane tiles:
+    GPT-2's one pool and EVA's two alike, by the kinds' `lanes`, never by
+    the model's name; rows of 64 lanes keep the gather, and asking for
+    the kernel over them is refused by their width. On the CPU `"auto"`
+    is the gather whatever the rows."""
+    from pytorchdistributed_tpu.models import GPT2, gpt2_config
+
+    def choice(model, params, **kw):
+        eng = ServingEngine(model, params, num_slots=2, block_size=4,
+                            prefill_chunk=8, prefix_cache=False, **kw)
+        mode = eng.summary()["paged_attn"]
+        eng.close()
+        return mode
+
+    def eva_engine(cfg, **kw):
+        w = jax.jit(lambda s: fam.make_weights(cfg, s))(
+            reference.seed_u32(35))
+        return choice(fam.program_model(cfg, {}),
+                      fam.to_program_tree(w, cfg, {}), **kw)
+
+    gpt2 = GPT2(gpt2_config("test", embed_dim=128, num_heads=2,
+                            num_layers=1, max_seq_len=64))
+    gpt2_params = gpt2.init(jax.random.key(0), np.zeros((1, 4), np.int32))
+    wide = wide_toy()
+    kinds = fam.program_model(wide, {}).cfg.cache_kinds
+    assert [(k.kind, k.lanes) for k in kinds] == [("summary", 128),
+                                                  ("window", 128)]
+    assert [k.lanes for k in gpt2.cfg.cache_kinds] == [128]
+    assert eva_engine(wide) == "gather" == choice(gpt2, gpt2_params)
+    assert eva_engine(wide, paged_attn="pallas") == "pallas"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert choice(gpt2, gpt2_params) == "pallas"      # one kind
+    assert eva_engine(wide) == "pallas"               # EVA's two
+    assert eva_engine(TOY) == "gather"                # rows of 64 lanes
+    with pytest.raises(ValueError, match="whole 128-lane tiles.* is 64"):
+        eva_engine(TOY, paged_attn="pallas")
+
+
+def test_a_latent_kind_keeps_the_gather_and_is_refused_by_its_name(
+        monkeypatch):
+    """A model one of whose pools holds latent rows that all heads share
+    stays on the gather on a TPU, and `paged_attn="pallas"` is refused
+    with a message that names that pool."""
+    from tests import test_latent_serving as latent
+
+    fam = manifest.load_family(manifest.BENCH_DIR, "dots3_note")
+    w = jax.jit(lambda s: fam.make_weights(latent.TOY, s))(
+        reference.seed_u32(35))
+    model = fam.program_model(latent.TOY, {})
+    assert [(k.kind, k.lanes) for k in model.cfg.cache_kinds] == [
+        ("latent", 0), ("window", 0)]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    eng = latent.make_engine(fam, latent.TOY, w)
+    assert eng.summary()["paged_attn"] == "gather"
+    eng.close()
+    with pytest.raises(ValueError, match="paged_attn='pallas' is not "
+                       "built for this model's 'latent' pool.*latents "
+                       "that all heads share"):
+        latent.make_engine(fam, latent.TOY, w, paged_attn="pallas")

@@ -456,6 +456,171 @@ def test_paged_flash_dead_tiles_never_read(kw):
     np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
 
 
+# Where a slot's live rows start is an operand (ISSUE 35): one rule for a
+# sliding window, a tumbling one and none. Cases of (lengths, starts,
+# table entries, sinks); a length of -1 with a start of 0 is how a pool
+# is read of which the query sees no row yet.
+def _tumbling(lengths, win):
+    return tuple(win * (n // win) for n in lengths)
+
+
+START_CASES = {
+    "zero": dict(lengths=(5, 17, 40, 63), starts=(0, 0, 0, 0)),
+    "inside_a_block": dict(lengths=(5, 17, 40, 63), starts=(3, 10, 21, 60)),
+    # a tumbling window of two blocks: the start is a block's first row
+    "tumbling": dict(lengths=(5, 16, 40, 63),
+                     starts=_tumbling((5, 16, 40, 63), 16)),
+    # 16 entries a tile at this fixture's block of 8: the walk starts in
+    # the second and the third tile, and at a tile's second position
+    "past_the_first_tile": dict(lengths=(200, 300, 319, 130),
+                                starts=(136, 256, 264, 129), mb=40),
+    # a start past the length, and a pool none of whose rows is seen
+    "nothing_to_attend": dict(lengths=(5, -1, 40, 9, -1, 33),
+                              starts=(0, 0, 41, 10, 5, 0)),
+    "nothing_at_all": dict(lengths=(-1, -1), starts=(0, 0)),
+    # a free slot ticks along at length 0 over a table of trash
+    "free_slot": dict(lengths=(0, 33, 0), starts=(0, 0, 0), free=(0, 2)),
+    # the sliding window and the sinks, as the static mask hands them over
+    "sliding": dict(lengths=(40, 64, 23, 9),
+                    starts=(25, 49, 8, 0), window=16),
+    "sink_sliding": dict(lengths=(40, 64, 23, 9), starts=(25, 49, 8, 0),
+                         window=16, sink=8),
+}
+
+
+def _masked_oracle(q, k_full, v_full, mask):
+    """(out [slots, heads, d], log-sum-exp [slots, heads]) of one softmax
+    of ``q [slots, heads, d]`` over the rows ``[slots, n, heads, d]``
+    that ``mask [slots, n]`` lets through, in float64; zeros and -inf
+    where it lets none."""
+    q, k, v = (np.asarray(a, np.float64) for a in (q, k_full, v_full))
+    scores = np.einsum("shd,snhd->shn", q, k) / np.sqrt(q.shape[-1])
+    scores = np.where(mask[:, None], scores, -np.inf)
+    top = scores.max(-1, keepdims=True)
+    p = np.exp(scores - np.where(np.isfinite(top), top, 0.0))
+    total = p.sum(-1)
+    out = np.einsum("shn,snhd->shd", p, v) / np.maximum(total, 1e-300)[
+        ..., None]
+    with np.errstate(divide="ignore"):
+        return out, np.where(total > 0, top[..., 0] + np.log(total), -np.inf)
+
+
+def _span_mask(rows, lengths, starts, sink=0):
+    """[slots, rows]: the positions ``start <= j <= length`` and, up to
+    the length, ``j < sink`` beside them."""
+    pos = np.arange(rows)
+    n, st = np.asarray(lengths)[:, None], np.asarray(starts)[:, None]
+    return (pos <= n) & ((pos >= st) | (pos < sink))
+
+
+@pytest.mark.parametrize("case", START_CASES)
+def test_paged_flash_starts(case):
+    """A slot attends ``start <= j <= length``: the output and each
+    head's log-sum-exp against one dense softmax under that mask, with
+    every table entry wholly before the start (past the sinks) or past
+    the length pointed at a block of NaN, which must never be read; a
+    slot with nothing to attend gives zeros and -inf; where the case is
+    a sliding window, the static ``window_tokens`` gives the same."""
+    from pytorchdistributed_tpu.ops.pallas_attention import (
+        paged_flash_attention,
+    )
+
+    spec = START_CASES[case]
+    lengths, starts = spec["lengths"], spec["starts"]
+    sink, mb = spec.get("sink", 0), spec.get("mb", 8)
+    q, pk, pv, tbl, _, kf, vf = _paged_fixture(
+        tuple(max(n, 0) for n in lengths), kvh=2, mb=mb)
+    bs = pk.shape[1]
+    poison = pk.shape[0]
+    pk = jnp.concatenate([pk, jnp.full_like(pk[:1], jnp.nan)])
+    pv = jnp.concatenate([pv, jnp.full_like(pv[:1], jnp.nan)])
+    tbl_np = np.asarray(tbl).copy()
+    for s, (n, st) in enumerate(zip(lengths, starts)):
+        for j in range(mb):
+            if j * bs > n or (j * bs >= sink and (j + 1) * bs <= st):
+                tbl_np[s, j] = poison
+    for s in spec.get("free", ()):
+        # every entry the trash block, whose rows are zeros here
+        tbl_np[s] = 0
+        kf, vf = kf.at[s].set(0.0), vf.at[s].set(0.0)
+    lens = jnp.asarray(lengths, jnp.int32)
+    want, want_lse = _masked_oracle(
+        q[:, 0], kf, vf, _span_mask(kf.shape[1], lengths, starts, sink))
+    got, lse = paged_flash_attention(
+        q[:, 0], pk, pv, jnp.asarray(tbl_np), lens,
+        starts=jnp.asarray(starts, jnp.int32), sink_tokens=sink,
+        return_lse=True)
+    got, lse = np.asarray(got), np.asarray(lse)
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(lse, want_lse, atol=2e-5, rtol=2e-5)
+    empty = ~np.isfinite(want_lse[:, 0])
+    assert empty.sum() == sum(
+        n < 0 or (st > n and not sink) for n, st in zip(lengths, starts))
+    assert (got[empty] == 0).all()
+    if not any(starts):
+        # no `starts` at all: the kernel is told statically that every
+        # slot attends from its first row, and leaves them unread
+        same = paged_flash_attention(
+            q[:, 0], pk, pv, jnp.asarray(tbl_np), lens, return_lse=True)
+        np.testing.assert_array_equal(np.asarray(same[0]), got)
+        np.testing.assert_array_equal(np.asarray(same[1]), lse)
+    if "window" in spec:
+        # the static mask is the same rule, handed over as `starts`
+        same = paged_flash_attention(
+            q[:, 0], pk, pv, jnp.asarray(tbl_np), lens, sink_tokens=sink,
+            window_tokens=spec["window"])
+        np.testing.assert_array_equal(np.asarray(same), got)
+        with pytest.raises(ValueError, match="pass one"):
+            paged_flash_attention(q[:, 0], pk, pv, tbl, lens, starts=lens,
+                                  window_tokens=spec["window"])
+
+
+def test_two_pools_merged_by_their_log_sum_exp_are_one_softmax():
+    """Two calls, each over its own pool through its own table, merged by
+    their log-sum-exp, equal one softmax over the rows of both: what a
+    model with two kinds of row under one softmax needs of the kernel
+    (models/eva.py). One slot sees no row of the second pool, one none
+    of either."""
+    from pytorchdistributed_tpu.ops.pallas_attention import (
+        merge_attention_parts,
+        paged_flash_attention,
+    )
+
+    la, sa = (40, 17, 63, -1), (16, 0, 48, 0)
+    lb, sb = (23, -1, 5, -1), (0, 0, 0, 0)
+    q, pka, pva, tbla, _, kfa, vfa = _paged_fixture((40, 17, 63, 0), kvh=2)
+    _, pkb, pvb, tblb, _, kfb, vfb = _paged_fixture((23, 0, 5, 0), kvh=2,
+                                                    seed=1)
+    parts = [paged_flash_attention(
+        q[:, 0], pk, pv, tbl, jnp.asarray(n, jnp.int32),
+        starts=jnp.asarray(st, jnp.int32), return_lse=True)
+        for pk, pv, tbl, n, st in ((pka, pva, tbla, la, sa),
+                                   (pkb, pvb, tblb, lb, sb))]
+    got = np.asarray(merge_attention_parts(parts))
+    # the second pool's rows after the first's
+    rows = kfa.shape[1]
+    want, _ = _masked_oracle(
+        q[:, 0], jnp.concatenate((kfa, kfb), axis=1),
+        jnp.concatenate((vfa, vfb), axis=1),
+        np.concatenate([_span_mask(rows, la, sa), _span_mask(rows, lb, sb)],
+                       axis=1))
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+    assert (got[3] == 0).all() and np.abs(got[:3]).min() > 0
+
+
+def test_head_sums_at_whole_tile_heads():
+    """A head that is a whole number of 128-lane tiles (EvaByte's 128) is
+    one lane reduce a slice, and sums as the masked form does."""
+    from pytorchdistributed_tpu.ops.pallas_attention import _head_sums
+
+    x = jnp.asarray(np.random.default_rng(0).normal(size=(8, 512)),
+                    jnp.float32)
+    for d in (16, 64, 128, 256):
+        want = jnp.repeat(x.reshape(8, -1, d).sum(-1), d, axis=-1)
+        np.testing.assert_allclose(np.asarray(_head_sums(x, d)),
+                                   np.asarray(want), atol=1e-5, rtol=1e-5)
+
+
 # ---------------------------------------------------------------------------
 # the paged engine: parity, reuse, chunking, preemption, leaks
 

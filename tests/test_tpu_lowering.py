@@ -90,32 +90,47 @@ def test_flash_fwd_bwd_lowers_for_tpu():
 
 
 def paged_args(heads=HEADS, kv_heads=None, int8=False, slots=SLOTS,
-               layers=0):
-    """q, the two pools, tables, lengths, then the scale planes and the
-    layer (None where the case has none)."""
+               layers=0, head_dim=HEAD_DIM, pages=SEQ // BLOCK, blocks=None,
+               merged=False):
+    """q, the two pools, tables, lengths, then the scale planes, the
+    layer and the starts (None where the case has none)."""
     kv_heads = kv_heads or heads
-    pages = SEQ // BLOCK
     stack = (layers,) if layers else ()
-    nb = slots * pages + 1
-    pool = sds(stack + (nb, BLOCK, kv_heads * HEAD_DIM),
+    nb = blocks or slots * pages + 1
+    pool = sds(stack + (nb, BLOCK, kv_heads * head_dim),
                jnp.int8 if int8 else jnp.bfloat16)
     scales = sds(stack + (nb, BLOCK, kv_heads), jnp.float32) if int8 else None
-    return [sds((slots, heads, HEAD_DIM)), pool, pool,
-            sds((slots, pages), jnp.int32), sds((slots,), jnp.int32),
-            scales, scales, sds((), jnp.int32) if layers else None]
+    per_slot = sds((slots,), jnp.int32)
+    return [sds((slots, heads, head_dim)), pool, pool,
+            sds((slots, pages), jnp.int32), per_slot,
+            scales, scales, sds((), jnp.int32) if layers else None,
+            per_slot if merged else None]
 
 
-def paged(q, kp, vp, tables, lengths, ks, vs, layer):
+def paged(q, kp, vp, tables, lengths, ks, vs, layer, starts):
+    # a call whose caller merges it with another's gives `starts` and
+    # asks for the log-sum-exp
     return paged_flash_attention(q, kp, vp, tables, lengths, k_scale=ks,
-                                 v_scale=vs, layer=layer, interpret=False)
+                                 v_scale=vs, layer=layer, starts=starts,
+                                 return_lse=starts is not None,
+                                 interpret=False)
 
 
+# EvaByte's pools in `evabyte-longgen-saturated`: 8 layers x 2,049 blocks
+# of 16 rows of 32 heads x 128 lanes (16 KB a row), 16 slots
+EVA_POOL = dict(heads=32, head_dim=128, slots=16, layers=8, blocks=2049,
+                merged=True)
 PAGED_CASES = {
     "bf16": dict(), "int8": dict(int8=True), "gqa": dict(kv_heads=HEADS // 3),
     # the serve cells' own call: gpt2-medium's layer-stacked pool (24 x
     # 2,049 blocks of 16 rows of 1,024 lanes), 32 slots of 64 table
     # entries, the layer traced
     "cell": dict(heads=16, slots=32, layers=24),
+    # an EVA tick's two calls (ISSUE 35): the window pool through a
+    # table of 32,768 positions from a tumbling start, the summary pool
+    # through one of 2,048 rows, each with its log-sum-exp for the merge
+    "eva_window": dict(EVA_POOL, pages=32768 // BLOCK),
+    "eva_summary": dict(EVA_POOL, pages=2048 // BLOCK),
 }
 
 
@@ -123,7 +138,8 @@ PAGED_CASES = {
 def test_paged_decode_kernel_lowers_for_tpu(case):
     """The pools stay in HBM and the kernel copies whole pool rows,
     ``(block_size, kv_heads*head_dim)`` a block (768 lanes, 256 for the
-    GQA group, 1,024 in the serve cells), by its own DMAs."""
+    GQA group, 1,024 in the GPT-2 serve cells, 4,096 in EvaByte's), by
+    its own DMAs."""
     assert MARKER in lower_for_tpu(paged, *paged_args(**PAGED_CASES[case]))
 
 
@@ -222,6 +238,27 @@ def test_engine_default_tick_lowers_the_paged_kernel(compiled_kernels):
     assert MARKER in engine.lower_tick(platforms=TPU).as_text()
 
 
+def test_eva_tick_lowers_the_kernel_once_a_pool(compiled_kernels):
+    """On a TPU a model whose two pools both hold per-head key and value
+    rows of whole lane tiles (EVA: the window's rows, the chunks'
+    summaries) ticks through the kernel too: the scanned layer holds two
+    calls of it, one a pool, and no gather of a whole window."""
+    from benchmark import manifest, reference
+    from pytorchdistributed_tpu.serving import ServingEngine
+    from tests.test_eva_serving import wide_toy
+
+    fam = manifest.load_family(manifest.BENCH_DIR, "evabyte")
+    toy = wide_toy()
+    w = jax.jit(lambda s: fam.make_weights(toy, s))(reference.seed_u32(35))
+    engine = ServingEngine(fam.program_model(toy, {}),
+                           fam.to_program_tree(w, toy, {}), num_slots=2,
+                           block_size=16, prefill_chunk=16,
+                           prefix_cache=False)
+    assert engine.summary()["paged_attn"] == "pallas"
+    assert engine.lower_tick(platforms=TPU).as_text().count(MARKER) == 2
+    engine.close()
+
+
 def test_engine_keeps_the_kernel_to_rows_of_whole_lane_tiles(
         compiled_kernels):
     """The kernel's own copies move whole 128-lane tiles (Mosaic refuses
@@ -246,9 +283,9 @@ def test_engine_keeps_the_kernel_to_rows_of_whole_lane_tiles(
 POOL_SLOTS, POOL_CHUNK = 32, 128
 
 
-def serve_program_for_v5e(program: str, scan_layers: bool):
-    """The engine's jitted tick or prefill chunk, compiled for one
-    abstract v5e chip at gpt2-medium width with two layers."""
+def serve_program_for_v5e(program: str, model, slots: int, blocks: int):
+    """The engine's jitted tick or prefill chunk of `model` over pools of
+    `blocks` blocks, compiled for one abstract v5e chip."""
     from jax.sharding import SingleDeviceSharding
 
     from pytorchdistributed_tpu.serving.engine import (
@@ -258,12 +295,8 @@ def serve_program_for_v5e(program: str, scan_layers: bool):
     )
 
     one = SingleDeviceSharding(v5e_devices(1)[0])
-    cfg = dataclasses.replace(gpt2_config("medium"), num_layers=2,
-                              scan_layers=scan_layers)
-    pages = cfg.max_seq_len // BLOCK
-    blocks = POOL_SLOTS * pages + 1
     tick_model, chunk_model = paged_slot_models(
-        GPT2(cfg), POOL_SLOTS, BLOCK, blocks, paged_attn="pallas")
+        model, slots, BLOCK, blocks, paged_attn="pallas")
 
     def arg(shape=(), dtype=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
@@ -271,14 +304,16 @@ def serve_program_for_v5e(program: str, scan_layers: bool):
     state = jax.tree.map(
         lambda leaf: arg(leaf.shape, leaf.dtype),
         jax.eval_shape(lambda: tick_model.init(
-            jax.random.key(0), jnp.zeros((POOL_SLOTS, 1), jnp.int32))))
+            jax.random.key(0), jnp.zeros((slots, 1), jnp.int32))))
     key = jax.eval_shape(lambda: jax.random.key_data(jax.random.key(0)))
     f32 = jnp.float32
+    pages = {k.table: k.pages(tick_model.cfg.kv_pages)
+             for k in tick_model.cfg.cache_kinds}
     if program == "tick":
-        per_slot = (POOL_SLOTS,)
+        per_slot = (slots,)
         lowered = paged_decode_tick.lower(
             tick_model, state["params"], state["cache"],
-            {"block_table": arg((POOL_SLOTS, pages))}, arg(per_slot),
+            {t: arg((slots, n)) for t, n in pages.items()}, arg(per_slot),
             arg(per_slot),
             arg(per_slot + key.shape, key.dtype), arg(per_slot),
             arg(per_slot, f32), arg(per_slot), arg(per_slot, f32),
@@ -286,12 +321,11 @@ def serve_program_for_v5e(program: str, scan_layers: bool):
     else:
         lowered = paged_prefill_chunk.lower(
             chunk_model, state["params"], state["cache"],
-            arg((1, POOL_CHUNK)), arg(), {"block_table": arg((pages,))},
-            arg(),
+            arg((1, POOL_CHUNK)), arg(),
+            {t: arg((n,)) for t, n in pages.items()}, arg(),
             arg(key.shape, key.dtype), arg(), arg((), f32), arg(),
             arg((), f32), candidates=64)
-    pool_elems = blocks * BLOCK * cfg.embed_dim
-    return lowered.compile(), pool_elems, cfg.num_layers
+    return lowered.compile()
 
 
 @pytest.mark.parametrize("stack", ["scanned", "unrolled"])
@@ -305,8 +339,19 @@ def test_serve_programs_move_nothing_pool_sized(compiled_kernels, program,
     output aliases both pools. (Before the pool was lane-dense and
     carried through the layer loop, every layer of every tick copied its
     67 MB of pool four times between two layouts.)"""
-    compiled, pool_elems, layers = serve_program_for_v5e(
-        program, stack == "scanned")
+    cfg = dataclasses.replace(gpt2_config("medium"), num_layers=2,
+                              scan_layers=stack == "scanned")
+    blocks = POOL_SLOTS * (cfg.max_seq_len // BLOCK) + 1
+    compiled = serve_program_for_v5e(program, GPT2(cfg), POOL_SLOTS, blocks)
+    pool_elems = blocks * BLOCK * cfg.embed_dim
+    assert not pool_sized_moves(compiled, pool_elems)
+    pools = 2 * cfg.num_layers * pool_elems * 2  # K and V, bf16
+    assert compiled.memory_analysis().alias_size_in_bytes >= pools
+
+
+def pool_sized_moves(compiled, pool_elems: int) -> str:
+    """The lines of a compiled program that copy, slice, transpose or
+    allocate as many elements as one layer's pool (none, it is hoped)."""
     moved = []
     for line in compiled.as_text().splitlines():
         m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]*)\]\S* "
@@ -319,9 +364,34 @@ def test_serve_programs_move_nothing_pool_sized(compiled_kernels, program,
             dims = [int(d) for d in m.group(1).split(",") if d]
             if int(np.prod(dims)) >= pool_elems:
                 moved.append(line.strip()[:160])
-    assert not moved, "\n".join(moved)
-    pools = 2 * layers * pool_elems * 2          # K and V, bf16
-    assert compiled.memory_analysis().alias_size_in_bytes >= pools
+    return "\n".join(moved)
+
+
+def test_eva_tick_reads_both_pools_in_place(compiled_kernels):
+    """EvaByte's tick at the cell's widths (32 heads of 128, 16 slots,
+    2,049 blocks a pool; two layers), compiled for one v5e chip: the
+    scanned layer holds the kernel twice, a call a pool, both pools are
+    written and read in place (the output aliases all four leaves), and
+    the whole-window gather's temporaries are gone."""
+    import json
+
+    from benchmark import manifest
+
+    fam = manifest.load_family(manifest.BENCH_DIR, "evabyte")
+    with open(os.path.join(REPO, "benchmark/configs/evabyte.json")) as f:
+        cfg = dict(json.load(f), num_hidden_layers=2)
+    slots, blocks = 16, 2049
+    model = fam.program_model(cfg, {})
+    model = model.clone(cfg=dataclasses.replace(
+        model.cfg, window_blocks=blocks))
+    compiled = serve_program_for_v5e("tick", model, slots, blocks)
+    assert compiled.as_text().count(MARKER) == 2
+    pool_elems = blocks * BLOCK * 4096
+    assert not pool_sized_moves(compiled, pool_elems)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 4 * 2 * pool_elems * 2   # bf16
+    # the gathered tick keeps 0.5 GB of copies of windows and summaries
+    assert mem.temp_size_in_bytes < 0.25e9
 
 
 # ---------------------------------------------------------------------------
