@@ -60,6 +60,12 @@ _QUICK = (
     # through the paged engine's two pools against the benchmark's plain
     # reference, and the pools' books (ISSUE 34)
     "test_eva_serving.py",
+    # layers of two kinds in one scanned stack (full NoPE layers, RoPE
+    # window layers) over two K/V pools, grouped heads and softmax-routed
+    # ReGLU experts routed before attention, through the paged engine
+    # against the benchmark's plain reference, with its planted faults
+    # (ISSUE 36)
+    "test_smallthinker_serving.py",
     # the engine's weights in the compute type, cast once where a tree is
     # taken: bitwise tokens and logits, no convert in the tick (ISSUE 31)
     "test_serving_weights.py",

@@ -274,12 +274,14 @@ def reset_cache_positions(cache, new_index):
 #: the rows of a model with two cache kinds (models/latent.py: the full
 #: layers' latent rows with the indexer's keys beside them, the sliding
 #: layers' window rows; models/eva.py: a chunk's summary key and value
-#: beside the window's exact ones). Everything else in the collection is counters
-#: and tables.
+#: beside the window's exact ones; models/periodic.py: the window
+#: layers' keys and values beside the full layers'). Everything else in
+#: the collection is counters and tables.
 KV_POOL_LEAVES = ("cached_key", "cached_value", "cached_key_scale",
                   "cached_value_scale", "cached_latent",
                   "cached_index_key", "cached_window",
-                  "cached_summary_key", "cached_summary_value")
+                  "cached_summary_key", "cached_summary_value",
+                  "cached_window_key", "cached_window_value")
 
 
 def kv_cache_bytes(cache) -> int:

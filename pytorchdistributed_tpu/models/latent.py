@@ -49,7 +49,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from pytorchdistributed_tpu.models.moe import DroplessMoE
+from pytorchdistributed_tpu.models.moe import DROPLESS_COUNTERS, DroplessMoE
 from pytorchdistributed_tpu.models.transformer import (
     CacheKind,
     Embedder,
@@ -61,9 +61,7 @@ from pytorchdistributed_tpu.models.transformer import (
 
 #: the device-side scalars of one call, in the order of the "counters"
 #: collection's one vector
-COUNTERS = ("moe_assignments_held", "moe_assignments_total",
-            "moe_experts_hit", "moe_load_max", "moe_load_mean",
-            "moe_dropped", "sparse_selected", "sparse_live")
+COUNTERS = DROPLESS_COUNTERS + ("sparse_selected", "sparse_live")
 
 #: a full layer's indexer pass and sort follow the longest live context
 #: of the call in this many steps of the longest sequence. A step is a
@@ -121,6 +119,10 @@ class LatentConfig:
     shared_experts: int = 1
     norm_topk_prob: bool = True
     routed_scale: float = 1.0
+    # `DroplessMoE`'s scoring and gate, of which this family has one each
+    # (constants of the class, not fields)
+    moe_scoring = "sigmoid"
+    moe_activation = "silu"
     lora_rescale: bool = True   # c_q, c_kv *= sqrt(embed/rank) after norm
     norm_eps: float = 1e-5
     index_norm_eps: float = 1e-6

@@ -40,10 +40,8 @@ class Llama(nn.Module):
     @property
     def counters(self) -> tuple:
         """Names of the "counters" collection's vector (the engine's
-        summary): what an EVA stack counts on the device a tick."""
-        from pytorchdistributed_tpu.models import eva
-
-        return eva.COUNTERS if self.cfg.eva_window else ()
+        summary): what the stack counts on the device a tick."""
+        return self.cfg.counter_names
 
     def _backbone(self, tokens, deterministic):
         x = self.embed(tokens)

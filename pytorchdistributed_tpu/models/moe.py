@@ -263,14 +263,29 @@ def _int8_rounded(x, axis):
     return (quantize(x, scale).astype(jnp.float32) * scale).astype(x.dtype)
 
 
+#: what `DroplessMoE` counts a call, in the order a model puts them into
+#: its "counters" vector
+DROPLESS_COUNTERS = ("moe_assignments_held", "moe_assignments_total",
+                     "moe_experts_hit", "moe_load_max", "moe_load_mean",
+                     "moe_dropped")
+
+
 class DroplessMoE(nn.Module):
     """An expert layer that drops nothing and holds a SHARE of its
     experts (the layer `SwitchMoE` is not: that one races for capacity
-    and drops the overflow). DeepSeek-V3 routing: ``s = sigmoid(W_r x)``
+    and drops the overflow). Under ``moe_scoring = "sigmoid"``
+    DeepSeek-V3's routing: ``s = sigmoid(W_r x)``
     in float32, the ``experts_per_token`` largest of ``s + b`` (``b`` the
     stored selection bias; one group), weights ``s_e / sum(chosen s)``
     times ``routed_scale``, SwiGLU experts, plus ``shared_experts``
-    SwiGLUs every token passes through.
+    SwiGLUs every token passes through. Under ``"softmax"``
+    (SmallThinker's) the largest logits are chosen, with no bias, and
+    weighed by a softmax over the chosen (which is a softmax over all,
+    renormalised over them). ``moe_activation`` is the gate's:
+    ``"silu"``, or ``"relu"`` (ReGLU: ``relu(gate) * up``).
+    ``shared_experts`` may be 0. ``route`` [b, s, d], where given, is
+    what the router reads in place of ``x`` (a router placed before
+    attention reads that sublayer's input; the experts read ``x``).
 
     The router keeps its published width ``router_experts``; this layer
     holds experts ``experts_held = [lo, hi)`` of them and computes their
@@ -284,7 +299,9 @@ class DroplessMoE(nn.Module):
 
     ``cfg`` needs ``embed_dim, moe_dim, router_experts, experts_held,
     experts_per_token, shared_experts, norm_topk_prob, routed_scale,
-    dtype, param_dtype, quant``. Call ``[b, s, d] -> ([b, s, d],
+    moe_scoring, moe_activation, dtype, param_dtype, quant``
+    (`models/latent.py:LatentConfig`, or `TransformerConfig` with
+    ``router_experts > 0``). Call ``[b, s, d] -> ([b, s, d],
     counters)``; ``live [b, s]`` says which tokens the counters count
     (every token is computed): assignments to held experts and in all,
     distinct held experts hit, the busiest held expert's tokens, the mean
@@ -294,10 +311,10 @@ class DroplessMoE(nn.Module):
     group sizes the product is given; 0 unless the dispatch is at fault).
     """
 
-    cfg: "LatentConfig"  # noqa: F821 — models/latent.py's config
+    cfg: "LatentConfig | TransformerConfig"  # noqa: F821
 
     @nn.compact
-    def __call__(self, x, live=None):
+    def __call__(self, x, live=None, route=None):
         from pytorchdistributed_tpu.models.transformer import (
             _cfg_dot_general,
         )
@@ -311,26 +328,37 @@ class DroplessMoE(nn.Module):
         init = nn.initializers.normal(stddev=0.02)
         pd = cfg.param_dtype
 
+        sigmoid = cfg.moe_scoring == "sigmoid"
+        gate_act = nn.silu if cfg.moe_activation == "silu" else nn.relu
         router = self.param("router", init, (d, e_pub), jnp.float32)
-        bias = self.param("router_bias", nn.initializers.zeros_init(),
-                          (e_pub,), jnp.float32)
+        if sigmoid:
+            bias = self.param("router_bias", nn.initializers.zeros_init(),
+                              (e_pub,), jnp.float32)
         e_gate = self.param("e_gate", init, (held, d, f), pd)
         e_up = self.param("e_up", init, (held, d, f), pd)
         e_down = self.param("e_down", init, (held, f, d), pd)
-        fs = f * cfg.shared_experts
-        s_gate = self.param("s_gate", init, (d, fs), pd)
-        s_up = self.param("s_up", init, (d, fs), pd)
-        s_down = self.param("s_down", init, (fs, d), pd)
+        if cfg.shared_experts:
+            fs = f * cfg.shared_experts
+            s_gate = self.param("s_gate", init, (d, fs), pd)
+            s_up = self.param("s_up", init, (d, fs), pd)
+            s_down = self.param("s_down", init, (fs, d), pd)
 
         xt = x.reshape(t, d).astype(cfg.dtype)
+        rt = xt if route is None else route.reshape(t, d).astype(cfg.dtype)
         # -- routing, in float32 as published -------------------------
-        score = jax.nn.sigmoid(jnp.matmul(
-            xt.astype(jnp.float32), router,
-            precision=lax.Precision.HIGHEST))               # [t, e_pub]
-        _, chosen = lax.top_k(score + bias, k)              # [t, k]
-        picked = jnp.take_along_axis(score, chosen, -1)
-        if cfg.norm_topk_prob:
-            picked = picked / picked.sum(-1, keepdims=True)
+        logits = jnp.matmul(rt.astype(jnp.float32), router,
+                            precision=lax.Precision.HIGHEST)  # [t, e_pub]
+        if sigmoid:
+            score = jax.nn.sigmoid(logits)
+            _, chosen = lax.top_k(score + bias, k)          # [t, k]
+            picked = jnp.take_along_axis(score, chosen, -1)
+            if cfg.norm_topk_prob:
+                picked = picked / picked.sum(-1, keepdims=True)
+        else:
+            picked, chosen = lax.top_k(logits, k)
+            # over the chosen alone this is the softmax over all,
+            # renormalised over them
+            picked = jax.nn.softmax(picked, -1)
         weight = picked * cfg.routed_scale
 
         # -- the held experts' part: sort, group, multiply -------------
@@ -352,7 +380,7 @@ class DroplessMoE(nn.Module):
             return lax.ragged_dot(lhs, w, sizes,
                                   preferred_element_type=jnp.float32)
 
-        h = (nn.silu(grouped(rows, e_gate))
+        h = (gate_act(grouped(rows, e_gate))
              * grouped(rows, e_up)).astype(cfg.dtype)
         if quant:
             h = _int8_rounded(h, 1)
@@ -373,9 +401,11 @@ class DroplessMoE(nn.Module):
             return dg(lhs, w.astype(cfg.dtype), dims,
                       preferred_element_type=jnp.float32)
 
-        hs = (nn.silu(dense(xt, s_gate)) * dense(xt, s_up)).astype(
-            cfg.dtype)
-        out = (routed + dense(hs, s_down)).astype(cfg.dtype)
+        if cfg.shared_experts:
+            hs = (gate_act(dense(xt, s_gate)) * dense(xt, s_up)).astype(
+                cfg.dtype)
+            routed = routed + dense(hs, s_down)
+        out = routed.astype(cfg.dtype)
 
         # -- what the tick brings back ---------------------------------
         lv = (jnp.ones((t,), bool) if live is None

@@ -142,6 +142,9 @@ class TransformerConfig:
     #                                     learned pos table when True)
     rope_theta: float = 10000.0
     num_kv_heads: int | None = None     # < num_heads = grouped-query attn
+    # a head's size where it is not embed_dim // num_heads (SmallThinker:
+    # 28 heads of 128 on a width of 2,560)
+    head_size: int | None = None
     use_bias: bool = True               # Llama: no biases anywhere
     # Autoregressive decode mode (inference.generate): attention keeps a
     # [b, max_seq_len, kv_heads, head_dim] K/V cache in the flax "cache"
@@ -251,6 +254,47 @@ class TransformerConfig:
     eva_window: int = 0
     eva_chunk: int = 0
     window_blocks: int = 0
+    # Layers of several kinds in one scanned stack: `period` holds one
+    # ``(rope, window)`` pair a layer of the pattern the stack repeats
+    # (SmallThinker's four: ``(False, 0)``, a layer that attends every
+    # position and has no positional encoding, then three of ``(True,
+    # 4096)``, RoPE over a sliding window that counts the query itself).
+    # The scanned body is then a whole period (`PeriodBlock`,
+    # ``num_layers // len(period)`` of them), `rope` says only that the
+    # embedder has no learned position table, and the two kinds keep
+    # their rows in two pools of different depth (`cache_kinds`,
+    # `pool_layers`): ``cached_key`` / ``cached_value`` at the blocks of
+    # ``block_table`` for the full layers, which grows with the stream,
+    # ``cached_window_key`` / ``cached_window_value`` at the blocks of
+    # ``window_table`` (`window_blocks` of them, sized by the engine) for
+    # the window layers, whose blocks the engine hands back once the
+    # window has passed them. Served through the paged engine only
+    # (models/periodic.py reads the pools). () = every layer alike.
+    period: tuple = ()
+    # models/moe.py:DroplessMoE as every block's feed-forward
+    # (`router_experts` > 0: the router's published width): the layer that
+    # drops nothing, holds experts ``experts_held = (lo, hi)`` of them,
+    # `experts_per_token` chosen a token, expert width `moe_dim`.
+    # `moe_scoring` "sigmoid" is DeepSeek-V3's (scores ``sigmoid(logits)``,
+    # chosen under a stored selection bias), "softmax" chooses the largest
+    # logits and weighs them by a softmax over the chosen;
+    # `moe_activation` is the gate's: "silu" (SwiGLU) or "relu" (ReGLU).
+    # `router_input` "attn" routes from the block's FIRST normed tensor,
+    # the one attention reads (SmallThinker: the router placed before
+    # attention), "ffn" from the tensor the experts read.
+    router_experts: int = 0
+    experts_held: tuple = ()
+    experts_per_token: int = 0
+    moe_dim: int = 0
+    moe_scoring: str = "sigmoid"        # sigmoid | softmax
+    moe_activation: str = "silu"        # silu | relu
+    router_input: str = "ffn"           # ffn | attn
+    # what `DroplessMoE` reads besides and this stack has one value of
+    # (no annotation: constants of the class, not fields): no shared
+    # expert, chosen scores normalised, no further scale
+    shared_experts = 0
+    norm_topk_prob = True
+    routed_scale = 1.0
     # `RMS(x) * (1 + g)`: the gain is stored about a unit offset
     norm_unit_offset: bool = False
     # the residual stream in float32 (the sublayers still compute in
@@ -333,7 +377,7 @@ class TransformerConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.embed_dim // self.num_heads
+        return self.head_size or self.embed_dim // self.num_heads
 
     def __post_init__(self):
         if self.quant not in ("none", "int8_fwd", "int8"):
@@ -429,6 +473,10 @@ class TransformerConfig:
                     f"(retirement is whole-block)")
         if self.eva_window:
             self._check_eva()
+        if self.period:
+            self._check_period()
+        if self.router_experts:
+            self._check_dropless()
         if self.norm_unit_offset and (self.norm != "rmsnorm"
                                       or self.fused_norms):
             raise ValueError("norm_unit_offset is built for the plain "
@@ -487,6 +535,68 @@ class TransformerConfig:
                 raise ValueError("window_blocks must be >= 2 (block 0 of "
                                  "the window pool is its trash block)")
 
+    def _check_period(self) -> None:
+        windows = {w for _, w in self.period if w}
+        if (len(windows) > 1 or min(w for _, w in self.period) != 0
+                or self.num_layers % len(self.period)):
+            raise ValueError(
+                f"period {self.period}: (rope, window) a layer, with a "
+                f"layer that attends every position (the stream's own "
+                f"pool is the one that never retires), one window size "
+                f"for the others, and num_layers {self.num_layers} a "
+                f"multiple of its length")
+        if not self.scan_layers or self.eva_window or not self.rope:
+            raise ValueError("a period of layer kinds is built for the "
+                             "scanned stack without a learned position "
+                             "table (scan_layers=True, rope=True), and "
+                             "not beside EVA")
+        if self.decode and not self.kv_block_size:
+            raise ValueError(
+                "a model with two cache kinds is served through the "
+                "paged engine only (block_size > 0): the dense per-slot "
+                "cache has one layout for every layer")
+        if (self.kv_dtype != "bf16" or self.kv_window_tokens
+                or self.kv_sink_tokens):
+            raise ValueError(
+                "kv_dtype='int8' and kv_window_tokens / kv_sink_tokens "
+                "are not built for a period's two pools: the window is "
+                "its window layers' own and is retired per kind")
+        if self.kv_block_size and windows:
+            (win,) = windows
+            if win % self.kv_block_size:
+                raise ValueError(
+                    f"the period's window {win} must be a multiple of "
+                    f"kv_block_size {self.kv_block_size} (the paged "
+                    f"decode kernel's window is whole blocks)")
+            if self.window_blocks < 2:
+                raise ValueError("window_blocks must be >= 2 (block 0 of "
+                                 "the window pool is its trash block)")
+
+    def _check_dropless(self) -> None:
+        if self.moe_experts:
+            raise ValueError("router_experts (DroplessMoE) and "
+                             "moe_experts (SwitchMoE) are two layers: "
+                             "set one")
+        lo, hi = self.experts_held or (0, 0)
+        if not (0 <= lo < hi <= self.router_experts
+                and 0 < self.experts_per_token <= self.router_experts
+                and self.moe_dim > 0):
+            raise ValueError(
+                f"DroplessMoE needs experts_held (lo, hi) inside the "
+                f"router's {self.router_experts}, experts_per_token and "
+                f"moe_dim; got {self.experts_held}, "
+                f"{self.experts_per_token}, {self.moe_dim}")
+        for name, allowed in (("moe_scoring", ("sigmoid", "softmax")),
+                              ("moe_activation", ("silu", "relu")),
+                              ("router_input", ("ffn", "attn"))):
+            if getattr(self, name) not in allowed:
+                raise ValueError(f"unknown {name} "
+                                 f"{getattr(self, name)!r}; one of "
+                                 f"{allowed}")
+        if self.router_input == "attn" and self.norm_position != "pre":
+            raise ValueError("router_input='attn' reads the pre-norm "
+                             "block's first normed tensor")
+
     @property
     def kv_heads(self) -> int:
         return (self.num_kv_heads if self.num_kv_heads is not None
@@ -502,7 +612,32 @@ class TransformerConfig:
                               stride=self.eva_chunk, lanes=lanes),
                     CacheKind("window", "window_table", self.eva_window,
                               tumbling=True, lanes=lanes))
+        window = max((w for _, w in self.period), default=0)
+        if window:
+            return (CacheKind("full", "block_table", lanes=lanes),
+                    CacheKind("window", "window_table", window,
+                              lanes=lanes))
         return (CacheKind(None, "block_table", lanes=lanes),)
+
+    @property
+    def counter_names(self) -> tuple:
+        """Names of the "counters" collection's one vector: what the
+        stack counts on the device a call (the engine's `summary()` sums
+        the ticks')."""
+        if self.eva_window:
+            from pytorchdistributed_tpu.models import eva
+
+            return eva.COUNTERS
+        names = ()
+        if self.router_experts:
+            from pytorchdistributed_tpu.models.moe import DROPLESS_COUNTERS
+
+            names += DROPLESS_COUNTERS
+        if self.period:
+            from pytorchdistributed_tpu.models import periodic
+
+            names += periodic.COUNTERS
+        return names
 
     @property
     def kv_pages(self) -> int:
@@ -530,10 +665,25 @@ class TransformerConfig:
             return {"cached_key": win, "cached_value": win,
                     "cached_summary_key": kv, "cached_summary_value": kv}
         leaves = {"cached_key": kv, "cached_value": kv}
+        if any(w for _, w in self.period):
+            # the window layers' rows: the same layout, a pool of their own
+            win = ((self.window_blocks,) + kv[0][1:], kv[1])
+            leaves.update(cached_window_key=win, cached_window_value=win)
         if int8:
             scale = (rows + (self.kv_heads,), jnp.float32)
             leaves.update(cached_key_scale=scale, cached_value_scale=scale)
         return leaves
+
+    def pool_layers(self, name: str) -> int:
+        """Layers whose rows the scanned stack's pool leaf `name` holds:
+        every layer's, unless the layers come in kinds (`period`), each
+        with a pool as deep as the kind has layers."""
+        if not self.period:
+            return self.num_layers
+        windowed = sum(1 for _, w in self.period if w)
+        of_kind = (windowed if name.startswith("cached_window")
+                   else len(self.period) - windowed)
+        return self.num_layers // len(self.period) * of_kind
 
     @property
     def ffn_dim(self) -> int:
@@ -668,10 +818,18 @@ class SelfAttention(nn.Module):
     layer's index in it: the layer writes its rows at ``[layer, block,
     offset]`` and the call returns ``(out, pool)``. Left at None, a paged
     layer owns its pool as "cache" variables (the unrolled stack).
+
+    ``rope`` and ``window`` are this layer's own where the layers come in
+    kinds (`TransformerConfig.period`): whether it rotates its queries
+    and keys, and the positions a query sees, itself included (0: every
+    one); ``layer`` is then its index in its kind's pool. None / 0: the
+    config's, as every layer of a uniform stack.
     """
 
     cfg: TransformerConfig
     deterministic: bool = True
+    rope: bool | None = None
+    window: int = 0
 
     @nn.compact
     def __call__(self, x, paging=None, pool=None, layer=None):
@@ -751,7 +909,7 @@ class SelfAttention(nn.Module):
                                       if cfg.decode_slots else (),
                                       jnp.int32))
                 idx = idx_var.value
-        if cfg.rope:
+        if cfg.rope if self.rope is None else self.rope:
             cos, sin = rope_tables(cfg.max_seq_len, cfg.head_dim,
                                    cfg.rope_theta)
             if cfg.decode and cfg.decode_slots:
@@ -787,6 +945,20 @@ class SelfAttention(nn.Module):
                 for name in ("eva_phi", "eva_mu"))
             out, pool = eva.paged_attention(cfg, q, k, v, phi, mu, paging,
                                             pool, layer)
+        elif cfg.period:
+            # layers of two kinds (models/periodic.py): this one's rows
+            # go to its kind's pool and are read from there, all of the
+            # stream's or the window's
+            if not (cfg.decode and cfg.kv_block_size) or pool is None:
+                raise NotImplementedError(
+                    "a period of full and window layers is served "
+                    "through the paged engine (ServingEngine(model, "
+                    "params, block_size=...)); a cacheless forward is "
+                    "the benchmark's plain reference")
+            from pytorchdistributed_tpu.models import periodic
+
+            out, pool = periodic.paged_attention(cfg, q, k, v, paging,
+                                                 pool, layer, self.window)
         elif cfg.decode:
             if cfg.kv_block_size:
                 # Paged KV (ISSUE 7): one pool of fixed-size blocks shared
@@ -1153,6 +1325,9 @@ class TransformerBlock(nn.Module):
     # None = cfg-driven (every block is MoE when moe_experts > 0); the
     # unrolled stack passes the per-layer moe_every interleaving decision.
     use_moe: bool | None = None
+    # this layer's kind in a period (SelfAttention's `rope`, `window`)
+    rope: bool | None = None
+    window: int = 0
 
     def _sow_diagnostics(self, x):
         """In-graph block-boundary health stats (ISSUE 6): sow
@@ -1193,16 +1368,37 @@ class TransformerBlock(nn.Module):
             return jax.ad_checkpoint.checkpoint_name(
                 _layer_norm(cfg, tag)(v).astype(cfg.dtype), "norm_out")
 
-        def ffn(h):
+        def ffn(h, route=None):
             moe = cfg.moe_experts > 0 and (self.use_moe is None
                                            or self.use_moe)
             if moe:
                 from pytorchdistributed_tpu.models.moe import SwitchMoE
 
                 return SwitchMoE(cfg, self.deterministic, name="moe")(h)
+            if cfg.router_experts:
+                from pytorchdistributed_tpu.models.moe import DroplessMoE
+
+                # a free slot ticks along at length 0: computed, never
+                # counted
+                live = None if paging is None else jnp.broadcast_to(
+                    (paging["index"] > 0)[:, None], h.shape[:2])
+                out, counted = DroplessMoE(cfg, name="moe")(h, live, route)
+                count(counted)
+                return out
             return MlpBlock(cfg, self.deterministic, name="mlp")(h)
 
-        attn_module = SelfAttention(cfg, self.deterministic, name="attn")
+        def count(counted):
+            """What a sublayer counted, onto the vector that rides the
+            scanned stack's carry beside the pools (`COUNTS`)."""
+            nonlocal pool
+            if pool is not None and COUNTS in pool:
+                pool = dict(pool)
+                pool[COUNTS] = pool[COUNTS] + jnp.stack([
+                    jnp.asarray(counted.get(n, 0.0), jnp.float32)
+                    for n in cfg.counter_names])
+
+        attn_module = SelfAttention(cfg, self.deterministic, self.rope,
+                                    self.window, name="attn")
 
         def attn(h):
             nonlocal pool
@@ -1215,6 +1411,12 @@ class TransformerBlock(nn.Module):
             # original-BERT residual order: LN AFTER each sublayer's add
             x = norm("ln1", x + attn(x))
             x = norm("ln2", x + ffn(x))
+        elif cfg.router_experts and cfg.router_input == "attn":
+            # the router placed before attention: it reads what attention
+            # reads, the experts the normed stream after it
+            u = norm("ln1", x)
+            x = x + attn(u)
+            x = x + ffn(norm("ln2", x), u)
         else:
             x = x + attn(norm("ln1", x))
             x = x + ffn(norm("ln2", x))
@@ -1222,6 +1424,38 @@ class TransformerBlock(nn.Module):
         x = nn.with_logical_constraint(
             x, (Logical.BATCH, Logical.SEQ, Logical.EMBED))
         return x if layer is None else (x, pool)
+
+
+#: the key under which what the blocks count rides the scanned stack's
+#: carry, beside the pools: one vector, `TransformerConfig.counter_names`
+COUNTS = "counts"
+
+
+class PeriodBlock(nn.Module):
+    """The scanned body where the layers come in kinds
+    (`TransformerConfig.period`): one whole period, a `TransformerBlock`
+    a layer with its own ``rope`` and ``window`` (``layer_<j>``). Layer
+    ``j`` of period ``p`` keeps its rows at index ``p * n + r`` of its
+    kind's pool, ``n`` the kind's layers a period and ``r`` its rank
+    among them."""
+
+    cfg: TransformerConfig
+    deterministic: bool = True
+
+    @nn.compact
+    def __call__(self, x, paging, pool, period):
+        cfg = self.cfg
+        windowed = sum(1 for _, w in cfg.period if w)
+        per_kind = {True: windowed, False: len(cfg.period) - windowed}
+        rank = {True: 0, False: 0}
+        for j, (rope, window) in enumerate(cfg.period):
+            kind = bool(window)
+            x, pool = TransformerBlock(
+                cfg, self.deterministic, rope=bool(rope), window=window,
+                name=f"layer_{j}")(
+                    x, paging, pool, period * per_kind[kind] + rank[kind])
+            rank[kind] += 1
+        return x, pool
 
 
 def check_pipeline_decomposition(cfg: TransformerConfig) -> int:
@@ -1369,28 +1603,38 @@ class TransformerStack(nn.Module):
                 # second, and the whole stack copied back after the loop
                 own = {name: self.variable(
                            "cache", name, jnp.zeros,
-                           (cfg.num_layers,) + shape, dtype)
+                           (cfg.pool_layers(name),) + shape, dtype)
                        for name, (shape, dtype)
                        in cfg.kv_pool_leaves.items()}
                 carried = {name: var.value for name, var in own.items()}
+                counts = None
                 if cfg.eva_window:
                     # what the layers count of the masks they attend
                     # under rides the carry beside the pools
                     from pytorchdistributed_tpu.models import eva
 
-                    carried[eva.COUNTS] = jnp.zeros(
-                        (len(eva.COUNTERS),), jnp.float32)
+                    counts = eva.COUNTS
+                elif cfg.counter_names:
+                    counts = COUNTS
+                if counts:
+                    carried[counts] = jnp.zeros(
+                        (len(cfg.counter_names),), jnp.float32)
+                if cfg.period:
+                    # the body is a whole period of layer kinds
+                    block = PeriodBlock
+                    scan = functools.partial(
+                        scan, length=cfg.num_layers // len(cfg.period))
                 (x, pool), _ = scan(
                     lambda mdl, carry, paging, layer: (
                         mdl(carry[0], paging, carry[1], layer), None),
                     in_axes=(nn.broadcast, 0),
                 )(block(cfg, deterministic, name="block"),
-                  (x, carried), paging, jnp.arange(cfg.num_layers))
-                if cfg.eva_window and self.is_mutable_collection(
-                        "counters"):
+                  (x, carried), paging,
+                  jnp.arange(cfg.num_layers // max(1, len(cfg.period))))
+                if counts and self.is_mutable_collection("counters"):
                     self.variable("counters", "tick", jnp.zeros,
-                                  (len(eva.COUNTERS),)).value = pool[
-                                      eva.COUNTS]
+                                  (len(cfg.counter_names),)).value = pool[
+                                      counts]
                 if not self.is_initializing():
                     for name, var in own.items():
                         var.value = pool[name]

@@ -1916,10 +1916,13 @@ class ServingEngine:
         return paged_prefill_chunk(
             model, weights, cache,
             jnp.asarray(chunk), jnp.int32(pos),
-            # copies (`jnp.array`): the next chunk's `_back_window`
+            # copies, made on the host: the next chunk's `_back_window`
             # edits the window row in place while this chunk may still
             # run, and on the CPU `jnp.asarray` can alias host memory
-            jax.tree.map(jnp.array, pf["table_row"]),
+            # (`jnp.array` of a numpy array does too: it copies jax
+            # arrays only)
+            {name: jnp.asarray(row.copy())
+             for name, row in pf["table_row"].items()},
             jnp.int32(pf["true_len"]),
             jnp.asarray(pf["kd"]),
             jnp.int32(pf["resume"]),

@@ -232,6 +232,56 @@ def moe_params(w, layer, lo, hi):
     return p
 
 
+def test_the_softmax_reglu_shares_add_up_to_the_uncut_layer():
+    """`DroplessMoE` as SmallThinker configures it (softmax over the
+    chosen logits, ReGLU experts, no shared expert, routed from another
+    tensor than the experts read) on a `TransformerConfig`: four shares
+    of 16 experts, each its held experts' part for the tokens routed to
+    them, add up to the uncut layer of the family's plain reference."""
+    from pytorchdistributed_tpu.models.transformer import TransformerConfig
+
+    st = manifest.load_family(manifest.BENCH_DIR, "smallthinker")
+    d, e, k, f = 64, 16, 4, 32
+    toy = {"hidden_size": d, "moe_num_primary_experts": e,
+           "moe_num_active_primary_experts": k, "moe_ffn_hidden_size": f,
+           "moe_primary_router_apply_softmax": True, "norm_topk_prob": True}
+    keys = jax.random.split(jax.random.key(11), 6)
+    lp = {"router": 0.125 * jax.random.normal(keys[0], (d, e)),
+          **{name: (0.02 * jax.random.normal(key, shape)).astype(
+              jnp.bfloat16)
+             for name, key, shape in (("e_gate", keys[1], (e, d, f)),
+                                      ("e_up", keys[2], (e, d, f)),
+                                      ("e_down", keys[3], (e, f, d)))}}
+    x = jax.random.normal(keys[4], (2, 24, d))
+    route = jax.random.normal(keys[5], (2, 24, d))
+    r = jnp.matmul(route.reshape(-1, d), lp["router"],
+                   precision=jax.lax.Precision.HIGHEST)
+    uncut = np.asarray(st._experts_out(toy, "f32", lp, x.reshape(-1, d), r))
+    total, held = 0.0, 0.0
+    for lo in range(0, e, 4):
+        cfg = TransformerConfig(
+            embed_dim=d, num_heads=4, router_experts=e,
+            experts_held=(lo, lo + 4), experts_per_token=k, moe_dim=f,
+            moe_scoring="softmax", moe_activation="relu",
+            router_input="attn", dtype=jnp.float32,
+            param_dtype=jnp.bfloat16)
+        params = {"router": lp["router"],
+                  **{n: lp[n][lo:lo + 4]
+                     for n in ("e_gate", "e_up", "e_down")}}
+        out, counters = DroplessMoE(cfg).apply({"params": params}, x,
+                                               None, route)
+        total = total + np.asarray(out).reshape(uncut.shape)
+        held += float(counters["moe_assignments_held"])
+        assert counters["moe_dropped"] == 0
+    np.testing.assert_allclose(total, uncut,
+                               atol=TOL * np.abs(uncut).max())
+    assert held == 48 * k                    # every assignment once
+    # the other tensor decides the routing: routed from `x`, another layer
+    other, _ = DroplessMoE(cfg).apply({"params": params}, x)
+    assert np.abs(np.asarray(other) - np.asarray(out)).max() > 10 * TOL * \
+        np.abs(uncut).max()
+
+
 def test_the_shares_add_up_to_the_uncut_layer(fam, weights):
     """Each share's part (its held experts for the tokens routed to them,
     plus the shared expert every share computes alike) summed over the

@@ -24,6 +24,7 @@ not a TPU); ``compiled_kernels`` flips that default the way a chip would.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import os
 import re
 import subprocess
@@ -91,9 +92,10 @@ def test_flash_fwd_bwd_lowers_for_tpu():
 
 def paged_args(heads=HEADS, kv_heads=None, int8=False, slots=SLOTS,
                layers=0, head_dim=HEAD_DIM, pages=SEQ // BLOCK, blocks=None,
-               merged=False):
+               merged=False, window=0):
     """q, the two pools, tables, lengths, then the scale planes, the
-    layer and the starts (None where the case has none)."""
+    layer and the starts (None where the case has none), and the static
+    window."""
     kv_heads = kv_heads or heads
     stack = (layers,) if layers else ()
     nb = blocks or slots * pages + 1
@@ -104,22 +106,24 @@ def paged_args(heads=HEADS, kv_heads=None, int8=False, slots=SLOTS,
     return [sds((slots, heads, head_dim)), pool, pool,
             sds((slots, pages), jnp.int32), per_slot,
             scales, scales, sds((), jnp.int32) if layers else None,
-            per_slot if merged else None]
+            per_slot if merged else None, window]
 
 
-def paged(q, kp, vp, tables, lengths, ks, vs, layer, starts):
+def paged(q, kp, vp, tables, lengths, ks, vs, layer, starts, window=0):
     # a call whose caller merges it with another's gives `starts` and
     # asks for the log-sum-exp
     return paged_flash_attention(q, kp, vp, tables, lengths, k_scale=ks,
                                  v_scale=vs, layer=layer, starts=starts,
                                  return_lse=starts is not None,
-                                 interpret=False)
+                                 window_tokens=window, interpret=False)
 
 
 # EvaByte's pools in `evabyte-longgen-saturated`: 8 layers x 2,049 blocks
 # of 16 rows of 32 heads x 128 lanes (16 KB a row), 16 slots
 EVA_POOL = dict(heads=32, head_dim=128, slots=16, layers=8, blocks=2049,
                 merged=True)
+ST_POOL = dict(heads=28, kv_heads=4, head_dim=128, slots=32,
+               pages=16384 // BLOCK)
 PAGED_CASES = {
     "bf16": dict(), "int8": dict(int8=True), "gqa": dict(kv_heads=HEADS // 3),
     # the serve cells' own call: gpt2-medium's layer-stacked pool (24 x
@@ -131,6 +135,13 @@ PAGED_CASES = {
     # through one of 2,048 rows, each with its log-sum-exp for the merge
     "eva_window": dict(EVA_POOL, pages=32768 // BLOCK),
     "eva_summary": dict(EVA_POOL, pages=2048 // BLOCK),
+    # SmallThinker's two calls in `smallthinker-mixedlen-steady` (ISSUE
+    # 36): 28 query heads over 4 key heads of 128 (rows of 512 lanes, a
+    # group of 7 queries a key head), 32 slots of 1,024 table entries; the
+    # full layers' pool from the first row, the window layers' under a
+    # sliding window of 4,096
+    "grouped_full": dict(ST_POOL, layers=2, blocks=32769),
+    "grouped_window": dict(ST_POOL, layers=6, blocks=8546, window=4096),
 }
 
 
@@ -138,9 +149,11 @@ PAGED_CASES = {
 def test_paged_decode_kernel_lowers_for_tpu(case):
     """The pools stay in HBM and the kernel copies whole pool rows,
     ``(block_size, kv_heads*head_dim)`` a block (768 lanes, 256 for the
-    GQA group, 1,024 in the GPT-2 serve cells, 4,096 in EvaByte's), by
-    its own DMAs."""
-    assert MARKER in lower_for_tpu(paged, *paged_args(**PAGED_CASES[case]))
+    GQA group, 1,024 in the GPT-2 serve cells, 4,096 in EvaByte's, 512
+    in SmallThinker's), by its own DMAs."""
+    *args, window = paged_args(**PAGED_CASES[case])
+    assert MARKER in lower_for_tpu(
+        functools.partial(paged, window=window), *args)
 
 
 @pytest.mark.slow
@@ -149,10 +162,12 @@ def test_paged_decode_kernel_compiles_for_v5e(case):
     from jax.sharding import SingleDeviceSharding
 
     one = SingleDeviceSharding(v5e_devices(1)[0])
+    *args, window = paged_args(**PAGED_CASES[case])
     args = [None if a is None
             else jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)
-            for a in paged_args(**PAGED_CASES[case])]
-    assert MARKER in jax.jit(paged).lower(*args).compile().as_text()
+            for a in args]
+    assert MARKER in jax.jit(functools.partial(paged, window=window)).lower(
+        *args).compile().as_text()
 
 
 # ---------------------------------------------------------------------------
@@ -259,6 +274,27 @@ def test_eva_tick_lowers_the_kernel_once_a_pool(compiled_kernels):
     engine.close()
 
 
+def test_period_tick_lowers_the_kernel_once_a_layer(compiled_kernels):
+    """On a TPU a model whose layers come in two kinds over two pools of
+    per-head rows of whole lane tiles (SmallThinker: full layers, window
+    layers, grouped heads) ticks through the kernel: the scanned period
+    holds four calls of it, one a layer, and no gather of a window."""
+    from benchmark import manifest, reference
+    from pytorchdistributed_tpu.serving import ServingEngine
+    from tests.test_smallthinker_serving import TOY
+
+    fam = manifest.load_family(manifest.BENCH_DIR, "smallthinker")
+    toy = dict(TOY, head_dim=64)                    # rows of 128 lanes
+    w = jax.jit(lambda s: fam.make_weights(toy, s))(reference.seed_u32(36))
+    engine = ServingEngine(fam.program_model(toy, {}),
+                           fam.to_program_tree(w, toy, {}), num_slots=2,
+                           block_size=16, prefill_chunk=16,
+                           prefix_cache=False)
+    assert engine.summary()["paged_attn"] == "pallas"
+    assert engine.lower_tick(platforms=TPU).as_text().count(MARKER) == 4
+    engine.close()
+
+
 def test_engine_keeps_the_kernel_to_rows_of_whole_lane_tiles(
         compiled_kernels):
     """The kernel's own copies move whole 128-lane tiles (Mosaic refuses
@@ -283,9 +319,11 @@ def test_engine_keeps_the_kernel_to_rows_of_whole_lane_tiles(
 POOL_SLOTS, POOL_CHUNK = 32, 128
 
 
-def serve_program_for_v5e(program: str, model, slots: int, blocks: int):
-    """The engine's jitted tick or prefill chunk of `model` over pools of
-    `blocks` blocks, compiled for one abstract v5e chip."""
+def serve_program_for_v5e(program: str, model, slots: int, blocks: int,
+                          chunk: int = POOL_CHUNK):
+    """The engine's jitted tick or prefill chunk (of `chunk` tokens) of
+    `model` over pools of `blocks` blocks, compiled for one abstract v5e
+    chip."""
     from jax.sharding import SingleDeviceSharding
 
     from pytorchdistributed_tpu.serving.engine import (
@@ -321,7 +359,7 @@ def serve_program_for_v5e(program: str, model, slots: int, blocks: int):
     else:
         lowered = paged_prefill_chunk.lower(
             chunk_model, state["params"], state["cache"],
-            arg((1, POOL_CHUNK)), arg(),
+            arg((1, chunk)), arg(),
             {t: arg((n,)) for t, n in pages.items()}, arg(),
             arg(key.shape, key.dtype), arg(), arg((), f32), arg(),
             arg((), f32), candidates=64)
@@ -365,6 +403,45 @@ def pool_sized_moves(compiled, pool_elems: int) -> str:
             if int(np.prod(dims)) >= pool_elems:
                 moved.append(line.strip()[:160])
     return "\n".join(moved)
+
+
+def test_period_programs_read_both_pools_in_place(compiled_kernels):
+    """SmallThinker's tick and chunk at the cell's widths (28 query heads
+    over 4 key heads of 128, 64 experts of 768, the vocabulary of
+    151,936, 32 slots, a full pool of 32,769 blocks and a window pool of
+    8,546; one period of four layers), compiled for one v5e chip: the
+    tick holds the kernel once a layer, both pools are written and read
+    in place (the outputs alias all four leaves), nothing as large as a
+    layer's pool is moved, and a chunk's temporaries (the scores of a
+    block of queries over the longest context) stay under half a GB."""
+    import json
+
+    from benchmark import manifest
+
+    fam = manifest.load_family(manifest.BENCH_DIR, "smallthinker")
+    with open(os.path.join(
+            REPO, "benchmark/configs/smallthinker-21ba3b.json")) as f:
+        cfg = json.load(f)
+    cfg = dict(cfg, num_hidden_layers=4, rope_layout=cfg["rope_layout"][:4],
+               sliding_window_layout=cfg["sliding_window_layout"][:4])
+    slots, full_blocks, window_blocks = 32, 32769, 8546
+    model = fam.program_model(cfg, {})
+    model = model.clone(cfg=dataclasses.replace(
+        model.cfg, window_blocks=window_blocks))
+    row = BLOCK * 512
+    pools = 2 * 2 * row * (1 * full_blocks + 3 * window_blocks)  # bf16
+    for program in ("tick", "chunk"):
+        compiled = serve_program_for_v5e(program, model, slots,
+                                         full_blocks, chunk=512)
+        # the experts' grouped products compile to kernels of XLA's own
+        kernels = sum(1 for ln in compiled.as_text().splitlines()
+                      if MARKER in ln and "ragged-dot" not in ln)
+        assert kernels == (4 if program == "tick" else 0)
+        assert not pool_sized_moves(compiled, window_blocks * row)
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes >= pools
+        assert mem.temp_size_in_bytes < 0.5e9, (program,
+                                                mem.temp_size_in_bytes)
 
 
 def test_eva_tick_reads_both_pools_in_place(compiled_kernels):
