@@ -66,6 +66,11 @@ _QUICK = (
     # against the benchmark's plain reference, with its planted faults
     # (ISSUE 36)
     "test_smallthinker_serving.py",
+    # the experts' grouped product: the kernel that reads a bank where it
+    # lies in a stack (interpreted) against `lax.ragged_dot` on the
+    # slice, and the scanned stack handing it its banks whole (ISSUE 37;
+    # ~1 min together)
+    "test_grouped_matmul.py",
     # the engine's weights in the compute type, cast once where a tree is
     # taken: bitwise tokens and logits, no convert in the tick (ISSUE 31)
     "test_serving_weights.py",

@@ -13,7 +13,7 @@ Two layers live here, and they differ in what happens to overflow:
     drops NOTHING: sigmoid scores with a selection bias, top-k of any k,
     SwiGLU experts and shared experts, a SHARE of the experts held
     (`experts_held`), the assignments sorted by expert and multiplied as
-    groups (`lax.ragged_dot`). Its docstring says the rest.
+    groups (`ops/grouped_matmul.py`). Its docstring says the rest.
 
 For `SwitchMoE`, TPU-idiomatic expert parallelism is *not* a per-token
 gather/scatter loop:
@@ -263,11 +263,43 @@ def _int8_rounded(x, axis):
     return (quantize(x, scale).astype(jnp.float32) * scale).astype(x.dtype)
 
 
+#: `DroplessMoE`'s three banks of expert matrices, a leaf each
+BANKS = ("e_gate", "e_up", "e_down")
+
 #: what `DroplessMoE` counts a call, in the order a model puts them into
 #: its "counters" vector
 DROPLESS_COUNTERS = ("moe_assignments_held", "moe_assignments_total",
                      "moe_experts_hit", "moe_load_max", "moe_load_mean",
                      "moe_dropped")
+
+
+def stack_hands_banks(cfg, banks) -> bool:
+    """Whether a scanned stack of ``cfg`` hands `DroplessMoE` its
+    ``banks`` (any tree of them) whole: not under ``quant``, which rounds
+    the weights a bank at a time, and only banks kept in the type the
+    products multiply in (the cast would be a copy of the whole stack)."""
+    from pytorchdistributed_tpu.models.transformer import _cfg_dot_general
+
+    return _cfg_dot_general(cfg) is None and all(
+        leaf.dtype == cfg.dtype for leaf in jax.tree.leaves(banks))
+
+
+def banks_read(cfg, params) -> str | None:
+    """`ServingEngine.summary()`'s ``expert_banks``: how the programs of
+    ``cfg`` over ``params`` read `DroplessMoE`'s banks. ``"in_place"``:
+    the kernel of `ops/grouped_matmul.py` fetches each expert hit from
+    where it lies, in a scanned stack's leaf or in a layer's own.
+    ``"sliced"``: `lax.ragged_dot` on the bank's slice (off a TPU), or a
+    scanned stack whose body slices its step's banks out first
+    (`stack_hands_banks`). None: no such experts."""
+    if not getattr(cfg, "router_experts", 0):
+        return None
+    in_place = jax.default_backend() == "tpu"
+    if in_place and getattr(cfg, "scan_layers", False):
+        in_place = stack_hands_banks(cfg, [
+            leaf for path, leaf in jax.tree_util.tree_leaves_with_path(params)
+            if getattr(path[-1], "key", None) in BANKS])
+    return "in_place" if in_place else "sliced"
 
 
 class DroplessMoE(nn.Module):
@@ -291,9 +323,16 @@ class DroplessMoE(nn.Module):
     holds experts ``experts_held = [lo, hi)`` of them and computes their
     part of the result for the tokens routed to them: the assignments are
     sorted by expert and the held ones go through one grouped matrix
-    product a projection (`lax.ragged_dot`, groups = the held experts'
-    token counts). The buffer is as long as every assignment, so no
-    imbalance can overflow it. What the absent experts would add is left
+    product a projection (`ops/grouped_matmul.py:grouped_product`, groups
+    = the held experts' token counts: on a TPU a kernel that reads each
+    expert hit from the bank where it lies, elsewhere `lax.ragged_dot`).
+    The buffer is as long as every assignment, so no imbalance can
+    overflow it. ``banks``, where given, is ``(leaves, step)``: the
+    three banks (`BANKS`) of a scanned stack's every step as one ``[steps
+    * held, ...]`` leaf each (`TransformerStack._expert_banks`), of which
+    this call's experts are the groups from ``step * held`` on; the
+    module's own parameters, the scan's slice of the same leaves, are
+    then not read. What the absent experts would add is left
     out (expert parallelism without its exchange: on one chip there is
     nobody to exchange with), and nothing stands in for them.
 
@@ -314,10 +353,11 @@ class DroplessMoE(nn.Module):
     cfg: "LatentConfig | TransformerConfig"  # noqa: F821
 
     @nn.compact
-    def __call__(self, x, live=None, route=None):
+    def __call__(self, x, live=None, route=None, banks=None):
         from pytorchdistributed_tpu.models.transformer import (
             _cfg_dot_general,
         )
+        from pytorchdistributed_tpu.ops.grouped_matmul import grouped_product
 
         cfg = self.cfg
         d, f, e_pub = cfg.embed_dim, cfg.moe_dim, cfg.router_experts
@@ -337,6 +377,13 @@ class DroplessMoE(nn.Module):
         e_gate = self.param("e_gate", init, (held, d, f), pd)
         e_up = self.param("e_up", init, (held, d, f), pd)
         e_down = self.param("e_down", init, (held, f, d), pd)
+        first = 0
+        if banks is not None:
+            # a scanned stack's banks, whole: this step's experts lie
+            # from group step * held on, and the scan's slice is unused
+            stack, step = banks
+            e_gate, e_up, e_down = (stack[name] for name in BANKS)
+            first = step * held
         if cfg.shared_experts:
             fs = f * cfg.shared_experts
             s_gate = self.param("s_gate", init, (d, fs), pd)
@@ -377,8 +424,7 @@ class DroplessMoE(nn.Module):
             w = w.astype(cfg.dtype)
             if quant:
                 w = _int8_rounded(w, 1)
-            return lax.ragged_dot(lhs, w, sizes,
-                                  preferred_element_type=jnp.float32)
+            return grouped_product(lhs, w, sizes, first)
 
         h = (gate_act(grouped(rows, e_gate))
              * grouped(rows, e_up)).astype(cfg.dtype)

@@ -1356,10 +1356,12 @@ class TransformerBlock(nn.Module):
                      saturation_fraction(x, axis=-1))
 
     @nn.compact
-    def __call__(self, x, paging=None, pool=None, layer=None):
+    def __call__(self, x, paging=None, pool=None, layer=None, banks=None):
         """``paging``, ``pool``, ``layer``: the paged stack's per-slot
         state, and the scanned stack's KV pool with this block's index in
-        it (SelfAttention); with a pool the call returns ``(x, pool)``."""
+        it (SelfAttention); with a pool the call returns ``(x, pool)``.
+        ``banks``: this block's part of `TransformerStack._expert_banks`
+        with the scan's step, where the stack hands them."""
         cfg = self.cfg
         x = nn.with_logical_constraint(
             x, (Logical.BATCH, Logical.SEQ, Logical.EMBED))
@@ -1382,7 +1384,8 @@ class TransformerBlock(nn.Module):
                 # counted
                 live = None if paging is None else jnp.broadcast_to(
                     (paging["index"] > 0)[:, None], h.shape[:2])
-                out, counted = DroplessMoE(cfg, name="moe")(h, live, route)
+                out, counted = DroplessMoE(cfg, name="moe")(
+                    h, live, route, banks and (banks[0]["moe"], banks[1]))
                 count(counted)
                 return out
             return MlpBlock(cfg, self.deterministic, name="mlp")(h)
@@ -1443,7 +1446,7 @@ class PeriodBlock(nn.Module):
     deterministic: bool = True
 
     @nn.compact
-    def __call__(self, x, paging, pool, period):
+    def __call__(self, x, paging, pool, period, banks=None):
         cfg = self.cfg
         windowed = sum(1 for _, w in cfg.period if w)
         per_kind = {True: windowed, False: len(cfg.period) - windowed}
@@ -1453,7 +1456,8 @@ class PeriodBlock(nn.Module):
             x, pool = TransformerBlock(
                 cfg, self.deterministic, rope=bool(rope), window=window,
                 name=f"layer_{j}")(
-                    x, paging, pool, period * per_kind[kind] + rank[kind])
+                    x, paging, pool, period * per_kind[kind] + rank[kind],
+                    banks and (banks[0][f"layer_{j}"], banks[1]))
             rank[kind] += 1
         return x, pool
 
@@ -1625,11 +1629,12 @@ class TransformerStack(nn.Module):
                     scan = functools.partial(
                         scan, length=cfg.num_layers // len(cfg.period))
                 (x, pool), _ = scan(
-                    lambda mdl, carry, paging, layer: (
-                        mdl(carry[0], paging, carry[1], layer), None),
-                    in_axes=(nn.broadcast, 0),
+                    lambda mdl, carry, paging, banks, step: (
+                        mdl(carry[0], paging, carry[1], step,
+                            banks and (banks, step)), None),
+                    in_axes=(nn.broadcast, nn.broadcast, 0),
                 )(block(cfg, deterministic, name="block"),
-                  (x, carried), paging,
+                  (x, carried), paging, self._expert_banks(),
                   jnp.arange(cfg.num_layers // max(1, len(cfg.period))))
                 if counts and self.is_mutable_collection("counters"):
                     self.variable("counters", "tick", jnp.zeros,
@@ -1651,6 +1656,38 @@ class TransformerStack(nn.Module):
         if paged and not self.is_initializing():
             state["index"].value = paging["index"] + x.shape[1]
         return x
+
+    def _expert_banks(self):
+        """The experts' banks (`models/moe.py:BANKS`) of every step of the
+        scan, as they lie in this stack's parameters: each ``[steps,
+        held, ...]`` leaf whole, as ``[steps * held, ...]`` (a bitcast),
+        under its names below ``block``. Handed to the scanned body as a
+        loop invariant beside ``paging``, `DroplessMoE`'s grouped
+        products read step ``p``'s experts from group ``p * held`` on and
+        the scan's own slice of the leaf is dead: `lax.ragged_dot` on
+        that slice has it copied out of the stack first, a layer's
+        experts before every product (ISSUE 37). None, and the body
+        slices as before, where there are no such experts, while the
+        parameters are made, where the banks are kept in another type
+        than the products multiply in (the cast would be a copy of the
+        whole stack), and under ``quant``, which rounds the weights a
+        bank at a time."""
+        from pytorchdistributed_tpu.models.moe import BANKS, stack_hands_banks
+
+        cfg = self.cfg
+        if not cfg.router_experts or self.is_initializing():
+            return None
+
+        def banks_of(tree):
+            if "moe" in tree:
+                return {"moe": {
+                    name: tree["moe"][name].reshape(
+                        (-1,) + tree["moe"][name].shape[2:])
+                    for name in BANKS}}
+            return {name: banks_of(sub) for name, sub in tree.items()}
+
+        banks = banks_of(self.variables["params"]["block"])
+        return banks if stack_hands_banks(cfg, banks) else None
 
     def _pipelined(self, x, deterministic: bool):
         """Apply-path GPipe: reuse the layer-stacked params the init-path

@@ -3466,6 +3466,11 @@ class ServingEngine:
                 out["kv_window_tokens"] = self.kv_window_tokens
                 out["kv_sink_tokens"] = self.kv_sink_tokens
             out["paged_attn"] = self.paged_attn
+            if getattr(self.cfg, "router_experts", 0):
+                from pytorchdistributed_tpu.models.moe import banks_read
+
+                out["expert_banks"] = banks_read(self._tick_model.cfg,
+                                                 self._weights)
             out["prefill_chunks"] = st["prefill_chunks"]
             out["preemptions"] = st["preemptions"]
             out["preempted_requests"] = st["preempted_requests"]

@@ -274,24 +274,37 @@ def test_eva_tick_lowers_the_kernel_once_a_pool(compiled_kernels):
     engine.close()
 
 
-def test_period_tick_lowers_the_kernel_once_a_layer(compiled_kernels):
+@pytest.mark.parametrize("banks,compute", [("in_place", "bfloat16"),
+                                           ("sliced", "float32")])
+def test_period_tick_lowers_the_kernel_once_a_layer(compiled_kernels, banks,
+                                                    compute):
     """On a TPU a model whose layers come in two kinds over two pools of
     per-head rows of whole lane tiles (SmallThinker: full layers, window
     layers, grouped heads) ticks through the kernel: the scanned period
-    holds four calls of it, one a layer, and no gather of a window."""
+    holds four calls of it, one a layer, and no gather of a window. Its
+    experts' three grouped products a layer are kernels too (ISSUE 37:
+    `ops/grouped_matmul.py`), and `summary()` says what they are handed:
+    the stack's banks whole where the engine holds them in the type it
+    computes in, as a cell's are; the scan's slice of them where not
+    (the toy's bf16 leaves served in float32)."""
     from benchmark import manifest, reference
     from pytorchdistributed_tpu.serving import ServingEngine
     from tests.test_smallthinker_serving import TOY
 
     fam = manifest.load_family(manifest.BENCH_DIR, "smallthinker")
-    toy = dict(TOY, head_dim=64)                    # rows of 128 lanes
+    toy = dict(TOY, head_dim=64, compute_dtype=compute)  # rows of 128 lanes
     w = jax.jit(lambda s: fam.make_weights(toy, s))(reference.seed_u32(36))
     engine = ServingEngine(fam.program_model(toy, {}),
                            fam.to_program_tree(w, toy, {}), num_slots=2,
                            block_size=16, prefill_chunk=16,
                            prefix_cache=False)
     assert engine.summary()["paged_attn"] == "pallas"
-    assert engine.lower_tick(platforms=TPU).as_text().count(MARKER) == 4
+    assert engine.summary()["expert_banks"] == banks
+    text = engine.lower_tick(platforms=TPU).as_text()
+    # the twelve products call two lowered functions, one a shape of
+    # projection (`kernel_product` is jitted): a kernel each
+    assert text.count(MARKER) == 4 + 2 and "ragged_dot" not in text
+    assert text.count("call @kernel_product") == 4 * 3
     engine.close()
 
 
@@ -409,11 +422,17 @@ def test_period_programs_read_both_pools_in_place(compiled_kernels):
     """SmallThinker's tick and chunk at the cell's widths (28 query heads
     over 4 key heads of 128, 64 experts of 768, the vocabulary of
     151,936, 32 slots, a full pool of 32,769 blocks and a window pool of
-    8,546; one period of four layers), compiled for one v5e chip: the
-    tick holds the kernel once a layer, both pools are written and read
-    in place (the outputs alias all four leaves), nothing as large as a
-    layer's pool is moved, and a chunk's temporaries (the scores of a
-    block of queries over the longest context) stay under half a GB."""
+    8,546; two periods of four layers), compiled for one v5e chip: the
+    tick holds the paged kernel once a layer, both pools are written and
+    read in place (the outputs alias all four leaves), nothing as large
+    as a layer's pool is moved, and a chunk's temporaries (the scores of
+    a block of queries over the longest context) stay under half a GB.
+    The experts' banks are read in place too (ISSUE 37): three grouped
+    products a layer through the kernel of `ops/grouped_matmul.py`, none
+    of XLA's own, and no copy, slice or fresh buffer as large as one
+    layer's bank of 64 x 2,560 x 768, where `lax.ragged_dot` on the
+    scan's slice had each of a period's twelve copied out of the stack
+    (a scan of ONE period has one trip and shows no copy)."""
     import json
 
     from benchmark import manifest
@@ -422,22 +441,23 @@ def test_period_programs_read_both_pools_in_place(compiled_kernels):
     with open(os.path.join(
             REPO, "benchmark/configs/smallthinker-21ba3b.json")) as f:
         cfg = json.load(f)
-    cfg = dict(cfg, num_hidden_layers=4, rope_layout=cfg["rope_layout"][:4],
-               sliding_window_layout=cfg["sliding_window_layout"][:4])
+    assert cfg["num_hidden_layers"] == 8
     slots, full_blocks, window_blocks = 32, 32769, 8546
     model = fam.program_model(cfg, {})
     model = model.clone(cfg=dataclasses.replace(
         model.cfg, window_blocks=window_blocks))
     row = BLOCK * 512
-    pools = 2 * 2 * row * (1 * full_blocks + 3 * window_blocks)  # bf16
+    pools = 2 * 2 * 2 * row * (1 * full_blocks + 3 * window_blocks)  # bf16
+    bank = 64 * 2560 * 768
     for program in ("tick", "chunk"):
         compiled = serve_program_for_v5e(program, model, slots,
                                          full_blocks, chunk=512)
-        # the experts' grouped products compile to kernels of XLA's own
-        kernels = sum(1 for ln in compiled.as_text().splitlines()
-                      if MARKER in ln and "ragged-dot" not in ln)
-        assert kernels == (4 if program == "tick" else 0)
-        assert not pool_sized_moves(compiled, window_blocks * row)
+        assert not pool_sized_moves(compiled, min(bank, window_blocks * row))
+        text = compiled.as_text()
+        assert "ragged-dot" not in text
+        kernels = sum(1 for ln in text.splitlines() if MARKER in ln)
+        # one period's: a paged call a layer in the tick, three products
+        assert kernels == (4 if program == "tick" else 0) + 4 * 3
         mem = compiled.memory_analysis()
         assert mem.alias_size_in_bytes >= pools
         assert mem.temp_size_in_bytes < 0.5e9, (program,
