@@ -1511,16 +1511,22 @@ class ServingEngine:
 
     def _decode_step(self) -> int:
         """One plain decode tick over all slots and its host bookkeeping:
-        build the operands and dispatch (`serve/tick_dispatch`), wait for
-        the device (`serve/tick_sync`), hand each slot's token to its
+        build the operands and dispatch (`serve/tick_dispatch`: every
+        put of the tick under `serve/tick_operands`, the call of the
+        jitted program up to its return under `serve/tick_call`), wait
+        for the device (`serve/tick_sync`), hand each slot's token to its
         request (`serve/deliver`). Returns the number of delivered
         tokens."""
-        t0 = time.perf_counter()
+        # the tick's length is `serve/decode_tick` in the ring; the clock
+        # is read again only for a ServingTelemetry's row
+        t0 = time.perf_counter() if self.telemetry is not None else 0.0
         with span("serve/decode_tick"), self._mesh_ctx():
             with span("serve/tick_dispatch"):
-                tick, args = self._tick_program()
-                out = tick(self._tick_model, *args,
-                           candidates=self.candidates)
+                with span("serve/tick_operands"):
+                    tick, args = self._tick_program()
+                with span("serve/tick_call"):
+                    out = tick(self._tick_model, *args,
+                               candidates=self.candidates)
                 self._cache, nxt = out[:2]
             with span("serve/tick_sync"):
                 if self._counter_names:
@@ -1532,7 +1538,7 @@ class ServingEngine:
                     self._stats["device_counters"] += counted
                 else:
                     toks = np.asarray(nxt)  # host sync: streaming delivery
-        dt = time.perf_counter() - t0
+        dt = time.perf_counter() - t0 if self.telemetry is not None else 0.0
         self._counts += 1
         self._progress += 1
         st = self._stats
@@ -1576,48 +1582,38 @@ class ServingEngine:
         st = self._stats
         heads = self._spec_heads > 0
         adaptive = self.adaptive_k
-        t0 = time.perf_counter()
+        t0 = time.perf_counter() if self.telemetry is not None else 0.0
         with span("serve/spec_tick"), self._mesh_ctx():
             with span("serve/tick_dispatch"):
-                # adaptive off keeps the k_eff=None operand list — the exact
-                # pre-ISSUE-16 program, so the serve_spec_tick invariant
-                # pin stays valid
-                tail = ((jnp.asarray(self._k_eff),) if adaptive else ())
-                if heads:
-                    (self._cache, self._draft_cache, out,
-                     nacc) = spec_decode_tick_heads(
-                        self._tick_model, self._draft_tick_model,
-                        self._weights, self._draft_weights, self._cache,
-                        self._draft_cache,
-                        self._device_tables(),
-                        jnp.asarray(self._lengths),
-                        jnp.asarray(self._spec_prev_start),
-                        jnp.asarray(self._spec_prev_tokens),
-                        jnp.asarray(self._spec_prev_idx),
-                        jnp.asarray(self._tokens),
+                with span("serve/tick_operands"):
+                    # the heads draft from last round's emitted buffer
+                    prev = ((jnp.asarray(self._spec_prev_start),
+                             jnp.asarray(self._spec_prev_tokens),
+                             jnp.asarray(self._spec_prev_idx))
+                            if heads else ())
+                    # adaptive off keeps the k_eff=None operand list — the
+                    # exact pre-ISSUE-16 program, so the serve_spec_tick
+                    # invariant pin stays valid
+                    tail = ((jnp.asarray(self._k_eff),) if adaptive else ())
+                    operands = (
+                        self._device_tables(), jnp.asarray(self._lengths),
+                        *prev, jnp.asarray(self._tokens),
                         jnp.asarray(self._key_data),
                         jnp.asarray(self._counts),
                         jnp.asarray(self._temps), jnp.asarray(self._top_ks),
-                        jnp.asarray(self._top_ps), *tail,
-                        spec_k=self.spec_k, candidates=self.candidates)
-                else:
-                    (self._cache, self._draft_cache, out,
-                     nacc) = spec_decode_tick(
-                        self._tick_model, self._draft_tick_model,
-                        self._weights, self._draft_weights, self._cache,
-                        self._draft_cache,
-                        self._device_tables(),
-                        jnp.asarray(self._lengths),
-                        jnp.asarray(self._tokens),
-                        jnp.asarray(self._key_data),
-                        jnp.asarray(self._counts),
-                        jnp.asarray(self._temps), jnp.asarray(self._top_ks),
-                        jnp.asarray(self._top_ps), *tail,
-                        spec_k=self.spec_k, candidates=self.candidates)
+                        jnp.asarray(self._top_ps), *tail)
+                with span("serve/tick_call"):
+                    (self._cache, self._draft_cache, out, nacc) = (
+                        spec_decode_tick_heads if heads
+                        else spec_decode_tick)(
+                            self._tick_model, self._draft_tick_model,
+                            self._weights, self._draft_weights, self._cache,
+                            self._draft_cache, *operands,
+                            spec_k=self.spec_k, candidates=self.candidates)
             with span("serve/tick_sync"):
                 toks = np.asarray(out)   # host sync: streaming delivery
                 ns = np.asarray(nacc)
-        dt = time.perf_counter() - t0
+        dt = time.perf_counter() - t0 if self.telemetry is not None else 0.0
         n_active = len(self._active)
         self._progress += 1
         st["ticks"] += 1
@@ -1903,33 +1899,37 @@ class ServingEngine:
         """One paged_prefill_chunk call for the admission in flight, at
         absolute position ``pos`` of its token stream — shared by the
         target and (spec mode) draft cache fills (same shapes, different
-        static model)."""
+        static model). The chunk's numpy build and its puts are
+        `serve/chunk_operands`, the call of the jitted program up to its
+        return `serve/chunk_call`."""
         req = pf["req"]
-        chunk = np.zeros((1, self.chunk), np.int32)
-        n = min(self.chunk, pf["true_len"] - pos)
-        chunk[0, :n] = pf["tokens"][pos:pos + n]
         for pool in self._pools[1:]:
             with span("serve/grow_slots", **pool.ids):
                 self._back_window(pool, pf["slot"],
                                   pf["table_row"][pool.table], pos,
                                   pos + self.chunk)
-        return paged_prefill_chunk(
-            model, weights, cache,
-            jnp.asarray(chunk), jnp.int32(pos),
-            # copies, made on the host: the next chunk's `_back_window`
-            # edits the window row in place while this chunk may still
-            # run, and on the CPU `jnp.asarray` can alias host memory
-            # (`jnp.array` of a numpy array does too: it copies jax
-            # arrays only)
-            {name: jnp.asarray(row.copy())
-             for name, row in pf["table_row"].items()},
-            jnp.int32(pf["true_len"]),
-            jnp.asarray(pf["kd"]),
-            jnp.int32(pf["resume"]),
-            jnp.float32(req.sampling.temperature),
-            jnp.int32(req.sampling.top_k),
-            jnp.float32(req.sampling.top_p),
-            candidates=self.candidates)
+        with span("serve/chunk_operands"):
+            chunk = np.zeros((1, self.chunk), np.int32)
+            n = min(self.chunk, pf["true_len"] - pos)
+            chunk[0, :n] = pf["tokens"][pos:pos + n]
+            operands = (
+                jnp.asarray(chunk), jnp.int32(pos),
+                # copies, made on the host: the next chunk's
+                # `_back_window` edits the window row in place while this
+                # chunk may still run, and on the CPU `jnp.asarray` can
+                # alias host memory (`jnp.array` of a numpy array does
+                # too: it copies jax arrays only)
+                {name: jnp.asarray(row.copy())
+                 for name, row in pf["table_row"].items()},
+                jnp.int32(pf["true_len"]),
+                jnp.asarray(pf["kd"]),
+                jnp.int32(pf["resume"]),
+                jnp.float32(req.sampling.temperature),
+                jnp.int32(req.sampling.top_k),
+                jnp.float32(req.sampling.top_p))
+        with span("serve/chunk_call"):
+            return paged_prefill_chunk(model, weights, cache, *operands,
+                                       candidates=self.candidates)
 
     def _prefill_chunk_step(self) -> int:
         """Run ONE chunk step of the in-flight admission — a target
@@ -3203,9 +3203,13 @@ class ServingEngine:
         """Run the compiled params-finite probe (one scalar sync) and
         record the verdict in ``health()['sick']``. False = this
         replica's weights carry NaN/Inf — every token it emits is
-        garbage and a router must quarantine it."""
-        with self._mesh_ctx():
-            ok = bool(params_finite(self._weights))
+        garbage and a router must quarantine it. `serve/probe` is the
+        whole check, `serve/probe_sync` under it the wait for the
+        device's answer."""
+        with span("serve/probe"), self._mesh_ctx():
+            finite = params_finite(self._weights)
+            with span("serve/probe_sync"):
+                ok = bool(finite)
         self._sick = not ok
         return ok
 

@@ -940,19 +940,21 @@ class SubprocessReplica:
         alternate instead. ``exclusive=True`` (a QUARANTINED replica,
         which is never stepped, so probes are the only traffic) lifts
         the alternation."""
-        resp = self._try_recv()
-        if resp is not None:
-            self._consume(resp)
-        if getattr(self, "_warming", False):
-            # async-respawn startup in flight: not ready is the honest
-            # verdict (the optimistic True below would let the rejoin
-            # streak run out before the worker can even serve)
-            return False
-        if (self._pending_op is None
-                and (exclusive
-                     or getattr(self, "_last_sent", None) != "probe")):
-            self._send({"op": "probe"})
-        return self._probe_result if self._probe_result is not None else True
+        with span("serve/probe"):
+            resp = self._try_recv()
+            if resp is not None:
+                self._consume(resp)
+            if getattr(self, "_warming", False):
+                # async-respawn startup in flight: not ready is the honest
+                # verdict (the optimistic True below would let the rejoin
+                # streak run out before the worker can even serve)
+                return False
+            if (self._pending_op is None
+                    and (exclusive
+                         or getattr(self, "_last_sent", None) != "probe")):
+                self._send({"op": "probe"})
+            return (self._probe_result if self._probe_result is not None
+                    else True)
 
     def apply_fault(self, kind: str, ms: float = 100.0) -> None:
         """One-shot tick-targeted faults ride PTD_FAULTS into the

@@ -4,6 +4,7 @@ the router, the engine and the Trainer enter with NO telemetry directory,
 and the counters at the same boundaries (queue wait + prefill span ==
 TTFT). All on the CPU sim; nothing here is a timing."""
 
+import collections
 import functools
 import gc
 import json
@@ -242,10 +243,14 @@ def test_engine_without_directory_fills_the_ring(no_files):
         "serve/admit": {"serve/engine_step"},
         "serve/start_prefill": {"serve/admit"},
         "serve/prefill": {"serve/admit"},
+        "serve/chunk_operands": {"serve/prefill"},
+        "serve/chunk_call": {"serve/prefill"},
         "serve/prefill_sync": {"serve/prefill"},
         "serve/grow_slots": {"serve/engine_step"},
         "serve/decode_tick": {"serve/engine_step"},
         "serve/tick_dispatch": {"serve/decode_tick"},
+        "serve/tick_operands": {"serve/tick_dispatch"},
+        "serve/tick_call": {"serve/tick_dispatch"},
         "serve/tick_sync": {"serve/decode_tick"},
         "serve/deliver": {"serve/engine_step"},
     }
@@ -268,6 +273,213 @@ def test_engine_without_directory_fills_the_ring(no_files):
             == len(by_name["serve/tick_dispatch"])
             == len(by_name["serve/tick_sync"]))
     engine.close()
+
+
+def _children(snap):
+    """{span id: [its children's names, in the order they were entered]}
+    over a snapshot."""
+    out: dict = {}
+    for s in sorted(snap, key=lambda s: s.t0_ns):
+        out.setdefault(s.parent, []).append(s.name)
+    return out
+
+
+def _under(snap, top):
+    """The spans of a snapshot under the span `top`, itself included."""
+    by_id = {s.id: s for s in snap}
+
+    def inside(s):
+        while s is not None and s.id != top.id:
+            s = by_id.get(s.parent)
+        return s is not None
+
+    return [s for s in snap if inside(s)]
+
+
+@pytest.mark.parametrize("spec_k", [0, 4], ids=["plain", "spec"])
+def test_tick_dispatch_is_its_operands_and_its_call(spec_k, no_files):
+    """`serve/tick_dispatch` is the tick's puts (`serve/tick_operands`)
+    and the call of the jitted program (`serve/tick_call`), one of each a
+    tick and nothing else, in the plain tick and in the speculative one;
+    what the two leave uncovered is their own entries and exits."""
+    model, params = _setup()
+    engine = ServingEngine(model, params, spec_k=spec_k, **ENGINE_KW)
+    for p in _prompts(20, 5, 9):
+        engine.submit(p, max_new_tokens=16)
+    engine.run_until_idle()
+    snap = spans.snapshot()
+    kids = _children(snap)
+    by_id = {s.id: s for s in snap}
+    dispatches = [s for s in snap if s.name == "serve/tick_dispatch"]
+    assert len(dispatches) == engine.summary()["ticks"] > 3
+    tick = "serve/spec_tick" if spec_k else "serve/decode_tick"
+    uncovered = []
+    for d in dispatches:
+        assert by_id[d.parent].name == tick
+        assert kids[d.id] == ["serve/tick_operands", "serve/tick_call"]
+        inner = [s for s in snap if s.parent == d.id]
+        assert all(d.t0_ns <= s.t0_ns <= s.t1_ns <= d.t1_ns for s in inner)
+        # both are leaves (a collection may fall into the first call,
+        # which compiles)
+        assert all(set(kids.get(s.id, ())) <= {spans.GC_SPAN}
+                   for s in inner)
+        uncovered.append(d.t1_ns - d.t0_ns
+                         - sum(s.t1_ns - s.t0_ns for s in inner))
+    # two entries and two exits at tests/test_telemetry.py's 10 us a
+    # span, with room: the best tick, so a core shared with five other
+    # workers cannot fail it
+    assert 0 <= min(uncovered) < 30_000, uncovered
+    engine.close()
+
+
+def test_a_chunk_is_its_operands_its_call_and_on_the_last_its_sync(
+        no_files):
+    engine = _engine(paged=True)
+    reqs = [engine.submit(p, max_new_tokens=3) for p in _prompts(40, 5)]
+    engine.run_until_idle()
+    snap = spans.snapshot()
+    kids = _children(snap)
+    chunks = [s for s in snap if s.name == "serve/prefill"]
+    # the 40-token prompt is three chunks of 16, the 5-token one is one
+    assert [(s.ids["request"], s.ids["pos"]) for s in chunks] == [
+        (reqs[0].id, 0), (reqs[0].id, 16), (reqs[0].id, 32),
+        (reqs[1].id, 0)]
+    body = ["serve/chunk_operands", "serve/chunk_call"]
+    assert [kids[s.id] for s in chunks] == [
+        body, body, body + ["serve/prefill_sync"],
+        body + ["serve/prefill_sync"]]
+    for s in snap:
+        if s.name in body:
+            assert set(kids.get(s.id, ())) <= {spans.GC_SPAN}, s.name
+    engine.close()
+
+
+def test_a_steps_own_spans_say_what_it_carried(no_files):
+    """What `benchmark/stepread.py` sorts steps by, with no id for it: a
+    step's `serve/prefill` spans are the chunks it ran (`summary()`'s
+    delta), its `serve/prefill_sync` spans the admissions it completed,
+    and its tick's `serve/deliver` carries the streams it handed a token
+    (what `step()` returned)."""
+    engine = _engine(paged=True)
+    prompts = _prompts(5, 40, 9, 7)        # 4 requests, 3 slots
+    engine.submit(prompts[0], max_new_tokens=8)
+    returned, chunk_counts = [engine.step()], [1]
+    # with a stream live, a step spends one chunk of the 40-token prompt
+    for p in prompts[1:]:
+        engine.submit(p, max_new_tokens=5)
+    while engine.queue_depth or engine.prefilling_count \
+            or engine.active_count:
+        before = engine.summary()["prefill_chunks"]
+        returned.append(engine.step())
+        chunk_counts.append(engine.summary()["prefill_chunks"] - before)
+    snap = spans.snapshot()
+    steps = [s for s in snap if s.name == "serve/engine_step"]
+    assert len(steps) == len(returned)
+    carried = []
+    for top, got, chunks in zip(steps, returned, chunk_counts):
+        assert set(top.ids) == {"step"}
+        inside = _under(snap, top)
+        count = collections.Counter(s.name for s in inside)
+        assert count["serve/prefill"] == chunks
+        assert count["serve/prefill_sync"] == got["admitted"]
+        # a plain tick hands every live slot one token
+        (deliver,) = [s for s in inside if s.name == "serve/deliver"]
+        assert deliver.ids == {"tokens": got["decoded"]}
+        carried.append((chunks, got["admitted"]))
+    summary = engine.summary()
+    assert sum(c for c, _ in carried) == summary["prefill_chunks"]
+    assert sum(a for _, a in carried) == summary["prefills"] == 4
+    # a step that ran a chunk, a step that ran none; a chunk that
+    # completed no admission
+    assert {0, 1} <= {min(c, 1) for c, _ in carried}
+    assert any(c and not a for c, a in carried)
+    engine.close()
+
+
+# A step's spans, counted, so that one more shows in a diff. PR 38 added
+# at most six to an engine step: two a tick (`serve/tick_operands`,
+# `serve/tick_call`), two a chunk (`serve/chunk_operands`,
+# `serve/chunk_call`) and, behind a router on a probing step,
+# `serve/probe` > `serve/probe_sync`. A span is ~3 us
+# (tests/test_telemetry.py pins 10).
+TICK = ["serve/engine_step", "serve/admit", "serve/grow_slots",
+        "serve/decode_tick", "serve/tick_dispatch", "serve/tick_operands",
+        "serve/tick_call", "serve/tick_sync", "serve/deliver"]
+CHUNK = ["serve/prefill", "serve/chunk_operands", "serve/chunk_call"]
+ADMISSION = ["serve/start_prefill"] + CHUNK + ["serve/prefill_sync"]
+ROUTER = ["serve/router_step", "serve/router_health",
+          "serve/router_dispatch", "serve/replica_step",
+          "serve/router_reap"]
+PROBE = ["serve/probe", "serve/probe_sync"]
+
+
+def _one_step_each(snap, top_name):
+    """[sorted names of the spans under one step] for every step."""
+    return [sorted(s.name for s in _under(snap, top))
+            for top in snap if top.name == top_name]
+
+
+def test_spans_a_step_of_the_toy_engine_enters(no_files):
+    engine = _engine(paged=True)
+    short, long = _prompts(5, 20)
+    # a step that starts an admission, finishes it in one chunk and ticks
+    engine.submit(short, max_new_tokens=8)
+    engine.step()
+    (first,) = _one_step_each(spans.snapshot(), "serve/engine_step")
+    assert first == sorted(TICK + ADMISSION) and len(first) == 14
+    # behind a live stream a step spends one chunk: the 20-token prompt's
+    # first, then its last, which completes the admission; then ticks
+    spans.ring().clear()
+    engine.submit(long, max_new_tokens=2)
+    engine.run_until_idle()
+    steps = _one_step_each(spans.snapshot(), "serve/engine_step")
+    assert steps[0] == sorted(TICK + ["serve/start_prefill"] + CHUNK)
+    assert steps[1] == sorted(TICK + CHUNK + ["serve/prefill_sync"])
+    assert len(steps[0]) == len(steps[1]) == 13
+    assert steps[2:] and all(s == sorted(TICK) for s in steps[2:])
+    assert len(TICK) == 9
+    engine.close()
+
+
+def test_spans_a_step_behind_a_router_enters(no_files):
+    """`serve/probe` > `serve/probe_sync` lies under `serve/router_health`
+    on the steps that probe, every fourth, and on no other: a reader
+    counts a step's probes by the `serve/probe` spans under it."""
+    model, params = _setup()
+    router = ReplicaRouter(model, params, replicas=1, health_every=4,
+                           engine_kwargs=ENGINE_KW, warmup_lens=(16,))
+    router.warmup()
+    spans.ring().clear()
+    router.submit(_prompts(5)[0], max_new_tokens=12)
+    router.run_until_idle()
+    snap = spans.snapshot()
+    by_id = {s.id: s for s in snap}
+    steps = [s for s in snap if s.name == "serve/router_step"]
+    assert len(steps) >= 8
+    probing = [s for s in steps if s.ids["step"] % 4 == 0]
+    assert len(probing) >= 2
+    for top in steps:
+        names = sorted(s.name for s in _under(snap, top))
+        probed = top in probing
+        assert set(top.ids) == {"step"}
+        assert names.count("serve/probe") == int(probed)
+        if top.ids["step"] == steps[0].ids["step"]:
+            continue     # the step that placed and admitted the request
+        engine_part = TICK if "serve/engine_step" in names else []
+        assert names == sorted(ROUTER + engine_part
+                               + (PROBE if probed else [])), top.ids
+    assert any(len(_under(snap, top)) == 16 for top in probing)
+    for s in snap:
+        if s.name == "serve/probe":
+            health = by_id[s.parent]
+            assert health.name == "serve/router_health"
+            assert by_id[health.parent] in probing
+        elif s.name == "serve/probe_sync":
+            assert by_id[s.parent].name == "serve/probe"
+    assert (sum(s.name == "serve/probe" for s in snap)
+            == sum(s.name == "serve/probe_sync" for s in snap)
+            == len(probing))
+    router.close()
 
 
 @pytest.mark.parametrize("paged", [True, False], ids=["paged", "dense"])
