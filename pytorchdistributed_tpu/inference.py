@@ -76,6 +76,62 @@ def matches_stop(tok, stop_ids: tuple[int, ...]):
     return hit
 
 
+# Width of a group of the candidate search: a lane tile, so that the
+# gather of the chosen groups moves whole rows (v5e: groups of 64 sort
+# half the numbers and read within 0.05 ms a tick of these).
+_GROUP = 128
+
+
+def _order_key(bits):
+    """Signed integers whose order is ``lax.top_k``'s order of the floats
+    they are the bit patterns of (XLA's total order: -NaN < -inf < ... <
+    -0 < +0 < ... < +inf < +NaN). Its own inverse."""
+    width = bits.dtype.itemsize * 8
+    return bits ^ ((bits >> (width - 1)) & jnp.iinfo(bits.dtype).max)
+
+
+def _top_candidates(logits, c: int):
+    """``lax.top_k(logits, c)`` bit for bit -- values descending, of equal
+    values the lower id first -- without a sort of the vocabulary where it
+    is wider than the ``c * _GROUP`` numbers sorted here: each whole
+    contiguous group's maximum, the ``c`` best groups, then ``lax.top_k``
+    over their members gathered in ascending group order, with the
+    ``v % _GROUP`` numbers past the last whole group behind them. Exact,
+    not approximate: a member of the row's top ``c`` whose group were not
+    chosen would have ``c`` groups before it, each holding a number that
+    ranks before it; and positions in the gathered row rise with the id,
+    so ties fall as they do in the whole row. The path follows from the
+    static shape alone; a narrow vocabulary takes ``lax.top_k`` itself."""
+    *lead, v = logits.shape
+    g = _GROUP
+    if v <= c * g:
+        return lax.top_k(logits, c)
+    groups, whole = v // g, v // g * g
+    # the search reads the logits as their producer writes them: without
+    # the barrier XLA folds the cut into groups into the head's matmul
+    # and, to feed it, copies the whole head into another layout every
+    # call (v5e, SmallThinker's [2,560, 151,936]: 2.4 ms a tick)
+    logits = lax.optimization_barrier(logits).reshape(-1, v)
+    x = logits[:, :whole].reshape(-1, groups, g)
+    # a group's maximum in top_k's own order (jnp.max would rank a group
+    # holding a -NaN last and leaves the sign of a zero open)
+    keys = _order_key(lax.bitcast_convert_type(
+        x, jnp.dtype(f"int{x.dtype.itemsize * 8}")))
+    best = lax.bitcast_convert_type(
+        _order_key(jnp.max(keys, axis=-1)), x.dtype)
+    chosen = jnp.sort(lax.top_k(best, c)[1], axis=-1)       # [n, c] ascending
+    members = jax.vmap(lambda row, ids: row[ids])(x, chosen)  # [n, c, g]
+    members = jnp.concatenate(
+        [members.reshape(-1, c * g), logits[:, whole:]], axis=-1)
+    ids = jnp.concatenate(
+        [(chosen[:, :, None] * g + jnp.arange(g)).reshape(-1, c * g),
+         jnp.broadcast_to(jnp.arange(whole, v), (x.shape[0], v - whole))],
+        axis=-1)
+    vals, pos = lax.top_k(members, c)
+    idxs = jnp.take_along_axis(ids, pos, axis=-1)
+    return vals.reshape(*lead, c), idxs.reshape(*lead, c)
+
+
 def _sample(logits, key, *, temperature: float, top_k: int | None,
             top_p: float | None = None, top_p_candidates: int = 256):
     """One sampling step over [b, vocab] fp32 logits (batch-uniform
@@ -86,16 +142,17 @@ def _sample(logits, key, *, temperature: float, top_k: int | None,
     logits = logits / temperature
     if top_p is not None:
         # Nucleus sampling over the top-C candidates (C = top_k or
-        # top_p_candidates): a full-vocab descending sort costs ~100x per
-        # tick on v5e at vocab 50k, and in practice the p-mass lives far
-        # inside the top 256. For flat/high-temperature distributions
-        # where the true nucleus may be wider, raise top_p_candidates
-        # (vocab_size recovers exact nucleus sampling). Drop candidates
+        # top_p_candidates), found exactly by groups (_top_candidates:
+        # a row's sort is of C * _GROUP numbers, not of the vocabulary);
+        # in practice the p-mass lives far inside the top 256. For
+        # flat/high-temperature distributions where the true nucleus may
+        # be wider, raise top_p_candidates (vocab_size recovers exact
+        # nucleus sampling). Drop candidates
         # once the cumulative probability BEFORE them reaches p (the
         # first token always survives); the retained mass is
         # renormalized over the candidate set.
         c = min(top_k or top_p_candidates, logits.shape[-1])
-        vals, idxs = lax.top_k(logits, c)  # descending
+        vals, idxs = _top_candidates(logits, c)  # descending
         probs = jax.nn.softmax(vals, axis=-1)
         cum = jnp.cumsum(probs, axis=-1) - probs
         vals = jnp.where(cum >= top_p, -jnp.inf, vals)
@@ -103,17 +160,20 @@ def _sample(logits, key, *, temperature: float, top_k: int | None,
         return jnp.take_along_axis(
             idxs, choice[:, None], axis=-1)[:, 0].astype(jnp.int32)
     if top_k is not None:
-        # lax.top_k, not a full-vocab sort: measured ~100x per-tick win on
-        # v5e at vocab 50k
-        kth = lax.top_k(logits, top_k)[0][:, -1:]
+        # the k-th largest logit, exact, without a sort of the vocabulary
+        kth = _top_candidates(logits, top_k)[0][:, -1:]
         logits = jnp.where(logits < kth, -jnp.inf, logits)
     return jax.random.categorical(key, logits, axis=-1).astype(jnp.int32)
 
 
 def _slot_candidates(logits, temperature, top_k, top_p, candidates: int):
     """The shared per-row candidate filter behind ``sample_slots`` and
-    ``slot_filtered_probs``: top-``candidates`` logits per row, rank-masked
-    by the dynamic per-row top_k, temperature-scaled, nucleus-masked
+    ``slot_filtered_probs``: top-``candidates`` logits per row (exactly
+    ``lax.top_k``'s, values descending and of equal values the lower id
+    first; found by groups where the vocabulary is wide, so that a row's
+    sort is of ``candidates * _GROUP`` numbers: ``_top_candidates``),
+    rank-masked by the dynamic per-row top_k, temperature-scaled,
+    nucleus-masked
     (drop candidates once the cumulative probability BEFORE them reaches
     p — the first candidate always survives, same rule as _sample).
     Returns ``(vals, idxs)``: [n, c] filtered/scaled logits (-inf at
@@ -122,7 +182,7 @@ def _slot_candidates(logits, temperature, top_k, top_p, candidates: int):
     apart — losslessness of the rejection kernel depends on q/p being
     EXACTLY the distributions the sampler draws from."""
     c = min(candidates, logits.shape[-1])
-    vals, idxs = lax.top_k(logits, c)            # [n, c] descending
+    vals, idxs = _top_candidates(logits, c)      # [n, c] descending
     k = jnp.where(top_k > 0, jnp.minimum(top_k, c), c)
     vals = jnp.where(jnp.arange(c)[None, :] < k[:, None], vals, -jnp.inf)
     vals = vals / jnp.maximum(temperature, 1e-6)[:, None]
@@ -143,10 +203,12 @@ def sample_slots(logits, keys, temperature, top_k, top_p, *,
       top_k:       [n] i32; <= 0 disables (row keeps all candidates).
       top_p:       [n] f32; >= 1 disables.
       candidates:  static candidate-set width C — per-row top_k is a rank
-        mask over the shared lax.top_k(C) prefix (a dynamic per-row k
-        cannot be a static top_k argument), so effective top_k caps at C.
+        mask over the shared top-C prefix (a dynamic per-row k cannot
+        be a static top_k argument), so effective top_k caps at C. The
+        prefix is ``lax.top_k(logits, C)`` bit for bit, found without
+        sorting the vocabulary (``_top_candidates``).
 
-    Greedy rows take idxs[:, 0] == argmax (lax.top_k is index-stable), so
+    Greedy rows take idxs[:, 0] == argmax (the search is index-stable), so
     a temperature-0 row is bitwise `jnp.argmax` — the parity property the
     serving tests pin against generate()."""
     vals, idxs = _slot_candidates(logits, temperature, top_k, top_p,

@@ -7,12 +7,14 @@ cache indexing, RoPE offsets, learned-position offsets, GQA cache layout,
 and mask bugs all at once)."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from pytorchdistributed_tpu import inference
 from pytorchdistributed_tpu.inference import (
     TRACE_COUNTS,
     generate,
@@ -310,3 +312,223 @@ def test_generate_exactly_fills_max_seq_len():
     assert out.shape == (1, 16)
     with pytest.raises(ValueError, match="max_seq_len"):
         generate(model, params, prompt, max_new_tokens=9)
+
+
+# -- the candidate search (ISSUE 39) ----------------------------------------
+#
+# `_top_candidates(logits, c)` must be `lax.top_k(logits, c)` bit for bit:
+# the same values, and of equal values the lower id first. The rows below
+# are made to break a search by groups; the widths lie on both sides of
+# the shape rule (`c * _GROUP` numbers and under: `lax.top_k` itself).
+
+_C, _G = 64, inference._GROUP
+_NEG_NAN = np.array([0xFFC00000], np.uint32).view(np.float32)[0]
+
+
+_ROWS = {}
+
+
+def _kind(fn):
+    """Enter a row maker `_row_<kind>(rng, v)` under its kind."""
+    _ROWS[fn.__name__[len("_row_"):]] = fn
+    return fn
+
+
+def _plant(row, positions, value):
+    positions = np.asarray(positions)
+    row[positions[positions < row.size]] = value
+
+
+@_kind
+def _row_random(rng, v):
+    return rng.standard_normal(v)
+
+
+@_kind
+def _row_rounded(rng, v):
+    """Quarters: equal numbers by the hundred, in groups whose maxima
+    differ, so the order of the gathered groups decides the ids."""
+    return np.round(rng.standard_normal(v) * 4) / 4
+
+
+@_kind
+def _row_all_equal(rng, v):
+    return np.full(v, 1.5)
+
+
+@_kind
+def _row_equal_maxima_in_more_than_c_groups(rng, v):
+    """Every group's maximum is the same number, at another place in each:
+    of equal maxima the lower group must win."""
+    row = rng.standard_normal(v)
+    groups = np.arange(-(-v // _G))
+    _plant(row, groups * _G + (groups * 37) % _G, 9.0)
+    return row
+
+
+@_kind
+def _row_ties_straddle_group_edges(rng, v):
+    """The last number of a group and the first of the next are equal, at
+    c + 16 edges: the top c are the lowest ids among them."""
+    row = rng.standard_normal(v)
+    edges = np.arange(1, _C + 17) * _G
+    _plant(row, np.concatenate([edges - 1, edges]), 9.0)
+    return row
+
+
+@_kind
+def _row_tie_across_chosen_and_unchosen(rng, v):
+    """c + 8 groups hold the maximum once, the first c as their last
+    number and the others as their first: positions c*g - 1 (chosen) and
+    c*g (not chosen) tie."""
+    row = rng.standard_normal(v)
+    groups = np.arange(_C + 8)
+    _plant(row, groups * _G + np.where(groups < _C, _G - 1, 0), 9.0)
+    return row
+
+
+@_kind
+def _row_neg_inf(rng, v):
+    return np.full(v, -np.inf)
+
+
+@_kind
+def _row_neg_inf_but_ten(rng, v):
+    """Ten finite numbers, one of them the row's last (past the last
+    whole group where the width has such numbers): 54 candidates are
+    -inf at the lowest ids."""
+    row = np.full(v, -np.inf)
+    row[rng.choice(v - 1, 9, replace=False)] = rng.standard_normal(9)
+    row[-1] = 0.25
+    return row
+
+
+@_kind
+def _row_pos_inf(rng, v):
+    row = rng.standard_normal(v)
+    row[[0, v // 2, v - 1]] = np.inf
+    return row
+
+
+@_kind
+def _row_more_than_c_pos_inf(rng, v):
+    row = rng.standard_normal(v)
+    row[rng.choice(v, _C + 30, replace=False)] = np.inf
+    return row
+
+
+@_kind
+def _row_nan(rng, v):
+    row = rng.standard_normal(v)
+    row[(2 * v) // 3] = np.nan
+    return row
+
+
+@_kind
+def _row_neg_nan_beside_the_maximum(rng, v):
+    """A NaN with its sign bit set ranks last for `lax.top_k`; the row's
+    largest number shares its group and must still be found."""
+    row = rng.standard_normal(v).astype(np.float32)
+    at = (v // 2) // _G * _G
+    row[at], row[at + 1] = _NEG_NAN, 50.0
+    return row
+
+
+@_kind
+def _row_neg_nan_but_ten(rng, v):
+    """Fewer than c numbers rank over -inf: the last candidates are the
+    row's own -NaN at the lowest ids (a search that padded the row's end
+    with -inf would return the padding's)."""
+    row = np.full(v, _NEG_NAN)
+    row[rng.choice(v - 1, 9, replace=False)] = rng.standard_normal(9)
+    row[-1] = 0.25
+    return row
+
+
+@_kind
+def _row_signed_zeros(rng, v):
+    """+0 ranks before -0 for `lax.top_k`."""
+    return np.where(rng.random(v) < 0.5, 0.0, -0.0)
+
+
+_WIDTHS = (320, _C * _G, _C * _G + 1, 19_008, 50_257, 151_936)
+
+
+def candidate_rows(v: int) -> np.ndarray:
+    """[kinds, v] float32, a row a kind in `_ROWS`' order."""
+    rng = np.random.default_rng(v)
+    return np.stack([np.asarray(fn(rng, v), np.float32)
+                     for fn in _ROWS.values()])
+
+
+@functools.lru_cache(maxsize=None)
+def _both_searches(v: int):
+    rows = jnp.asarray(candidate_rows(v))
+    want = jax.jit(lambda x: jax.lax.top_k(x, _C))(rows)
+    got = jax.jit(lambda x: inference._top_candidates(x, _C))(rows)
+    return rows, jax.device_get(want), jax.device_get(got)
+
+
+def _same_bits(got, want):
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
+    bits = f"uint{np.asarray(want[0]).dtype.itemsize * 8}"
+    np.testing.assert_array_equal(np.asarray(got[0]).view(bits),
+                                  np.asarray(want[0]).view(bits))
+
+
+@pytest.mark.parametrize("kind", list(_ROWS))
+@pytest.mark.parametrize("v", _WIDTHS)
+def test_top_candidates_is_lax_top_k_bit_for_bit(v, kind):
+    rows, want, got = _both_searches(v)
+    i = list(_ROWS).index(kind)
+    _same_bits((got[0][i], got[1][i]), (want[0][i], want[1][i]))
+    assert got[1].dtype == np.int32
+    row, ids = np.asarray(rows[i]), got[1][i]
+    if kind == "nan":
+        # what lax.top_k does today: a NaN ranks before +inf
+        assert ids[0] == (2 * v) // 3 and np.isnan(got[0][i][0])
+    elif kind == "neg_nan_beside_the_maximum":
+        assert ids[0] == (v // 2) // _G * _G + 1
+    elif kind == "signed_zeros":
+        assert ids[0] == np.flatnonzero(~np.signbit(row))[0]
+    elif kind != "neg_nan_but_ten":
+        assert ids[0] == np.argmax(row)        # the greedy parity
+
+
+@pytest.mark.parametrize("c,dtype", [(10, "float32"), (256, "float32"),
+                                     (64, "bfloat16")])
+def test_top_candidates_other_counts_and_types(c, dtype):
+    """`_sample`'s counts (a request's top_k, its 256 nucleus candidates)
+    and a 16-bit row, whose values tie by the hundred."""
+    rows = jnp.asarray(candidate_rows(50_257)).astype(dtype)
+    assert rows.shape[-1] > c * _G
+    want = jax.jit(lambda x: jax.lax.top_k(x, c))(rows)
+    got = jax.jit(lambda x: inference._top_candidates(x, c))(rows)
+    _same_bits(jax.device_get(got), jax.device_get(want))
+
+
+def test_wide_vocabulary_samples_what_a_whole_sort_samples(monkeypatch):
+    """`sample_slots`, `slot_filtered_probs` and `_sample` over a
+    vocabulary the search cuts into groups, against themselves with
+    `lax.top_k` in its place: greedy, sampling and nucleus rows."""
+    v, n = 19_008, 6
+    logits = jnp.asarray(candidate_rows(v)[:n]) * 3.0
+    keys = jax.random.split(jax.random.key(7), n)
+    temps = jnp.asarray([0.0, 0.7, 1.0, 1.3, 0.0, 0.9], jnp.float32)
+    tks = jnp.asarray([0, 5, 0, 40, 0, 64], jnp.int32)
+    tps = jnp.asarray([1.0, 1.0, 0.9, 0.5, 1.0, 0.95], jnp.float32)
+
+    def everything():
+        return (inference.sample_slots(logits, keys, temps, tks, tps),
+                inference.slot_filtered_probs(logits, temps, tks, tps),
+                inference._sample(logits, keys[0], temperature=0.8,
+                                  top_k=50, top_p=0.9),
+                inference._sample(logits, keys[1], temperature=0.8,
+                                  top_k=50))
+
+    got = jax.device_get(everything())
+    monkeypatch.setattr(inference, "_top_candidates", jax.lax.top_k)
+    for a, b in zip(got, jax.device_get(everything())):
+        np.testing.assert_array_equal(a, b)
+    np.testing.assert_array_equal(got[0][[0, 4]],
+                                  np.argmax(np.asarray(logits)[[0, 4]], -1))

@@ -308,6 +308,52 @@ def test_period_tick_lowers_the_kernel_once_a_layer(compiled_kernels, banks,
     engine.close()
 
 
+def sorted_widths(text: str) -> list[int]:
+    """The last axis of every `top_k` and `sort` operand of a lowered
+    program."""
+    shapes = re.findall(
+        r"chlo\.top_k\([^)]*\)[^\n]*? : tensor<([0-9x]+)x\w+>", text)
+    shapes += re.findall(
+        r'"stablehlo\.sort"\(.*?\}\) : \(tensor<([0-9x]+)x\w+>', text,
+        flags=re.S)
+    return [int(shape.split("x")[-1]) for shape in shapes]
+
+
+@pytest.mark.parametrize("vocab,sorts_it", [
+    (320, True),        # EvaByte's: under the rule, today's program
+    (50_257, False),    # GPT-2 medium's
+    (151_936, False),   # SmallThinker's
+])
+def test_tick_sorts_no_row_of_a_wide_vocabulary(compiled_kernels, vocab,
+                                                sorts_it):
+    """The sampler's candidates (ISSUE 39: `inference._top_candidates`)
+    are found by groups where the vocabulary is wider than the
+    `candidates * _GROUP` numbers sorted anyway, and by `lax.top_k` over
+    the row where not; the choice is static, so the lowered tick says
+    whether it engaged: no `top_k` or `sort` whose operand's last axis is
+    the vocabulary, none wider than the chosen groups' `candidates *
+    _GROUP` members and the numbers past the last whole group."""
+    from pytorchdistributed_tpu import inference
+    from pytorchdistributed_tpu.serving import ServingEngine
+
+    model = GPT2(gpt2_config("test", embed_dim=128, num_heads=2,
+                             num_layers=1, vocab_size=vocab))
+    params = model.init(jax.random.key(0), jnp.zeros((1, 4), jnp.int32))
+    engine = ServingEngine(model, params, num_slots=2, block_size=16)
+    widths = sorted_widths(engine.lower_tick(platforms=TPU).as_text())
+    assert widths, "the tick holds no top_k at all"
+    if sorts_it:
+        assert widths == [vocab]
+    else:
+        c, g = engine.candidates, inference._GROUP
+        assert sorted(widths) == [
+            c,                      # the chosen groups' ids
+            vocab // g,             # the whole groups' maxima
+            c * g + vocab % g]      # their members and the row's last few
+        assert max(widths) < (c + 1) * g < vocab
+    engine.close()
+
+
 def test_engine_keeps_the_kernel_to_rows_of_whole_lane_tiles(
         compiled_kernels):
     """The kernel's own copies move whole 128-lane tiles (Mosaic refuses
