@@ -66,6 +66,13 @@ _QUICK = (
     # against the benchmark's plain reference, with its planted faults
     # (ISSUE 36)
     "test_smallthinker_serving.py",
+    # Mamba-1 mixers beside NoPE attention in one scanned stack, a
+    # recurrent state a slot beside the K/V pool, through the paged engine
+    # against the benchmark's plain reference, its planted faults and
+    # refusals (ISSUE 40; ~2.5 min), and the selective-scan kernel
+    # (interpreted) against `lax.scan`
+    "test_jamba_serving.py",
+    "test_ssm_scan.py",
     # the experts' grouped product: the kernel that reads a bank where it
     # lies in a stack (interpreted) against `lax.ragged_dot` on the
     # slice, and the scanned stack handing it its banks whole (ISSUE 37;

@@ -346,9 +346,16 @@ KV_POOL_LEAVES = ("cached_key", "cached_value", "cached_key_scale",
                   "cached_window_key", "cached_window_value")
 
 
+#: the "cache" collection's recurrent states (models/ssm.py): a fixed row
+#: a slot, ``[layers, slots, ...]``, overwritten in place every call; no
+#: blocks, so nothing that moves blocks touches them
+STATE_LEAVES = ("cached_ssm_state", "cached_conv_state")
+
+
 def kv_cache_bytes(cache) -> int:
     """HBM bytes of a decode cache collection's K/V payload (dense rows
-    or the paged block pool — the counter/table leaves are noise).
+    or the paged block pool, and a recurrent model's states — the
+    counter/table leaves are noise).
     Includes the int8 pool's fp32 scale planes: they are real HBM the
     compressed pool pays, so "same HBM budget" A/Bs charge for them.
     The serving engine's summary reads it, so both sides of a "same HBM
@@ -356,7 +363,7 @@ def kv_cache_bytes(cache) -> int:
     total = 0
     for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]:
         name = getattr(path[-1], "key", str(path[-1]))
-        if name in KV_POOL_LEAVES:
+        if name in KV_POOL_LEAVES or name in STATE_LEAVES:
             total += int(np.prod(leaf.shape)) * leaf.dtype.itemsize
     return total
 

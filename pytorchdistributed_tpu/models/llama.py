@@ -35,7 +35,8 @@ class Llama(nn.Module):
         self.embed = Embedder(cfg)
         self.h = TransformerStack(cfg)
         self.ln_f = _layer_norm(cfg, None)
-        self.lm_head = LMHead(cfg)
+        if not cfg.tie_embeddings:
+            self.lm_head = LMHead(cfg)
 
     @property
     def counters(self) -> tuple:
@@ -52,6 +53,9 @@ class Llama(nn.Module):
 
     def __call__(self, tokens, *, deterministic: bool = True):
         x = self._backbone(tokens, deterministic)
+        if self.cfg.tie_embeddings:
+            # the head is the embedding (Jamba's `tie_word_embeddings`)
+            return self.embed.attend(x).astype(jnp.float32)
         return self.lm_head(x).astype(jnp.float32)
 
     def loss_per_position(self, tokens, targets, *,

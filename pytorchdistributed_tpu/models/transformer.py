@@ -42,9 +42,13 @@ Dtype = Any
 
 class CacheKind(NamedTuple):
     """One kind of cache a paged model keeps, as the serving engine's
-    `SlotPool` takes it (`cfg.cache_kinds`, the stream's own first)."""
+    `SlotPool` takes it (`cfg.cache_kinds`, the stream's own first). A
+    kind without a table is a recurrent state (`TransformerConfig.
+    state_leaves`): one fixed state a slot, overwritten in place every
+    call, with no rows and no blocks to page."""
     kind: str | None        # the `pool` id on the engine's spans
-    table: str              # the "cache" leaf the model reads block ids from
+    # the "cache" leaf the model reads block ids from; None: no blocks
+    table: str | None
     # positions a query of this pool's layers sees, itself included; 0:
     # every position (the pool grows with the stream and never retires)
     window: int = 0
@@ -269,8 +273,19 @@ class TransformerConfig:
     # ``window_table`` (`window_blocks` of them, sized by the engine) for
     # the window layers, whose blocks the engine hands back once the
     # window has passed them. Served through the paged engine only
-    # (models/periodic.py reads the pools). () = every layer alike.
+    # (models/periodic.py reads the pools). () = every layer alike. A
+    # layer may instead be ``"mamba"``: a Mamba-1 mixer (models/ssm.py)
+    # in place of attention, whose cache is one fixed state a slot
+    # (`state_leaves`, the `CacheKind` without a table) and no rows.
     period: tuple = ()
+    # the Mamba-1 mixer of a ``"mamba"`` layer: inner width `ssm_inner`
+    # (d_inner), `ssm_state` states a channel, a causal depthwise
+    # convolution of `ssm_conv` taps with a bias, the step's rank
+    # `ssm_dt_rank` (models/ssm.py)
+    ssm_inner: int = 0
+    ssm_state: int = 16
+    ssm_conv: int = 4
+    ssm_dt_rank: int = 0
     # models/moe.py:DroplessMoE as every block's feed-forward
     # (`router_experts` > 0: the router's published width): the layer that
     # drops nothing, holds experts ``experts_held = (lo, hi)`` of them,
@@ -536,15 +551,23 @@ class TransformerConfig:
                                  "the window pool is its trash block)")
 
     def _check_period(self) -> None:
-        windows = {w for _, w in self.period if w}
-        if (len(windows) > 1 or min(w for _, w in self.period) != 0
+        attn = self._attention_layers
+        if any(e[0] == "mamba" for e in attn):
+            raise ValueError(
+                f"period {self.period}: a mamba layer is \"mamba\" alone: "
+                f"it has no window and no rotation, its state is the "
+                f"whole past of the stream")
+        windows = {w for _, w in attn if w}
+        if (not attn or len(windows) > 1 or min(w for _, w in attn) != 0
                 or self.num_layers % len(self.period)):
             raise ValueError(
-                f"period {self.period}: (rope, window) a layer, with a "
-                f"layer that attends every position (the stream's own "
-                f"pool is the one that never retires), one window size "
-                f"for the others, and num_layers {self.num_layers} a "
-                f"multiple of its length")
+                f"period {self.period}: (rope, window) or \"mamba\" a "
+                f"layer, with a layer that attends every position (the "
+                f"stream's own pool is the one that never retires), one "
+                f"window size for the others, and num_layers "
+                f"{self.num_layers} a multiple of its length")
+        if "mamba" in self.period:
+            self._check_mamba()
         if not self.scan_layers or self.eva_window or not self.rope:
             raise ValueError("a period of layer kinds is built for the "
                              "scanned stack without a learned position "
@@ -555,6 +578,12 @@ class TransformerConfig:
                 "a model with two cache kinds is served through the "
                 "paged engine only (block_size > 0): the dense per-slot "
                 "cache has one layout for every layer")
+        if self.kv_dtype != "bf16" and "mamba" in self.period:
+            raise ValueError(
+                "kv_dtype='int8' is not built beside a recurrent state: "
+                "a mamba layer's state is summed into at every position "
+                "and kept in float32, and int8 codes with a scale a row "
+                "would round it anew every step")
         if (self.kv_dtype != "bf16" or self.kv_window_tokens
                 or self.kv_sink_tokens):
             raise ValueError(
@@ -571,6 +600,15 @@ class TransformerConfig:
             if self.window_blocks < 2:
                 raise ValueError("window_blocks must be >= 2 (block 0 of "
                                  "the window pool is its trash block)")
+
+    def _check_mamba(self) -> None:
+        if (self.ssm_inner < 1 or self.ssm_dt_rank < 1
+                or self.ssm_state < 1 or self.ssm_conv < 2):
+            raise ValueError(
+                f"a mamba layer needs ssm_inner and ssm_dt_rank, at least "
+                f"one state and a convolution of two taps; got "
+                f"{self.ssm_inner}, {self.ssm_dt_rank}, {self.ssm_state}, "
+                f"{self.ssm_conv}")
 
     def _check_dropless(self) -> None:
         if self.moe_experts:
@@ -612,12 +650,16 @@ class TransformerConfig:
                               stride=self.eva_chunk, lanes=lanes),
                     CacheKind("window", "window_table", self.eva_window,
                               tumbling=True, lanes=lanes))
-        window = max((w for _, w in self.period), default=0)
+        # a recurrent state a slot beside the stream's own pool (one kind
+        # of rows: the kind keeps the single pool's name)
+        state = ((CacheKind("state", None),) if "mamba" in self.period
+                 else ())
+        window = max((w for _, w in self._attention_layers), default=0)
         if window:
             return (CacheKind("full", "block_table", lanes=lanes),
                     CacheKind("window", "window_table", window,
-                              lanes=lanes))
-        return (CacheKind(None, "block_table", lanes=lanes),)
+                              lanes=lanes)) + state
+        return (CacheKind(None, "block_table", lanes=lanes),) + state
 
     @property
     def counter_names(self) -> tuple:
@@ -637,6 +679,10 @@ class TransformerConfig:
             from pytorchdistributed_tpu.models import periodic
 
             names += periodic.COUNTERS
+        if "mamba" in self.period:
+            from pytorchdistributed_tpu.models import ssm
+
+            names += ssm.COUNTERS
         return names
 
     @property
@@ -665,7 +711,7 @@ class TransformerConfig:
             return {"cached_key": win, "cached_value": win,
                     "cached_summary_key": kv, "cached_summary_value": kv}
         leaves = {"cached_key": kv, "cached_value": kv}
-        if any(w for _, w in self.period):
+        if any(w for _, w in self._attention_layers):
             # the window layers' rows: the same layout, a pool of their own
             win = ((self.window_blocks,) + kv[0][1:], kv[1])
             leaves.update(cached_window_key=win, cached_window_value=win)
@@ -674,15 +720,43 @@ class TransformerConfig:
             leaves.update(cached_key_scale=scale, cached_value_scale=scale)
         return leaves
 
+    @property
+    def state_leaves(self) -> dict:
+        """name -> (shape, dtype) of one mamba layer's recurrent state,
+        a slot's row each (models/ssm.py): the scan's state ``[state,
+        inner]`` (the channels on the lanes) and the convolution's last
+        ``ssm_conv - 1`` inputs, side by side in one row. Empty where no
+        layer is a mamba layer."""
+        if "mamba" not in self.period:
+            return {}
+        slots = self.decode_slots
+        return {"cached_ssm_state": ((slots, self.ssm_state,
+                                      self.ssm_inner), jnp.float32),
+                "cached_conv_state": ((slots, (self.ssm_conv - 1)
+                                       * self.ssm_inner), self.dtype)}
+
+    @property
+    def _attention_layers(self) -> list:
+        """The ``(rope, window)`` entries of `period`, its mamba layers
+        left out."""
+        return [e for e in self.period if e != "mamba"]
+
+    def layer_kind(self, entry) -> str:
+        """A `period` entry's kind: "mamba", "window" or "full"."""
+        if entry == "mamba":
+            return "mamba"
+        return "window" if entry[1] else "full"
+
     def pool_layers(self, name: str) -> int:
-        """Layers whose rows the scanned stack's pool leaf `name` holds:
-        every layer's, unless the layers come in kinds (`period`), each
-        with a pool as deep as the kind has layers."""
+        """Layers whose rows (or states) the scanned stack's leaf `name`
+        holds: every layer's, unless the layers come in kinds (`period`),
+        each with a pool as deep as the kind has layers."""
         if not self.period:
             return self.num_layers
-        windowed = sum(1 for _, w in self.period if w)
-        of_kind = (windowed if name.startswith("cached_window")
-                   else len(self.period) - windowed)
+        kind = ("mamba" if name in self.state_leaves
+                else "window" if name.startswith("cached_window")
+                else "full")
+        of_kind = sum(1 for e in self.period if self.layer_kind(e) == kind)
         return self.num_layers // len(self.period) * of_kind
 
     @property
@@ -1325,9 +1399,11 @@ class TransformerBlock(nn.Module):
     # None = cfg-driven (every block is MoE when moe_experts > 0); the
     # unrolled stack passes the per-layer moe_every interleaving decision.
     use_moe: bool | None = None
-    # this layer's kind in a period (SelfAttention's `rope`, `window`)
+    # this layer's kind in a period (SelfAttention's `rope`, `window`;
+    # `mixer` "mamba": models/ssm.py's mixer in attention's place)
     rope: bool | None = None
     window: int = 0
+    mixer: str = "attention"
 
     def _sow_diagnostics(self, x):
         """In-graph block-boundary health stats (ISSUE 6): sow
@@ -1400,8 +1476,13 @@ class TransformerBlock(nn.Module):
                     jnp.asarray(counted.get(n, 0.0), jnp.float32)
                     for n in cfg.counter_names])
 
-        attn_module = SelfAttention(cfg, self.deterministic, self.rope,
-                                    self.window, name="attn")
+        if self.mixer == "mamba":
+            from pytorchdistributed_tpu.models.ssm import MambaMixer
+
+            attn_module = MambaMixer(cfg, name="mamba")
+        else:
+            attn_module = SelfAttention(cfg, self.deterministic, self.rope,
+                                        self.window, name="attn")
 
         def attn(h):
             nonlocal pool
@@ -1437,10 +1518,10 @@ COUNTS = "counts"
 class PeriodBlock(nn.Module):
     """The scanned body where the layers come in kinds
     (`TransformerConfig.period`): one whole period, a `TransformerBlock`
-    a layer with its own ``rope`` and ``window`` (``layer_<j>``). Layer
-    ``j`` of period ``p`` keeps its rows at index ``p * n + r`` of its
-    kind's pool, ``n`` the kind's layers a period and ``r`` its rank
-    among them."""
+    a layer with its own ``rope`` and ``window``, or its mamba mixer
+    (``layer_<j>``). Layer ``j`` of period ``p`` keeps its rows (a mamba
+    layer: its states) at index ``p * n + r`` of its kind's pool, ``n``
+    the kind's layers a period and ``r`` its rank among them."""
 
     cfg: TransformerConfig
     deterministic: bool = True
@@ -1448,15 +1529,15 @@ class PeriodBlock(nn.Module):
     @nn.compact
     def __call__(self, x, paging, pool, period, banks=None):
         cfg = self.cfg
-        windowed = sum(1 for _, w in cfg.period if w)
-        per_kind = {True: windowed, False: len(cfg.period) - windowed}
-        rank = {True: 0, False: 0}
-        for j, (rope, window) in enumerate(cfg.period):
-            kind = bool(window)
+        kinds = [cfg.layer_kind(e) for e in cfg.period]
+        rank = dict.fromkeys(kinds, 0)
+        for j, (entry, kind) in enumerate(zip(cfg.period, kinds)):
+            at = dict(mixer="mamba") if kind == "mamba" else dict(
+                rope=bool(entry[0]), window=entry[1])
             x, pool = TransformerBlock(
-                cfg, self.deterministic, rope=bool(rope), window=window,
-                name=f"layer_{j}")(
-                    x, paging, pool, period * per_kind[kind] + rank[kind],
+                cfg, self.deterministic, name=f"layer_{j}", **at)(
+                    x, paging, pool,
+                    period * kinds.count(kind) + rank[kind],
                     banks and (banks[0][f"layer_{j}"], banks[1]))
             rank[kind] += 1
         return x, pool
@@ -1580,7 +1661,12 @@ class TransformerStack(nn.Module):
                 **{kind.table: self.variable(
                        "cache", kind.table, jnp.zeros,
                        (slots, kind.pages(cfg.kv_pages)), jnp.int32)
-                   for kind in cfg.cache_kinds}}
+                   for kind in cfg.cache_kinds if kind.table}}
+            if cfg.state_leaves:
+                # where each slot's tokens of this call end (exclusive):
+                # a recurrent state takes no step past it
+                state["stop"] = self.variable(
+                    "cache", "stop", lambda: jnp.zeros((slots,), jnp.int32))
             if cfg.per_slot_kv_limits and cfg.kv_window_tokens:
                 state["kv_sinks"] = self.variable(
                     "cache", "kv_sinks",
@@ -1609,7 +1695,8 @@ class TransformerStack(nn.Module):
                            "cache", name, jnp.zeros,
                            (cfg.pool_layers(name),) + shape, dtype)
                        for name, (shape, dtype)
-                       in cfg.kv_pool_leaves.items()}
+                       in {**cfg.kv_pool_leaves,
+                           **cfg.state_leaves}.items()}
                 carried = {name: var.value for name, var in own.items()}
                 counts = None
                 if cfg.eva_window:
@@ -1849,6 +1936,12 @@ class Embedder(nn.Module):
     def attend(self, x):
         x = x.astype(self.cfg.dtype)
         dg = _cfg_dot_general(self.cfg)
+        if self.cfg.fp32_logits:
+            # as `LMHead`'s: products in `dtype`, summed in float32
+            emb = self.tok.embedding.astype(self.cfg.dtype)
+            return (dg or jax.lax.dot_general)(
+                x, emb, (((x.ndim - 1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
         if dg is None:
             return self.tok.attend(x)
         # the tied logit projection [.., embed] x [vocab, embed]ᵀ through

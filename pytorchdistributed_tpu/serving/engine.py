@@ -77,6 +77,7 @@ import numpy as np
 
 from pytorchdistributed_tpu.inference import (
     KV_POOL_LEAVES,
+    STATE_LEAVES,
     _zero_cache,
     draft_and_verify,
     draft_and_verify_heads,
@@ -266,11 +267,17 @@ def _override_paging(cache, tables, lengths):
     state on every compiled call, which is what makes prefix sharing,
     block growth and preemption pure host bookkeeping. ``tables`` is
     {table leaf: [slots, pages]}, one entry a kind of cache the model
-    keeps (``block_table`` alone where it keeps one pool)."""
+    keeps (``block_table`` alone where it keeps one pool). A model with
+    a recurrent state also reads where each slot's tokens of the call
+    end (``stop``): one past its length for a live slot, its length 0
+    for a free one, whose state the tick then leaves as it is."""
     def fix(path, leaf):
         name = _leaf_name(path)
         if name in ("index", "pos_index"):
             return jnp.broadcast_to(lengths, leaf.shape).astype(leaf.dtype)
+        if name == "stop":
+            return jnp.broadcast_to(jnp.where(lengths > 0, lengths + 1, 0),
+                                    leaf.shape).astype(leaf.dtype)
         if name in tables:
             return jnp.broadcast_to(tables[name],
                                     leaf.shape).astype(leaf.dtype)
@@ -316,20 +323,30 @@ def paged_decode_tick(model, weights, cache, tables, lengths, tokens,
     return mut["cache"], nxt, dict(mut.get("counters", {}))
 
 
-def paged_chunk_logits(model, weights, cache, chunk, start, table_row):
+def paged_chunk_logits(model, weights, cache, chunk, start, table_row,
+                       stop=None, slot=None):
     """The model's part of a prefill chunk: logits ``[1, C, vocab]`` of
     every position of the chunk and the mutated cache. Apart from
     `paged_prefill_chunk` for the same reason as `paged_tick_logits`.
     ``table_row`` is {table leaf: [pages]}: the one request's rows of
-    `_override_paging`'s tables."""
+    `_override_paging`'s tables. A model with a recurrent state
+    (`STATE_LEAVES`) also takes where the request's tokens end (``stop``,
+    its true length) and its ``slot``, whose state row the chunk reads
+    (from zeros where ``start`` is 0: the model's own rule)."""
     def shrink(path, leaf):
         # the chunk model is the same module tree at decode_slots=1:
         # pool leaves pass through untouched (no slot dim), counter and
-        # table leaves shrink to the one-request row
+        # table leaves shrink to the one-request row, a state leaf to
+        # the slot's row
         name = _leaf_name(path)
         if name in ("index", "pos_index"):
             return jnp.broadcast_to(
                 start, leaf.shape[:-1] + (1,)).astype(leaf.dtype)
+        if name == "stop":
+            return jnp.broadcast_to(
+                stop, leaf.shape[:-1] + (1,)).astype(leaf.dtype)
+        if name in STATE_LEAVES:
+            return jax.lax.dynamic_slice_in_dim(leaf, slot, 1, axis=1)
         if name in table_row:
             row = table_row[name]
             return jnp.broadcast_to(
@@ -347,7 +364,7 @@ def paged_chunk_logits(model, weights, cache, chunk, start, table_row):
     donate_argnames=("cache",))
 def paged_prefill_chunk(model, weights, cache, chunk, start, table_row,
                         true_len, key_data, count, temperature, top_k,
-                        top_p, *, candidates: int):
+                        top_p, slot=None, *, candidates: int):
     """One fixed-size prefill chunk of one request, written straight
     into ITS blocks of the shared pool. ``chunk`` is [1, C] tokens
     covering absolute positions [start, start+C) (right-padded past
@@ -360,16 +377,21 @@ def paged_prefill_chunk(model, weights, cache, chunk, start, table_row,
     longer head-of-line-blocks their TTFT. Samples the request's next
     token at the (dynamic) last true position — only the final chunk's
     sample is used; ``count`` is its fold_in index (> 0 when a preempted
-    request resumes mid-generation)."""
+    request resumes mid-generation). ``slot``: the request's slot, where
+    the model keeps a recurrent state a slot (`paged_chunk_logits`); its
+    row is written back in place."""
     TRACE_COUNTS["paged_prefill_chunk"] += 1
     logits, mut = paged_chunk_logits(model, weights, cache, chunk, start,
-                                     table_row)
+                                     table_row, true_len, slot)
 
     def merge(path, big, new):
         # only the pools mutated (K/V codes AND, on an int8 pool, their
-        # scale planes); the big cache's counter/table leaves are
-        # scratch the engine re-stamps anyway
-        return new if _leaf_name(path) in POOL_LEAF_AXIS else big
+        # scale planes) and the slot's state row; the big cache's
+        # counter/table leaves are scratch the engine re-stamps anyway
+        name = _leaf_name(path)
+        if name in STATE_LEAVES:
+            return jax.lax.dynamic_update_slice_in_dim(big, new, slot, 1)
+        return new if name in POOL_LEAF_AXIS else big
 
     new_cache = jax.tree_util.tree_map_with_path(merge, cache, mut["cache"])
     off = jnp.clip(true_len - 1 - start, 0, chunk.shape[1] - 1)
@@ -987,6 +1009,11 @@ class ServingEngine:
         # a chunk that grows with the stream, and a pool of the current
         # window's exact rows that retires a whole window at a time)
         self._kinds = tuple(model.cfg.cache_kinds)
+        # the kinds with rows (a `SlotPool` each); a kind without a table
+        # is a recurrent state a slot (models/ssm.py), sized by the slot
+        # count in the model's own cache and moved by no block path
+        paged_kinds = [k for k in self._kinds if k.table]
+        self._stateful = len(paged_kinds) < len(self._kinds)
         self._pools: list[SlotPool] = []
         self._refuse_two_kinds(
             "the radix prefix cache" if prefix_cache else
@@ -1020,8 +1047,8 @@ class ServingEngine:
         # the kernel reads per-head key and value rows (`CacheKind.lanes`
         # of them a row), a call a pool, and copies them by its own DMAs,
         # which Mosaic takes in whole 128-lane tiles only
-        rowless = [k.kind for k in self._kinds if not k.lanes]
-        ragged = [k.lanes for k in self._kinds if k.lanes % 128]
+        rowless = [k.kind for k in paged_kinds if not k.lanes]
+        ragged = [k.lanes for k in paged_kinds if k.lanes % 128]
         if paged_attn == "auto":
             # backend-aware default: the fused kernel is the hot path on
             # real accelerators wherever every pool of the model is such
@@ -1061,7 +1088,7 @@ class ServingEngine:
                     f"block_size {block_size} must divide max_seq_len "
                     f"{max_len}")
             # the stream's own pool's blocks a full-context slot
-            pages = self._kinds[0].pages(max_len // block_size)
+            pages = paged_kinds[0].pages(max_len // block_size)
             if num_blocks is None:
                 # dense-equivalent HBM by default: one full context per
                 # slot, plus the trash block — shrink it to oversubscribe
@@ -1073,7 +1100,7 @@ class ServingEngine:
                     f"max_seq_len/block_size + the trash block)")
             self.block_size = block_size
             self.num_blocks = num_blocks
-            for kind in self._kinds[1:]:
+            for kind in paged_kinds[1:]:
                 model = self._with_window_pool(
                     model, kind, block_size,
                     prefill_chunk or prefill_bucket)
@@ -1107,7 +1134,7 @@ class ServingEngine:
             # whole blocks) and fit the context
             self.chunk = min(self._round_up(chunk, block_size),
                              self.cfg.max_seq_len)
-            for kind in self._kinds:
+            for kind in paged_kinds:
                 if kind.tumbling and kind.window % self.chunk:
                     raise ValueError(
                         f"prefill_chunk {self.chunk} does not divide the "
@@ -1128,7 +1155,7 @@ class ServingEngine:
                                block_size, num_slots,
                                k.pages(self.cfg.kv_pages),
                                k.window, k.stride, k.tumbling)
-                for k in self._kinds]
+                for k in paged_kinds]
             self._alloc = self._pools[0].alloc
             self._tables = self._pools[0].tables
             self._slot_blocks = self._pools[0].blocks
@@ -1683,6 +1710,13 @@ class ServingEngine:
     def _refuse_two_kinds(self, what: str | None) -> None:
         """What the engine has and a model with two cache kinds cannot
         use yet raises with its reason; nothing falls back silently."""
+        if what is not None and self._stateful:
+            raise ValueError(
+                f"{what} is not built for a model with a recurrent state "
+                f"a stream: a state cannot be rebuilt from kept blocks (it "
+                f"is overwritten in place every step, and no snapshot of "
+                f"it is kept at a block's edge), so a prefix, a draft, an "
+                f"exported or a parked stream would resume without it")
         if what is not None and len(self._kinds) > 1:
             raise ValueError(
                 f"{what} is not built for a model with two cache kinds: "
@@ -1927,6 +1961,9 @@ class ServingEngine:
                 jnp.float32(req.sampling.temperature),
                 jnp.int32(req.sampling.top_k),
                 jnp.float32(req.sampling.top_p))
+            if self._stateful:
+                # the slot whose state row the chunk reads and writes
+                operands += (jnp.int32(pf["slot"]),)
         with span("serve/chunk_call"):
             return paged_prefill_chunk(model, weights, cache, *operands,
                                        candidates=self.candidates)

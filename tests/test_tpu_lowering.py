@@ -274,6 +274,42 @@ def test_eva_tick_lowers_the_kernel_once_a_pool(compiled_kernels):
     engine.close()
 
 
+@pytest.mark.parametrize("steps,channels", [(512, 5120), (7, 256)])
+def test_ssm_scan_kernel_lowers_for_tpu(steps, channels):
+    """The selective-scan kernel (ops/ssm_scan.py) at a chunk of Jamba's
+    published widths (512 positions, 5,120 channels, 16 states) and at a
+    ragged toy length: one Mosaic call, named so that a trace finds it."""
+    from pytorchdistributed_tpu.ops import ssm_scan
+
+    f32 = jnp.float32
+    rows, bc = sds((1, steps, channels), f32), sds((1, steps, 16), f32)
+    text = lower_for_tpu(
+        functools.partial(ssm_scan.kernel_scan, interpret=False), rows,
+        rows, bc, bc, sds((16, channels), f32), sds((1, 16, channels), f32))
+    assert text.count(MARKER) == 1 and "ssm_scan" in text
+
+
+def test_mamba_period_ticks_through_the_paged_kernel(compiled_kernels):
+    """A period of mamba mixers and one NoPE attention layer whose rows
+    are one 128-lane tile (one key/value head of 128): on a TPU the tick
+    reads the attention pool through the paged kernel, and its states by
+    XLA's one elementwise step (no scan kernel in a tick)."""
+    from benchmark import manifest, reference
+    from pytorchdistributed_tpu.serving import ServingEngine
+    from tests.test_jamba_serving import TOY
+
+    fam = manifest.load_family(manifest.BENCH_DIR, "jamba")
+    toy = dict(TOY, hidden_size=256, num_attention_heads=2)   # heads of 128
+    w = jax.jit(lambda s: fam.make_weights(toy, s))(reference.seed_u32(40))
+    engine = ServingEngine(fam.program_model(toy, {}),
+                           fam.to_program_tree(w, toy, {}), num_slots=2,
+                           block_size=16, prefill_chunk=16,
+                           prefix_cache=False)
+    assert engine.summary()["paged_attn"] == "pallas"
+    text = engine.lower_tick(platforms=TPU).as_text()
+    assert text.count(MARKER) == 1 and "ssm_scan" not in text
+
+
 @pytest.mark.parametrize("banks,compute", [("in_place", "bfloat16"),
                                            ("sliced", "float32")])
 def test_period_tick_lowers_the_kernel_once_a_layer(compiled_kernels, banks,
