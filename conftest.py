@@ -206,6 +206,10 @@ _QUICK = (
     # tier-1); plus the HLO byte-identity pin for diagnostics-off
     "test_diagnostics.py",
     "test_compiled_invariants.py::test_diag_off_hlo_byte_identical",
+    # the served layout: the tick's plane leaves go straight
+    # into their products, and the train step is the same program
+    "test_compiled_invariants.py::test_served_tick_reads_plane_leaves",
+    "test_compiled_invariants.py::test_train_step_is_unchanged_by_the_served",
     # paged KV cache (ISSUE 7): the whole file is quick-tier by design —
     # allocator/radix units, the bitwise paged-attention parity ladder
     # (ragged + block-boundary + trash-garbage), the Pallas pool-native

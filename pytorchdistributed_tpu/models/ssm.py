@@ -103,7 +103,9 @@ class MambaMixer(nn.Module):
         ones = nn.initializers.ones_init()
         # [h | z] side by side in one [width, 2 D] matrix: a stacked
         # [width, 2, D] kernel is copied out of the scanned stack into
-        # another layout before every product
+        # another layout before every product (the Llama dialect's fused
+        # kernels are served as [2, width, D] planes for the same reason:
+        # transformer.py:fused_kernel)
         hz = matmul(u.astype(dt), param("in_kernel", (u.shape[-1], 2 * di),
                                         (Logical.EMBED, Logical.MLP)),
                     "bse,ef->bsf")

@@ -14,7 +14,10 @@ Conventions handled:
   * Our fused stacks: GPT-2 ``qkv_kernel [E, 3, H·D]`` from c_attn's
     contiguous q|k|v columns; Llama ``kv_kernel [E, 2, KV·D]`` and
     swiglu ``wi_kernel [E, 2, F]`` (index 0 = gate/silu, 1 = up — the
-    convention in models/transformer.py MlpBlock).
+    convention in models/transformer.py MlpBlock). This is the checkpoint
+    layout, the one `init` and the Trainer use too; the serving engine
+    re-lays its own copy of a scanned stack's fused kernels as planes
+    (serving/weights.py:served), so an imported tree serves as it is.
   * ``scan_layers=True`` trees stack the per-layer leaves on a leading
     layer axis (``h.block``); unrolled trees use ``h.block_{i}``.
   * Architecture fidelity comes from the family presets: ``norm_eps``

@@ -877,6 +877,38 @@ def _dense_general(features: int, kernel_axes, cfg, name, *,
     )
 
 
+#: the fused kernels by their name in a checkpoint, and the name each is
+#: served under as planes (`fused_kernel`)
+PLANES = {f"{name}_kernel": f"{name}_planes" for name in ("qkv", "kv", "wi")}
+
+
+def fused_kernel(module, name, stack, width, axes):
+    """`stack` matrices of `[embed, width]` applied side by side to the
+    `[batch, seq, embed]` input, and the einsum that applies them to it
+    (`[batch, seq, stack, width]` out, the matrices' order kept).
+
+    Initialised, trained and checkpointed as ``<name>_kernel [embed,
+    stack, width]``, logical `axes`. The serving engine holds a scanned
+    stack's as ``<name>_planes [stack, embed, width]``
+    (serving/weights.py:served): the scan's slice of the stacked leaf
+    is then `stack` contiguous matrices that the product reads where they
+    lie, where the fused axis as the second-minor dimension has XLA copy
+    the layer's whole kernel into another layout first. The same numbers
+    in the same sums: which name the tree holds says which to read."""
+    cfg = module.cfg
+    init = nn.initializers.normal(stddev=0.02)
+    if module.has_variable("params", PLANES[f"{name}_kernel"]):
+        planes = module.param(
+            PLANES[f"{name}_kernel"],
+            nn.with_logical_partitioning(init, (axes[1], axes[0], axes[2])),
+            (stack, cfg.embed_dim, width), cfg.param_dtype)
+        return planes, "bse,cef->bscf"
+    kernel = module.param(
+        f"{name}_kernel", nn.with_logical_partitioning(init, axes),
+        (cfg.embed_dim, stack, width), cfg.param_dtype)
+    return kernel, "bse,ecf->bscf"
+
+
 class SelfAttention(nn.Module):
     """Multi-head self-attention with Megatron-ready head sharding.
 
@@ -921,24 +953,26 @@ class SelfAttention(nn.Module):
         # its kernel init, which breaks rank-3 logical partitioning.
         # Grouped-query attention (kv_heads < num_heads) splits into a q
         # kernel + a fused [embed, 2, kv_heads·head_dim] kv kernel — both
-        # still shard whole heads on the "heads" logical axis.
+        # still shard whole heads on the "heads" logical axis (served as
+        # planes from a scanned stack: `fused_kernel`).
         def heads(t, n):
             t = t.reshape(b, s, n, cfg.head_dim)
             return nn.with_logical_constraint(
                 t, (Logical.BATCH, Logical.SEQ, Logical.HEADS, Logical.KV))
 
         def fused_proj(name, stack, width):
-            kernel = self.param(
-                f"{name}_kernel",
-                nn.with_logical_partitioning(
-                    nn.initializers.normal(stddev=0.02),
-                    (Logical.EMBED, None, Logical.HEADS) if stack > 1
-                    else (Logical.EMBED, Logical.HEADS)),
-                (cfg.embed_dim, stack, width) if stack > 1
-                else (cfg.embed_dim, width),
-                cfg.param_dtype,
-            )
-            eq = "bse,ecf->bscf" if stack > 1 else "bse,ef->bsf"
+            if stack > 1:
+                kernel, eq = fused_kernel(
+                    self, name, stack, width,
+                    (Logical.EMBED, None, Logical.HEADS))
+            else:
+                kernel = self.param(
+                    f"{name}_kernel",
+                    nn.with_logical_partitioning(
+                        nn.initializers.normal(stddev=0.02),
+                        (Logical.EMBED, Logical.HEADS)),
+                    (cfg.embed_dim, width), cfg.param_dtype)
+                eq = "bse,ef->bsf"
             out = jnp.einsum(eq, x, kernel.astype(cfg.dtype),
                              _dot_general=_site_dot_general(
                                  cfg, "column", jax.lax.dot_general))
@@ -1260,17 +1294,12 @@ class MlpBlock(nn.Module):
         if cfg.activation == "swiglu":
             # Llama FFN: silu(x@W_gate) * (x@W_up), gate+up fused into one
             # [embed, 2, ffn] kernel (same MXU-utilization rationale as the
-            # fused qkv projection); the stacked "2" dim is unsharded so
+            # fused qkv projection; served as planes from a scanned stack:
+            # `fused_kernel`); the stacked "2" dim is unsharded so
             # "mlp"→tensor still splits clean columns.
-            kernel = self.param(
-                "wi_kernel",
-                nn.with_logical_partitioning(
-                    nn.initializers.normal(stddev=0.02),
-                    (Logical.EMBED, None, Logical.MLP)),
-                (cfg.embed_dim, 2, cfg.ffn_dim),
-                cfg.param_dtype,
-            )
-            gu = jnp.einsum("bse,ecf->bscf", x, kernel.astype(cfg.dtype),
+            kernel, eq = fused_kernel(self, "wi", 2, cfg.ffn_dim,
+                                      (Logical.EMBED, None, Logical.MLP))
+            gu = jnp.einsum(eq, x, kernel.astype(cfg.dtype),
                             _dot_general=_site_dot_general(
                                 cfg, "column", jax.lax.dot_general))
             if cfg.use_bias:

@@ -90,7 +90,7 @@ from pytorchdistributed_tpu.serving.paging import (
     SlotPool,
 )
 from pytorchdistributed_tpu.serving.telemetry import ServingTelemetry
-from pytorchdistributed_tpu.serving.weights import cast_only, narrowed, wider
+from pytorchdistributed_tpu.serving.weights import cast_only, served, wider
 from pytorchdistributed_tpu.telemetry.spans import span
 from pytorchdistributed_tpu.telemetry.tracing import (
     TraceContext,
@@ -1271,7 +1271,7 @@ class ServingEngine:
             # with LogicallyPartitioned boxes as often as plain trees,
             # and the hot-swap path compares TREEDEFS — a boxed boot
             # tree would refuse every trainer-produced (unboxed) swap
-            self._draft_weights, _ = self._compute_copy(
+            self._draft_weights, _, _ = self._compute_copy(
                 self._draft_tick_model, self._draft_cache, nn.meta.unbox(
                     draft_params["params"] if "params" in draft_params
                     else draft_params))
@@ -3257,27 +3257,30 @@ class ServingEngine:
         NaN'd replica by reloading a verified checkpoint here, then the
         router's warmup re-admission probes it healthy again.
 
-        The engine keeps ONE tree, in the type the programs compute in:
-        a leaf stored wider than ``cfg.dtype`` that the model would only
-        cast to it (every matrix it multiplies by, the embeddings) is
-        cast here, once, and every other leaf (a norm's float32 gain, a
-        tree already stored in the compute type) is kept as it came
-        (serving/weights.py). So a float32 checkpoint and the bfloat16
-        tree of another replica are both good arguments, and neither
-        retraces."""
-        self._weights, self._weight_bytes_cast = self._compute_copy(
+        The engine keeps ONE tree, in the type the programs compute in
+        and the layout they read: a leaf stored wider than ``cfg.dtype``
+        that the model would only cast to it (every matrix it multiplies
+        by, the embeddings) is cast here, once, a scanned stack's fused
+        kernels are held as planes, and every other leaf (a norm's
+        float32 gain, a tree already stored in the compute type) is kept
+        as it came (serving/weights.py). So a float32 checkpoint and the
+        served tree of another replica are both good arguments, and
+        neither retraces."""
+        (self._weights, self._weight_bytes_cast,
+         self._weight_bytes_relaid) = self._compute_copy(
             self._tick_model, self._cache,
             params["params"] if "params" in params else params)
 
     def _compute_copy(self, model, cache, tree):
-        """`tree` as `model`'s programs take it (module docstring of
-        serving/weights.py), and the bytes the leaves cast here held
-        before. A tree with no leaf wider than the compute type comes
-        back as it is, untraced."""
+        """`tree` as `model`'s programs take it (`serving/weights.py:
+        served`), the bytes the leaves cast here held before, and the
+        bytes of the leaves re-laid here. The forward pass is traced only
+        for a tree with a leaf wider than the compute type; a tree with
+        nothing to cast or re-lay comes back as it is."""
         dtype = model.cfg.dtype
         leaves, treedef = jax.tree.flatten(tree)
         if not any(wider(leaf, dtype) for leaf in leaves):
-            return tree, 0
+            return served(tree, None, dtype)
         flags = self._cast_only.get((model, treedef))
         if flags is None:
             tokens = jnp.zeros((self.num_slots, 1), jnp.int32)
@@ -3291,7 +3294,7 @@ class ServingEngine:
                         {"params": w, "cache": c}, tokens, method=method,
                         mutable=["cache", "counters"]),
                     tree, dtype, cache)
-        return narrowed(tree, flags, dtype)
+        return served(tree, flags, dtype)
 
     def set_draft_params(self, params) -> None:
         """Hot-swap the DRAFT weights mid-serving (ISSUE 16) — the
@@ -3309,8 +3312,11 @@ class ServingEngine:
                 "== 0): there is no draft to swap")
         import flax.linen as nn
 
-        new = nn.meta.unbox(params["params"] if "params" in params
-                            else params)
+        # in the layout the resident draft is served in (its fused
+        # kernels as planes), so that the two compare leaf for leaf
+        new, _, _ = served(nn.meta.unbox(params["params"] if "params" in
+                                         params else params),
+                           None, self._draft_tick_model.cfg.dtype)
         old_leaves = jax.tree_util.tree_flatten_with_path(
             self._draft_weights)
         new_leaves = jax.tree_util.tree_flatten_with_path(new)
@@ -3330,8 +3336,8 @@ class ServingEngine:
         # the swap in the type the tick computes in, as the resident tree
         # is held (set_params): a float32 checkpoint of a draft that was
         # booted from float32 matches, leaf for leaf
-        new, _ = self._compute_copy(self._draft_tick_model,
-                                    self._draft_cache, new)
+        new, _, _ = self._compute_copy(self._draft_tick_model,
+                                       self._draft_cache, new)
         for (path, a), b in zip(old_leaves[0], jax.tree.leaves(new)):
             if jnp.asarray(b).dtype != getattr(a, "dtype", None):
                 raise ValueError(
@@ -3481,12 +3487,14 @@ class ServingEngine:
                     np.asarray(st[f"{key}_s"], np.float64), q)) * 1e3, 3)
         out["admit_blocked"] = st["admit_blocked"]
         out["kv_hbm_bytes"] = self.kv_hbm_bytes
-        # the tree the programs take, and what its leaves held before
-        # the last set_params cast them to the compute type (0: served
-        # in the type it came in)
+        # the tree the programs take, what its leaves held before the
+        # last set_params cast them to the compute type (0: served in the
+        # type it came in), and the bytes of the fused kernels it re-laid
+        # as planes (0: none, or a tree that came as planes)
         out["weight_bytes_served"] = sum(
             leaf.nbytes for leaf in jax.tree.leaves(self._weights))
         out["weight_bytes_cast"] = self._weight_bytes_cast
+        out["weight_bytes_relaid"] = self._weight_bytes_relaid
         if self.paged:
             out["block_size"] = self.block_size
             out["num_blocks"] = self.num_blocks

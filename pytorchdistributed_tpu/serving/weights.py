@@ -1,4 +1,4 @@
-"""The engine's weights in the model's compute type.
+"""The engine's weights in the model's compute type and the served layout.
 
 A model with ``dtype=bfloat16`` and ``param_dtype=float32`` casts every
 matrix it multiplies by to bfloat16 inside the compiled program: right
@@ -17,15 +17,31 @@ its stored width (a norm's gain, a router that scores in float32) is
 kept as it came, and so is every leaf of a tree that is already stored
 in the compute type. The cast rounds each value exactly as the program's
 own cast did, so logits and tokens are bitwise what they were.
+
+The same place lays out once what the programs would otherwise copy
+every call. A fused kernel of a scanned stack (`models/transformer.py:
+fused_kernel`: ``wi_kernel [layers, embed, 2, ffn]``, ``qkv_kernel`` /
+``kv_kernel [layers, embed, 3|2, width]``) has its fused axis as the
+second-minor dimension, and XLA copies each layer's slice into the
+layout its product reads before every product, in every tick and chunk.
+`served` holds such a leaf as planes, ``<name>_planes [layers, 3|2,
+embed, width]``, whose slice the product reads where it lies. The
+numbers and their sums are the same; a checkpoint, `init` and the
+`Trainer` keep the fused layout, and a tree that already holds planes
+is taken as it is.
 """
 
 from __future__ import annotations
 
 import functools
+from collections.abc import Mapping
 
 import jax
 import jax.numpy as jnp
+from flax.core import meta
 from jax.extend import core as jex_core
+
+from pytorchdistributed_tpu.models.transformer import PLANES
 
 #: equations that hand their operands on to a body, and how many leading
 #: operands the body does not take (a cond's branch index). An equation
@@ -103,22 +119,63 @@ def cast_only(forward, tree, dtype, *operands) -> tuple[bool, ...]:
 
 
 @functools.partial(jax.jit, static_argnums=1)
-def _cast(leaves, dtype):
-    return [leaf.astype(dtype) for leaf in leaves]
+def _serve(leaves, how):
+    """Each leaf in the type `how` gives it and, where its flag says,
+    with the fused axis of ``[.., embed, c, width]`` moved before the
+    embedding's: ``[.., c, embed, width]``."""
+    return [jnp.moveaxis(leaf.astype(dtype), -2, -3) if planes
+            else leaf.astype(dtype)
+            for leaf, (dtype, planes) in zip(leaves, how, strict=True)]
 
 
-def narrowed(tree, flags, dtype):
-    """`tree` with each flagged leaf that is `wider` than `dtype` cast to
-    it and every other leaf the very object it was, and the bytes the
-    cast leaves held before. One program casts them all, where a cast a
-    leaf would compile one small program a shape."""
-    leaves, treedef = jax.tree.flatten(tree)
-    picks = [i for i, (leaf, flag) in enumerate(zip(leaves, flags,
-                                                    strict=True))
-             if flag and wider(leaf, dtype)]
-    cast_bytes = sum(leaves[i].nbytes for i in picks)
-    if picks:
-        for i, leaf in zip(picks, _cast([leaves[i] for i in picks],
-                                        jnp.dtype(dtype))):
-            leaves[i] = leaf
-    return treedef.unflatten(leaves), cast_bytes
+def _fused(path, leaf) -> bool:
+    """Whether a leaf is a fused kernel of a scanned stack: one of
+    `PLANES` with the stack's leading axis, ``[layers, embed, c,
+    width]``."""
+    names = [k.key for k in path if isinstance(k, jax.tree_util.DictKey)]
+    return bool(names) and names[-1] in PLANES and leaf.ndim == 4
+
+
+def served(tree, flags, dtype):
+    """`tree` as the engine's programs take it: each flagged leaf that is
+    `wider` than `dtype` cast to it (`flags`: `cast_only`'s, or None where
+    nothing is cast), each fused kernel of a scanned stack re-laid as
+    planes under its plane name (`PLANES`; unboxed), and every other leaf
+    the very object it was. Returns the tree, the bytes the cast leaves
+    held before, and the bytes the re-laid leaves hold. One program makes
+    them all, where one a leaf would compile a small program a shape; a
+    tree with nothing to do comes back as it is."""
+    pairs, treedef = jax.tree_util.tree_flatten_with_path(tree)
+    leaves = [leaf for _, leaf in pairs]
+    flags = flags or (False,) * len(leaves)
+    how = [(jnp.dtype(dtype) if flag and wider(leaf, dtype)
+            else leaf.dtype, _fused(path, leaf))
+           for (path, leaf), flag in zip(pairs, flags, strict=True)]
+    picks = [i for i, (to, planes) in enumerate(how)
+             if planes or to != leaves[i].dtype]
+    if not picks:
+        return tree, 0, 0
+    cast_bytes = sum(leaves[i].nbytes for i in picks
+                     if how[i][0] != leaves[i].dtype)
+    for i, leaf in zip(picks, _serve([leaves[i] for i in picks],
+                                     tuple(how[i] for i in picks))):
+        leaves[i] = leaf
+    relaid = {tuple(k.key for k in pairs[i][0]
+                    if isinstance(k, jax.tree_util.DictKey))
+              for i in picks if how[i][1]}
+
+    def renamed(node, path):
+        if not isinstance(node, Mapping):
+            return node
+        out = {}
+        for key, sub in node.items():
+            at = path + (key,)
+            if at in relaid:
+                out[PLANES[key]] = meta.unbox(sub)
+            else:
+                out[key] = renamed(sub, at)
+        return out
+
+    out = treedef.unflatten(leaves)
+    return (renamed(out, ()) if relaid else out, cast_bytes,
+            sum(leaves[i].nbytes for i in picks if how[i][1]))

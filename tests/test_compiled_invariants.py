@@ -984,3 +984,99 @@ def test_comm_stall_frac_pinned(name):
                      / acct.ici_bytes_per_s / sec), 4)
     assert acct.comm_stall_frac(sec) == want
     assert acct.comm_stall_frac(0.0) is None
+
+
+# ---------------------------------------------------------------------------
+# the served layout: a scanned stack's fused kernels as planes
+
+
+def _first_uses(jaxpr, var):
+    """Every equation that uses `var` in `jaxpr` with the operand's
+    index, followed into the bodies of the calls and loops that hand it
+    on whole (a scan hands its body the step's slice of it)."""
+    from pytorchdistributed_tpu.serving.weights import _bodies
+
+    for eqn in jaxpr.eqns:
+        for i, v in enumerate(eqn.invars):
+            if v is not var:
+                continue
+            inner = [body for body in _bodies(eqn)
+                     if len(body.invars) == len(eqn.invars)]
+            if inner:
+                for body in inner:
+                    yield from _first_uses(body, body.invars[i])
+            else:
+                yield eqn, i
+
+
+def test_served_tick_reads_plane_leaves_straight_into_their_products():
+    """The scanned toy Llama's jitted tick takes its fused k/v and gate/up
+    as planes, `[layers, 2, embed, width]`, and every use of such a leaf,
+    through the layer loop, is the right operand of a `dot_general` that
+    contracts the embedding: nothing but the scan's slice stands between
+    the two, no transpose, reshape or convert. (XLA then reads a layer's
+    planes where they lie: tests/test_tpu_lowering.py.)"""
+    import jax
+    import jax.numpy as jnp
+
+    from pytorchdistributed_tpu.models import Llama, llama_config
+    from pytorchdistributed_tpu.serving import ServingEngine
+
+    model = Llama(llama_config("test", max_seq_len=64))
+    params = model.init(jax.random.key(0), jnp.zeros((1, 4), jnp.int32))
+    engine = ServingEngine(model, params, num_slots=2, block_size=8,
+                           prefill_chunk=8, prefix_cache=False)
+    tick, args = engine._tick_program()
+    closed = tick.trace(engine._tick_model, *args,
+                        candidates=engine.candidates).jaxpr
+    paths = [jax.tree_util.keystr(path) for path, _ in
+             jax.tree_util.tree_leaves_with_path(args[0])]
+    planes = {i: path for i, path in enumerate(paths) if "_planes" in path}
+    assert sorted(p.split("'")[-2] for p in planes.values()) == [
+        "kv_planes", "wi_planes"]
+    assert not any(p.endswith(("['kv_kernel']", "['wi_kernel']"))
+                   for p in paths)
+    cfg = model.cfg
+    for i, path in planes.items():
+        leaf = closed.jaxpr.invars[i]
+        assert leaf.aval.shape[:3] == (cfg.num_layers, 2, cfg.embed_dim)
+        uses = list(_first_uses(closed.jaxpr, leaf))
+        assert uses, path
+        for eqn, operand in uses:
+            assert (eqn.primitive.name, operand) == ("dot_general", 1), (
+                path, eqn)
+            (_, rhs_contract), _ = eqn.params["dimension_numbers"]
+            assert tuple(rhs_contract) == (1,), (path, eqn)
+            assert eqn.invars[1].aval.shape == leaf.aval.shape[1:]
+    engine.close()
+
+
+def test_train_step_is_unchanged_by_the_served_layout():
+    """Serving re-lays the engine's own copy of a tree; the `Trainer`
+    keeps the checkpoint's fused q/k/v `[layers, embed, 3, width]`, and
+    its compiled step is the same program, to the byte, before and after
+    an engine serves the same model."""
+    import jax
+    import jax.numpy as jnp
+
+    from pytorchdistributed_tpu.models import GPT2, gpt2_config
+    from pytorchdistributed_tpu.serving import ServingEngine
+    from pytorchdistributed_tpu.utils.hlo import hlo_fingerprint
+
+    trainer, batch = BUILDERS["dp8"]()
+    before = hlo_fingerprint(trainer.lower_step(batch).compile())
+    state = trainer.init(batch)
+    cfg = gpt2_config("test")
+    attn = state.params["params"]["h"]["block"]["attn"]
+    qkv = attn["qkv_kernel"]
+    assert qkv.shape == (cfg.num_layers, cfg.embed_dim, 3, cfg.embed_dim)
+    model = GPT2(cfg)
+    engine = ServingEngine(model, model.init(
+        jax.random.key(0), jnp.zeros((1, 4), jnp.int32)), num_slots=2,
+        block_size=8, prefill_chunk=8, prefix_cache=False)
+    assert engine.summary()["weight_bytes_relaid"] > 0
+    engine.submit(np.arange(5, dtype=np.int32), max_new_tokens=3)
+    engine.run_until_idle()
+    engine.close()
+    assert hlo_fingerprint(trainer.lower_step(batch).compile()) == before
+    assert "qkv_planes" not in attn

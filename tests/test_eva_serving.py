@@ -341,3 +341,18 @@ def test_a_latent_kind_keeps_the_gather_and_is_refused_by_its_name(
                        "built for this model's 'latent' pool.*latents "
                        "that all heads share"):
         latent.make_engine(fam, latent.TOY, w, paged_attn="pallas")
+
+
+@pytest.mark.parametrize("compute", ["float32", "bfloat16"])
+def test_planes_serve_what_the_checkpoint_layout_serves(fam, weights,
+                                                        compute, monkeypatch):
+    """The scanned stack's fused q/k/v and gate/up, held by the engine as
+    planes (serving/weights.py:served): tokens and every logit of every
+    chunk and tick bitwise what the checkpoint's layout serves, with the
+    program in float32 and in bfloat16."""
+    from tests.test_serving_weights import served_both_ways
+
+    cfg = dict(TOY, compute_dtype=compute)
+    served_both_ways(lambda: make_engine(fam, weights, cfg),
+                     [(WIN + 5, 8), (5, 6)], TOY["vocab_size"], LogitSpy,
+                     monkeypatch)

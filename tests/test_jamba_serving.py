@@ -374,3 +374,18 @@ def test_the_model_and_its_tree(fam, weights):
         for n in ("cached_key", "cached_value", "cached_ssm_state",
                   "cached_conv_state"))
     eng.close()
+
+
+@pytest.mark.parametrize("compute", ["float32", "bfloat16"])
+def test_planes_serve_what_the_checkpoint_layout_serves(fam, weights,
+                                                        compute, monkeypatch):
+    """The attention layers' fused k/v and every layer's gate/up, held by
+    the engine as planes (serving/weights.py:served): tokens and every
+    logit of every chunk and tick bitwise what the checkpoint's layout
+    serves, with the program in float32 and in bfloat16."""
+    from tests.test_serving_weights import served_both_ways
+
+    cfg = dict(TOY, compute_dtype=compute)
+    served_both_ways(lambda: make_engine(fam, weights, cfg),
+                     [(37, 8), (5, 6)], TOY["vocab_size"], LogitSpy,
+                     monkeypatch)

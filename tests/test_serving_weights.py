@@ -1,5 +1,5 @@
-"""The engine serves from weights in the compute type (ISSUE 31:
-serving/weights.py, `ServingEngine.set_params`).
+"""The engine serves from weights in the compute type and the served
+layout (serving/weights.py, `ServingEngine.set_params`).
 
 A model with bfloat16 compute and float32 parameters casts every matrix
 it multiplies by inside the program; the engine now makes that cast once,
@@ -8,7 +8,10 @@ float32 tree gives (the old behaviour, called directly with the uncast
 tree, is the reference), no leaf the model reads in float32 is narrowed,
 the compiled tick converts no parameter, a swap of weights re-casts
 without a retrace, and a tree that is stored in the compute type passes
-through untouched.
+through untouched but for a scanned stack's fused kernels, which the
+engine holds as planes: tokens and logits bitwise the checkpoint
+layout's, in either precision, under int8_fwd too, and a tree of either
+layout is taken without a retrace.
 """
 
 import dataclasses
@@ -29,7 +32,8 @@ from pytorchdistributed_tpu.serving.engine import (
     paged_decode_tick,
     paged_tick_logits,
 )
-from pytorchdistributed_tpu.serving.weights import cast_only, narrowed
+from pytorchdistributed_tpu.serving import weights as served_weights
+from pytorchdistributed_tpu.serving.weights import cast_only, served
 
 BF16, F32 = jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32)
 LENS, NEWS = (5, 13, 3, 9), (6, 4, 8, 5)
@@ -158,6 +162,11 @@ def test_lowered_tick_converts_no_float32_parameter(scan_layers):
                            block_size=8)
     gain = cfg.num_layers * cfg.embed_dim   # a stacked norm's gain
     assert _param_converts(engine.lower_tick().as_text(), gain) == []
+    # a scanned stack's fused q/k/v is re-laid as planes, an unrolled
+    # stack's layers are left as they are
+    qkv = 3 * cfg.num_layers * cfg.embed_dim ** 2 * 2       # bf16
+    assert engine.summary()["weight_bytes_relaid"] == (
+        qkv if scan_layers else 0)
     # the same tick over the float32 tree does convert them: the pattern
     # sees what it is meant to see
     _, args = engine._tick_program()
@@ -216,35 +225,146 @@ def test_set_params_recasts_without_retrace():
     whole = sum(leaf.nbytes for leaf in jax.tree.leaves(second))
     assert s["weight_bytes_cast"] == whole - norms
     assert s["weight_bytes_served"] == (whole - norms) // 2 + norms
+    # the checkpoint's fused q/k/v, cast and re-laid as planes
+    assert s["weight_bytes_relaid"] == _by_path(second)[
+        QKV + ".value"].nbytes // 2
     # the engine's own tree handed back (the chaos path's restore, a
-    # sibling replica's tree) is held as it is
+    # sibling replica's tree) is held as it is: its planes are taken as
+    # planes, and neither layout retraces
     held = engine._weights
     engine.set_params(held)
     assert all(a is b for a, b in zip(jax.tree.leaves(engine._weights),
                                       jax.tree.leaves(held)))
     assert engine.summary()["weight_bytes_cast"] == 0
+    assert engine.summary()["weight_bytes_relaid"] == 0
     assert dict(serving_engine.TRACE_COUNTS) == traces
     engine.close()
+
+
+def _by_path(tree) -> dict:
+    return {jax.tree_util.keystr(path): leaf
+            for path, leaf in jax.tree_util.tree_leaves_with_path(tree)}
+
+
+QKV = "['h']['block']['attn']['qkv_kernel']"
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
                          ids=["bf16-leaves", "f32-compute"])
 def test_tree_in_the_compute_type_is_served_as_it_came(dtype):
     """bfloat16 leaves under bfloat16 compute, float32 under float32:
-    nothing is wider than the compute type, so nothing is traced or cast
-    and the engine holds the very arrays it was given."""
+    nothing is wider than the compute type, so nothing is traced or cast,
+    and every leaf the engine holds is the very array it was given but
+    the scanned stack's fused q/k/v kernel, which it holds as planes
+    under their own name: the same numbers, `[layers, 3, embed, width]`
+    (serving/weights.py:served)."""
     cfg = dataclasses.replace(_cfg(), dtype=dtype, param_dtype=dtype)
     model = GPT2(cfg)
     params = _init(model)
     engine = ServingEngine(model, params, num_slots=2, prefill_bucket=16,
                            block_size=8)
-    assert engine._weights is params
+    given, held = _by_path(params), _by_path(engine._weights)
+    kernel = given[QKV + ".value"]                        # boxed by init
+    planes = QKV.replace("qkv_kernel", "qkv_planes")
+    assert set(given) - set(held) == {QKV + ".value"}
+    assert set(held) - set(given) == {planes}
+    assert all(held[k] is given[k] for k in held if k != planes)
+    np.testing.assert_array_equal(held[planes],
+                                  np.moveaxis(np.asarray(kernel), 2, 1))
+    assert held[planes].shape == (cfg.num_layers, 3, cfg.embed_dim,
+                                  cfg.embed_dim)
     assert engine._cast_only == {}
     s = engine.summary()
     assert s["weight_bytes_cast"] == 0
+    assert s["weight_bytes_relaid"] == kernel.nbytes
     assert s["weight_bytes_served"] == sum(
         leaf.nbytes for leaf in jax.tree.leaves(params))
     engine.close()
+
+
+# ---------------------------------------------------------------------------
+# the served planes against the checkpoint's layout
+
+
+def served_both_ways(build, requests, vocab, spy, monkeypatch, seed=0):
+    """`requests` ((prompt length, new tokens) each) served by the engine
+    `build()` makes, once as it holds a scanned stack's fused kernels
+    (planes) and once with the re-lay switched off (the checkpoint's
+    layout, which the modules read as they always did): asserts that
+    each engine holds the layout it should, and that the tokens and
+    every logit of every chunk and tick (`spy`, a LogitSpy) are bitwise
+    equal between the two."""
+    runs = []
+    for planes in (True, False):
+        with monkeypatch.context() as patch:
+            if not planes:
+                patch.setattr(served_weights, "_fused", lambda *_: False)
+            engine = build()
+            names = list(_by_path(engine._weights))
+            assert any("_planes" in n for n in names) == planes
+            assert any(k in n for k in served_weights.PLANES
+                       for n in names) != planes
+            assert (engine.summary()["weight_bytes_relaid"] > 0) == planes
+            logits = spy(engine, patch).logits
+            rng = np.random.default_rng(seed)
+            reqs = [engine.submit(
+                rng.integers(0, vocab, n).astype(np.int32),
+                max_new_tokens=m) for n, m in requests]
+            engine.run_until_idle()
+            runs.append([(r.new_tokens, logits[r.id]) for r in reqs])
+            engine.close()
+    for (tokens, logits), (ref_tokens, ref_logits) in zip(*runs):
+        assert tokens == ref_tokens
+        assert sorted(logits) == sorted(ref_logits)
+        for pos in logits:
+            np.testing.assert_array_equal(logits[pos], ref_logits[pos])
+
+
+def _family(family: str, dtype):
+    if family == "gpt2":
+        return GPT2(dataclasses.replace(_cfg(), dtype=dtype))
+    quant = "int8_fwd" if family == "llama-int8fwd" else "none"
+    return Llama(llama_config("test", max_seq_len=64, dtype=dtype,
+                              param_dtype=jnp.float32, quant=quant))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("family", ["gpt2", "llama", "llama-int8fwd"])
+def test_planes_serve_what_the_checkpoint_layout_serves(family, dtype,
+                                                        monkeypatch):
+    """GPT-2's fused q/k/v, and Llama's fused k/v and gate/up (under
+    int8_fwd too, whose products quantize the planes by the same
+    channels), served as planes: tokens and every chunk's and tick's
+    logits bitwise the checkpoint layout's, in float32 and in bfloat16
+    (a float32 tree then cast and re-laid in one program)."""
+    from tests.test_latent_serving import LogitSpy
+
+    model = _family(family, dtype)
+    params = _init(model)
+    served_both_ways(
+        lambda: ServingEngine(model, params, num_slots=3, block_size=8,
+                              prefill_chunk=8, prefix_cache=False),
+        [(5, 6), (13, 4), (19, 8)], model.cfg.vocab_size, LogitSpy,
+        monkeypatch)
+
+
+@pytest.mark.parametrize("compute", ["float32", "bfloat16"])
+def test_smallthinker_planes_serve_what_the_checkpoint_layout_serves(
+        compute, monkeypatch):
+    """A toy SmallThinker (grouped heads: a fused k/v kernel beside its q
+    kernel; its experts are `DroplessMoE`'s own leaves and stay as they
+    are): tokens and logits bitwise the checkpoint layout's."""
+    from benchmark import manifest, reference
+    from tests.test_latent_serving import LogitSpy
+    from tests.test_smallthinker_serving import TOY, make_engine
+
+    fam = manifest.load_family(manifest.BENCH_DIR, "smallthinker")
+    cfg = dict(TOY, compute_dtype=compute)
+    w = jax.jit(lambda s: fam.make_weights(cfg, s))(reference.seed_u32(42))
+    served_both_ways(lambda: make_engine(fam, w, cfg),
+                     [(24, 6), (5, 5)], cfg["vocab_size"], LogitSpy,
+                     monkeypatch)
 
 
 def test_draft_swap_takes_a_float32_checkpoint():
@@ -318,12 +438,13 @@ def test_cast_only_follows_uses_through_bodies_and_moves():
         cast=True, raw=False, both=False, rows=True, unused=False,
         f16=False, scanned=True, branch=True)
     flags = [name != "raw" for name in sorted(tree)]
-    out, cast = narrowed(tree, flags, BF16)
+    out, cast, relaid = served(tree, flags, BF16)
     assert out["raw"] is tree["raw"]
     assert all(out[k].dtype == BF16 for k in out if k != "raw")
     assert cast == sum(tree[k].nbytes for k in tree if k != "raw")
-    kept, cast = narrowed(out, flags, BF16)
-    assert cast == 0 and all(kept[k] is out[k] for k in out)
+    assert relaid == 0                  # no fused kernel of a stack here
+    kept, cast, relaid = served(out, flags, BF16)
+    assert cast == relaid == 0 and kept is out
 
 
 @pytest.mark.parametrize("family", ["llama", "moe"])
@@ -353,4 +474,50 @@ def test_leaves_read_in_float32_are_kept(family):
         ref = generate(dm, {"params": params}, jnp.asarray(p)[None],
                        max_new_tokens=5)
         np.testing.assert_array_equal(r.output_ids, np.asarray(ref)[0])
+    engine.close()
+
+
+def test_tensor_sharded_planes_split_what_the_kernels_split():
+    """A Megatron tensor-sharded tree on a dp x tp mesh: each plane leaf
+    keeps its kernel's sharding with the fused axis moved, so the tensor
+    axis still splits whole columns of gate and up, and of k and v, and
+    no plane is gathered onto a device."""
+    import optax
+
+    from pytorchdistributed_tpu.runtime.mesh import Axis, create_mesh
+    from pytorchdistributed_tpu.training import (
+        Trainer,
+        token_cross_entropy_loss,
+    )
+
+    cfg = llama_config("test", max_seq_len=64)
+    model = Llama(cfg)
+    tr = Trainer(model, optax.sgd(1e-2), token_cross_entropy_loss,
+                 mesh=create_mesh(data=2, tensor=4), strategy="tp")
+    tokens = np.zeros((8, 8), np.int32)
+    tr.init({"tokens": tokens, "targets": tokens})
+    tree = tr.state.params["params"]
+    engine = ServingEngine(model, tree, num_slots=2, block_size=8,
+                           prefill_chunk=8, prefix_cache=False,
+                           mesh=tr.mesh)
+    held = _by_path(engine._weights)
+
+    def split(leaf):
+        """The mesh axes of more than one device that split each axis
+        of `leaf`."""
+        spec = tuple(leaf.sharding.spec)
+        spec += (None,) * (leaf.ndim - len(spec))
+        return tuple(tuple(a for a in ((e,) if isinstance(e, str) else e or ())
+                           if tr.mesh.shape[a] > 1) for e in spec)
+
+    relaid = 0
+    for path, kernel in _by_path(tree).items():
+        if not path.endswith(("['wi_kernel']", "['kv_kernel']")):
+            continue
+        want = split(kernel)
+        assert want[-1] == (Axis.TENSOR,), (path, want)
+        planes = held[path.replace("_kernel", "_planes")]
+        assert split(planes) == want[:1] + want[2:3] + want[1:2] + want[3:]
+        relaid += 1
+    assert relaid == 2
     engine.close()

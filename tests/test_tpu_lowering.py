@@ -415,10 +415,11 @@ POOL_SLOTS, POOL_CHUNK = 32, 128
 
 
 def serve_program_for_v5e(program: str, model, slots: int, blocks: int,
-                          chunk: int = POOL_CHUNK):
+                          chunk: int = POOL_CHUNK, planes: bool = False):
     """The engine's jitted tick or prefill chunk (of `chunk` tokens) of
     `model` over pools of `blocks` blocks, compiled for one abstract v5e
-    chip."""
+    chip; with `planes`, on the tree as the engine serves it (a scanned
+    stack's fused kernels as planes: serving/weights.py:served)."""
     from jax.sharding import SingleDeviceSharding
 
     from pytorchdistributed_tpu.serving.engine import (
@@ -426,6 +427,7 @@ def serve_program_for_v5e(program: str, model, slots: int, blocks: int,
         paged_prefill_chunk,
         paged_slot_models,
     )
+    from pytorchdistributed_tpu.serving.weights import served
 
     one = SingleDeviceSharding(v5e_devices(1)[0])
     tick_model, chunk_model = paged_slot_models(
@@ -438,6 +440,9 @@ def serve_program_for_v5e(program: str, model, slots: int, blocks: int,
         lambda leaf: arg(leaf.shape, leaf.dtype),
         jax.eval_shape(lambda: tick_model.init(
             jax.random.key(0), jnp.zeros((slots, 1), jnp.int32))))
+    if planes:
+        state["params"] = jax.eval_shape(
+            lambda w: served(w, None, model.cfg.dtype)[0], state["params"])
     key = jax.eval_shape(lambda: jax.random.key_data(jax.random.key(0)))
     f32 = jnp.float32
     pages = {k.table: k.pages(tick_model.cfg.kv_pages)
@@ -544,6 +549,54 @@ def test_period_programs_read_both_pools_in_place(compiled_kernels):
         assert mem.alias_size_in_bytes >= pools
         assert mem.temp_size_in_bytes < 0.5e9, (program,
                                                 mem.temp_size_in_bytes)
+
+
+def fused_slices(compiled, shapes) -> str:
+    """The instructions of a compiled program, outside the bodies of its
+    fusions, that make a buffer of one layer of a stacked fused kernel: a
+    shape that holds the numbers of a ``[1, embed, c, width]`` of
+    `shapes` in any order (none, it is hoped). A product that reads the
+    layer where it lies has the stack's slice inside its own fusion."""
+    want = {tuple(sorted((1,) + shape)) for shape in shapes}
+    found, fused = [], False
+    for line in compiled.as_text().splitlines():
+        if line and not line[0].isspace():
+            fused = "fused_computation" in line.split("(")[0]
+            continue
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]*)\]", line)
+        if m and not fused and tuple(sorted(
+                int(d) for d in m.group(1).split(",") if d)) in want:
+            found.append(line.strip()[:160])
+    return "\n".join(found)
+
+
+@pytest.mark.parametrize("program,planes", [("tick", True), ("chunk", True),
+                                            ("tick", False)])
+def test_served_planes_are_read_in_place(compiled_kernels, program, planes):
+    """EvaByte's tick and chunk at the cell's widths (two layers),
+    compiled for one v5e chip on the tree as the engine serves it: the
+    scanned stack's fused gate/up and q/k/v as planes, `[layers, 2|3,
+    embed, width]`, whose layer the products read where it lies, with no
+    copy, transpose or slice of it first. In the checkpoint's layout
+    (`[layers, embed, 2|3, width]`, the fused axis second-minor) the same
+    tick copies each layer's 90 MB of gate/up out of the stack before its
+    product: the test sees what it is meant to see."""
+    import json
+
+    from benchmark import manifest
+
+    fam = manifest.load_family(manifest.BENCH_DIR, "evabyte")
+    with open(os.path.join(REPO, "benchmark/configs/evabyte.json")) as f:
+        cfg = dict(json.load(f), num_hidden_layers=2)
+    blocks = 2049
+    model = fam.program_model(cfg, {})
+    model = model.clone(cfg=dataclasses.replace(
+        model.cfg, window_blocks=blocks))
+    compiled = serve_program_for_v5e(program, model, 16, blocks,
+                                     planes=planes)
+    e, f = model.cfg.embed_dim, model.cfg.ffn_dim
+    found = fused_slices(compiled, [(e, 2, f), (e, 3, e)])
+    assert (found == "") == planes, found
 
 
 def test_eva_tick_reads_both_pools_in_place(compiled_kernels):
