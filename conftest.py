@@ -73,6 +73,15 @@ _QUICK = (
     # (interpreted) against `lax.scan`
     "test_jamba_serving.py",
     "test_ssm_scan.py",
+    # the parallel block (one LayerNorm without a bias for attention and
+    # the experts), sigmoid-routed experts of which a share is held beside
+    # averaged shared experts, GPT-J RoPE window layers and NoPE full
+    # layers over two K/V pools and a tied head, through the paged engine
+    # against the benchmark's plain reference, its planted faults, the
+    # eight expert-parallel shares summed, the chunks' device counters,
+    # the query block sized by the heads and a chunk of many heads through
+    # the flash kernel (interpreted)
+    "test_cohere2_serving.py",
     # the experts' grouped product: the kernel that reads a bank where it
     # lies in a stack (interpreted) against `lax.ragged_dot` on the
     # slice, and the scanned stack handing it its banks whole (ISSUE 37;

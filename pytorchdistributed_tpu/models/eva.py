@@ -163,13 +163,14 @@ def paged_attention(cfg, q, k, v, phi, mu, paging, pool, layer):
             else _gathered_read)
     out, n_window, n_summary = read(cfg, q, pool, layer, wtable, stable, pos)
 
-    # -- the counts; a free slot ticks along at length 0: computed, never
-    # counted
-    live = (idx > 0)[:, None]
+    # -- the counts, of a stream's own positions alone
+    live = paging["live"]
+    # a summary is a stream's own where its chunk's last position is
+    written = live if s == 1 else live[:, chunk - 1::chunk]
     counts = jnp.stack([
         jnp.where(live, n_window, 0).sum(),
         jnp.where(live, n_summary, 0).sum(),
-        jnp.where(live & (sblk > 0), 1, 0).sum()]).astype(jnp.float32)
+        jnp.where(written & (sblk > 0), 1, 0).sum()]).astype(jnp.float32)
     pool[COUNTS] = pool[COUNTS] + counts
     return out.astype(dt), pool
 

@@ -503,7 +503,7 @@ class LatentLayer(nn.Module):
             y, counters = DroplessMoE(cfg, name="ffn")(xn, live)
         else:
             y, counters = SwiGLU(cfg, name="ffn")(xn), {}
-        # a free slot ticks along at length 0: attended, never counted
+        # a stream's own positions alone (`LatentLM`'s `live`)
         counters = dict(counters,
                         sparse_selected=jnp.where(live, sparse[0], 0).sum(),
                         sparse_live=jnp.where(live, sparse[1], 0).sum())
@@ -534,8 +534,14 @@ class LatentLM(nn.Module):
             **{table: self.variable("cache", table, jnp.zeros,
                                     (slots, pages), jnp.int32).value
                for _, table, *_ in cfg.cache_kinds}}
-        # a free slot ticks along at length 0: computed, never counted
-        live = jnp.broadcast_to((paging["index"] > 0)[:, None], (b, s))
+        # where each slot's tokens of the call end (exclusive): what is
+        # counted is a stream's own positions, a live slot's in a tick (a
+        # free one ticks along at length 0, computed and never counted)
+        # and those before the prompt's end in a chunk
+        stop = self.variable("cache", "stop", jnp.zeros, (slots,),
+                             jnp.int32).value
+        live = (paging["index"][:, None] + jnp.arange(s)
+                < stop[:, None])
         x = Embedder(cfg, name="embed")(tokens)
         total = dict.fromkeys(COUNTERS, jnp.zeros((), jnp.float32))
         for i, kind in enumerate(cfg.layer_kinds):

@@ -37,9 +37,15 @@ the call in `CONTEXT_STEPS` steps of the longest sequence (a branch a
 step, chosen on the device, so a chunk early in a prompt does not score
 against the whole table), a window layer's cover the blocks from its
 first query's window to its last query. A chunk walks its queries in
-blocks of `QUERY_BLOCK`, so that no ``[heads, chunk, context]`` tensor is
+blocks of `query_block` rows (`QUERY_BLOCK`, fewer where the heads are
+many: `QUERY_HEAD_ROWS`), so that no ``[heads, chunk, context]`` tensor is
 built (float32 scores of 512 queries over 16,384 keys and 28 heads are
-0.94 GB a copy).
+0.94 GB a copy). Where the heads cut that block below `QUERY_BLOCK` rows
+(Command A+'s 128), a chunk under ``"pallas"`` gathers the same rows for
+all its queries at once and attends them through the flash kernel
+`ops/pallas_attention.chunk_attention`, which keeps the scores in VMEM:
+XLA's scores of 128 heads, in blocks of 32 queries, cost a chunk time in
+proportion to its context, several passes of float32 over every row.
 """
 
 from __future__ import annotations
@@ -58,8 +64,19 @@ COUNTERS = ("attn_full_rows", "attn_window_rows")
 #: call in this many steps of the longest sequence
 CONTEXT_STEPS = 16
 
-#: rows of one block of queries inside a prefill chunk
+#: rows of one block of queries inside a prefill chunk, and the most
+#: query rows times query heads one block may hold: 128 rows up to 32
+#: heads, 32 rows at Command A+'s 128 (float32 scores of 8 kv heads x 16
+#: x 32 queries over 33,792 keys are 0.55 GB a copy; 128 queries would
+#: be 2.2 GB)
 QUERY_BLOCK = 128
+QUERY_HEAD_ROWS = 4096
+
+
+def query_block(heads: int, s: int) -> int:
+    """Query rows a block of a call of `s` positions walks at `heads`
+    query heads."""
+    return max(1, min(QUERY_BLOCK, QUERY_HEAD_ROWS // heads, s))
 
 
 def paged_attention(cfg, q, k, v, paging, pool, layer, window: int):
@@ -102,31 +119,43 @@ def paged_attention(cfg, q, k, v, paging, pool, layer, window: int):
         if window:
             attended = jnp.minimum(attended, window)
     else:
-        out, attended = _gathered_read(cfg, q, pool[names[0]],
-                                       pool[names[1]], layer, table, pos,
-                                       window)
+        out, attended = _gathered_read(
+            cfg, q, pool[names[0]], pool[names[1]], layer, table, pos,
+            window, kernel=(cfg.paged_attn == "pallas"
+                            and query_block(h, s) < min(QUERY_BLOCK, s)))
 
-    # a free slot ticks along at length 0: computed, never counted
-    n = jnp.where((idx > 0)[:, None], attended, 0).sum().astype(
-        jnp.float32)
+    # a stream's own positions alone (`paging["live"]`)
+    n = jnp.where(paging["live"], attended, 0).sum().astype(jnp.float32)
     at = cfg.counter_names.index(COUNTERS[bool(window)])
     pool[COUNTS] = pool[COUNTS].at[at].add(n)
     return out.astype(dt), pool
 
 
-def _gathered_read(cfg, q, k_pool, v_pool, layer, table, pos, window):
+def _gathered_read(cfg, q, k_pool, v_pool, layer, table, pos, window,
+                   kernel: bool = False):
     """The read by XLA's gathers, of a tick or a chunk: `q` [b, s, heads,
     d] at `pos` [b, s] -> (out [b, s, heads, d] float32, the rows each
-    query attended [b, s], summed from the mask)."""
+    query attended [b, s], summed from the mask). With `kernel`, the rows
+    gathered are attended by `ops/pallas_attention.chunk_attention`, all
+    the call's queries in one block."""
     b, s, h, d = q.shape
     bs, dt = cfg.kv_block_size, cfg.dtype
     hk = cfg.kv_heads
     pages = table.shape[1]
     scale = d ** -0.5
-    qb = min(QUERY_BLOCK, s)
+    qb = s if kernel else query_block(h, s)
+    whole = 1
+    if kernel:
+        from pytorchdistributed_tpu.ops.pallas_attention import (
+            CHUNK_BLOCK_K,
+            chunk_attention,
+        )
+
+        # the kernel's blocks of keys are whole blocks of the gather
+        whole = max(1, CHUNK_BLOCK_K // bs)
     if s % qb:
         raise ValueError(f"a chunk of {s} tokens is not a multiple of "
-                         f"QUERY_BLOCK {qb}")
+                         f"the query block {qb}")
 
     def rows_from(first, count):
         """`count` consecutive blocks of each stream from logical block
@@ -149,6 +178,11 @@ def _gathered_read(cfg, q, k_pool, v_pool, layer, table, pos, window):
         live = (kpos[:, None, :] >= 0) & (dist >= 0)
         if window:
             live &= dist < window
+        if kernel:
+            return (chunk_attention(qq, kk.reshape(b, -1, hk * d),
+                                    vv.reshape(b, -1, hk * d), qpos, kpos,
+                                    window=window, scale=scale),
+                    live.sum(-1, dtype=jnp.int32))
         scores = jnp.einsum("bnkgd,bmkd->bkgnm",
                             qq.reshape(b, n, hk, h // hk, d), kk,
                             preferred_element_type=jnp.float32) * scale
@@ -172,6 +206,7 @@ def _gathered_read(cfg, q, k_pool, v_pool, layer, table, pos, window):
     if window:
         # the blocks from the first query's window to the last query
         nb = -(-(qb + window - 1) // bs) + 1
+        nb = -(-nb // whole) * whole
 
         def one(qq, qpos):
             return attend(qq, qpos,
@@ -182,7 +217,8 @@ def _gathered_read(cfg, q, k_pool, v_pool, layer, table, pos, window):
     def upto(nblocks: int):
         """Where no stream of the call is longer than `nblocks` blocks."""
         def read():
-            rows = rows_from(jnp.zeros((b,), jnp.int32), nblocks)
+            rows = rows_from(jnp.zeros((b,), jnp.int32),
+                             -(-nblocks // whole) * whole)
             return blocks_of_queries(
                 lambda qq, qpos: attend(qq, qpos, *rows))
         return read

@@ -97,6 +97,11 @@ class TransformerConfig:
     # BERT: LN(x + Attn(x))) — released BERT checkpoints are post-LN, so
     # bert_config flips this for architectural fidelity.
     norm_position: str = "pre"          # pre | post
+    # "sequential" (x + Attn(LN1(x)), then + FFN(LN2(.))) or "parallel"
+    # (GPT-J's and Cohere's use_parallel_block: one norm, attention and
+    # the feed-forward side by side, x + Attn(LN1(x)) + FFN(LN1(x)); no
+    # ln2, and a router reads the one normed tensor)
+    block_form: str = "sequential"      # sequential | parallel
     # GELU flavor: tanh approximation (GPT-2's "gelu_new", the flax
     # default) vs exact erf (BERT's "gelu").
     gelu_approximate: bool = True
@@ -304,10 +309,13 @@ class TransformerConfig:
     moe_scoring: str = "sigmoid"        # sigmoid | softmax
     moe_activation: str = "silu"        # silu | relu
     router_input: str = "ffn"           # ffn | attn
+    # `shared_experts` SwiGLUs (ReGLUs) of width `moe_dim` every token
+    # passes through beside the routed ones, summed (a loader folds a
+    # published average into their down projections)
+    shared_experts: int = 0
     # what `DroplessMoE` reads besides and this stack has one value of
-    # (no annotation: constants of the class, not fields): no shared
-    # expert, chosen scores normalised, no further scale
-    shared_experts = 0
+    # (no annotation: constants of the class, not fields): chosen scores
+    # normalised, no further scale
     norm_topk_prob = True
     routed_scale = 1.0
     # `RMS(x) * (1 + g)`: the gain is stored about a unit offset
@@ -486,6 +494,18 @@ class TransformerConfig:
                     f"kv_sink_tokens {self.kv_sink_tokens} must be "
                     f"multiples of kv_block_size {self.kv_block_size} "
                     f"(retirement is whole-block)")
+        if self.block_form not in ("sequential", "parallel"):
+            raise ValueError(f"unknown block_form {self.block_form!r}; "
+                             f"one of ('sequential', 'parallel')")
+        if self.block_form == "parallel" and self.norm_position != "pre":
+            raise ValueError("block_form='parallel' is a pre-norm block: "
+                             "one norm both sublayers read")
+        if self.fused_norms and self.norm == "layernorm" and not self.use_bias:
+            raise ValueError("the fused LayerNorm (fused_norms) is built "
+                             "with a bias; use_bias=False asks for none")
+        if self.shared_experts < 0:
+            raise ValueError(f"shared_experts must be >= 0, got "
+                             f"{self.shared_experts}")
         if self.eva_window:
             self._check_eva()
         if self.period:
@@ -1388,6 +1408,8 @@ def _layer_norm(cfg, name):
         epsilon=cfg.norm_eps,
         dtype=jnp.float32,  # normalize in fp32 regardless of compute dtype
         param_dtype=cfg.param_dtype,
+        # a gain alone where the dialect has no bias anywhere (Cohere)
+        use_bias=getattr(cfg, "use_bias", True),
         scale_init=scale_init,
         bias_init=bias_init,
         name=name,
@@ -1421,7 +1443,8 @@ def apply_rope(x, cos, sin):
 
 
 class TransformerBlock(nn.Module):
-    """Pre-LN block: x + Attn(LN(x)); x + MLP(LN(x))."""
+    """Pre-LN block: x + Attn(LN(x)); x + MLP(LN(x)). Under
+    ``block_form="parallel"`` one norm: x + Attn(LN(x)) + MLP(LN(x))."""
 
     cfg: TransformerConfig
     deterministic: bool = True
@@ -1485,12 +1508,9 @@ class TransformerBlock(nn.Module):
             if cfg.router_experts:
                 from pytorchdistributed_tpu.models.moe import DroplessMoE
 
-                # a free slot ticks along at length 0: computed, never
-                # counted
-                live = None if paging is None else jnp.broadcast_to(
-                    (paging["index"] > 0)[:, None], h.shape[:2])
                 out, counted = DroplessMoE(cfg, name="moe")(
-                    h, live, route, banks and (banks[0]["moe"], banks[1]))
+                    h, None if paging is None else paging["live"], route,
+                    banks and (banks[0]["moe"], banks[1]))
                 count(counted)
                 return out
             return MlpBlock(cfg, self.deterministic, name="mlp")(h)
@@ -1524,6 +1544,11 @@ class TransformerBlock(nn.Module):
             # original-BERT residual order: LN AFTER each sublayer's add
             x = norm("ln1", x + attn(x))
             x = norm("ln2", x + ffn(x))
+        elif cfg.block_form == "parallel":
+            # one norm, attention and the feed-forward side by side
+            # (Cohere's use_parallel_block): both read `u`, a router too
+            u = norm("ln1", x)
+            x = x + attn(u) + ffn(u)
         elif cfg.router_experts and cfg.router_input == "attn":
             # the router placed before attention: it reads what attention
             # reads, the experts the normed stream after it
@@ -1691,9 +1716,10 @@ class TransformerStack(nn.Module):
                        "cache", kind.table, jnp.zeros,
                        (slots, kind.pages(cfg.kv_pages)), jnp.int32)
                    for kind in cfg.cache_kinds if kind.table}}
-            if cfg.state_leaves:
+            if cfg.state_leaves or cfg.counter_names:
                 # where each slot's tokens of this call end (exclusive):
-                # a recurrent state takes no step past it
+                # a recurrent state takes no step past it, and what the
+                # layers count on the device counts no position past it
                 state["stop"] = self.variable(
                     "cache", "stop", lambda: jnp.zeros((slots,), jnp.int32))
             if cfg.per_slot_kv_limits and cfg.kv_window_tokens:
@@ -1706,6 +1732,14 @@ class TransformerStack(nn.Module):
                     lambda: jnp.full((slots,), cfg.kv_window_tokens,
                                      jnp.int32))
             paging = {name: var.value for name, var in state.items()}
+            if "stop" in paging:
+                # the call's positions that are a stream's own, which the
+                # layers' counters count: a live slot's in a tick (a free
+                # one ticks along at length 0, computed and never
+                # counted), those before the prompt's end in a chunk
+                paging["live"] = (paging["index"][:, None]
+                                  + jnp.arange(x.shape[1])
+                                  < paging["stop"][:, None])
         if cfg.scan_layers:
             scan = functools.partial(
                 nn.scan,

@@ -516,6 +516,143 @@ def flash_attention_sharded(q, k, v, *, causal: bool = False,
 
 
 # ---------------------------------------------------------------------------
+# A prefill chunk's attention over rows at stated positions: the flash
+# recurrence of `_fwd_kernel`, its mask from positions instead of indices.
+# A serving chunk's queries stand at `pos .. pos + s - 1` of one stream and
+# its keys are the stream's rows gathered from the pool in position order
+# (`models/periodic.py:_gathered_read`); XLA's read of them builds float32
+# scores of every query head over every row, several passes each: at 128
+# heads and 33,792 rows XLA's cost analysis of the v5e compile counts ~50
+# GB of them a chunk, against ~5 GB at 2,048 rows. Here a kv head's group
+# of query heads rides as rows of one program (``group x s`` rows over the
+# head's keys), so a block of keys is fetched once for all of them, and no
+# score leaves VMEM.
+
+
+def _chunk_kernel(q_ref, k_ref, v_ref, qpos_ref, kpos_ref, o_ref,
+                  acc_s, m_s, l_s, *, scale: float, window: int,
+                  num_k_blocks: int):
+    """One (kv head, block of query rows) over one block of keys: a key
+    at ``kp`` is live for a query at ``qp`` iff ``kp >= 0`` and ``0 <= qp
+    - kp`` (and ``< window`` where there is one)."""
+    ki = pl.program_id(2)
+
+    @pl.when(ki == 0)
+    def _init():
+        acc_s[...] = jnp.zeros_like(acc_s)
+        m_s[...] = jnp.full_like(m_s, _NEG_INF)
+        l_s[...] = jnp.zeros_like(l_s)
+
+    logits = jax.lax.dot_general(
+        q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale      # [bq, bk]
+    kp = kpos_ref[0]                                      # [1, bk]
+    dist = qpos_ref[0] - kp                               # [bq, bk]
+    live = (kp >= 0) & (dist >= 0)
+    if window:
+        live = live & (dist < window)
+    logits = jnp.where(live, logits, _NEG_INF)
+    m_prev = m_s[...]
+    m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
+    corr = jnp.exp(m_prev - m_new)
+    p = jnp.where(live, jnp.exp(logits - m_new), 0.0)
+    l_s[...] = l_s[...] * corr + jnp.sum(p, -1, keepdims=True)
+    m_s[...] = m_new
+    v = v_ref[0]
+    acc_s[...] = acc_s[...] * corr + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+    @pl.when(ki == num_k_blocks - 1)
+    def _finalize():
+        o_ref[0] = acc_s[...] / jnp.maximum(l_s[...], 1e-30)
+
+
+#: keys a block of `chunk_attention` walks (rows of a gather made whole
+#: blocks of it need no padding)
+CHUNK_BLOCK_K = 512
+
+
+def chunk_attention(q, k, v, qpos, kpos, *, window: int = 0,
+                    scale: float | None = None, block_q: int = 512,
+                    block_k: int = CHUNK_BLOCK_K,
+                    interpret: bool | None = None):
+    """Queries `q` [b, s, heads, d] at `qpos` [b, s] over the rows `k`,
+    `v` [b, m, kv_heads * d] (lane-dense, as a pool holds them) at `kpos`
+    [b, m] (a negative position is no row): a key is attended iff its
+    position is at most the query's (and within `window` of it where
+    `window` is not 0). Returns [b, s, heads, d] float32; a query with no
+    live key reads zeros.
+
+    The same softmax as XLA's masked read in `models/periodic.py`, in the
+    flash recurrence: grid (batch x kv heads, blocks of `block_q` of the
+    ``group x s`` query rows of a kv head, blocks of `block_k` keys), the
+    running maximum, sum and output in VMEM across the key blocks, the
+    probabilities cast to the values' type for their product as there.
+    A block of keys is a kv head's `d` lanes of `block_k` rows where
+    they lie, so the rows are neither transposed nor copied; they are
+    padded to whole blocks (at position -1) only where they are not
+    whole. Every block of keys is computed: a chunk's rows are chosen to
+    cover its queries, so few are dead."""
+    b, s, h, d = q.shape
+    m, width = k.shape[1], k.shape[2]
+    hk = width // d
+    if width % d or h % hk:
+        raise ValueError(f"rows of {width} lanes do not hold whole heads "
+                         f"of {d} that divide the {h} q heads")
+    group = h // hk
+    rows = group * s
+    block_q = min(block_q, rows)
+    if rows % block_q:
+        raise ValueError(f"{rows} query rows a kv head are not whole "
+                         f"blocks of {block_q}")
+    scale = (d**-0.5) if scale is None else scale
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    kpos = kpos.astype(jnp.int32)
+    pad = -m % block_k
+    if pad:
+        k, v = (jnp.pad(t, ((0, 0), (0, pad), (0, 0))) for t in (k, v))
+        kpos = jnp.pad(kpos, ((0, 0), (0, pad)), constant_values=-1)
+    nk = (m + pad) // block_k
+    # kv head j's rows: its group's heads one after another, each the s
+    # queries in order, so row r stands at qpos[r mod s]
+    qf = q.reshape(b, s, hk, group, d).transpose(0, 2, 3, 1, 4).reshape(
+        b * hk, rows, d)
+    qpos = jnp.tile(qpos.astype(jnp.int32), (1, group))[..., None]
+
+    kernel = functools.partial(_chunk_kernel, scale=scale,
+                               window=int(window), num_k_blocks=nk)
+    out = pl.pallas_call(
+        kernel,
+        grid=(b * hk, rows // block_q, nk),
+        in_specs=[
+            pl.BlockSpec((1, block_q, d), lambda i, qi, ki: (i, qi, 0)),
+            pl.BlockSpec((1, block_k, d),
+                         lambda i, qi, ki: (i // hk, ki, i % hk)),
+            pl.BlockSpec((1, block_k, d),
+                         lambda i, qi, ki: (i // hk, ki, i % hk)),
+            # the positions ride as [b, rows, 1] and [b, 1, m]: a block's
+            # unit dim equals the array's, as the row statistics' do above
+            pl.BlockSpec((1, block_q, 1),
+                         lambda i, qi, ki: (i // hk, qi, 0)),
+            pl.BlockSpec((1, 1, block_k),
+                         lambda i, qi, ki: (i // hk, 0, ki)),
+        ],
+        out_specs=pl.BlockSpec((1, block_q, d), lambda i, qi, ki: (i, qi, 0)),
+        out_shape=jax.ShapeDtypeStruct((b * hk, rows, d), jnp.float32),
+        scratch_shapes=[
+            _vmem_scratch((block_q, d)),
+            _vmem_scratch((block_q, 1)),
+            _vmem_scratch((block_q, 1)),
+        ],
+        interpret=interpret,
+    )(qf, k, v, qpos, kpos[:, None, :])
+    return out.reshape(b, hk, group, s, d).transpose(0, 3, 1, 2, 4).reshape(
+        b, s, h, d)
+
+
+# ---------------------------------------------------------------------------
 # Paged decode attention (ISSUE 7; this dataflow ISSUE 33): the Pallas
 # twin of ops/attention.paged_attention. One decode tick's q ([slots,
 # heads, d]) attends each slot's block-table-mapped KV blocks streamed

@@ -329,10 +329,16 @@ def paged_chunk_logits(model, weights, cache, chunk, start, table_row,
     every position of the chunk and the mutated cache. Apart from
     `paged_prefill_chunk` for the same reason as `paged_tick_logits`.
     ``table_row`` is {table leaf: [pages]}: the one request's rows of
-    `_override_paging`'s tables. A model with a recurrent state
-    (`STATE_LEAVES`) also takes where the request's tokens end (``stop``,
-    its true length) and its ``slot``, whose state row the chunk reads
-    (from zeros where ``start`` is 0: the model's own rule)."""
+    `_override_paging`'s tables. ``stop`` is where the request's tokens
+    end (its true length; by default the chunk's end): a recurrent state
+    takes no step past it and the device's counters count no position
+    past it. A model with a recurrent state (`STATE_LEAVES`) also takes
+    its ``slot``, whose state row the chunk reads (from zeros where
+    ``start`` is 0: the model's own rule). The mutated collections are
+    the cache and what the model counted ("counters")."""
+    if stop is None:
+        stop = start + chunk.shape[1]
+
     def shrink(path, leaf):
         # the chunk model is the same module tree at decode_slots=1:
         # pool leaves pass through untouched (no slot dim), counter and
@@ -355,7 +361,7 @@ def paged_chunk_logits(model, weights, cache, chunk, start, table_row,
 
     small = jax.tree_util.tree_map_with_path(shrink, cache)
     return model.apply({"params": weights, "cache": small}, chunk,
-                       mutable=["cache"])
+                       mutable=["cache", "counters"])
 
 
 @functools.partial(
@@ -379,7 +385,10 @@ def paged_prefill_chunk(model, weights, cache, chunk, start, table_row,
     sample is used; ``count`` is its fold_in index (> 0 when a preempted
     request resumes mid-generation). ``slot``: the request's slot, where
     the model keeps a recurrent state a slot (`paged_chunk_logits`); its
-    row is written back in place."""
+    row is written back in place. The third output is what the model
+    counted on the device over the chunk's positions before
+    ``true_len``, as the tick's is (empty for a model that counts
+    nothing)."""
     TRACE_COUNTS["paged_prefill_chunk"] += 1
     logits, mut = paged_chunk_logits(model, weights, cache, chunk, start,
                                      table_row, true_len, slot)
@@ -401,7 +410,7 @@ def paged_prefill_chunk(model, weights, cache, chunk, start, table_row,
     first = sample_slots(last[:, 0].astype(jnp.float32), subs,
                          temperature[None], top_k[None], top_p[None],
                          candidates=candidates)[0]
-    return new_cache, first
+    return new_cache, first, dict(mut.get("counters", {}))
 
 
 @functools.partial(
@@ -1983,15 +1992,23 @@ class ServingEngine:
             if pf["pos"] < pf["true_len"]:
                 pos = pf["pos"]
                 final_t = pos + self.chunk >= pf["true_len"]
-                self._cache, first = self._chunk_call(
+                self._cache, first, counted = self._chunk_call(
                     self._chunk_model, self._weights, self._cache, pf, pos)
+                if self._counter_names:
+                    # the chunk's device counters, on their way to the
+                    # host while the device works on; summed at the
+                    # prompt's last chunk (`_fold_chunk_counters`)
+                    counted = jax.tree.leaves(counted)[0]
+                    counted.copy_to_host_async()
+                    self._chunk_counted.append(counted)
                 if final_t:
                     # sync: the TTFT timestamp is honest
                     with span("serve/prefill_sync", request=req.id):
                         pf["first"] = int(first)
+                    self._fold_chunk_counters()
                 pf["pos"] = pos + self.chunk
             if self.spec_k and pf["dpos"] < pf["true_len"]:
-                self._draft_cache, _ = self._chunk_call(
+                self._draft_cache, *_ = self._chunk_call(
                     self._draft_chunk_model, self._draft_weights,
                     self._draft_cache, pf, pf["dpos"])
                 pf["dpos"] += self.chunk
@@ -3434,14 +3451,27 @@ class ServingEngine:
                            session_detaches=0, session_attaches=0,
                            session_seed_tokens=0, session_demotes=0,
                            session_dropped=0)
-        # what the model counts on the device a tick (summed here), and
-        # each windowed pool's share of the host's bookkeeping
+        # what the model counts on the device a tick and a chunk (summed
+        # here; a chunk's still on its way are dropped with the stats),
+        # and each windowed pool's share of the host's bookkeeping
+        self._chunk_counted = []
         if self._counter_names:
             self._stats["device_counters"] = np.zeros(
+                len(self._counter_names))
+            self._stats["chunk_device_counters"] = np.zeros(
                 len(self._counter_names))
         for pool in self._pools[1:]:
             self._stats[f"{pool.kind}_blocks_retired"] = 0
             self._stats[f"{pool.kind}_block_used_sum"] = 0.0
+
+    def _fold_chunk_counters(self) -> None:
+        """The device counters of the chunks dispatched so far, into the
+        stats (each fetched as it was dispatched: no wait here but for
+        a chunk still running)."""
+        if self._chunk_counted:
+            self._stats["chunk_device_counters"] += np.sum(
+                jax.device_get(self._chunk_counted), axis=0)
+            self._chunk_counted = []
 
     @property
     def queue_depth(self) -> int:
@@ -3565,10 +3595,14 @@ class ServingEngine:
             if self._radix is not None:
                 out["prefix_cache"] = self._radix.stats()
         if self._counter_names:
-            # the tick's device-side counters, summed over the window's
-            # ticks (models/latent.py: COUNTERS)
+            # the device-side counters (models/latent.py: COUNTERS),
+            # summed over the window's ticks, and under `chunk_` names
+            # over its prefill chunks
+            self._fold_chunk_counters()
             out.update(zip(self._counter_names,
                            (float(v) for v in st["device_counters"])))
+            out.update(zip((f"chunk_{n}" for n in self._counter_names),
+                           (float(v) for v in st["chunk_device_counters"])))
         if len(self._pools) > 1:
             # each pool by its kind; the first's share over the ticks is
             # `block_utilization`, above, and under its kind's name here

@@ -38,6 +38,7 @@ import pytest
 
 from pytorchdistributed_tpu.models import GPT2, gpt2_config
 from pytorchdistributed_tpu.ops.pallas_attention import (
+    chunk_attention,
     flash_attention,
     paged_flash_attention,
 )
@@ -124,6 +125,8 @@ EVA_POOL = dict(heads=32, head_dim=128, slots=16, layers=8, blocks=2049,
                 merged=True)
 ST_POOL = dict(heads=28, kv_heads=4, head_dim=128, slots=32,
                pages=16384 // BLOCK)
+CA_POOL = dict(heads=128, kv_heads=8, head_dim=128, slots=32,
+               pages=33792 // BLOCK)
 PAGED_CASES = {
     "bf16": dict(), "int8": dict(int8=True), "gqa": dict(kv_heads=HEADS // 3),
     # the serve cells' own call: gpt2-medium's layer-stacked pool (24 x
@@ -142,6 +145,12 @@ PAGED_CASES = {
     # sliding window of 4,096
     "grouped_full": dict(ST_POOL, layers=2, blocks=32769),
     "grouped_window": dict(ST_POOL, layers=6, blocks=8546, window=4096),
+    # Command A+'s two calls in `cmdaplus-rag-steady`: 128 query heads
+    # over 8 key heads of 128 (rows of 1,024 lanes, a group of 16 queries
+    # a key head), 32 slots of 2,112 table entries; one full layer's pool
+    # and three window layers' under a sliding window of 4,096
+    "wide_group_full": dict(CA_POOL, layers=1, blocks=32769),
+    "wide_group_window": dict(CA_POOL, layers=3, blocks=8546, window=4096),
 }
 
 
@@ -168,6 +177,47 @@ def test_paged_decode_kernel_compiles_for_v5e(case):
             for a in args]
     assert MARKER in jax.jit(functools.partial(paged, window=window)).lower(
         *args).compile().as_text()
+
+
+# Command A+'s chunk in `cmdaplus-rag-steady`: 512 queries of 128 heads
+# over 8 key heads of 128, read from rows gathered in whole blocks of 512:
+# its full layer's at the longest context (33,792 positions) and a window
+# layer's (4,096 + 511 positions and a block, made whole)
+CHUNK_CASES = {"full": (33792, 0), "window": (5120, 4096)}
+
+
+def chunk_args(rows):
+    return (sds((1, 512, 128, 128)), sds((1, rows, 1024)),
+            sds((1, rows, 1024)), sds((1, 512), jnp.int32),
+            sds((1, rows), jnp.int32))
+
+
+@pytest.mark.parametrize("case", CHUNK_CASES)
+def test_chunk_kernel_lowers_for_tpu(case):
+    """The chunk's flash kernel: a block of keys is a kv head's 128 lanes
+    of the gathered rows where they lie, the positions ride as blocks
+    beside them."""
+    rows, window = CHUNK_CASES[case]
+    assert lower_for_tpu(functools.partial(
+        chunk_attention, window=window, interpret=False),
+        *chunk_args(rows)).count(MARKER) == 1
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("case", CHUNK_CASES)
+def test_chunk_kernel_compiles_for_v5e(case):
+    from jax.sharding import SingleDeviceSharding
+
+    one = SingleDeviceSharding(v5e_devices(1)[0])
+    rows, window = CHUNK_CASES[case]
+    args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)
+            for a in chunk_args(rows)]
+    compiled = jax.jit(functools.partial(
+        chunk_attention, window=window, interpret=False)).lower(
+            *args).compile()
+    assert MARKER in compiled.as_text()
+    # nothing of the rows is copied: no score, no transposed key
+    assert compiled.memory_analysis().temp_size_in_bytes < 64e6
 
 
 # ---------------------------------------------------------------------------
